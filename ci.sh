@@ -15,9 +15,12 @@ cd "$(dirname "$0")"
 
 export HOROVOD_PLATFORM=cpu
 export JAX_PLATFORMS=cpu
-# Persistent XLA compile cache (see tests/conftest.py): dryrun/entry
-# stages and every spawned rank share compiled programs with the suite.
-export JAX_COMPILATION_CACHE_DIR=${JAX_COMPILATION_CACHE_DIR:-/tmp/horovod_tpu_jax_cache}
+# Persistent XLA compile cache: dryrun/entry stages and every spawned
+# rank share compiled programs with the suite.  One resolution for the
+# whole repo (common/platform.ensure_compile_cache): the variable when
+# set, else <checkout>/.jax_cache.
+JAX_COMPILATION_CACHE_DIR=$(python -c "from horovod_tpu.common.platform import ensure_compile_cache; print(ensure_compile_cache())")
+export JAX_COMPILATION_CACHE_DIR
 export JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS=${JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS:-0.5}
 
 fail=0
@@ -291,7 +294,7 @@ print('trace schema ok:', len(trace['traceEvents']), 'events')
     # Noise-aware perf-regression gate: a real CPU bench run gated
     # against the checked-in baseline must pass (exit 0 on a rerun of
     # the baseline)...
-    stage perf-gate env BENCH_PROBE_ATTEMPTS=1 BENCH_MODELS=resnet50 \
+    stage perf-gate env BENCH_MODELS=resnet50 \
         BENCH_SKIP_SIDE=1 \
         python bench.py --compare tests/data/bench_baseline_cpu.json
     # ...and an injected regression on the very same result must trip
@@ -385,8 +388,7 @@ print('goodput conserves wall-clock: %.1fs attributed of %.1fs '
 import json, subprocess, sys, os
 env = dict(os.environ)
 env.update({'HOROVOD_HEALTH': '1', 'HOROVOD_FAULT_SPEC': 'nan:grads*',
-            'BENCH_PROBE_ATTEMPTS': '1', 'BENCH_MODELS': 'resnet50',
-            'BENCH_SKIP_SIDE': '1', 'BENCH_NO_REPROBE': '1'})
+            'BENCH_MODELS': 'resnet50', 'BENCH_SKIP_SIDE': '1'})
 r = subprocess.run([sys.executable, 'bench.py', '--health-gate'],
                    capture_output=True, text=True, env=env)
 assert r.returncode == 4, (r.returncode, r.stderr[-800:])

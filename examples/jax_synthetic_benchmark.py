@@ -28,28 +28,24 @@ import time
 import numpy as np
 
 
-def main() -> None:
-    p = argparse.ArgumentParser()
-    p.add_argument("--model", default="ResNet50")
-    p.add_argument("--batch-size", type=int, default=64,
-                   help="per-device batch")
-    p.add_argument("--num-iters", type=int, default=10)
-    p.add_argument("--num-batches-per-iter", type=int, default=10)
-    p.add_argument("--num-warmup-batches", type=int, default=3)
-    p.add_argument("--fp16-allreduce", action="store_true")
-    args = p.parse_args()
+def build_trainer(hvd, model_name: str = "ResNet50", batch_size: int = 64,
+                  fp16_allreduce: bool = False,
+                  image_side: int | None = None):
+    """The synthetic data-parallel trainer over ``hvd.world_mesh()``.
 
+    Returns ``(step, state, batch)``: ``step(*state, *batch, step_idx)``
+    is the jitted train step returning ``(*state, loss[1])``, ``state``
+    is ``(params, batch_stats, opt_state)`` and ``batch`` the fixed
+    synthetic ``(images, labels)`` sharded over the ``hvd`` axis.
+    ``chip_smoke.py`` drives the same function."""
     import jax
     import jax.numpy as jnp
     import optax
     from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    import horovod_tpu as hvd
     from horovod_tpu.models import inception, mnist, resnet, vgg
 
-    hvd.init()
-    n = hvd.size()
     registry = {
         "ResNet18": resnet.ResNet18, "ResNet34": resnet.ResNet34,
         "ResNet50": resnet.ResNet50, "ResNet101": resnet.ResNet101,
@@ -60,12 +56,14 @@ def main() -> None:
         # CPU-smoke stand-in, like the reference tf2 bench's SmallCNN
         "SmallCNN": mnist.SmallCNN,
     }
-    if args.model not in registry:
-        raise SystemExit(f"unknown model {args.model}; choose from "
+    if model_name not in registry:
+        raise SystemExit(f"unknown model {model_name}; choose from "
                          f"{sorted(registry)}")
-    model_cls = registry[args.model]
+    n = hvd.size()
+    model_cls = registry[model_name]
     model = model_cls(num_classes=1000, dtype=jnp.bfloat16)
-    side = {"InceptionV3": 299, "SmallCNN": 96}.get(args.model, 224)
+    side = image_side or {"InceptionV3": 299,
+                          "SmallCNN": 96}.get(model_name, 224)
 
     rngs = {"params": jax.random.PRNGKey(0),
             "dropout": jax.random.PRNGKey(1)}
@@ -75,7 +73,7 @@ def main() -> None:
     batch_stats = variables.get("batch_stats", {})
     has_bn = "batch_stats" in variables
 
-    compression = (hvd.Compression.fp16 if args.fp16_allreduce
+    compression = (hvd.Compression.fp16 if fp16_allreduce
                    else hvd.Compression.none)
     opt = hvd.DistributedOptimizer(optax.sgd(0.01), op=hvd.Average,
                                    axis_name="hvd",
@@ -113,13 +111,37 @@ def main() -> None:
                              in_specs=(*rep, P("hvd"), P("hvd"), P()),
                              out_specs=(*rep, P())))
 
-    shape = (args.batch_size * n, side, side, 3)
+    shape = (batch_size * n, side, side, 3)
     rng_np = np.random.RandomState(0)
     data_sh = NamedSharding(mesh, P("hvd"))
     images = jax.device_put(jnp.asarray(rng_np.rand(*shape), jnp.float32),
                             data_sh)
     labels = jax.device_put(
         jnp.asarray(rng_np.randint(0, 1000, shape[0]), jnp.int32), data_sh)
+    return step, (params, batch_stats, opt_state), (images, labels)
+
+
+def main() -> None:
+    p = argparse.ArgumentParser()
+    p.add_argument("--model", default="ResNet50")
+    p.add_argument("--batch-size", type=int, default=64,
+                   help="per-device batch")
+    p.add_argument("--num-iters", type=int, default=10)
+    p.add_argument("--num-batches-per-iter", type=int, default=10)
+    p.add_argument("--num-warmup-batches", type=int, default=3)
+    p.add_argument("--fp16-allreduce", action="store_true")
+    args = p.parse_args()
+
+    import jax.numpy as jnp
+
+    import horovod_tpu as hvd
+
+    hvd.init()
+    n = hvd.size()
+    step, (params, batch_stats, opt_state), (images, labels) = \
+        build_trainer(hvd, args.model, args.batch_size,
+                      args.fp16_allreduce)
+    n_images = images.shape[0]
 
     def log(msg):
         if hvd.rank() == 0:
@@ -146,7 +168,7 @@ def main() -> None:
             step_no += 1
         float(np.asarray(loss)[0])
         dt = time.perf_counter() - t0
-        rate = shape[0] * args.num_batches_per_iter / dt / n
+        rate = n_images * args.num_batches_per_iter / dt / n
         log(f"Iter #{i}: {rate:.1f} img/sec per device")
         img_secs.append(rate)
 

@@ -20,10 +20,6 @@ Typical use::
 
 __version__ = "0.1.0"
 
-# Must run before any sibling import touches jax: bridges older jax
-# releases (jax.shard_map / lax.axis_size / pallas CompilerParams).
-from horovod_tpu.common import jax_compat as _jax_compat  # noqa: F401
-
 from horovod_tpu.common.basics import (  # noqa: F401
     ccl_built,
     cross_rank,

@@ -58,9 +58,6 @@ COORDINATION_ENV = frozenset({
     "HOROVOD_ELASTIC_NP", "HOROVOD_RESTART_ATTEMPT",
     "HOROVOD_RESUME_STEP", "HOROVOD_RUNFUNC_NO_SHARED_FS",
 })
-# Operator-internal orchestration prefixes (bench probe machinery).
-INTERNAL_PREFIXES = ("HOROVOD_BENCH_",)
-
 # Help-text phrases that claim cross-rank agreement; the handshake
 # vector and these markers must agree in both directions.
 HANDSHAKE_MARKERS = ("round-0 handshake", "must agree on every rank")
@@ -469,13 +466,12 @@ def _registry_rules(root: str) -> list:
                 for env in _ENV_RE.findall(node.value):
                     seen.setdefault(env, node.lineno)
         for env, lineno in sorted(seen.items()):
-            if env in env_to_name or env in COORDINATION_ENV \
-                    or env.startswith(INTERNAL_PREFIXES):
+            if env in env_to_name or env in COORDINATION_ENV:
                 continue
             findings.append(_f(
                 "KNOB-BENCH-DRIFT", f"bench.py:{lineno}",
                 f"bench references {env}, which is neither a "
-                "registered knob nor a known coordination/internal "
+                "registered knob nor a known coordination "
                 "var — the PR 10 unregistered-knob drift class",
                 "register the knob in common/config.py (or add it to "
                 "knob_lint's coordination set with a rationale)"))

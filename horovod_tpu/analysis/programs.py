@@ -74,7 +74,6 @@ def _ensure_backend() -> None:
 
 def run() -> list:
     _ensure_backend()
-    import horovod_tpu.common.jax_compat  # noqa: F401  (jax.shard_map shim)
     import jax
     import jax.numpy as jnp
     import numpy as np

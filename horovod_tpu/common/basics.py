@@ -154,25 +154,21 @@ def init(comm=None, mesh=None) -> None:
             # stalls the job for minutes; the reference's launcher kills
             # the whole job as soon as one rank dies
             # (gloo_run.py:294-304) and these knobs make that prompt.
-            import inspect
-
-            kwargs = {}
-            sig = inspect.signature(jax.distributed.initialize)
-            if "heartbeat_timeout_seconds" in sig.parameters:
-                # When the control-plane liveness layer is on (its own
-                # hb/<epoch>/<rank> heartbeats + coordinated abort,
-                # docs/fault-tolerance.md), it must win the race to
-                # report a dead peer — jax's service detection QFATALs
-                # the survivors with an undiagnosable abort.  Keep the
-                # service as a loose backstop (3x) in that case; with
-                # liveness disabled it stays the primary detector.
-                hb = max(int(_config.get("heartbeat_timeout")), 1)
-                if float(_config.get("heartbeat_interval")) > 0:
-                    hb = max(hb * 3, 30)
-                kwargs["heartbeat_timeout_seconds"] = hb
-            if "shutdown_timeout_seconds" in sig.parameters:
-                kwargs["shutdown_timeout_seconds"] = int(
-                    _config.get("shutdown_timeout"))
+            # When the control-plane liveness layer is on (its own
+            # hb/<epoch>/<rank> heartbeats + coordinated abort,
+            # docs/fault-tolerance.md), it must win the race to report
+            # a dead peer — jax's service detection QFATALs the
+            # survivors with an undiagnosable abort.  Keep the service
+            # as a loose backstop (3x) in that case; with liveness
+            # disabled it stays the primary detector.
+            hb = max(int(_config.get("heartbeat_timeout")), 1)
+            if float(_config.get("heartbeat_interval")) > 0:
+                hb = max(hb * 3, 30)
+            kwargs = {
+                "heartbeat_timeout_seconds": hb,
+                "shutdown_timeout_seconds": int(
+                    _config.get("shutdown_timeout")),
+            }
             if pod_auto:
                 jax.distributed.initialize(**kwargs)
             elif _config.get("elastic"):
@@ -199,22 +195,31 @@ def init(comm=None, mesh=None) -> None:
                     process_id=env_rank,
                     **kwargs)
 
-        _state.rank = jax.process_index()
         _state.size = jax.process_count()
         if pod_auto:
+            _state.rank = jax.process_index()
             os.environ["HOROVOD_RANK"] = str(_state.rank)
             os.environ["HOROVOD_SIZE"] = str(_state.size)
-        elif env_size > 1 and (_state.rank != env_rank
-                               or _state.size != env_size):
-            raise HorovodTpuError(
-                f"Launcher env rank/size ({env_rank}/{env_size}) disagrees "
-                f"with XLA runtime ({_state.rank}/{_state.size}).")
+        elif env_size > 1:
+            if _state.size != env_size:
+                raise HorovodTpuError(
+                    f"Launcher env size ({env_size}) disagrees with the "
+                    f"XLA runtime ({_state.size} processes).")
+            # The launcher's numbering is the job's.  jax's own process
+            # index need not equal it: a TPU backend numbers processes
+            # by where their chips sit, whatever process_id
+            # jax.distributed was given (docs/launcher.md), so the world
+            # mesh is ordered by rank explicitly (_build_meshes).
+            _state.rank = env_rank
+        else:
+            _state.rank = jax.process_index()
 
         _state.epoch += 1
         _compute_local_cross_topology()
         _build_meshes()
         _apply_mesh_arg(mesh)
         _build_data_mesh()
+        _log_idle_devices()
         # Device-side capture starts here, not in the background
         # runtime: at size 1 that runtime is lazy, and a compiled-only
         # training run would otherwise record nothing.
@@ -371,21 +376,69 @@ def _compute_local_cross_topology() -> None:
     _state.homogeneous = len(set(counts.values())) == 1
 
 
+def _process_of_each_rank() -> list:
+    """jax process index of every rank, in rank order.  Ranks publish
+    theirs through the coordination service's key-value store (no
+    collective exists yet at init time) and read the whole directory
+    back after a barrier — one request per rank, not one per peer.
+    Keys carry the epoch so a shutdown()+init() on the still-live
+    service cannot read a previous generation's."""
+    import jax
+
+    if _state.size == 1:
+        return [jax.process_index()]
+    from jax._src import distributed as _jd
+
+    client = _jd.global_state.client
+    prefix = f"hvd_proc/{_state.epoch}/"
+    client.key_value_set(f"{prefix}{_state.rank}", str(jax.process_index()))
+    client.wait_at_barrier(f"hvd_proc_{_state.epoch}", timeout_in_ms=60_000)
+    by_rank = {int(k.rsplit("/", 1)[1]): int(v)
+               for k, v in client.key_value_dir_get(prefix)}
+    procs = [by_rank.get(r) for r in range(_state.size)]
+    if sorted(p for p in procs if p is not None) != list(range(_state.size)):
+        raise HorovodTpuError(
+            f"ranks 0..{_state.size - 1} report jax process indices "
+            f"{procs}; every process must belong to exactly one rank")
+    return procs
+
+
 def _build_meshes() -> None:
     import jax
     from jax.sharding import Mesh
 
-    devices = jax.devices()
+    by_proc: dict = {}
+    for d in jax.devices():
+        by_proc.setdefault(d.process_index, []).append(d)
+    # mesh position r holds rank r's lead device
     leads = []
-    for p in range(_state.size):
-        mine = [d for d in devices if d.process_index == p]
-        if not mine:
-            raise HorovodTpuError(f"process {p} exposes no devices")
-        leads.append(mine[0])
+    for r, p in enumerate(_process_of_each_rank()):
+        if p not in by_proc:
+            raise HorovodTpuError(f"rank {r} (process {p}) exposes no "
+                                  "devices")
+        leads.append(by_proc[p][0])
     _state.mesh = Mesh(np.array(leads), ("hvd",))
-    local = [d for d in devices if d.process_index == _state.rank]
+    local = by_proc[jax.process_index()]
     _state.local_mesh = Mesh(np.array(local), ("local",))
     _state.lead_device = local[0]
+
+
+def _log_idle_devices() -> None:
+    """Say it when this process sees several devices and the world mesh
+    takes one of them: on ``hvd.world_mesh()`` the DistributedOptimizer
+    then trains on that one and the rest stay idle.  (Forced host
+    devices on the CPU test mesh have the same shape but are nobody's
+    hardware, hence info there.)"""
+    local = _state.local_mesh.devices.size
+    if local == 1 or _state.data_mesh is not None:
+        return
+    say = _log.info if _state.lead_device.platform == "cpu" else _log.warning
+    say(f"this process sees {local} local devices and the world mesh "
+        f"uses 1 of them ({_state.mesh.devices.size} device(s) for "
+        f"{_state.size} process(es)): the others stay idle on "
+        "hvd.world_mesh() unless the job runs one process per chip "
+        "(hvdrun -np <chips>) or names a data mesh (hvd.init(mesh=...))",
+        rank=_state.rank)
 
 
 def _apply_mesh_arg(mesh) -> None:
@@ -486,22 +539,20 @@ def _elastic_distributed_init(coord: str, n: int, rank: int) -> None:
     (the PR3 rationale: the diagnosable RanksDownError abort must win
     the race against jax's undiagnosable fatal teardown)."""
     from jax._src import distributed as _jd
-    from jax._src.lib import xla_extension as _xe
+    from jax._src.lib import _jax
 
     gs = _jd.global_state
-    hb_int = max(1, int(float(_config.get("heartbeat_interval")) or 1))
-    hb_to = max(int(_config.get("heartbeat_timeout")), 1)
-    missing = max(3, (max(hb_to * 3, 30) + hb_int - 1) // hb_int)
+    hb_to = max(max(int(_config.get("heartbeat_timeout")), 1) * 3, 30)
+    shutdown_to = max(2, int(_config.get("shutdown_timeout")))
     if rank == 0 and gs.service is None:
         port = coord.rsplit(":", 1)[1]
-        gs.service = _xe.get_distributed_runtime_service(
-            "[::]:" + port, n, heartbeat_interval=hb_int,
-            max_missing_heartbeats=missing)
-    gs.client = _xe.get_distributed_runtime_client(
-        coord, rank, init_timeout=120,
-        shutdown_timeout=max(2, int(_config.get("shutdown_timeout"))),
-        heartbeat_interval=hb_int, max_missing_heartbeats=missing,
-        shutdown_on_destruction=False, use_compression=True)
+        gs.service = _jax.get_distributed_runtime_service(
+            "[::]:" + port, n, heartbeat_timeout=hb_to,
+            shutdown_timeout=shutdown_to)
+    gs.client = _jax.get_distributed_runtime_client(
+        coord, rank, init_timeout=120, shutdown_timeout=shutdown_to,
+        heartbeat_timeout=hb_to, shutdown_on_destruction=False,
+        use_compression=True)
     gs.client.connect()
     gs.process_id = rank
     gs.num_processes = n
@@ -573,20 +624,12 @@ def teardown_distributed(bound_s: float | None = None) -> None:
     from horovod_tpu.ops import xla_exec as _exec
 
     _exec.clear_cache()
-    try:
-        from jax._src import xla_bridge as _xb
+    from jax._src import xla_bridge as _xb
 
-        _xb._clear_backends()
-        cached = [_xb.get_backend, _xb.local_devices, _xb.process_count]
-    except Exception:  # newer jax: public surface only
-        cached = []
-        clear = getattr(getattr(getattr(jax, "extend", None), "backend",
-                                None), "clear_backends", None)
-        if clear is not None:
-            _swallow(clear)
-    cached += [jax.process_count, jax.process_index, jax.device_count,
-               jax.local_device_count, jax.devices, jax.local_devices]
-    for fn in cached:
+    _xb._clear_backends()
+    for fn in (_xb.get_backend, _xb.local_devices, _xb.process_count,
+               jax.process_count, jax.process_index, jax.device_count,
+               jax.local_device_count, jax.devices, jax.local_devices):
         cc = getattr(fn, "cache_clear", None)
         if cc is not None:
             _swallow(cc)

@@ -342,13 +342,6 @@ _register("profile_keep", Knob(
     cli="--profile-keep", config_key="profiling.keep",
     help="How many sampled step captures each rank keeps "
          "(oldest rotated out), bounding disk use on long runs."))
-_register("peak_flops", Knob(
-    "HOROVOD_PEAK_FLOPS_PER_CHIP", 0.0, float,
-    cli="--peak-flops-per-chip", config_key="profiling.peak_flops",
-    help="Peak chip FLOP/s used as the MFU denominator by the perf "
-         "observatory; 0 (default) auto-detects from the TPU "
-         "generation's spec sheet.  Set explicitly for hardware the "
-         "table predates, or to give CPU test runs an MFU number."))
 _register("flight_dir", Knob(
     "HOROVOD_FLIGHT_DIR", "", str,
     cli="--flight-dir", config_key="flight.dir",

@@ -40,6 +40,32 @@ def _enable_overlap_xla_flags() -> None:
             filter(None, [existing] + added))
 
 
+# The checkout (or install prefix) that holds this package: the default
+# compile cache lives inside it, so every process started from the same
+# tree resolves the same path without being told.
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def ensure_compile_cache() -> str:
+    """Place JAX's persistent compile cache; returns the directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: nothing to do — JAX reads it at
+    import, and no code path sets another.  Unset: ``<checkout>/.jax_cache``
+    (a fixed place: a directory that moves between runs never hits),
+    exported too so spawned ranks and child processes inherit it.  This
+    is the only place that names a compile cache path."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(_CHECKOUT, ".jax_cache")
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = path
+        import jax
+
+        # jax read the (unset) variable when it was imported
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def ensure_platform() -> None:
     """Apply HOROVOD_PLATFORM / CPU-collective config before backend init.
 
@@ -56,6 +82,7 @@ def ensure_platform() -> None:
 
     from horovod_tpu.common import config as _config
 
+    ensure_compile_cache()
     if _config.get("overlap"):
         _enable_overlap_xla_flags()
 
@@ -63,8 +90,8 @@ def ensure_platform() -> None:
     import jax
 
     if platform:
-        # Late config.update is required: plugin site hooks may have
-        # already overridden jax_platforms at interpreter start.
+        # jax read JAX_PLATFORMS when it was imported; HOROVOD_PLATFORM
+        # wins over it for this process.
         jax.config.update("jax_platforms", platform)
     effective = jax.config.jax_platforms or ""
     if platform == "cpu" or effective == "cpu":
@@ -77,11 +104,34 @@ def ensure_platform() -> None:
         multiproc = (_config.get("coordinator_addr")
                      or int(os.environ.get("HOROVOD_SIZE", "1") or 1) > 1)
         if multiproc:
-            try:
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", "gloo")
-            except Exception:  # older jaxlib without gloo support
-                pass
+            jax.config.update(
+                "jax_cpu_collectives_implementation", "gloo")
+
+
+def cpu_asked_for(env=None) -> bool:
+    """Whether this environment asks for the CPU platform outright
+    (``HOROVOD_PLATFORM=cpu``, else ``JAX_PLATFORMS=cpu``).  Only then
+    may a measuring entry point run without a TPU, and only then does
+    the launcher leave its ranks' chip assignment out."""
+    env = os.environ if env is None else env
+    return (env.get("HOROVOD_PLATFORM") or env.get("JAX_PLATFORMS")
+            or "").strip().lower() == "cpu"
+
+
+def pallas_interpret(requested: bool | None = None) -> bool:
+    """``interpret=`` for a ``pallas_call``: kernels compile through
+    Mosaic on a TPU backend and run interpreted elsewhere (the CPU test
+    mesh).  Asking for the interpreter on a TPU backend raises — a
+    kernel that quietly stops being a kernel is measured as one."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        if requested:
+            raise ValueError(
+                "Pallas interpret mode was requested on a TPU backend; "
+                "kernels compile through Mosaic there")
+        return False
+    return True if requested is None else bool(requested)
 
 
 def platform_name() -> str:

@@ -1,7 +1,7 @@
 """Status / error types and dtype tables.
 
 Parity with the reference's ``horovod/common/common.h``:
-``Status`` kinds (``common.h:122-136``), the error taxonomy surfaced to
+``Status`` kinds (``common.h:122-136``), the error kinds surfaced to
 users (duplicate names ``common.h:161``, crashed-rank semantics
 ``common.h:154-159``), and the supported dtype table.  On TPU, dtypes
 map to JAX/XLA dtypes rather than framework enums; bfloat16 is

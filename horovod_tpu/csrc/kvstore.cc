@@ -28,7 +28,7 @@
 // An empty server secret disables verification (single-user unit-test
 // mode); the launcher always generates one per job.
 //
-// Build: g++ -O2 -fPIC -shared -pthread -o libhvdkv.so kvstore.cc
+// Build: runtime/native_build.py (g++ -O2 -fPIC -shared -pthread)
 
 #include <arpa/inet.h>
 #include <netinet/in.h>

@@ -10,8 +10,9 @@ block-level causal masking from *global* sequence offsets (the carried
 state is what makes it composable with the ring — a plain fused
 attention kernel could not resume from a previous block's state).
 
-Falls back to interpret mode off-TPU, so the same code path is exercised
-by the CPU test mesh.
+Compiled by Mosaic on a TPU backend; interpreted elsewhere
+(``common.platform.pallas_interpret``), so the same kernel code is
+exercised by the CPU test mesh.
 """
 
 from __future__ import annotations
@@ -22,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from horovod_tpu.common.platform import pallas_interpret
 
 _NEG_INF = float("-inf")
 
@@ -100,8 +103,7 @@ def _flash_block_step_impl(q, k, v, m, l, o, q_offset, k_offset,
     if lq % bq or lk % bk:
         raise ValueError(f"block sizes ({bq}, {bk}) must divide the "
                          f"sequence chunks ({lq}, {lk})")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret(interpret)
     scale = 1.0 / (d ** 0.5)
     offs = jnp.asarray([q_offset, k_offset], jnp.int32)
     ml = jnp.concatenate(
@@ -271,8 +273,7 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_offset, k_offset, *,
     if lq % bq or lk % bk:
         raise ValueError(f"block sizes ({bq}, {bk}) must divide the "
                          f"sequence chunks ({lq}, {lk})")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret(interpret)
     scale = 1.0 / (d ** 0.5)
     offs = jnp.asarray([q_offset, k_offset], jnp.int32)
     ld = _pack_ld(lse, delta, bh, lq)
@@ -314,8 +315,7 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, q_offset, k_offset, *,
     if lq % bq or lk % bk:
         raise ValueError(f"block sizes ({bq}, {bk}) must divide the "
                          f"sequence chunks ({lq}, {lk})")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret(interpret)
     scale = 1.0 / (d ** 0.5)
     offs = jnp.asarray([q_offset, k_offset], jnp.int32)
     ld = _pack_ld(lse, delta, bh, lq)

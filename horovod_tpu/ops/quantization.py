@@ -34,10 +34,10 @@ is that wire format plus the scale-aware reductions that ride it:
 
 * **Pallas kernels** — fused quantize / dequantize TPU kernels keep the
   int8 conversion in VMEM (no HBM round-trip between absmax, scale and
-  cast); the pure-jnp fallback is selected off-TPU, the same pattern as
+  cast); the pure-jnp path is selected off-TPU, the same pattern as
   :mod:`horovod_tpu.ops.pallas_attention`.  ``HOROVOD_QUANT_PALLAS=1``
-  forces the kernels (interpret mode off-TPU, test hook), ``0`` forces
-  the jnp path.
+  forces the kernels (interpret mode off-TPU, test hook; a block size
+  they cannot tile then raises), ``0`` forces the jnp path.
 
 Two more lossy codecs ride the same per-block-scale + error-feedback
 contract (docs/compression.md's mode ladder):
@@ -81,6 +81,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from horovod_tpu.common import config as _config
+from horovod_tpu.common import logging as _log
+from horovod_tpu.common.platform import pallas_interpret
 
 DEFAULT_BLOCK_SIZE = 256
 _QMAX = 127  # symmetric int8: values in [-127, 127] (-128 unused)
@@ -183,15 +185,33 @@ def _pallas_mode() -> str:
     return str(_config.get("quant_pallas")).strip().lower()
 
 
-def _use_pallas(block: int) -> bool:
+_warned_unaligned: set = set()
+
+
+def _use_pallas(block: int, lanes: int = _LANES) -> bool:
+    """Kernel or jnp path for one block size.  The kernels tile blocks
+    over whole 128-lane rows (``lanes`` = the alignment the block must
+    have): an unaligned block raises when the kernels were asked for
+    (``HOROVOD_QUANT_PALLAS=1``) and is logged once when the choice was
+    automatic — never a wordless switch to the reference."""
     mode = _pallas_mode()
     if mode in ("0", "off", "jnp", "false"):
         return False
-    if block % _LANES:
-        return False  # lane-unaligned block: kernel tiling impossible
-    if mode in ("1", "on", "force", "true"):
-        return True
-    return jax.default_backend() == "tpu"
+    forced = mode in ("1", "on", "force", "true")
+    if not forced and jax.default_backend() != "tpu":
+        return False
+    if block % lanes:
+        msg = (f"quantization block size {block} is not a multiple of "
+               f"{lanes}: the Pallas codec kernels cannot tile it")
+        if forced:
+            raise ValueError(
+                msg + " (HOROVOD_QUANT_PALLAS=1 asked for them; use an "
+                "aligned HOROVOD_QUANT_BLOCK_SIZE)")
+        if (block, lanes) not in _warned_unaligned:
+            _warned_unaligned.add((block, lanes))
+            _log.warning(msg + "; using the jnp codec")
+        return False
+    return True
 
 
 def _pad_rows(x2d, rows: int):
@@ -254,7 +274,7 @@ def quantize_values(x2d, scales, qmax: int = _QMAX):
     """int8 values for blocked fp32 ``x2d`` under given per-block
     scales (Pallas on TPU, jnp elsewhere)."""
     if _use_pallas(x2d.shape[1]):
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
         return _quantize_pallas_call(x2d, scales, int(qmax), interpret)
     return _quantize_jnp(x2d, scales, qmax)
 
@@ -262,7 +282,7 @@ def quantize_values(x2d, scales, qmax: int = _QMAX):
 def dequantize_values(q2d, scales):
     """fp32 values for blocked int8 (or int partial-sum) ``q2d``."""
     if _use_pallas(q2d.shape[1]):
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
         return _dequantize_pallas_call(q2d, scales, interpret)
     return _dequantize_jnp(q2d, scales)
 
@@ -489,7 +509,7 @@ def _unpack4_kernel(p_ref, s_ref, x_ref, *, half: int):
 
 def _use_pallas4(block: int) -> bool:
     # the packed payload must itself stay lane-aligned: block % 256
-    return _use_pallas(block) and (block // 2) % _LANES == 0
+    return _use_pallas(block, 2 * _LANES)
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3))
@@ -544,7 +564,7 @@ def quantize_pack4_values(x2d, scales, qmax: int = _QMAX4):
     the int8 wire (Pallas on TPU, jnp elsewhere)."""
     _check_int4_block(x2d.shape[1])
     if _use_pallas4(x2d.shape[1]):
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
         return _pack4_pallas_call(x2d, scales, int(qmax), interpret)
     return _quantize_pack4_jnp(x2d, scales, qmax)
 
@@ -553,7 +573,7 @@ def unpack_dequantize4_values(p2d, scales):
     """fp32 values for packed int4 bytes (or their sum-safe partial
     sums)."""
     if _use_pallas4(p2d.shape[1] * 2):
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
         return _unpack4_pallas_call(p2d, scales, interpret)
     return _unpack_dequantize4_jnp(p2d, scales)
 

@@ -15,7 +15,12 @@ expressions verbatim (``optax.sgd`` / ``optax.trace`` /
 ``optax.scale_by_adam`` + ``scale_by_learning_rate``), so
 ``HOROVOD_FUSED_UPDATE=1`` is bit-exact against the unfused chain —
 the parity matrix in ``tests/test_fused_update.py`` proves it per
-dtype-group x optimizer x ZeRO stage x int8-EF cell.  That contract is
+dtype-group x optimizer x ZeRO stage x int8-EF cell (interpret mode,
+CPU).  Compiled by Mosaic on a v5e, SGD, momentum and Adam's moments
+(multiplies and adds) stay bit-exact; Adam's update divides and takes
+a square root, which Mosaic and XLA round differently — 4.7e-7
+relative at most over a ResNet-50-sized buffer (chip_smoke.py, PR 21).
+That contract is
 only possible when the hyperparameters are knowable, so fusion applies
 to optimizers built by :func:`sgd` / :func:`adam` below (plain optax
 ``GradientTransformation``s are closures — their hyperparameters are
@@ -43,6 +48,7 @@ import jax.numpy as jnp
 
 from horovod_tpu.common import config as _config
 from horovod_tpu.common import logging as _log
+from horovod_tpu.common.platform import pallas_interpret
 from horovod_tpu.runtime import metrics as _metrics
 
 # Row tile: (16, 128) covers the native f32 (8, 128) and bf16 (16, 128)
@@ -362,7 +368,7 @@ def _apply_buffer(spec: FusedSpec, g, mu, nu, bc1, bc2, navg: int,
         return z, (z if mu is not None else None), \
             (z if nu is not None else None)
     if _use_pallas():
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
         g2d, n = _pad2d(g.reshape(-1))
         if spec.kind == "sgd":
             o = _sgd_pallas(g2d, dtype, navg, -spec.lr, interpret)

@@ -14,9 +14,10 @@ One ring driver, two block-step implementations with the same packed
 step (XLA fuses it well — the safe fallback everywhere), and
 ``impl="pallas"`` is the hand-tiled flash kernel
 (:mod:`horovod_tpu.ops.pallas_attention`) that keeps softmax state in
-VMEM scratch and feeds the MXU with aligned blocks.  Default picks
-pallas on TPU; chunk lengths with no MXU-aligned divisor fall back to
-xla.  The pallas path is differentiable through a ring-level custom
+VMEM scratch and feeds the MXU with aligned blocks.  The default picks
+by score-block size on TPU (:func:`auto_impl`); a chunk length with no
+aligned divisor raises when pallas was asked for and logs once when
+the pick was automatic.  The pallas path is differentiable through a ring-level custom
 VJP: the forward saves only (q, k, v, out, lse) and the backward is a
 second ring pass over hand-written saved-LSE flash backward kernels,
 with dK/dV accumulators rotating alongside KV — no O(Lq·Lk) score
@@ -65,9 +66,13 @@ def xla_block_step(q, k, v, m, l, o, q_offset, k_offset, *,
     return m_new, l_new, o_new
 
 
+_warned_untiled: set = set()
+
+
 def _pick_block(n: int, preferred: int = 128) -> int | None:
     """Largest MXU-friendly block size dividing n (None if there is
-    none — the caller falls back to the XLA step)."""
+    none — ``ring_attention`` then raises for an explicit
+    ``impl="pallas"`` and logs once for an automatic pick)."""
     for c in (preferred, 64, 32, 16, 8):
         if c <= n and n % c == 0:
             return c
@@ -102,9 +107,8 @@ def auto_impl(batch: int, heads: int, seq_q: int,
     of this shape on TPU.  Shared with ``bench.py``'s crossover
     side-measure so its labels can never drift from the product
     decision.  The XLA step materializes fp32 scores plus an fp32
-    softmax transient, hence 8 bytes per score element; measured on
-    v5e (GPT-2-small, seq 1024) XLA wins 95.2k vs 60.7k tokens/s while
-    that block fits HBM comfortably."""
+    softmax transient, hence 8 bytes per score element.  Where the
+    two impls actually cross over on the chip is not measured."""
     from horovod_tpu.common import config as _config
 
     seq_k = seq_q if seq_k is None else seq_k
@@ -133,9 +137,7 @@ def _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, bq, bk):
     def step(j, carry):
         m, l, o, kj, vj = carry
         # Global offsets feed only the causal mask; keep the
-        # axis_index chain out of the non-causal trace entirely (a
-        # dead partition-id operand trips older XLA's SPMD
-        # partitioner once the kernel never loads it).
+        # axis_index chain out of the non-causal trace entirely.
         qo, ko = (idx * lc, ((idx - j) % sp) * lc) if causal else (0, 0)
         m, l, o = flash_block_step(qp, kj, vj, m, l, o, qo, ko,
                                    causal=causal, block_q=bq,
@@ -247,6 +249,7 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
     idx = lax.axis_index(axis_name)
     b, lc, h, d = q.shape
 
+    asked = impl is not None
     if impl is None:
         impl = (auto_impl(b, h, lc)
                 if jax.default_backend() == "tpu" else "xla")
@@ -257,7 +260,15 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
     if impl == "pallas":
         bq, bk = _block_sizes(lc, lc)  # ring KV blocks are lc long too
         if bq is None or bk is None:
-            impl = "xla"  # no aligned tiling for this chunk length
+            msg = (f"sequence chunk {lc} has no tile size the Pallas "
+                   "attention kernel can use (a multiple of 8 dividing "
+                   "it)")
+            if asked:
+                raise ValueError(msg + "; impl='pallas' was asked for")
+            if lc not in _warned_untiled:
+                _warned_untiled.add(lc)
+                _log.warning(msg + "; using the XLA block step")
+            impl = "xla"
     if impl == "pallas":
         from horovod_tpu.common import config as _config
 

@@ -17,8 +17,7 @@ numbers every wire-efficiency claim in this repo actually needs
   ``hvd_zero3_ag<k>``, ...);
 * MFU when a flops-per-step hint is available (XLA ``cost_analysis``
   flops, supplied by bench or the capture hook) against the chip's
-  peak (spec-sheet table below, ``HOROVOD_PEAK_FLOPS_PER_CHIP``
-  override for hardware the table predates).
+  peak (spec-sheet table below; an unknown device raises).
 
 Works on TPU device planes and on the CPU backend's host-plane XLA
 executor events alike (both carry an ``hlo_op`` stat), so the whole
@@ -31,9 +30,11 @@ import re
 
 from horovod_tpu.perf import xplane as _xp
 
-# bf16 peak FLOP/s per chip by TPU generation (public spec sheets;
-# bench.py carries the same table — kept in both because bench must not
-# import the package before its subprocess backend probe).
+# bf16 peak FLOP/s per chip, keyed by a tag found in jax's
+# ``device_kind`` (lower-cased, spaces removed; "TPU v5 lite" is what a
+# v5e announces).  Source: Google Cloud TPU documentation, the "System
+# architecture" page of each generation.  The one table in the repo:
+# bench.py imports it.
 _PEAK_FLOPS = [
     ("v6", 918e12), ("v5p", 459e12), ("v5lite", 197e12), ("v5e", 197e12),
     ("v5", 459e12), ("v4", 275e12), ("v3", 123e12), ("v2", 46e12),
@@ -60,23 +61,18 @@ _COMM_SCOPE = re.compile(
 _HVD_SCOPE = re.compile(r"^hvd_\w+$")
 
 
-def peak_flops_per_chip(device_kind: str) -> float | None:
-    """Spec-sheet bf16 peak for a ``jax`` ``device_kind`` string; the
-    ``HOROVOD_PEAK_FLOPS_PER_CHIP`` knob overrides (new hardware, or a
-    CPU run that still wants an MFU denominator for CI)."""
-    from horovod_tpu.common import config as _config
-
-    try:
-        override = float(_config.get("peak_flops"))
-    except Exception:
-        override = 0.0
-    if override > 0:
-        return override
+def peak_flops_per_chip(device_kind: str) -> float:
+    """Spec-sheet bf16 peak for a ``jax`` ``device_kind`` string.  A
+    device the table does not know raises: a utilization against a
+    guessed or absent peak is not a measurement.  (A CPU run has no
+    utilization at all — callers do not ask for one.)"""
     kind = (device_kind or "").lower().replace(" ", "")
     for tag, peak in _PEAK_FLOPS:
         if tag in kind:
             return peak
-    return None
+    raise ValueError(
+        f"no peak FLOP/s known for device_kind {device_kind!r}; add it "
+        "to perf/attribution._PEAK_FLOPS with its source")
 
 
 # ---------------------------------------------------------------------------

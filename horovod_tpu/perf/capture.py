@@ -13,8 +13,8 @@ registry:
   much of it the overlap/ZeRO schedules actually hid under math;
 * ``hvd_device_comm_kind_seconds{kind=...}`` — per-collective split;
 * ``hvd_mfu`` — when a flops-per-step hint is registered
-  (:func:`set_step_flops`, stamped by bench's cost analysis) and the
-  chip's peak is known (spec table or ``HOROVOD_PEAK_FLOPS_PER_CHIP``).
+  (:func:`set_step_flops`, stamped by bench's cost analysis), on an
+  accelerator (the spec table's peak; a CPU run reports none).
 
 The gauges ride the KV snapshot publisher to the launcher's fleet
 ``/metrics`` merge and land on flight-recorder dumps, so device truth
@@ -255,13 +255,18 @@ def drain(timeout_s: float = 30.0) -> None:
         t.join(max(0.0, deadline - time.monotonic()))
 
 
-def _device_kind() -> str:
-    try:
-        import jax
+def _peak_flops() -> float | None:
+    """The MFU denominator: the chip's spec-sheet peak.  A CPU run has
+    no utilization to report; an accelerator the table does not know
+    raises there."""
+    import jax
 
-        return jax.devices()[0].device_kind
-    except Exception:
-        return ""
+    from horovod_tpu.perf import attribution as _attr
+
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return None
+    return _attr.peak_flops_per_chip(dev.device_kind)
 
 
 def _analyze(token: dict, flops, wire_bytes) -> None:
@@ -312,7 +317,7 @@ def analyze_capture(capture_dir: str, flops_per_step=None,
     if path is None:
         return None
     space = _xp.read_xspace(path, want_stats=_xp.ANALYSIS_STATS)
-    peak = _attr.peak_flops_per_chip(_device_kind())
+    peak = _peak_flops()
     result = _attr.attribute(space, flops_per_step=flops_per_step,
                              peak_flops=peak, wire_bytes=wire_bytes)
     result["xplane_path"] = path

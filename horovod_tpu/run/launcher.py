@@ -29,6 +29,7 @@ from dataclasses import dataclass
 
 from horovod_tpu.common import config as _config
 from horovod_tpu.common import logging as _log
+from horovod_tpu.common.platform import cpu_asked_for
 
 
 def _start_metrics_aggregator(base_env: dict, kv, local_only: bool,
@@ -47,10 +48,6 @@ def _start_metrics_aggregator(base_env: dict, kv, local_only: bool,
     if port <= 0:
         return None
     base_env["HOROVOD_METRICS_PORT"] = str(port + 1)
-    if kv is None:
-        print("[hvdrun] metrics aggregation disabled: no native KV "
-              "rendezvous for ranks to publish through", file=sys.stderr)
-        return None
     from horovod_tpu.runtime import metrics as _metrics
     from horovod_tpu.runtime.kvstore import KVStoreClient, decode_secret
 
@@ -301,9 +298,9 @@ def _rank_preexec():
 
     SIGKILL, not SIGTERM: libraries in the rank (PJRT plugins, coord
     services) register Python-level SIGTERM handlers, and a rank whose
-    main thread is parked in a C++ futex (a dead peer's barrier, a
-    wedged tunnel) never runs them — observed as multi-hour 2 GB
-    orphans surviving a launcher kill -9.  PDEATHSIG fires only when
+    main thread is parked in a C++ futex (a dead peer's barrier)
+    never runs them — observed as multi-hour 2 GB orphans surviving a
+    launcher kill -9.  PDEATHSIG fires only when
     the launcher is already gone, so there is nobody left to escalate
     TERM → KILL; every launcher-alive path still sends SIGTERM first
     (graceful drain) before the KILL deadline.
@@ -612,9 +609,52 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
+# One process per chip (docs/launcher.md): how the local ranks of one
+# TPU host split its chips.  libtpu takes the split from the
+# environment, before the backend opens: each process is told which
+# chip it may open, its place in a grid of processes that mirrors the
+# host's chip grid, and where its host-local peers listen, so the
+# processes form one ICI world instead of each opening every chip.
+# Local rank r opens chip r.  jax then numbers the processes by where
+# their chips sit in the grid, not by rank; hvd.init() keeps the
+# launcher's numbering and orders the world mesh by rank itself.
+# local ranks -> process grid; established on a v5litepod-4 host
+# (PR 21), other hosts have no entry yet.
+_TPU_PROCESS_GRIDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1"}
+_TPU_PROCESS_PORT0 = 8476
+
+
+def _tpu_chip_env(slot: SlotInfo) -> dict:
+    """The libtpu variables that give each of the ``n`` local ranks its
+    own chip and join them into one ICI world."""
+    n, r = slot.local_size, slot.local_rank
+    if slot.cross_size > 1:
+        if n == 1:
+            return {}   # one process per host drives all of its chips
+        raise ValueError(
+            "one process per chip is established for one host only; "
+            "across hosts launch one process per host (it drives all "
+            "of its chips)")
+    if n not in _TPU_PROCESS_GRIDS:
+        raise ValueError(
+            f"{n} ranks on one TPU host: no chip grid is known for that "
+            f"count (known: {sorted(_TPU_PROCESS_GRIDS)})")
+    return {
+        "TPU_VISIBLE_CHIPS": str(r),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": _TPU_PROCESS_GRIDS[n],
+        "TPU_PROCESS_ADDRESSES": ",".join(
+            f"localhost:{_TPU_PROCESS_PORT0 + i}" for i in range(n)),
+        "TPU_PROCESS_PORT": str(_TPU_PROCESS_PORT0 + r),
+        "CLOUD_TPU_TASK_ID": str(r),
+    }
+
+
 def _rank_env(slot: SlotInfo, coord_addr: str, kv_addr: str, kv_port: int,
               base_env: dict) -> dict:
     env = dict(base_env)
+    if not cpu_asked_for(env):
+        env.update(_tpu_chip_env(slot))
     env.update({
         "HOROVOD_RANK": str(slot.rank),
         "HOROVOD_SIZE": str(slot.size),
@@ -843,18 +883,12 @@ def _launch_once(command: list[str], slots: list[SlotInfo], this_host: str,
     if owns_kv:
         job_secret = os.environ.get("HOROVOD_SECRET_KEY") or \
             _secrets.token_hex(32)
-        try:
-            kv = KVStoreServer(secret=decode_secret(job_secret))
-            kv_port = kv.port
-        except Exception as exc:  # no g++/unwritable dir: JaxCoordTransport
-            print(f"[hvdrun] native KV store unavailable ({exc}); ranks "
-                  "will use the coordination-service transport",
-                  file=sys.stderr)
-            kv = None
-            kv_port = 0
+        # a failed native build raises: the launcher does not start a
+        # job on another transport than the one it was asked for
+        kv = KVStoreServer(secret=decode_secret(job_secret))
     else:
         job_secret = (env or os.environ).get("HOROVOD_SECRET_KEY", "")
-        kv_port = kv.port
+    kv_port = kv.port
     coord = f"{coord_host}:{_free_port()}"
 
     base_env = dict(os.environ if env is None else env)
@@ -939,7 +973,7 @@ def _launch_once(command: list[str], slots: list[SlotInfo], this_host: str,
         _sweep_health_dir(base_env)
         _sweep_profile_dir(base_env)
         _stop_metrics_aggregator(metrics_agg)
-        if kv is not None and owns_kv:
+        if owns_kv:
             kv.stop()
     bad = {r: c for r, c in exit_codes.items() if c != 0}
     if bad:
@@ -1030,19 +1064,9 @@ def _launch_elastic(command: list[str], slots: list[SlotInfo],
     if owns_kv:
         job_secret = os.environ.get("HOROVOD_SECRET_KEY") or \
             _secrets.token_hex(32)
-        try:
-            kv = KVStoreServer(secret=decode_secret(job_secret))
-        except Exception as exc:
-            # Elastic re-forms need a rendezvous that outlives the jax
-            # coordination service; without the native KV server there
-            # is none, so degrade to the classic fail-fast job.
-            print(f"[hvdrun] elastic mode needs the native KV store "
-                  f"({exc}); falling back to fail-fast launch",
-                  file=sys.stderr)
-            return _launch_once(command, slots, this_host, local_only,
-                                kv_addr, coord_host, output_filename,
-                                verbose, env, kv_server, prefix_timestamp,
-                                extra_env)
+        # Elastic re-forms need a rendezvous that outlives the jax
+        # coordination service; a failed native build raises.
+        kv = KVStoreServer(secret=decode_secret(job_secret))
     else:
         job_secret = (env or os.environ).get("HOROVOD_SECRET_KEY", "")
     kv_port = kv.port
@@ -1606,7 +1630,7 @@ def _launch_elastic(command: list[str], slots: list[SlotInfo],
                 kvc.close()
             except Exception:
                 pass
-        if kv is not None and owns_kv:
+        if owns_kv:
             kv.stop()
     if deaths:
         print(f"[hvdrun elastic] job saw {len(deaths)} rank death(s) "

@@ -57,7 +57,7 @@ from horovod_tpu.common import config as _config
 from horovod_tpu.common import logging as _log
 from horovod_tpu.runtime import metrics as _metrics
 
-SCHEMA = 1
+SCHEMA = 2
 _SUFFIX = ".aot"
 
 _M_HITS = _metrics.counter(
@@ -237,8 +237,16 @@ def _try_load(program_key, args):
         if fmt == "exec":
             from jax.experimental import serialize_executable as _se
 
-            blob, in_tree, out_tree = rec["payload"]
-            return _se.deserialize_and_load(blob, in_tree, out_tree)
+            import jax
+
+            blob, in_tree, out_tree, device_ids = rec["payload"]
+            # Load onto the program's own devices, in its assignment
+            # order: the default is every local device, and a 1-device
+            # program loaded onto 8 refuses its arguments.
+            by_id = {d.id: d for d in jax.devices()}
+            return _se.deserialize_and_load(
+                blob, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in device_ids])
         import jax
         import jax.export as _je
 
@@ -271,7 +279,9 @@ def _serialize(compiled, fn, args, fmt: str):
     if fmt == "exec":
         from jax.experimental import serialize_executable as _se
 
-        return _se.serialize(compiled)
+        device_ids = [d.id for d in
+                      compiled._executable._unloaded_executable.device_list]
+        return (*_se.serialize(compiled), device_ids)
     import jax.export as _je
 
     return bytes(_je.export(fn)(*args).serialize())

@@ -29,9 +29,8 @@ the heartbeat-piggybacked offset samples (``clk`` events), emits one
 Perfetto/Chrome trace with a process per rank, and runs the
 straggler / critical-path analyzer.  See docs/flight-recorder.md.
 
-Import stays stdlib-only (no jax, no package siblings at import time):
-the bench backend probe child records its ring before PJRT init, the
-exact place a wedge makes everything else unobservable.
+Import stays stdlib-only (no jax, no package siblings at import time),
+so a ring can record before a backend exists.
 """
 
 from __future__ import annotations

@@ -5,7 +5,7 @@ The server side plays the reference launcher's ``RendezvousServer``
 ``HTTPStore``/gloo store C++ client (``horovod/common/gloo/http_store.h``)
 and implements the transport interface the KV controller needs
 (set/set_once/get_blocking/try_get/delete).  The shared library builds
-on demand with the in-tree Makefile (g++ only, no external deps).
+on demand through :mod:`native_build` (g++ only, no external deps).
 """
 
 from __future__ import annotations
@@ -14,7 +14,6 @@ import ctypes
 import os
 import random
 import socket
-import subprocess
 import threading
 import time
 
@@ -51,9 +50,6 @@ _M_SRV_PENDING = _metrics.gauge(
     "server, labeled by port.  Sampled when "
     "KVStoreServer.pending_gets() is called.")
 
-_CSRC = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "csrc")
-_LIB_PATH = os.path.join(_CSRC, "libhvdkv.so")
 _build_lock = threading.Lock()
 _lib = None
 
@@ -76,16 +72,6 @@ def job_secret() -> bytes:
     return decode_secret(os.environ.get("HOROVOD_SECRET_KEY", ""))
 
 
-def _stale(lib_path: str, src: str) -> bool:
-    if not os.path.exists(lib_path):
-        return True
-    if not os.path.exists(src):
-        # pip-installed wheel ships only the built lib; nothing to
-        # compare against — use what exists rather than crashing
-        return False
-    return os.path.getmtime(lib_path) < os.path.getmtime(src)
-
-
 def _load():
     global _lib
     if _lib is not None:
@@ -93,26 +79,11 @@ def _load():
     with _build_lock:
         if _lib is not None:
             return _lib
-        src = os.path.join(_CSRC, "kvstore.cc")
-        path = _LIB_PATH
-        if _stale(path, src):
-            try:
-                subprocess.run(["make", "-C", _CSRC, "-B"], check=True,
-                               capture_output=True)
-            except (OSError, subprocess.CalledProcessError):
-                # installed read-only / no make: build into a user cache
-                cache = os.path.join(
-                    os.environ.get("XDG_CACHE_HOME",
-                                   os.path.expanduser("~/.cache")),
-                    "horovod_tpu")
-                os.makedirs(cache, exist_ok=True)
-                path = os.path.join(cache, "libhvdkv.so")
-                if _stale(path, src):
-                    subprocess.run(
-                        ["g++", "-O2", "-fPIC", "-std=c++17", "-pthread",
-                         "-shared", "-o", path, src],
-                        check=True, capture_output=True)
-        lib = ctypes.CDLL(path)
+        # No fallback: the launcher's rendezvous and every rank's
+        # control plane need this library, so a failed build raises.
+        from horovod_tpu.runtime import native_build
+
+        lib = native_build.load_shared("libhvdkv", "kvstore.cc")
         lib.hvd_kv_server_start.restype = ctypes.c_void_p
         lib.hvd_kv_server_start.argtypes = [ctypes.c_int, ctypes.c_char_p,
                                             ctypes.c_int]

@@ -1,14 +1,18 @@
-"""On-demand builder/loader for CPython extension modules in ``csrc/``.
+"""On-demand builder/loader for the native sources in ``csrc/``.
 
 The reference ships its native core as extensions compiled by a 1626-line
 ``setup.py``; here the toolchain is just ``g++`` against the running
-interpreter's headers, building into the source tree (or a user cache
-when the tree is read-only).  Python↔C++ binding is the CPython C API —
+interpreter's headers.  A built artifact is named by a hash of its
+source file's contents and lives beside the source, so what gets loaded
+is always what the checked-in source says: an artifact left behind by
+another revision (or copied in with meaningless mtimes) has another
+name and is never picked up.  Python↔C++ binding is the CPython C API —
 no pybind11 dependency.
 """
 
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import os
 import subprocess
@@ -21,80 +25,65 @@ _lock = threading.Lock()
 _loaded: dict = {}
 
 
+def artifact_path(stem: str, source: str, suffix: str = ".so") -> str:
+    """``csrc/<stem>-<sha256 of the source bytes, 16 hex><suffix>``."""
+    with open(os.path.join(_CSRC, source), "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_CSRC, f"{stem}-{digest}{suffix}")
+
+
+def _build(stem: str, source: str, suffix: str, python_ext: bool) -> str:
+    out = artifact_path(stem, source, suffix)
+    if not os.path.exists(out):
+        _compile(os.path.join(_CSRC, source), out, python_ext)
+    return out
+
+
 def load_extension(mod_name: str, source: str):
-    """Compile (once) and import ``csrc/<source>`` as ``mod_name``.
-    Raises on any build failure — callers fall back to pure Python."""
+    """Compile (once per source content) and import ``csrc/<source>`` as
+    ``mod_name``.  Raises on any build failure."""
     with _lock:
-        if mod_name in _loaded:
-            return _loaded[mod_name]
-        src = os.path.join(_CSRC, source)
-        suffix = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
-        out = os.path.join(_CSRC, mod_name + suffix)
-        if (not os.path.exists(out)
-                or os.path.getmtime(out) < os.path.getmtime(src)):
-            try:
-                _compile(src, out)
-            except (OSError, subprocess.CalledProcessError):
-                cache = os.path.join(
-                    os.environ.get("XDG_CACHE_HOME",
-                                   os.path.expanduser("~/.cache")),
-                    "horovod_tpu")
-                os.makedirs(cache, exist_ok=True)
-                out = os.path.join(cache, mod_name + suffix)
-                if (not os.path.exists(out)
-                        or os.path.getmtime(out) < os.path.getmtime(src)):
-                    _compile(src, out)
-        spec = importlib.util.spec_from_file_location(mod_name, out)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        _loaded[mod_name] = mod
-        return mod
+        if mod_name not in _loaded:
+            suffix = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
+            out = _build(mod_name, source, suffix, python_ext=True)
+            spec = importlib.util.spec_from_file_location(mod_name, out)
+            mod = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(mod)
+            _loaded[mod_name] = mod
+        return _loaded[mod_name]
 
 
 def load_shared(lib_name: str, source: str):
-    """Compile (once) and dlopen ``csrc/<source>`` as a plain shared
-    library (C ABI via ctypes, no Python.h).  Raises on build failure."""
+    """Compile (once per source content) and dlopen ``csrc/<source>`` as
+    a plain shared library (C ABI via ctypes, no Python.h).  Raises on
+    build failure."""
     import ctypes
 
     with _lock:
-        if lib_name in _loaded:
-            return _loaded[lib_name]
-        src = os.path.join(_CSRC, source)
-        out = os.path.join(_CSRC, lib_name)
-        if (not os.path.exists(out)
-                or os.path.getmtime(out) < os.path.getmtime(src)):
-            try:
-                _compile(src, out, python_ext=False)
-            except (OSError, subprocess.CalledProcessError):
-                cache = os.path.join(
-                    os.environ.get("XDG_CACHE_HOME",
-                                   os.path.expanduser("~/.cache")),
-                    "horovod_tpu")
-                os.makedirs(cache, exist_ok=True)
-                out = os.path.join(cache, lib_name)
-                if (not os.path.exists(out)
-                        or os.path.getmtime(out) < os.path.getmtime(src)):
-                    _compile(src, out, python_ext=False)
-        lib = ctypes.CDLL(out)
-        _loaded[lib_name] = lib
-        return lib
+        if lib_name not in _loaded:
+            out = _build(lib_name, source, ".so", python_ext=False)
+            _loaded[lib_name] = ctypes.CDLL(out)
+        return _loaded[lib_name]
 
 
-def _compile(src: str, out: str, python_ext: bool = True) -> None:
-    include = sysconfig.get_paths()["include"]
+def _compile(src: str, out: str, python_ext: bool) -> None:
     # per-process tmp: N ranks on one host may all compile on first use;
     # each builds privately and the atomic rename makes last-writer win
     # with a complete .so either way
     tmp = f"{out}.{os.getpid()}.tmp"
     cmd = ["g++", "-O2", "-fPIC", "-shared", "-std=c++17"]
     if python_ext:
-        cmd.append(f"-I{include}")
+        cmd.append(f"-I{sysconfig.get_paths()['include']}")
     else:
         cmd.append("-pthread")
     cmd += [src, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True)
         os.replace(tmp, out)
+    except subprocess.CalledProcessError as exc:
+        raise RuntimeError(
+            f"g++ failed building {os.path.basename(src)}:\n"
+            f"{exc.stderr.decode(errors='replace')[-2000:]}") from exc
     finally:
         if os.path.exists(tmp):
             os.unlink(tmp)

@@ -1,8 +1,8 @@
 """Deterministic in-process fleet simulator (docs/control-plane.md).
 
-No hardware run here can validate 1024 ranks (the TPU PJRT attempts
-wedged at init — BENCH_r03/r04), so the scaling claims of the
-hierarchical control plane are proven *in CI* instead: hundreds of
+No hardware here can validate 1024 ranks (one chip, or one four-chip
+host), so the scaling claims of the hierarchical control plane are
+checked *in CI* instead: hundreds of
 simulated ranks, each a cooperative thread driving a **real**
 :class:`~horovod_tpu.runtime.controller.KVController` (not a mock)
 over a simulated KV wire, through negotiation rounds, elastic re-form

@@ -28,7 +28,7 @@ class NativeTimeline:
 
         from horovod_tpu.runtime import native_build
 
-        lib = native_build.load_shared("libhvdtl.so", "timeline.cc")
+        lib = native_build.load_shared("libhvdtl", "timeline.cc")
         lib.hvd_tl_open.restype = ctypes.c_void_p
         lib.hvd_tl_open.argtypes = [ctypes.c_char_p]
         lib.hvd_tl_event.argtypes = [ctypes.c_void_p, ctypes.c_char_p,

@@ -10,26 +10,11 @@ import os
 os.environ.setdefault("HOROVOD_PLATFORM", "cpu")
 # Persistent XLA compile cache: the suite compiles the same tiny
 # programs over and over (every spawned rank recompiles its 2-proc
-# program; many files reuse shapes) — caching them cuts suite wall
-# time ~2-3x on this 1-core image (measured 149s -> 41s on
-# test_transformer.py alone).  Keyed by HLO hash, so stale entries are
-# structurally impossible; spawned rank processes inherit the env.
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      "/tmp/horovod_tpu_jax_cache")
+# program; many files reuse shapes).  ensure_platform() below places it
+# (common/platform.ensure_compile_cache: JAX_COMPILATION_CACHE_DIR when
+# set, else <checkout>/.jax_cache) and exports the path, so spawned
+# rank processes inherit it.
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
-# jaxlib 0.4.x only: deserializing a cached multi-device CPU executable
-# segfaults nondeterministically (~50% on the forced-8-device mesh,
-# observed on jaxlib 0.4.36 — the crash kills the whole pytest
-# process).  Force the cache off there, even when the env opted in; a
-# cold compile is slow but never aborts the suite.
-try:
-    from importlib.metadata import version as _pkg_version
-
-    if tuple(int(p) for p in
-             _pkg_version("jaxlib").split(".")[:2]) < (0, 5):
-        os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
-except Exception:
-    pass
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (

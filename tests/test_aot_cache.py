@@ -317,13 +317,17 @@ def test_cold_then_warm_2proc(tmp_path):
     cache = str(tmp_path / "aot")
     cold = _run_world(2, cache)
     for s in cold:
-        assert s["misses"] >= 4 and s["hits"] == 0, s
-        assert s["compile_s_cold"] > 0 and s["compile_s_warm"] == 0, s
+        # Entries are keyed by program and topology, not by rank, so a
+        # rank that reaches a program second may already load the file
+        # its peer just persisted: hits can be > 0 even in a cold world.
+        assert s["misses"] >= 1 and s["hits"] + s["misses"] >= 4, s
+        assert s["compile_s_cold"] > 0, s
     assert [n for n in os.listdir(cache) if n.endswith(".aot")]
     warm = _run_world(2, cache)
     for c, w in zip(cold, warm):
         assert w["misses"] == 0, w          # zero XLA compiles of cached
-        assert w["hits"] == c["misses"], w  # every program came warm
+        # every program came warm
+        assert w["hits"] == c["hits"] + c["misses"], w
         assert w["evictions"] == 0, w
         total_warm = w["compile_s_warm"] + w["compile_s_cold"]
         assert c["compile_s_cold"] > 2 * total_warm, (c, w)

@@ -1,11 +1,12 @@
-"""bench.py robustness: a number must land no matter what breaks.
+"""bench.py says where it ran, and fails when something failed.
 
-VERDICT r2 #1: BENCH_r01 and BENCH_r02 both exited rc=1 with no JSON —
-r02 lost an already-measured ResNet-50 headline to a VGG dropout bug
-because the per-model loop had no isolation.  These tests run the real
-bench script as a subprocess (the way the driver does) with
-``BENCH_FORCE_FAIL`` injecting deterministic model failures, and assert
-the JSON line still lands with the failure recorded in ``extra``.
+The script used to probe the backend in a child, fall back to the CPU
+when the probe hung and exit 0 with a CPU number under a device
+metric's name; a failing section became an ``extra["*_error"]`` note.
+These tests run the real script as a subprocess (the way a driver does)
+and hold it to the opposite: no TPU and no request for the CPU means a
+non-zero exit and no metric line; an asked-for CPU run names
+``platform: cpu`` on its line; a failing model fails the run.
 """
 
 from __future__ import annotations
@@ -26,12 +27,11 @@ import bench as bench_mod  # noqa: E402
 
 def _run_bench(tmp_path, env_extra, timeout=600):
     env = dict(os.environ)
-    env.update({
-        "HOROVOD_PLATFORM": "cpu",
-        "BENCH_PROBE_ATTEMPTS": "1",
-        "BENCH_PROBE_TIMEOUT": "120",
-    })
+    env["HOROVOD_PLATFORM"] = "cpu"
     env.update(env_extra)
+    for k, v in list(env.items()):
+        if v is None:
+            del env[k]
     r = subprocess.run(
         [sys.executable, BENCH], capture_output=True, text=True,
         timeout=timeout, cwd=str(tmp_path), env=env)
@@ -39,30 +39,77 @@ def _run_bench(tmp_path, env_extra, timeout=600):
 
 
 def _last_json(text):
-    return bench_mod._last_json_obj(text)
+    """Last stdout line that parses to the bench's result dict."""
+    for line in reversed(text.strip().splitlines()):
+        try:
+            obj = json.loads(line)
+        except ValueError:
+            continue
+        if isinstance(obj, dict) and "metric" in obj:
+            return obj
+    return None
 
 
-def test_all_models_failing_still_emits_json(tmp_path):
-    """Every model throwing must still produce the one JSON line with
-    per-model errors and a partial-results file — never a bare rc=1."""
+def test_no_tpu_and_cpu_not_asked_exits_nonzero_without_metric_line(
+        tmp_path):
+    """JAX finds no TPU here.  Unless the CPU was asked for, that is a
+    failed run: non-zero exit, a message that says so, and no result
+    line for anyone to read a number from."""
+    r, doc = _run_bench(tmp_path, {"HOROVOD_PLATFORM": None,
+                                   "JAX_PLATFORMS": None,
+                                   "BENCH_MODELS": "none"}, timeout=180)
+    assert r.returncode != 0
+    assert doc is None, r.stdout
+    assert "metric" not in r.stdout
+    assert "no TPU found" in r.stderr
+    assert not (tmp_path / "bench_partial.json").exists()
+
+
+@pytest.mark.parametrize("var", ["JAX_PLATFORMS", "HOROVOD_PLATFORM"])
+def test_asked_for_cpu_run_names_its_platform_on_the_line(tmp_path, var):
+    r, doc = _run_bench(tmp_path, {"HOROVOD_PLATFORM": None,
+                                   "JAX_PLATFORMS": None, var: "cpu",
+                                   "BENCH_MODELS": "none"}, timeout=180)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert doc["platform"] == "cpu" and doc["device_kind"] == "cpu"
+    assert doc["device_count"] >= 1
+    assert doc["value"] is None and doc["vs_baseline"] is None
+
+
+def test_a_failing_model_fails_the_run(tmp_path):
+    """A failure is not a note in ``extra``: the run exits 1 and the
+    line (it still lands, with whatever was measured) carries the
+    error.  The old script exited by the ResNet-50 headline alone."""
     r, doc = _run_bench(tmp_path, {
-        "BENCH_MODELS": "resnet50,vgg16",
-        "BENCH_FORCE_FAIL": "resnet50,vgg16",
-    })
-    assert doc is not None, f"no JSON line in stdout: {r.stdout!r}\n{r.stderr[-2000:]}"
-    assert r.returncode == 2  # headline missing is rc=2, not a crash
-    assert doc["value"] is None
-    assert "BENCH_FORCE_FAIL" in doc["extra"]["resnet50_error"]
-    assert "BENCH_FORCE_FAIL" in doc["extra"]["vgg16_error"]
-    # incremental checkpoint must exist and agree
+        "BENCH_MODELS": "vgg16", "BENCH_FORCE_FAIL": "vgg16"}, timeout=180)
+    assert r.returncode == 1
+    assert doc is not None, f"no JSON line: {r.stdout!r}\n{r.stderr[-2000:]}"
+    assert "BENCH_FORCE_FAIL" in doc["error"]
+    assert doc["platform"] == "cpu"
+    assert not any(k.endswith("_error") for k in doc["extra"])
     partial = json.loads((tmp_path / "bench_partial.json").read_text())
     assert partial["metric"] == doc["metric"]
+
+
+def test_unknown_model_name_fails_the_run(tmp_path):
+    """A typo in BENCH_MODELS must not read as "measure nothing, exit
+    0"."""
+    r, doc = _run_bench(tmp_path, {"BENCH_MODELS": "resnet"}, timeout=180)
+    assert r.returncode == 1
+    assert "unknown model" in doc["error"]
+
+
+def test_no_fallback_left_in_the_script():
+    src = open(BENCH).read()
+    for gone in ("falling back", "_probe_" + "backend", "_run_" + "sections",
+                 "BENCH_CHILD", "BENCH_NO_REPROBE", "_PEAK_FLOPS"):
+        assert gone not in src, gone
 
 
 @pytest.mark.slow
 def test_resnet_bench_int8_compression_cpu(tmp_path):
     """The quantized (HOROVOD_COMPRESSION=int8) ResNet-50 synthetic
-    bench runs end-to-end on the CPU fallback: a headline number lands,
+    bench runs end-to-end on an asked-for CPU run: a number lands,
     the extras record the compression mode + block size (a quantized
     img/s is not comparable to a full-precision one without them), and
     the training loss stays finite — the accuracy-regression guard for
@@ -83,7 +130,7 @@ def test_resnet_bench_int8_compression_cpu(tmp_path):
 
 @pytest.mark.slow
 def test_resnet_bench_zero3_cpu(tmp_path):
-    """--zero-stage 3 end-to-end on the CPU fallback: the train step
+    """--zero-stage 3 end-to-end on an asked-for CPU run: the train step
     runs on shard-resident params (forward through the prefetched
     gather, shard-shaped updates), a headline number lands, and the
     extras stamp the N-fold memory story (zero_stage + param/grad/
@@ -121,19 +168,6 @@ def test_transformer_bench_tiny_cpu(tmp_path):
     })
     assert doc is not None, f"no JSON: {r.stdout!r}\n{r.stderr[-2000:]}"
     assert doc["extra"].get("transformer_lm_tokens_per_sec", 0) > 0, doc
-
-
-@pytest.mark.slow
-def test_one_model_failing_keeps_other_numbers(tmp_path):
-    """A forced resnet50 failure must not cost VGG-16 its measurement —
-    and VGG exercises the real dropout-rngs path that killed r02."""
-    r, doc = _run_bench(tmp_path, {
-        "BENCH_MODELS": "vgg16,resnet50",
-        "BENCH_FORCE_FAIL": "resnet50",
-    })
-    assert doc is not None, f"no JSON line in stdout: {r.stdout!r}\n{r.stderr[-2000:]}"
-    assert doc["extra"].get("vgg16_img_s_per_chip", 0) > 0
-    assert "resnet50_error" in doc["extra"]
 
 
 def test_build_step_steps_per_dispatch_equivalence(hvd_single):
@@ -186,48 +220,6 @@ def test_build_step_steps_per_dispatch_equivalence(hvd_single):
         assert np.allclose(np.asarray(a), np.asarray(b), atol=1e-6)
 
 
-@pytest.mark.slow
-def test_cpu_fallback_reprobes_backend_before_accepting(tmp_path):
-    """VERDICT r3 #1: after a CPU fallback run, the bench must probe the
-    TPU once more before accepting the CPU number (a transient wedge can
-    clear while the fallback runs).  Here the backend stays broken
-    (bogus platform name): the re-probe must fail quietly and the CPU
-    artifact must land intact — no half-reset state."""
-    r, doc = _run_bench(tmp_path, {
-        "HOROVOD_PLATFORM": "notaplatform",
-        "BENCH_MODELS": "resnet50",
-        "BENCH_SKIP_SIDE": "1",
-        "BENCH_REPROBE_TIMEOUT": "60",
-    })
-    assert doc is not None, f"no JSON: {r.stdout!r}\n{r.stderr[-2000:]}"
-    assert r.returncode == 0, (r.stdout, r.stderr[-1000:])
-    assert doc["value"] is not None          # CPU number landed
-    assert "tpu_unavailable" in doc["extra"]
-    assert "tpu_recovered_after_fallback" not in doc["extra"]
-    assert "re-running the real sections" not in r.stderr
-
-
-@pytest.mark.slow  # tier-1 runtime trim: heaviest cold-compile/subprocess tests;
-# ci.sh's full (unfiltered) suite still runs them
-def test_subprocess_orchestrator_sections(tmp_path):
-    """On TPU the run is split into per-section children so a mid-run
-    backend wedge costs one section, not the whole run (a wedged PJRT
-    call cannot be interrupted in-process).  Forced on CPU here:
-    resnet lands the headline, an injected vgg failure is recorded in
-    extra, and the merged JSON still has rc=0."""
-    r, doc = _run_bench(tmp_path, {
-        "BENCH_FORCE_SUBPROC": "1",
-        "BENCH_SECTIONS": "resnet50,vgg16",
-        "BENCH_FORCE_FAIL": "vgg16",
-    }, timeout=900)
-    assert doc is not None, f"no JSON: {r.stdout!r}\n{r.stderr[-2000:]}"
-    assert r.returncode == 0, (r.returncode, doc)
-    assert doc["value"] is not None
-    assert "BENCH_FORCE_FAIL" in doc["extra"]["vgg16_error"]
-    partial = json.loads((tmp_path / "bench_partial.json").read_text())
-    assert partial["value"] == doc["value"]
-
-
 def test_sigterm_still_emits_json(tmp_path):
     """An outer timeout kills with SIGTERM; the handler must flush the
     JSON line (finally blocks don't run on default SIGTERM)."""
@@ -235,13 +227,12 @@ def test_sigterm_still_emits_json(tmp_path):
     import time as _time
 
     env = dict(os.environ)
-    env.update({"HOROVOD_PLATFORM": "cpu", "BENCH_PROBE_ATTEMPTS": "1",
-                "BENCH_MODELS": "resnet50", "BENCH_NO_SUBPROC": "1",
+    env.update({"HOROVOD_PLATFORM": "cpu", "BENCH_MODELS": "resnet50",
                 "BENCH_SIGTERM_TEST_SLEEP": "60"})
     proc = subprocess.Popen([sys.executable, BENCH],
                             stdout=subprocess.PIPE, text=True,
                             cwd=str(tmp_path), env=env)
-    _time.sleep(8)  # probe + early startup
+    _time.sleep(8)  # imports + hvd.init()
     proc.send_signal(signal.SIGTERM)
     out, _ = proc.communicate(timeout=120)
     doc = _last_json(out)
@@ -249,127 +240,9 @@ def test_sigterm_still_emits_json(tmp_path):
     assert "terminated by signal" in doc.get("error", "")
 
 
-def test_orchestrator_unknown_section_fails_fast(tmp_path):
-    """A filter that matches nothing must error out, not silently run
-    every section (~1h on TPU) or report an empty success."""
-    r, doc = _run_bench(tmp_path, {
-        "BENCH_FORCE_SUBPROC": "1",
-        "BENCH_SECTIONS": "resnet",  # typo for resnet50
-    }, timeout=180)
-    assert doc is not None
-    assert r.returncode == 2
-    assert "matched no sections" in doc["error"]
-
-
-def test_probe_knobs_and_wedge_cache(monkeypatch):
-    """Probe satellite: HOROVOD_BENCH_PROBE_RETRIES /
-    HOROVOD_BENCH_PROBE_TIMEOUT_SECONDS are the operator knobs (BENCH_*
-    kept as the orchestrator's internal overrides), and a wedged
-    verdict is cached for the rest of the run so children / later
-    probes don't re-burn the full timeout per retry (BENCH_r04 spent
-    ~4.5 min exactly there)."""
-    monkeypatch.setenv("HOROVOD_BENCH_PROBE_RETRIES", "7")
-    monkeypatch.setenv("HOROVOD_BENCH_PROBE_TIMEOUT_SECONDS", "33")
-    assert bench_mod._probe_knobs() == (7, 33)
-    monkeypatch.delenv("HOROVOD_BENCH_PROBE_RETRIES")
-    monkeypatch.delenv("HOROVOD_BENCH_PROBE_TIMEOUT_SECONDS")
-    monkeypatch.setenv("BENCH_PROBE_ATTEMPTS", "2")
-    monkeypatch.setenv("BENCH_PROBE_TIMEOUT", "60")
-    assert bench_mod._probe_knobs() == (2, 60)
-
-    # cached wedge verdict short-circuits without spawning a probe
-    monkeypatch.setenv("BENCH_PROBE_WEDGED", "probe hung >120s")
-    import time as _time
-
-    t0 = _time.monotonic()
-    r = bench_mod._probe_backend(attempts=3, probe_timeout=120)
-    assert _time.monotonic() - t0 < 1.0, "cached verdict still probed"
-    assert not r["ok"] and "cached wedged verdict" in r["error"]
-    # the recovery re-probe bypasses the cache (and, here, succeeds on
-    # CPU — which must clear the verdict)
-    monkeypatch.setenv("HOROVOD_PLATFORM", "cpu")
-    r = bench_mod._probe_backend(attempts=1, probe_timeout=120,
-                                 ignore_cache=True)
-    assert r["ok"], r
-    assert "BENCH_PROBE_WEDGED" not in os.environ
-
-
-def test_probe_hang_sets_wedged_cache(monkeypatch):
-    """Two consecutive probe hangs record the wedged verdict in the
-    process env so every later probe in this run is bounded."""
-    import subprocess as _sp
-
-    monkeypatch.delenv("BENCH_PROBE_WEDGED", raising=False)
-
-    def fake_run(*a, **kw):
-        raise _sp.TimeoutExpired(cmd="probe", timeout=kw.get("timeout"))
-
-    monkeypatch.setattr(bench_mod.subprocess, "run", fake_run)
-    monkeypatch.setattr(bench_mod.time, "sleep", lambda s: None)
-    r = bench_mod._probe_backend(attempts=3, probe_timeout=1)
-    assert not r["ok"]
-    assert "wedged" in os.environ.get("BENCH_PROBE_WEDGED", "")
-    # wedge forensics ride the verdict: phase + timeout + libtpu flags
-    # land in the result and the cached env, so a BENCH artifact can
-    # say WHERE the probe wedged instead of a bare "hung >180s"
-    assert r["probe"]["phase"] == "unknown"  # fake run: no phase file
-    assert r["probe"]["timeout_s"] == 1
-    assert "libtpu_args" in r["probe"]
-    cached_info = json.loads(os.environ["BENCH_PROBE_WEDGED_INFO"])
-    assert cached_info["phase"] == "unknown"
-    cached = bench_mod._probe_backend(attempts=3, probe_timeout=1)
-    assert cached["probe"]["timeout_s"] == 1
-    monkeypatch.delenv("BENCH_PROBE_WEDGED")
-    monkeypatch.delenv("BENCH_PROBE_WEDGED_INFO")
-
-
-def test_probe_phase_file_names_wedge_location(tmp_path, monkeypatch):
-    """A real (unpatched) probe that times out reports the last phase
-    the child stamped before the clock ran out, plus its timestamp —
-    the diagnostics ROADMAP item 6 needs to debug a wedged PJRT init."""
-    monkeypatch.delenv("BENCH_PROBE_WEDGED", raising=False)
-    monkeypatch.delenv("BENCH_PROBE_WEDGED_INFO", raising=False)
-    monkeypatch.setenv("HOROVOD_PLATFORM", "cpu")
-    # a fraction of a second: the child cannot finish importing jax, so
-    # the probe times out in 'start' or 'import_jax'
-    r = bench_mod._probe_backend(attempts=1, probe_timeout=1)
-    try:
-        # A hot page cache can import jax and finish the whole probe
-        # inside 1 s — that environment cannot produce the wedge this
-        # test diagnoses, so the timeout-path assertions apply only
-        # when the probe actually timed out (the phase-file parsing
-        # half below runs either way).
-        if not r.get("ok"):
-            assert r["probe"]["phase"] in ("start", "import_jax",
-                                           "unknown")
-            assert "in phase" in r["error"]
-            if r["probe"]["phase"] != "unknown":
-                # the child ran the flight recorder: its ring rides
-                # the wedge verdict (last events before the hang)
-                events = r["probe"].get("events") or []
-                assert any(e.get("kind") == "probe"
-                           for e in events), events
-    finally:
-        os.environ.pop("BENCH_PROBE_WEDGED", None)
-        os.environ.pop("BENCH_PROBE_WEDGED_INFO", None)
-    # phase-file parsing itself: legacy text form, the flight-ring JSON
-    # form the child writes now, and a never-materialized file
-    p = tmp_path / "phase"
-    p.write_text("pjrt_init 12.3")
-    assert bench_mod._read_probe_phase(str(p)) == ("pjrt_init", 12.3, [])
-    p.write_text(json.dumps({
-        "phase": "pjrt_init", "elapsed": 5.0,
-        "events": [{"kind": "flag_export", "flag": "--x=1"}]}))
-    phase, elapsed, events = bench_mod._read_probe_phase(str(p))
-    assert (phase, elapsed) == ("pjrt_init", 5.0)
-    assert events[0]["kind"] == "flag_export"
-    assert bench_mod._read_probe_phase(str(tmp_path / "nope")) == (
-        "unknown", None, [])
-
-
 def test_overlap_flags_export_env(monkeypatch):
     """--overlap / --overlap-chunks export the HOROVOD_* env for every
-    section child and spawned rank."""
+    spawned rank."""
     args = bench_mod._parse_args(["--overlap", "--overlap-chunks", "6"])
     assert args.overlap is True and args.overlap_chunks == 6
     args = bench_mod._parse_args([])
@@ -382,115 +255,3 @@ def test_zero_stage_cli(monkeypatch):
     assert args.zero_stage == 3 and args.zero_prefetch_chunks == 8
     args = bench_mod._parse_args([])
     assert args.zero_stage is None and args.zero_prefetch_chunks is None
-
-
-def test_probe_pjrt_wedge_retries_with_stripped_overlap_flags(
-        monkeypatch):
-    """Probe unblocker (ROADMAP item 6): a hang exactly at pjrt_init
-    with the PR 5 overlap libtpu flags staged triggers ONE retry with
-    them stripped; when the stripped probe succeeds the verdict names
-    the culprit flag set in the probe forensics and the run proceeds
-    without the wedging flags."""
-    import subprocess as _sp
-
-    monkeypatch.delenv("BENCH_PROBE_WEDGED", raising=False)
-    monkeypatch.delenv("BENCH_PROBE_WEDGED_INFO", raising=False)
-    staged = ("--foo=1 --xla_tpu_enable_latency_hiding_scheduler=true "
-              "--xla_tpu_enable_async_collective_permute=true")
-    monkeypatch.setenv("LIBTPU_INIT_ARGS", staged)
-    calls = []
-
-    def fake_run(cmd, **kw):
-        env = kw.get("env")
-        flags = (env or os.environ).get("LIBTPU_INIT_ARGS", "")
-        calls.append(flags)
-        if "latency_hiding" in flags:
-            # staged flags wedge libtpu init: stamp the phase the real
-            # child would have reached, then hang (argv is
-            # [..., phase_path, flight_module_path])
-            with open(cmd[-2], "w") as f:
-                f.write("pjrt_init 5.0")
-            raise _sp.TimeoutExpired(cmd="probe",
-                                     timeout=kw.get("timeout"))
-
-        class R:
-            returncode = 0
-            stdout = "8|tpu|FakeChip v9\n"
-            stderr = ""
-
-        return R()
-
-    monkeypatch.setattr(bench_mod.subprocess, "run", fake_run)
-    monkeypatch.setattr(bench_mod.time, "sleep", lambda s: None)
-    r = bench_mod._probe_backend(attempts=3, probe_timeout=1)
-    assert r["ok"], r
-    assert len(calls) == 2  # staged hang + exactly one stripped retry
-    assert "latency_hiding" not in calls[1]
-    assert r["probe"]["flag_set_succeeded"] == "stripped"
-    assert r["probe"]["flag_retry"] == "stripped"
-    assert r["probe"]["phase"] == "pjrt_init"
-    # the run itself proceeds without the wedging flags
-    assert "latency_hiding" not in os.environ["LIBTPU_INIT_ARGS"]
-    assert "--foo=1" in os.environ["LIBTPU_INIT_ARGS"]
-    assert "BENCH_PROBE_WEDGED" not in os.environ
-
-
-def test_probe_pjrt_wedge_stripped_also_hangs_names_neither(
-        monkeypatch):
-    """Both flag sets hang: the verdict records flag_set_succeeded=none
-    and the wedged cache engages as before (no infinite retries)."""
-    import subprocess as _sp
-
-    monkeypatch.delenv("BENCH_PROBE_WEDGED", raising=False)
-    monkeypatch.delenv("BENCH_PROBE_WEDGED_INFO", raising=False)
-    monkeypatch.setenv(
-        "LIBTPU_INIT_ARGS",
-        "--xla_tpu_enable_latency_hiding_scheduler=true")
-    calls = []
-
-    def fake_run(cmd, **kw):
-        calls.append(1)
-        with open(cmd[-2], "w") as f:
-            f.write("pjrt_init 5.0")
-        raise _sp.TimeoutExpired(cmd="probe", timeout=kw.get("timeout"))
-
-    monkeypatch.setattr(bench_mod.subprocess, "run", fake_run)
-    monkeypatch.setattr(bench_mod.time, "sleep", lambda s: None)
-    try:
-        r = bench_mod._probe_backend(attempts=4, probe_timeout=1)
-        assert not r["ok"]
-        assert r["probe"]["flag_set_succeeded"] == "none"
-        assert len(calls) == 2  # staged + stripped, then wedged verdict
-        assert "BENCH_PROBE_WEDGED" in os.environ
-    finally:
-        os.environ.pop("BENCH_PROBE_WEDGED", None)
-        os.environ.pop("BENCH_PROBE_WEDGED_INFO", None)
-
-
-def test_section_filter_respects_models_and_skip_side(monkeypatch):
-    """BENCH_MODELS / BENCH_SKIP_SIDE keep their pre-orchestrator
-    meaning when mapped onto sections."""
-    monkeypatch.delenv("BENCH_SECTIONS", raising=False)
-    monkeypatch.setenv("BENCH_MODELS", "resnet50")
-    monkeypatch.setenv("BENCH_SKIP_SIDE", "1")
-    assert [s[0] for s in bench_mod._section_filter()] == ["resnet50"]
-
-    monkeypatch.setenv("BENCH_SKIP_SIDE", "0")
-    names = [s[0] for s in bench_mod._section_filter()]
-    assert "resnet50" in names and "eager" in names
-    assert "vgg16" not in names
-
-    monkeypatch.delenv("BENCH_MODELS")
-    monkeypatch.setenv("BENCH_SKIP_SIDE", "1")
-    assert [s[0] for s in bench_mod._section_filter()] == [
-        "resnet50", "vgg16", "inception3"]
-
-    # a models filter that matches nothing must NOT mean "all"
-    monkeypatch.setenv("BENCH_MODELS", "resnet")  # typo
-    assert bench_mod._section_filter() == []
-    monkeypatch.setenv("BENCH_MODELS", "none")   # explicit nothing
-    assert bench_mod._section_filter() == []
-
-    monkeypatch.delenv("BENCH_MODELS")
-    monkeypatch.delenv("BENCH_SKIP_SIDE")
-    assert len(bench_mod._section_filter()) == 6

@@ -604,7 +604,6 @@ def test_bench_partial_run_keeps_goodput_ledger(tmp_path):
             # model step would ride out the slow gloo deadline instead)
             "BENCH_MODELS": "none",
             "BENCH_EAGER": "1",
-            "BENCH_PROBE_ATTEMPTS": "1",
             # round1: rank 1 dies at the first DATA round, after the
             # round-0 handshake completed — plain die:rank1 could fire
             # before rank 1 ever published a heartbeat, leaving rank 0

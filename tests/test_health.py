@@ -25,7 +25,7 @@ import numpy as np
 import optax
 import pytest
 
-import horovod_tpu as hvd  # noqa: F401  (jax_compat bridge first)
+import horovod_tpu as hvd  # noqa: F401
 import jax
 import jax.numpy as jnp
 from jax import shard_map

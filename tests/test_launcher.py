@@ -345,10 +345,11 @@ def test_allocate_heterogeneous_sets_flag():
 
     slots = allocate([("a", 3), ("b", 2), ("c", 1)], 6)
     assert all(not s.homogeneous for s in slots)
-    env = _rank_env(slots[3], "localhost:1", "", 0, {})
+    cpu = {"HOROVOD_PLATFORM": "cpu"}
+    env = _rank_env(slots[3], "localhost:1", "", 0, cpu)
     assert env["HOROVOD_IS_HOMOGENEOUS"] == "0"
 
     slots = allocate([("a", 2), ("b", 2)], 4)
     assert all(s.homogeneous for s in slots)
     assert _rank_env(slots[0], "localhost:1", "", 0,
-                     {})["HOROVOD_IS_HOMOGENEOUS"] == "1"
+                     cpu)["HOROVOD_IS_HOMOGENEOUS"] == "1"

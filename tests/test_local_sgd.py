@@ -29,7 +29,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-import horovod_tpu as hvd  # noqa: F401  (installs the jax_compat shim)
+import horovod_tpu as hvd  # noqa: F401
 
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P

@@ -156,20 +156,20 @@ def test_impl_validation():
             in_specs=(P(None, "sp"),) * 3, out_specs=P(None, "sp")))(q, k, v)
 
 
-def test_unaligned_chunk_falls_back_to_xla():
-    """lc=12 has no MXU-aligned divisor; impl='pallas' must silently
-    use the XLA step and stay correct."""
+def test_unaligned_chunk_raises_when_pallas_asked():
+    """lc=12 has no tile the kernel can use: an explicit impl='pallas'
+    raises instead of quietly running the XLA step under the kernel's
+    name (the automatic pick logs once and uses XLA)."""
     sp = 4
     mesh = Mesh(np.array(jax.devices()[:sp]), ("sp",))
     q, k, v = _qkv(4, l=48)  # lc = 12
-    expected = reference_attention(q, k, v, causal=True)
     fn = jax.jit(shard_map(
         lambda a, b_, c: ring_attention(a, b_, c, "sp", causal=True,
                                         impl="pallas"),
         mesh=mesh, check_vma=False,
         in_specs=(P(None, "sp"),) * 3, out_specs=P(None, "sp")))
-    np.testing.assert_allclose(np.asarray(fn(q, k, v)),
-                               np.asarray(expected), rtol=2e-4, atol=2e-5)
+    with pytest.raises(ValueError, match="no tile size"):
+        fn(q, k, v)
 
 
 def test_grad_through_pallas_ring():
