@@ -23,6 +23,7 @@ from horovod_tpu.common import config as _config
 from horovod_tpu.common import logging as _log
 from horovod_tpu.common.platform import ensure_platform
 from horovod_tpu.common.types import HorovodTpuError
+from horovod_tpu.runtime import flight as _flight
 
 
 class _State:
@@ -89,239 +90,268 @@ def init(comm=None, mesh=None) -> None:
     Multi-process wiring: if ``HOROVOD_SIZE`` > 1 (exported by the
     launcher), connects to the jax.distributed coordinator at
     ``HOROVOD_COORDINATOR_ADDR`` so every chip joins one XLA runtime.
+
+    Each phase runs under a flight-recorder span, ``hvd_init`` around
+    ``hvd_init.distributed`` / ``.backend`` / ``.topology`` /
+    ``.meshes`` / ``.planes`` / ``.runtime`` (docs/flight-recorder.md):
+    where a slow start went is in every rank's ring.
     """
     if comm not in (None, 0):
         raise HorovodTpuError(
             "init(comm=...) with a rank subset is not supported on TPU; "
             "the device mesh is global.")
-    with _state.lock:
-        if _state.initialized:
-            return
-        # Goodput ledger (docs/goodput.md): the wall clock starts at
-        # the first init() and the bring-up wall lands in the "init"
-        # phase; a re-init (elastic re-form) adds its own init span to
-        # the same run-long ledger.  Advisory: observability must never
-        # fail init.
-        import time as _time
+    if _state.initialized:   # a repeated call is no bring-up: no span
+        return
+    with _flight.span("hvd_init"):
+        with _state.lock:
+            if _state.initialized:
+                return
+            _init_locked(mesh)
+        if _state.size > 1:
+            # Spawn the background runtime now, like the reference's
+            # InitializeHorovodOnce (operations.cc:604-650) — NOT lazily
+            # on first enqueue: every rank must participate in
+            # negotiation rounds from the start or the coordinator
+            # blocks mid-round on a rank that simply hasn't submitted
+            # anything yet, and the stall inspector can never observe
+            # the hold-out.
+            from horovod_tpu.ops import eager as _eager
 
-        _t_init_gp = _time.monotonic()
-        try:
-            from horovod_tpu.perf import goodput as _goodput
+            with _flight.span("hvd_init.runtime"):
+                _eager._runtime()
 
-            _goodput.start()
-        except Exception:
-            _goodput = None
-        ensure_platform()
-        import jax
 
-        env_size = int(os.environ.get("HOROVOD_SIZE", "1"))
-        env_rank = int(os.environ.get("HOROVOD_RANK", "0"))
-        pod_auto = False
-        if ("HOROVOD_SIZE" not in os.environ
-                and "HOROVOD_RANK" not in os.environ):
-            # TPU-pod orchestrator (no launcher): rank/size/coordinator
-            # from pod metadata env — the LSF/jsrun-introspection analog
-            # (reference run/util/lsf.py).  An explicitly exported
-            # HOROVOD_SIZE (even =1, a forced single-process debug run)
-            # suppresses auto-detection.
-            from horovod_tpu.run import pod as _pod
+def _init_locked(mesh) -> None:
+    """``init()`` under ``_state.lock``, not yet initialized."""
+    # Goodput ledger (docs/goodput.md): the wall clock starts at the
+    # first init() and the bring-up wall lands in the "init" phase; a
+    # re-init (elastic re-form) adds its own init span to the same
+    # run-long ledger.  Advisory: observability must never fail init.
+    import time as _time
 
-            info = _pod.detect()
-            if info is not None and info.auto:
-                # multislice topology: jax's own cluster resolution
-                # understands it natively; hand off below.
-                pod_auto = True
-                _log.info(f"pod metadata ({info.source}): deferring "
-                          "topology to jax.distributed auto-detect")
-            elif info is not None and info.size > 1:
-                env_size, env_rank = info.size, info.rank
-                os.environ.setdefault("HOROVOD_COORDINATOR_ADDR",
-                                      info.coordinator)
-                # export like the launcher would: rank-tagged logging
-                # and child tools read these
-                os.environ["HOROVOD_RANK"] = str(info.rank)
-                os.environ["HOROVOD_SIZE"] = str(info.size)
-                _log.info(f"pod metadata ({info.source}): rank="
-                          f"{info.rank} size={info.size}", rank=info.rank)
-        # NB: must not touch the backend (jax.devices/process_count)
-        # before jax.distributed.initialize — probe the distributed
-        # client state instead.
-        from jax._src import distributed as _jd
+    _t_init_gp = _time.monotonic()
+    try:
+        from horovod_tpu.perf import goodput as _goodput
 
-        if (env_size > 1 or pod_auto) and _jd.global_state.client is None:
-            # Tight failure-detection timeouts: with jax's defaults
-            # (heartbeat 100s, shutdown barrier 300s) a crashed peer
-            # stalls the job for minutes; the reference's launcher kills
-            # the whole job as soon as one rank dies
-            # (gloo_run.py:294-304) and these knobs make that prompt.
-            # When the control-plane liveness layer is on (its own
-            # hb/<epoch>/<rank> heartbeats + coordinated abort,
-            # docs/fault-tolerance.md), it must win the race to report
-            # a dead peer — jax's service detection QFATALs the
-            # survivors with an undiagnosable abort.  Keep the service
-            # as a loose backstop (3x) in that case; with liveness
-            # disabled it stays the primary detector.
-            hb = max(int(_config.get("heartbeat_timeout")), 1)
-            if float(_config.get("heartbeat_interval")) > 0:
-                hb = max(hb * 3, 30)
-            kwargs = {
-                "heartbeat_timeout_seconds": hb,
-                "shutdown_timeout_seconds": int(
-                    _config.get("shutdown_timeout")),
-            }
-            if pod_auto:
-                jax.distributed.initialize(**kwargs)
-            elif _config.get("elastic"):
-                # Elastic mode builds the distributed runtime by hand:
-                # jax.distributed.initialize's client has no bounded
-                # shutdown (a re-form around a dead peer would hang in
-                # its 60 s barrier and leave the error-poll thread
-                # alive to QFATAL the survivor later).
-                coord = _config.get("coordinator_addr")
-                if not coord:
-                    raise HorovodTpuError(
-                        "HOROVOD_SIZE > 1 but HOROVOD_COORDINATOR_ADDR "
-                        "is not set (the launcher exports it).")
-                _elastic_distributed_init(coord, env_size, env_rank)
-            else:
-                coord = _config.get("coordinator_addr")
-                if not coord:
-                    raise HorovodTpuError(
-                        "HOROVOD_SIZE > 1 but HOROVOD_COORDINATOR_ADDR "
-                        "is not set (the launcher exports it).")
-                jax.distributed.initialize(
-                    coordinator_address=coord,
-                    num_processes=env_size,
-                    process_id=env_rank,
-                    **kwargs)
+        _goodput.start()
+    except Exception:
+        _goodput = None
+    ensure_platform()
+    import jax
 
+    env_size = int(os.environ.get("HOROVOD_SIZE", "1"))
+    env_rank = int(os.environ.get("HOROVOD_RANK", "0"))
+    pod_auto = False
+    if ("HOROVOD_SIZE" not in os.environ
+            and "HOROVOD_RANK" not in os.environ):
+        # TPU-pod orchestrator (no launcher): rank/size/coordinator
+        # from pod metadata env — the LSF/jsrun-introspection analog
+        # (reference run/util/lsf.py).  An explicitly exported
+        # HOROVOD_SIZE (even =1, a forced single-process debug run)
+        # suppresses auto-detection.
+        from horovod_tpu.run import pod as _pod
+
+        info = _pod.detect()
+        if info is not None and info.auto:
+            # multislice topology: jax's own cluster resolution
+            # understands it natively; hand off below.
+            pod_auto = True
+            _log.info(f"pod metadata ({info.source}): deferring "
+                      "topology to jax.distributed auto-detect")
+        elif info is not None and info.size > 1:
+            env_size, env_rank = info.size, info.rank
+            os.environ.setdefault("HOROVOD_COORDINATOR_ADDR",
+                                  info.coordinator)
+            # export like the launcher would: rank-tagged logging
+            # and child tools read these
+            os.environ["HOROVOD_RANK"] = str(info.rank)
+            os.environ["HOROVOD_SIZE"] = str(info.size)
+            _log.info(f"pod metadata ({info.source}): rank="
+                      f"{info.rank} size={info.size}", rank=info.rank)
+    # NB: must not touch the backend (jax.devices/process_count)
+    # before jax.distributed.initialize — probe the distributed
+    # client state instead.
+    from jax._src import distributed as _jd
+
+    if (env_size > 1 or pod_auto) and _jd.global_state.client is None:
+        with _flight.span("hvd_init.distributed"):
+            _connect_distributed(env_size, env_rank, pod_auto)
+
+    with _flight.span("hvd_init.backend"):   # the first backend call
         _state.size = jax.process_count()
-        if pod_auto:
-            _state.rank = jax.process_index()
-            os.environ["HOROVOD_RANK"] = str(_state.rank)
-            os.environ["HOROVOD_SIZE"] = str(_state.size)
-        elif env_size > 1:
-            if _state.size != env_size:
-                raise HorovodTpuError(
-                    f"Launcher env size ({env_size}) disagrees with the "
-                    f"XLA runtime ({_state.size} processes).")
-            # The launcher's numbering is the job's.  jax's own process
-            # index need not equal it: a TPU backend numbers processes
-            # by where their chips sit, whatever process_id
-            # jax.distributed was given (docs/launcher.md), so the world
-            # mesh is ordered by rank explicitly (_build_meshes).
-            _state.rank = env_rank
-        else:
-            _state.rank = jax.process_index()
+    if pod_auto:
+        _state.rank = jax.process_index()
+        os.environ["HOROVOD_RANK"] = str(_state.rank)
+        os.environ["HOROVOD_SIZE"] = str(_state.size)
+    elif env_size > 1:
+        if _state.size != env_size:
+            raise HorovodTpuError(
+                f"Launcher env size ({env_size}) disagrees with the "
+                f"XLA runtime ({_state.size} processes).")
+        # The launcher's numbering is the job's.  jax's own process
+        # index need not equal it: a TPU backend numbers processes
+        # by where their chips sit, whatever process_id
+        # jax.distributed was given (docs/launcher.md), so the world
+        # mesh is ordered by rank explicitly (_build_meshes).
+        _state.rank = env_rank
+    else:
+        _state.rank = jax.process_index()
 
-        _state.epoch += 1
+    _state.epoch += 1
+    with _flight.span("hvd_init.topology"):
         _compute_local_cross_topology()
+    with _flight.span("hvd_init.meshes"):
         _build_meshes()
         _apply_mesh_arg(mesh)
         _build_data_mesh()
-        _log_idle_devices()
-        # Device-side capture starts here, not in the background
-        # runtime: at size 1 that runtime is lazy, and a compiled-only
-        # training run would otherwise record nothing.
-        prof_dir = _config.get("jax_profiler")
-        if prof_dir:
-            from horovod_tpu.runtime.timeline import JaxProfilerBridge
+    _log_idle_devices()
+    with _flight.span("hvd_init.planes"):
+        _start_planes()
+    if _goodput is not None:
+        try:
+            _goodput.observe("init",
+                             _time.monotonic() - _t_init_gp)
+        except Exception:
+            pass
+    _state.initialized = True
+    _log.info(
+        "horovod_tpu initialized: rank=%d size=%d local_rank=%d "
+        "local_size=%d cross_rank=%d cross_size=%d platform=%s"
+        % (_state.rank, _state.size, _state.local_rank,
+           _state.local_size, _state.cross_rank, _state.cross_size,
+           _state.lead_device.platform), rank=_state.rank)
 
-            if _state.profiler is not None:
-                # A prior generation's bridge still holds the profiler
-                # (e.g. a teardown path that never ran): close it so the
-                # old capture lands and start_trace can't collide.
-                try:
-                    _state.profiler.close()
-                except Exception:
-                    pass
-                _state.profiler = None
-            # Generation is relative to the first time THIS process
-            # opened THIS logdir — epoch counts every init() in the
-            # process, so a plain shutdown()+init() against a fresh dir
-            # must still get the documented rank<k> layout; only a
-            # re-form over the same dir (where a prior generation's
-            # capture lives) moves to gen<g>/rank<k>.
-            base = _PROF_DIR_EPOCH0.setdefault(str(prof_dir),
-                                               _state.epoch)
+
+def _connect_distributed(env_size: int, env_rank: int,
+                         pod_auto: bool) -> None:
+    """Join the jax.distributed coordinator: every chip one XLA
+    runtime."""
+    import jax
+
+    # Tight failure-detection timeouts: with jax's defaults
+    # (heartbeat 100s, shutdown barrier 300s) a crashed peer
+    # stalls the job for minutes; the reference's launcher kills
+    # the whole job as soon as one rank dies
+    # (gloo_run.py:294-304) and these knobs make that prompt.
+    # When the control-plane liveness layer is on (its own
+    # hb/<epoch>/<rank> heartbeats + coordinated abort,
+    # docs/fault-tolerance.md), it must win the race to report
+    # a dead peer — jax's service detection QFATALs the
+    # survivors with an undiagnosable abort.  Keep the service
+    # as a loose backstop (3x) in that case; with liveness
+    # disabled it stays the primary detector.
+    hb = max(int(_config.get("heartbeat_timeout")), 1)
+    if float(_config.get("heartbeat_interval")) > 0:
+        hb = max(hb * 3, 30)
+    kwargs = {
+        "heartbeat_timeout_seconds": hb,
+        "shutdown_timeout_seconds": int(
+            _config.get("shutdown_timeout")),
+    }
+    if pod_auto:
+        jax.distributed.initialize(**kwargs)
+        return
+    coord = _config.get("coordinator_addr")
+    if not coord:
+        raise HorovodTpuError(
+            "HOROVOD_SIZE > 1 but HOROVOD_COORDINATOR_ADDR "
+            "is not set (the launcher exports it).")
+    if _config.get("elastic"):
+        # Elastic mode builds the distributed runtime by hand:
+        # jax.distributed.initialize's client has no bounded
+        # shutdown (a re-form around a dead peer would hang in
+        # its 60 s barrier and leave the error-poll thread
+        # alive to QFATAL the survivor later).
+        _elastic_distributed_init(coord, env_size, env_rank)
+    else:
+        jax.distributed.initialize(
+            coordinator_address=coord,
+            num_processes=env_size,
+            process_id=env_rank,
+            **kwargs)
+
+
+def _start_planes() -> None:
+    """The observability planes of this generation: profiler bridge,
+    metrics endpoint and publisher, flight handlers, the AOT cache's
+    announcement."""
+    # Device-side capture starts here, not in the background
+    # runtime: at size 1 that runtime is lazy, and a compiled-only
+    # training run would otherwise record nothing.
+    prof_dir = _config.get("jax_profiler")
+    if prof_dir:
+        from horovod_tpu.runtime.timeline import JaxProfilerBridge
+
+        if _state.profiler is not None:
+            # A prior generation's bridge still holds the profiler
+            # (e.g. a teardown path that never ran): close it so the
+            # old capture lands and start_trace can't collide.
             try:
-                _state.profiler = JaxProfilerBridge(
-                    prof_dir, _state.rank,
-                    generation=_state.epoch - base + 1)
-            except Exception as exc:  # capture is advisory, never fatal
-                _log.warning(f"jax profiler capture unavailable: {exc!r}")
-        # Metrics plane (docs/metrics.md): topology gauges always; the
-        # per-rank HTTP endpoint only when HOROVOD_METRICS_PORT is set.
-        # An elastic re-form re-enters init() with a new rank/epoch, so
-        # the endpoint follows the rank to its new port and the gauges
-        # reflect the new generation.
-        from horovod_tpu.runtime import metrics as _metrics
-
-        _metrics.gauge(
-            "hvd_world_size", "Current world size.").set(_state.size)
-        _metrics.gauge(
-            "hvd_generation",
-            "Communicator generation (KV epoch; bumps on every "
-            "elastic re-form).").set(_state.epoch)
-        if _state.metrics_server is not None:
-            _state.metrics_server.close()
-        _state.metrics_server = _metrics.start_rank_endpoint(_state.rank)
-        # KV snapshot publisher for the launcher's fleet aggregate —
-        # controller-independent so a size-1 elastic survivor (whose
-        # LocalController has no transport) still reports its
-        # generation/size to the launcher.
-        if _state.metrics_publisher is not None:
-            _state.metrics_publisher.stop()
-        _state.metrics_publisher = _metrics.maybe_start_kv_publisher(
-            _state.rank, _state.size, _state.epoch)
-        # Flight recorder (docs/flight-recorder.md): lifecycle event +
-        # fatal-signal dump handlers (SIGTERM/SIGABRT), so a killed or
-        # aborting rank leaves its event ring in HOROVOD_FLIGHT_DIR.
-        # Installed here (main thread at first init); an elastic
-        # re-init from a worker thread is a no-op.
-        from horovod_tpu.runtime import flight as _flight
-
-        _flight.install_signal_handlers()
-        _flight.record("init", rank=_state.rank, size=_state.size,
-                       generation=_state.epoch)
-        # Persistent AOT executable cache (docs/aot-cache.md): nothing
-        # to open — entries are keyed per program on demand — but the
-        # operator should see where warm starts will come from, and a
-        # re-init (elastic re-form) must announce under the NEW
-        # topology (the key context includes world size, so the old
-        # generation's entries simply stop matching).
-        from horovod_tpu.runtime import aot_cache as _aot
-
-        if _aot.enabled():
-            _log.info(
-                f"aot-cache: {_aot.cache_dir()} (mode={_aot.mode()}) — "
-                "negotiated programs will load from cache when keys "
-                "match", rank=_state.rank)
-            _flight.record("aot", event="enabled", dir=_aot.cache_dir(),
-                           mode=_aot.mode())
-        if _goodput is not None:
-            try:
-                _goodput.observe("init",
-                                 _time.monotonic() - _t_init_gp)
+                _state.profiler.close()
             except Exception:
                 pass
-        _state.initialized = True
-        _log.info(
-            "horovod_tpu initialized: rank=%d size=%d local_rank=%d "
-            "local_size=%d cross_rank=%d cross_size=%d platform=%s"
-            % (_state.rank, _state.size, _state.local_rank,
-               _state.local_size, _state.cross_rank, _state.cross_size,
-               _state.lead_device.platform), rank=_state.rank)
-    if _state.size > 1:
-        # Spawn the background runtime now, like the reference's
-        # InitializeHorovodOnce (operations.cc:604-650) — NOT lazily on
-        # first enqueue: every rank must participate in negotiation
-        # rounds from the start or the coordinator blocks mid-round on
-        # a rank that simply hasn't submitted anything yet, and the
-        # stall inspector can never observe the hold-out.
-        from horovod_tpu.ops import eager as _eager
+            _state.profiler = None
+        # Generation is relative to the first time THIS process
+        # opened THIS logdir — epoch counts every init() in the
+        # process, so a plain shutdown()+init() against a fresh dir
+        # must still get the documented rank<k> layout; only a
+        # re-form over the same dir (where a prior generation's
+        # capture lives) moves to gen<g>/rank<k>.
+        base = _PROF_DIR_EPOCH0.setdefault(str(prof_dir),
+                                           _state.epoch)
+        try:
+            _state.profiler = JaxProfilerBridge(
+                prof_dir, _state.rank,
+                generation=_state.epoch - base + 1)
+        except Exception as exc:  # capture is advisory, never fatal
+            _log.warning(f"jax profiler capture unavailable: {exc!r}")
+    # Metrics plane (docs/metrics.md): topology gauges always; the
+    # per-rank HTTP endpoint only when HOROVOD_METRICS_PORT is set.
+    # An elastic re-form re-enters init() with a new rank/epoch, so
+    # the endpoint follows the rank to its new port and the gauges
+    # reflect the new generation.
+    from horovod_tpu.runtime import metrics as _metrics
 
-        _eager._runtime()
+    _metrics.gauge(
+        "hvd_world_size", "Current world size.").set(_state.size)
+    _metrics.gauge(
+        "hvd_generation",
+        "Communicator generation (KV epoch; bumps on every "
+        "elastic re-form).").set(_state.epoch)
+    if _state.metrics_server is not None:
+        _state.metrics_server.close()
+    _state.metrics_server = _metrics.start_rank_endpoint(_state.rank)
+    # KV snapshot publisher for the launcher's fleet aggregate —
+    # controller-independent so a size-1 elastic survivor (whose
+    # LocalController has no transport) still reports its
+    # generation/size to the launcher.
+    if _state.metrics_publisher is not None:
+        _state.metrics_publisher.stop()
+    _state.metrics_publisher = _metrics.maybe_start_kv_publisher(
+        _state.rank, _state.size, _state.epoch)
+    # Flight recorder (docs/flight-recorder.md): lifecycle event +
+    # fatal-signal dump handlers (SIGTERM/SIGABRT), so a killed or
+    # aborting rank leaves its event ring in HOROVOD_FLIGHT_DIR.
+    # Installed here (main thread at first init); an elastic
+    # re-init from a worker thread is a no-op.
+    _flight.install_signal_handlers()
+    _flight.record("init", rank=_state.rank, size=_state.size,
+                   generation=_state.epoch)
+    # Persistent AOT executable cache (docs/aot-cache.md): nothing
+    # to open — entries are keyed per program on demand — but the
+    # operator should see where warm starts will come from, and a
+    # re-init (elastic re-form) must announce under the NEW
+    # topology (the key context includes world size, so the old
+    # generation's entries simply stop matching).
+    from horovod_tpu.runtime import aot_cache as _aot
+
+    if _aot.enabled():
+        _log.info(
+            f"aot-cache: {_aot.cache_dir()} (mode={_aot.mode()}) — "
+            "negotiated programs will load from cache when keys "
+            "match", rank=_state.rank)
+        _flight.record("aot", event="enabled", dir=_aot.cache_dir(),
+                       mode=_aot.mode())
 
 
 def _compute_local_cross_topology() -> None:
@@ -412,7 +442,9 @@ def _build_meshes() -> None:
         by_proc.setdefault(d.process_index, []).append(d)
     # mesh position r holds rank r's lead device
     leads = []
-    for r, p in enumerate(_process_of_each_rank()):
+    with _flight.span("hvd_init.topology"):   # an exchange over the KV
+        processes = _process_of_each_rank()
+    for r, p in enumerate(processes):
         if p not in by_proc:
             raise HorovodTpuError(f"rank {r} (process {p}) exposes no "
                                   "devices")
@@ -646,8 +678,6 @@ def shutdown() -> None:
     with _state.lock:
         if not _state.initialized:
             return
-        from horovod_tpu.runtime import flight as _flight
-
         _flight.record("shutdown", rank=_state.rank,
                        generation=_state.epoch)
         # The goodput ledger's final accounting: a clean shutdown dumps

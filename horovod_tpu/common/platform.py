@@ -47,6 +47,17 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 
+# The version of the names the compiled programs carry: the ``hvd_*``
+# scopes and kernel names in every instruction's ``op_name``
+# (docs/perf.md).  They are metadata, which JAX keeps out of the cache
+# key, so the cache serves an executable compiled from a source with
+# other names, or none, and a profile of it shows those
+# (``jax_compilation_cache_include_metadata_in_key`` would key on every
+# file path and line number as well).  Raise it with a change to a
+# scope's or a kernel's name.
+NAMES_VERSION = "hvd-names-1"
+
+
 def ensure_compile_cache() -> str:
     """Place JAX's persistent compile cache; returns the directory.
 
@@ -54,7 +65,11 @@ def ensure_compile_cache() -> str:
     import, and no code path sets another.  Unset: ``<checkout>/.jax_cache``
     (a fixed place: a directory that moves between runs never hits),
     exported too so spawned ranks and child processes inherit it.  This
-    is the only place that names a compile cache path."""
+    is the only place that names a compile cache path.  Either way
+    ``NAMES_VERSION`` becomes part of every key."""
+    from jax._src import cache_key
+
+    cache_key.custom_hook = lambda: NAMES_VERSION
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
         path = os.path.join(_CHECKOUT, ".jax_cache")

@@ -164,7 +164,9 @@ def _block(cfg: TransformerConfig, lp, x, moe_params=None):
     qkv = (h.astype(cd) @ lp["wqkv"].astype(cd))
     qkv = qkv.reshape(b, lc, 3, nh_local, cfg.head_dim)
     q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-    attn = ring_attention(q, k, v, "sp", causal=True, impl=cfg.attn_impl)
+    with jax.named_scope("hvd_attn"):
+        attn = ring_attention(q, k, v, "sp", causal=True,
+                              impl=cfg.attn_impl)
     attn = attn.reshape(b, lc, nh_local * cfg.head_dim)
     proj = (attn.astype(cd) @ lp["wo"].astype(cd)).astype(jnp.float32)
     proj = reduce_from_tp(proj, "tp")  # Megatron "g": row-parallel reduce
@@ -256,7 +258,9 @@ def forward(params, tokens, cfg: TransformerConfig):
         aux = jnp.float32(0.0)
 
     x = _rmsnorm(x, params["ln_f"])
-    logits = (x.astype(cd) @ params["embed"].astype(cd).T).astype(jnp.float32)
+    with jax.named_scope("hvd_loss_head"):
+        logits = (x.astype(cd)
+                  @ params["embed"].astype(cd).T).astype(jnp.float32)
     return logits, aux
 
 
@@ -271,8 +275,10 @@ def loss_fn(params, tokens, targets, cfg: TransformerConfig):
     Report the global loss by psumming this value outside the grad.
     """
     logits, aux = forward(params, tokens, cfg)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+    with jax.named_scope("hvd_loss_head"):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, targets[..., None],
+                                   axis=-1)[..., 0]
     data_ranks = lax.axis_size("dp") * lax.axis_size("sp")
     global_tokens = jnp.float32(nll.size) * data_ranks
     return jnp.sum(nll) / global_tokens + 0.01 * aux / data_ranks
@@ -319,8 +325,9 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer,
             axes = grad_reduce_axes(spec)
             return lax.psum(g, axes) if axes else g
 
-        grads = tree_map_with_specs(reduce, grads, pspecs)
-        loss = lax.psum(local_loss, ("dp", "sp"))
+        with jax.named_scope("hvd_grad_reduce"):
+            grads = tree_map_with_specs(reduce, grads, pspecs)
+            loss = lax.psum(local_loss, ("dp", "sp"))
         return grads, loss.reshape(1)
 
     grad_fn = shard_map(per_device_grads, mesh=mesh, check_vma=False,
@@ -337,8 +344,9 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer,
         def one(carry, _):
             p, s = carry
             grads, loss = grad_fn(p, tokens, targets)
-            updates, s = optimizer.update(grads, s, p)
-            p = optax.apply_updates(p, updates)
+            with jax.named_scope("hvd_optimizer"):
+                updates, s = optimizer.update(grads, s, p)
+                p = optax.apply_updates(p, updates)
             return (p, s), loss[0]
 
         if steps_per_dispatch <= 1:

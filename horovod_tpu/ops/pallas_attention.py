@@ -116,6 +116,7 @@ def _flash_block_step_impl(q, k, v, m, l, o, q_offset, k_offset,
     grid = (bh, lq // bq, lk // bk)
     mlo, oo = pl.pallas_call(
         kernel,
+        name="hvd_flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),               # offsets
@@ -281,6 +282,7 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_offset, k_offset, *,
                                scale=scale, bq=bq, bk=bk)
     return pl.pallas_call(
         kernel,
+        name="hvd_flash_bwd_dq",
         grid=(bh, lq // bq, lk // bk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -323,6 +325,7 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, q_offset, k_offset, *,
                                scale=scale, bq=bq, bk=bk)
     return pl.pallas_call(
         kernel,
+        name="hvd_flash_bwd_dkv",
         grid=(bh, lk // bk, lq // bq),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

@@ -290,9 +290,12 @@ def allreduce_gradients(grads, op: int = Average,
     if not leaves:
         return grads
     if _in_trace(leaves):
-        reduced = _coll.grouped_allreduce(leaves, axis_name=axis_name,
-                                          op=op, compression=compression,
-                                          overlap=overlap)
+        # the collective and what is packed, cast and divided around it;
+        # a bucketed schedule's own scopes nest inside (docs/perf.md)
+        with jax.named_scope("hvd_grad_reduce"):
+            reduced = _coll.grouped_allreduce(
+                leaves, axis_name=axis_name, op=op,
+                compression=compression, overlap=overlap)
         return jax.tree_util.tree_unflatten(treedef, reduced)
     _check_eager_mesh()
     # Quantized wire on the eager path is knob-driven inside the
@@ -328,11 +331,12 @@ def allreduce_gradients_with_feedback(grads, residuals, op: int = Average,
         return (allreduce_gradients(grads, op=op, axis_name=axis_name,
                                     compression=compression),
                 residuals)
-    injected = _quant.apply_error_feedback(grads, residuals)
-    ileaves = jax.tree_util.tree_flatten(injected)[0]
-    outs, errs = _coll.grouped_quantized_allreduce(
-        ileaves, axis_name=axis_name, op=op, with_error=True,
-        overlap=overlap, mode=wire_mode(compression))
+    with jax.named_scope("hvd_grad_reduce"):
+        injected = _quant.apply_error_feedback(grads, residuals)
+        ileaves = jax.tree_util.tree_flatten(injected)[0]
+        outs, errs = _coll.grouped_quantized_allreduce(
+            ileaves, axis_name=axis_name, op=op, with_error=True,
+            overlap=overlap, mode=wire_mode(compression))
     return (jax.tree_util.tree_unflatten(treedef, outs),
             jax.tree_util.tree_unflatten(treedef, errs))
 
@@ -1591,6 +1595,14 @@ def DistributedOptimizer(optimizer, named_parameters=None,
             if res is None:
                 return _base_update(grads, state, params, **extra)
             return res
+
+    # The inner optimizer under its own name in the compiled step
+    # (docs/perf.md), on every path below: each calls it through this.
+    _inner_update = update_fn
+
+    def update_fn(grads, state, params=None, **extra):  # noqa: F811
+        with jax.named_scope("hvd_optimizer"):
+            return _inner_update(grads, state, params, **extra)
 
     # Observability (docs/metrics.md): record the resolved schedule so
     # hvd.metrics() shows what the optimizer actually runs with (the

@@ -54,6 +54,8 @@ _COMM_KINDS = (
     ("all-to-all", ("all-to-all", "alltoall", "all_to_all")),
 )
 
+_KINDS = frozenset(kind for kind, _ in _COMM_KINDS)
+
 # Framework scopes whose WORK is communication even when the individual
 # ops inside are slices/dynamic-updates around the wire op.
 _COMM_SCOPE = re.compile(
@@ -118,10 +120,11 @@ def _intersect(a: list, b: list) -> list:
 
 
 def _scope_of(op_name: str) -> str | None:
-    """First ``hvd_*`` component of a scoped op_name path, e.g.
+    """Last ``hvd_*`` component of a scoped op_name path, e.g.
     ``jit(f)/jit(main)/hvd_overlap_rs0/dot_general`` -> that bucket.
-    Nested scopes resolve to the outermost hvd component."""
-    for part in op_name.split("/"):
+    Nested scopes resolve to the innermost hvd component: a bucket
+    inside ``hvd_grad_reduce`` stays that bucket."""
+    for part in reversed(op_name.split("/")):
         if _HVD_SCOPE.match(part):
             return part
     return None
@@ -139,38 +142,76 @@ def _comm_kind(*names) -> str | None:
     return None
 
 
+_OPCODE = re.compile(r"([a-z][a-z0-9_\-]*)\(")
+
+
+def _name_and_opcode(text: str) -> tuple:
+    """``(instruction name, opcode)`` of an event's name.  A TPU device
+    plane names an event by the instruction's whole text
+    (``%fusion.7 = f32[8]{0} fusion(...), kind=kLoop``): the name is
+    its head, the opcode the first word before an opening bracket.  A
+    bare name (``fusion.7``, the CPU backend and the ``hlo_op`` stat)
+    has no opcode."""
+    head, found, rest = text.partition(" = ")
+    match = _OPCODE.search(rest) if found else None
+    return head.lstrip("%"), match.group(1) if match else None
+
+
 def _op_events(space: _xp.XSpace, scopes: dict):
     """Yield ``(event, scope, comm_kind)`` for every execution-looking
-    event: device-plane op lines, plus any event carrying an ``hlo_op``
-    stat (the CPU backend's executor threads live on the host plane).
+    event: the ``XLA Ops`` line of a device plane, plus any event
+    carrying an ``hlo_op`` stat (the CPU backend's executor threads live
+    on the host plane).  An asynchronous collective is one event, in
+    flight from the start of its ``-start`` to the end of the next
+    ``-done`` of its kind.
     """
     for plane in space.planes:
         on_device = plane.name.startswith("/device:")
         for line in plane.lines:
-            # Device planes carry derived bookkeeping lines whose rows
-            # restate the op timeline — counting them doubles everything.
-            if on_device and line.name in ("Steps", "XLA Modules",
-                                           "Framework Ops",
-                                           "Source", "Framework Name Scope"):
+            # A device plane's other lines (Steps, XLA Modules, Async
+            # XLA Ops, TC Overlay, ...) restate the op timeline —
+            # counting them doubles everything.
+            if on_device and line.name != "XLA Ops":
                 continue
-            for ev in line.events:
+            started: dict = {}      # kind -> the -start events in flight
+            for ev in sorted(line.events, key=lambda e: e.start_ps):
                 if ev.duration_ps <= 0:
                     continue
                 hlo_op = ev.stats.get("hlo_op")
                 if not on_device and not hlo_op:
                     continue
-                key = hlo_op if isinstance(hlo_op, str) else ev.name
-                if key.split(".")[0] in ("call", "while", "conditional"):
+                name, opcode = _name_and_opcode(ev.name)
+                key = hlo_op if isinstance(hlo_op, str) else name
+                if (opcode or key.split(".")[0]) in ("call", "while",
+                                                     "conditional"):
                     # whole-computation wrapper thunks: their span COVERS
                     # the inner ops (comm included) — counting them as
                     # compute would report every collective as "hidden"
                     continue
-                op_name = scopes.get(key) or scopes.get(ev.name) or ""
+                op_name = scopes.get(key) or scopes.get(name) or ""
                 scope = _scope_of(op_name)
-                tf_op = ev.stats.get("tf_op")
-                kind = _comm_kind(
-                    ev.name, key, op_name,
-                    tf_op if isinstance(tf_op, str) else None)
+                if opcode is None:
+                    tf_op = ev.stats.get("tf_op")
+                    kind = _comm_kind(
+                        ev.name, key, op_name,
+                        tf_op if isinstance(tf_op, str) else None)
+                else:
+                    # by opcode: a fusion that reads %all-reduce.1 is
+                    # no communication
+                    stem = opcode.removesuffix("-start") \
+                        .removesuffix("-done")
+                    kind = stem if stem in _KINDS else None
+                    if kind and opcode.endswith("-start"):
+                        started.setdefault(kind, []).append((ev, scope))
+                        continue
+                    if kind and opcode.endswith("-done"):
+                        if not started.get(kind):
+                            continue    # its -start is outside the capture
+                        first, scope = started[kind].pop(0)
+                        ev = _xp.XEvent(
+                            first.name, first.start_ps,
+                            ev.start_ps + ev.duration_ps - first.start_ps,
+                            first.stats)
                 yield ev, scope, kind
 
 

@@ -35,6 +35,7 @@ so a ring can record before a backend exists.
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import signal
@@ -181,6 +182,62 @@ def reset() -> None:
     global _recorder
     with _recorder_lock:
         _recorder = None
+
+
+_span_ids = itertools.count(1)    # next() is one bytecode: thread-safe
+_open_spans = threading.local()   # .ids: the spans open on this thread
+
+
+class span:
+    """``with flight.span("hvd_init.backend"): ...`` — a ``B``/``E``
+    pair of ``kind`` in the global ring.  Both events carry the span's
+    ``id``; ``B`` also carries ``parent``, the id of the span open
+    around it on this thread, when there is one.  ``fields`` go on both
+    events; what the body adds to ``.fields`` goes on ``E`` only (a
+    step's measured split).
+
+    The span is a profiler annotation too: once ``jax`` is imported it
+    holds a ``jax.profiler.TraceAnnotation(kind)`` open, so under a
+    running capture the same span lands on the device trace's clock.
+    ``annotation`` replaces that one (``hvd.trace_step`` passes its
+    ``StepTraceAnnotation``).  An exception in the body closes both and
+    passes through."""
+
+    __slots__ = ("kind", "fields", "id", "_annotation")
+
+    def __init__(self, kind: str, annotation=None, **fields):
+        self.kind, self.fields, self._annotation = kind, fields, annotation
+
+    def __enter__(self):
+        self.id = next(_span_ids)
+        try:
+            open_ids = _open_spans.ids
+        except AttributeError:
+            open_ids = _open_spans.ids = []
+        if open_ids:
+            record(self.kind, "B", id=self.id, parent=open_ids[-1],
+                   **self.fields)
+        else:
+            record(self.kind, "B", id=self.id, **self.fields)
+        open_ids.append(self.id)
+        if self._annotation is None and "jax" in sys.modules:
+            try:
+                self._annotation = sys.modules["jax"].profiler \
+                    .TraceAnnotation(self.kind)
+            except Exception:      # jax half imported: the ring suffices
+                pass
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        try:
+            _open_spans.ids.remove(self.id)
+        except (AttributeError, ValueError):   # closed on another thread
+            pass
+        record(self.kind, "E", id=self.id, **self.fields)
 
 
 def flight_dir() -> str:

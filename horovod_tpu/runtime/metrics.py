@@ -501,8 +501,6 @@ def trace_step(step: int | None = None, name: str = "hvd_step"):
     dwait0 = _DATA_WAIT.total()
     compile0 = _compile_total()
     _open_steps += 1
-    _flight.record("step", ph="B",
-                   step=int(step) if step is not None else -1)
     # Sampled device capture (docs/perf.md): every N-th span is
     # captured with the jax profiler and analyzed in the background
     # into hvd_device_*/hvd_mfu gauges.  Started BEFORE the step
@@ -516,35 +514,42 @@ def trace_step(step: int | None = None, name: str = "hvd_step"):
             cap = _capture.maybe_start(step)
     except Exception:
         cap = None
-    ann = None
     try:  # capture is advisory; jax may not be importable/ready
         import jax
 
         ann = (jax.profiler.StepTraceAnnotation(name, step_num=int(step))
                if step is not None else jax.profiler.TraceAnnotation(name))
-        ann.__enter__()
     except Exception:
         ann = None
+    # Flight-recorder step span: the per-step comm/compute/blocked
+    # split lands on the postmortem record too, so the trace analyzer
+    # can show where each rank's step time went.  The span holds the
+    # profiler annotation open and closes both BEFORE the capture
+    # teardown below: stopping a sampled capture fences the devices and
+    # serializes the xplane to disk (up to seconds on real captures) —
+    # folding that into `wall` would make every N-th step a systematic
+    # outlier in hvd_step_time_seconds and fail a profiled run's
+    # --compare gate on capture overhead instead of a real regression.
     try:
-        yield
-    finally:
-        if ann is not None:
+        with _flight.span("step", ann, step=int(step)
+                          if step is not None else -1) as sp:
             try:
-                ann.__exit__(None, None, None)
-            except Exception:
-                pass
-        # Clock the step BEFORE the capture teardown below: stopping a
-        # sampled capture fences the devices and serializes the xplane
-        # to disk (up to seconds on real captures) — folding that into
-        # `wall` would make every N-th step a systematic outlier in
-        # hvd_step_time_seconds and fail a profiled run's --compare
-        # gate on capture overhead instead of a real regression.
-        wall = time.perf_counter() - t0
-        _open_steps = max(0, _open_steps - 1)
-        blocked = min(max(0.0, _BLOCKED.total() - blocked0), wall)
-        comm = min(max(0.0, _COMM.total() - comm0), wall)
-        input_wait = min(max(0.0, _DATA_WAIT.total() - dwait0), wall)
-        compile_d = max(0.0, _compile_total() - compile0)
+                yield
+            finally:
+                wall = time.perf_counter() - t0
+                _open_steps = max(0, _open_steps - 1)
+                blocked = min(max(0.0, _BLOCKED.total() - blocked0), wall)
+                comm = min(max(0.0, _COMM.total() - comm0), wall)
+                input_wait = min(
+                    max(0.0, _DATA_WAIT.total() - dwait0), wall)
+                compile_d = max(0.0, _compile_total() - compile0)
+                compute = max(0.0, wall - blocked - input_wait)
+                sp.fields.update(wall_s=round(wall, 6),
+                                 compute_s=round(compute, 6),
+                                 comm_s=round(comm, 6),
+                                 blocked_s=round(blocked, 6),
+                                 input_wait_s=round(input_wait, 6))
+    finally:
         if cap is not None:
             try:
                 from horovod_tpu.perf import capture as _capture
@@ -552,7 +557,6 @@ def trace_step(step: int | None = None, name: str = "hvd_step"):
                 _capture.stop_and_analyze(cap)
             except Exception:
                 pass
-        compute = max(0.0, wall - blocked - input_wait)
         _STEP_HIST.observe(wall)
         _STEPS.inc()
         _PHASE.inc(compute, phase="compute")
@@ -598,16 +602,6 @@ def trace_step(step: int | None = None, name: str = "hvd_step"):
                 compile_s=compile_in, exposed_source=exposed_src)
         except Exception:
             pass
-        # Flight-recorder step span: the per-step comm/compute/blocked
-        # split lands on the postmortem record too, so the trace
-        # analyzer can show where each rank's step time went.
-        _flight.record("step", ph="E",
-                       step=int(step) if step is not None else -1,
-                       wall_s=round(wall, 6),
-                       compute_s=round(compute, 6),
-                       comm_s=round(comm, 6),
-                       blocked_s=round(blocked, 6),
-                       input_wait_s=round(input_wait, 6))
 
 
 # ---------------------------------------------------------------------------

@@ -68,6 +68,35 @@ def test_compile_cache_default_is_fixed_inside_the_checkout(tmp_path):
         assert banned not in want.replace(REPO, "")
 
 
+def test_compile_cache_key_holds_the_version_of_the_names(monkeypatch):
+    """The ``hvd_*`` scopes are metadata, which JAX keeps out of the
+    cache key: without their version in it the cache serves an
+    executable compiled from a source with other names, and a trace
+    reads those (PR 23: the parent's executables gave no scope at all).
+    With another version the same program has another key."""
+    import jax
+    import numpy as np
+    from jax._src import cache_key, compiler
+
+    from horovod_tpu.common import platform
+
+    platform.ensure_compile_cache()
+    assert cache_key.custom_hook() == platform.NAMES_VERSION
+
+    module = jax.jit(lambda x: x + 1).lower(1.0).compiler_ir("stablehlo")
+    devices = np.array(jax.devices()[:1])
+
+    def key() -> str:
+        return cache_key.get(module, devices,
+                             compiler.get_compile_options(1, 1),
+                             devices[0].client)
+
+    first = key()
+    assert key() == first
+    monkeypatch.setattr(platform, "NAMES_VERSION", "hvd-names-other")
+    assert key() != first
+
+
 def test_only_one_function_names_a_compile_cache_path():
     """The acceptance grep: nothing under the package, bench.py,
     chip_smoke.py or examples/ sets a compile cache path except

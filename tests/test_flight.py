@@ -147,6 +147,162 @@ def test_record_is_syscall_free_and_bounded():
 
 
 # ---------------------------------------------------------------------------
+# Spans
+# ---------------------------------------------------------------------------
+
+
+def test_span_records_a_pair_with_id_and_parent(monkeypatch):
+    """``B`` and ``E`` of the kind, the same id on both, the enclosing
+    span's id as ``parent`` on ``B``; the fields given on both and what
+    the body adds on ``E`` only; siblings share the parent."""
+    monkeypatch.setattr(flight, "_recorder", flight.FlightRecorder(32))
+    with flight.span("outer", rank=3) as outer:
+        with flight.span("first"):
+            pass
+        with flight.span("second") as second:
+            second.fields["n"] = 2
+        outer.fields["wall_s"] = 0.5
+    evs = [{k: v for k, v in e.items() if k not in ("seq", "mono", "wall")}
+           for e in flight.recorder().snapshot()]
+    a, b, c = outer.id, outer.id + 1, second.id
+    assert evs == [
+        {"kind": "outer", "ph": "B", "id": a, "rank": 3},
+        {"kind": "first", "ph": "B", "id": b, "parent": a},
+        {"kind": "first", "ph": "E", "id": b},
+        {"kind": "second", "ph": "B", "id": c, "parent": a},
+        {"kind": "second", "ph": "E", "id": c, "n": 2},
+        {"kind": "outer", "ph": "E", "id": a, "rank": 3, "wall_s": 0.5}]
+    assert len({a, b, c}) == 3
+    monos = [e["mono"] for e in flight.recorder().snapshot()]
+    assert monos == sorted(monos)
+
+
+def test_span_survives_an_exception_and_another_thread(monkeypatch):
+    """The pair closes when the body raises, the exception passes
+    through, and the next span on the thread has no stale parent; a
+    span on another thread is no child of this thread's."""
+    import threading
+
+    monkeypatch.setattr(flight, "_recorder", flight.FlightRecorder(32))
+    with pytest.raises(KeyError):
+        with flight.span("breaks"):
+            with flight.span("inner"):
+                raise KeyError("boom")
+    def elsewhere():
+        with flight.span("other"):
+            pass
+
+    with flight.span("outer"):
+        t = threading.Thread(target=elsewhere)
+        t.start()
+        t.join(10)
+        assert not t.is_alive()
+    evs = flight.recorder().snapshot()
+    assert [(e["kind"], e["ph"]) for e in evs] == [
+        ("breaks", "B"), ("inner", "B"), ("inner", "E"), ("breaks", "E"),
+        ("outer", "B"), ("other", "B"), ("other", "E"), ("outer", "E")]
+    assert "parent" not in evs[4] and "parent" not in evs[5]
+
+
+def test_span_costs_two_records_and_no_more(monkeypatch):
+    """Two ring writes a span, no syscall, and a bound in time of the
+    kind ``record()`` is held to."""
+    ring = flight.FlightRecorder(64)
+    monkeypatch.setattr(flight, "_recorder", ring)
+    t0 = time.perf_counter()
+    for i in range(10000):
+        with flight.span("hot", round=i):
+            pass
+    dt = time.perf_counter() - t0
+    assert ring.recorded_total() == 20000
+    assert len(ring._slots) == 64
+    assert dt < 5.0, f"span too slow: {dt:.2f}s for 10k spans"
+
+
+def test_span_is_a_profiler_annotation_once_jax_is_there(monkeypatch):
+    """With ``jax`` imported the span holds a ``TraceAnnotation`` of its
+    kind open (or the one it was given), so a running capture shows it
+    on the trace's clock."""
+    import jax
+
+    opened = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            opened.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            opened.append(("exit", self.name))
+
+    monkeypatch.setattr(flight, "_recorder", flight.FlightRecorder(8))
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    with flight.span("hvd_init.backend"):
+        with flight.span("step", Annotation("hvd_step")):
+            pass
+    assert opened == [("enter", "hvd_init.backend"), ("enter", "hvd_step"),
+                      ("exit", "hvd_step"), ("exit", "hvd_init.backend")]
+
+
+def test_flight_and_its_span_need_no_jax():
+    """The module alone, by its file: the standard library is enough to
+    import it and to record a span, and neither imports ``jax``."""
+    code = (
+        "import importlib.util, sys\n"
+        f"spec = importlib.util.spec_from_file_location('flight', "
+        f"{os.path.join(REPO, 'horovod_tpu', 'runtime', 'flight.py')!r})\n"
+        "flight = importlib.util.module_from_spec(spec)\n"
+        "spec.loader.exec_module(flight)\n"
+        "with flight.span('a'):\n"
+        "    pass\n"
+        "assert [e['ph'] for e in flight.recorder().snapshot()] "
+        "== ['B', 'E']\n"
+        "assert not {'jax', 'numpy', 'horovod_tpu'} & set(sys.modules)\n")
+    done = subprocess.run([sys.executable, "-S", "-c", code],
+                          capture_output=True, text=True, timeout=60)
+    assert done.returncode == 0, done.stderr
+
+
+def test_init_spans_its_phases(monkeypatch):
+    """After ``hvd.init()`` the ring holds ``hvd_init`` and, inside it
+    and in order, the spans of its phases (a world of one opens no
+    ``distributed`` and no ``runtime``); a second call, which brings
+    nothing up, records none."""
+    import horovod_tpu as hvd
+
+    if hvd.is_initialized():
+        hvd.shutdown()
+    monkeypatch.setattr(flight, "_recorder", flight.FlightRecorder(64))
+    hvd.init()
+    try:
+        hvd.init()
+        evs = [e for e in flight.recorder().snapshot()
+               if e["kind"].startswith("hvd_init")]
+    finally:
+        hvd.shutdown()
+    assert [(e["kind"], e["ph"]) for e in evs] == [
+        ("hvd_init", "B"),
+        ("hvd_init.backend", "B"), ("hvd_init.backend", "E"),
+        ("hvd_init.topology", "B"), ("hvd_init.topology", "E"),
+        ("hvd_init.meshes", "B"),
+        ("hvd_init.topology", "B"), ("hvd_init.topology", "E"),
+        ("hvd_init.meshes", "E"),
+        ("hvd_init.planes", "B"), ("hvd_init.planes", "E"),
+        ("hvd_init", "E")]
+    whole, meshes = evs[0]["id"], evs[5]["id"]
+    assert "parent" not in evs[0]
+    assert [e["parent"] for e in evs[1:] if e["ph"] == "B"] \
+        == [whole, whole, whole, meshes, whole]
+    assert evs[0]["mono"] <= evs[1]["mono"] and \
+        evs[-2]["mono"] <= evs[-1]["mono"]
+    # the lifecycle event init() always recorded is inside the planes
+    kinds = [e["kind"] for e in flight.recorder().snapshot()]
+    assert kinds.index("init") > kinds.index("hvd_init.planes")
+
+
+# ---------------------------------------------------------------------------
 # Dumps
 # ---------------------------------------------------------------------------
 

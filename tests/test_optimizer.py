@@ -117,3 +117,52 @@ def test_eager_fused_pytree_mixed_dtypes(hvd_single):
 def test_rejects_non_optax():
     with pytest.raises(TypeError):
         hvd.DistributedOptimizer(object())
+
+
+def _compiled_op_names(mesh, monkeypatch, overlap: bool):
+    import re
+
+    monkeypatch.setenv("HOROVOD_OVERLAP", "1" if overlap else "0")
+    opt = hvd.DistributedOptimizer(optax.sgd(0.1), axis_name="hvd")
+
+    def per_rank(w, t):
+        g = jax.grad(lambda w: jnp.sum((w - t[0]) ** 2))(w)
+        updates, _ = opt.update(g, opt.init(w), w)
+        return optax.apply_updates(w, updates)
+
+    text = jax.jit(shard_map(
+        per_rank, mesh=mesh, check_vma=False, in_specs=(P(), P("hvd")),
+        out_specs=P())).lower(
+            jnp.ones((4096,)), jnp.arange(N, dtype=jnp.float32)
+    ).compile().as_text()
+    return set(re.findall(r'op_name="([^"]*)"', text))
+
+
+def test_stage0_step_names_reduction_and_optimizer(mesh, monkeypatch):
+    """docs/perf.md: a stage-0 ``DistributedOptimizer`` step shows the
+    gradient collective under ``hvd_grad_reduce`` and the wrapped
+    update under ``hvd_optimizer``; the family's own ``apply_updates``
+    stays bare."""
+    names = _compiled_op_names(mesh, monkeypatch, overlap=False)
+    assert any("hvd_grad_reduce/psum" in n for n in names), names
+    assert any("hvd_optimizer/" in n for n in names), names
+    assert not any("hvd_grad_reduce" in n and "hvd_optimizer" in n
+                   for n in names)
+
+
+def test_overlap_buckets_keep_their_scope_inside_the_reduction(
+        mesh, monkeypatch):
+    """``hvd_grad_reduce`` encloses the bucketed schedule's own scopes;
+    ``perf/attribution`` takes the innermost, so per-bucket seconds read
+    as before."""
+    from horovod_tpu.perf import attribution
+
+    names = _compiled_op_names(mesh, monkeypatch, overlap=True)
+    nested = [n for n in names
+              if "hvd_grad_reduce/" in n and "hvd_overlap_" in n]
+    assert nested, names
+    found = {attribution._scope_of(n) for n in nested}
+    assert found and all(s.startswith("hvd_overlap_") for s in found), found
+    assert any(s.startswith("hvd_overlap_rs") for s in found)
+    assert attribution._scope_of(
+        "jit(f)/hvd_grad_reduce/psum") == "hvd_grad_reduce"

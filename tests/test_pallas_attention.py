@@ -325,3 +325,31 @@ def test_kernel_compiles_through_mosaic_on_tpu():
     ref = np.einsum("bqk,bkd->bqd", p / p.sum(-1, keepdims=True),
                     np.asarray(v))
     np.testing.assert_allclose(out, ref, atol=2e-2)
+
+
+@pytest.mark.parametrize("kernel", ["hvd_flash_fwd", "hvd_flash_bwd_dq",
+                                    "hvd_flash_bwd_dkv"])
+def test_each_kernel_is_lowered_under_its_own_name(kernel):
+    """Lowered for a TPU (no chip, no TPU library: the Mosaic call is
+    made while lowering), each of the three ``pallas_call``s is a
+    ``tpu_custom_call`` named as docs/perf.md names it, so that a trace
+    tells the kernels apart by name and not by result shape."""
+    import re
+
+    from horovod_tpu.ops import pallas_attention as pa
+
+    bh, l, d = 2, 256, 64
+    x = jax.ShapeDtypeStruct((bh, l, d), jnp.bfloat16)
+    row = jax.ShapeDtypeStruct((bh, l), jnp.float32)
+    acc = jax.ShapeDtypeStruct((bh, l, d), jnp.float32)
+    calls = {
+        "hvd_flash_fwd": (pa.flash_block_step, (x, x, x, row, row, acc)),
+        "hvd_flash_bwd_dq": (pa.flash_bwd_dq, (x, x, x, x, row, row)),
+        "hvd_flash_bwd_dkv": (pa.flash_bwd_dkv, (x, x, x, x, row, row)),
+    }
+    fn, args = calls[kernel]
+    text = jax.jit(lambda *a: fn(*a, 0, 0, interpret=False)).trace(
+        *args).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert "tpu_custom_call" in text
+    assert set(re.findall(r'kernel_name = "(\w+)"', text)) == {kernel}
+    assert f"{kernel}/pallas_call" in text      # and in the op_name

@@ -168,6 +168,75 @@ def test_attribute_mfu():
     assert res["totals"]["mfu"] == pytest.approx(0.5)
 
 
+def test_a_v5e_event_is_read_by_its_head_not_by_substring():
+    """A TPU device plane names an event by the instruction's whole
+    text: the name and the opcode come from its head, so the scope map
+    hits and a fusion that merely reads ``%all-reduce.1`` is compute;
+    the lines that restate ``XLA Ops`` are not counted again; an
+    asynchronous collective is in flight from ``-start`` to ``-done``."""
+    fusion = ("%fusion.7 = f32[8]{0:T(8)} fusion(f32[8]{0} %all-reduce.1), "
+              "kind=kLoop, calls=%fused_computation.7")
+    start = ("%all-gather-start.2 = (f32[8]{0}, f32[32]{0}) "
+             "all-gather-start(f32[8]{0} %fusion.7), dimensions={0}")
+    done = ("%all-gather-done.2 = f32[32]{0} all-gather-done((f32[8]{0}, "
+            "f32[32]{0}) %all-gather-start.2)")
+    loop = "%while.3 = (s32[], f32[8]{0}) while((s32[], f32[8]{0}) %t)"
+    assert A._name_and_opcode(fusion) == ("fusion.7", "fusion")
+    assert A._name_and_opcode(start) == ("all-gather-start.2",
+                                         "all-gather-start")
+    assert A._name_and_opcode("fusion.7") == ("fusion.7", None)
+    dev = _plane(
+        "/device:TPU:0",
+        _event_meta(1, fusion) + _event_meta(2, start)
+        + _event_meta(3, done) + _event_meta(4, loop)
+        + _line("XLA Ops", 0,
+                _event(4, 0, 100 * US) + _event(2, 0, 1 * US)
+                + _event(1, 10 * US, 20 * US) + _event(3, 39 * US, 1 * US))
+        + _line("Async XLA Ops", 0, _event(2, 0, 40 * US))
+        + _line("TC Overlay", 0, _event(1, 10 * US, 20 * US)))
+    space = X.parse_xspace(dev)
+    got = list(A._op_events(space, {"fusion.7": "jit(f)/hvd_optimizer/mul"}))
+    assert [(e.start_ps, e.duration_ps, scope, kind)
+            for e, scope, kind in got] == [
+        (10 * US, 20 * US, "hvd_optimizer", None),
+        (0, 40 * US, None, "all-gather")]
+    (step,) = A.attribute(space)["steps"]
+    assert step["compute_s"] == pytest.approx(20e-6)
+    assert step["comm_s"] == pytest.approx(40e-6)
+    assert step["comm_hidden_s"] == pytest.approx(20e-6)
+
+
+def test_attribute_agrees_with_the_benchmarks_reduction_on_a_v5e_trace():
+    """The program keeps this reducer and the benchmark its own
+    (``benchmark/reduce.py``): on the recorded v5e trace of
+    ``gpt2-124m.s8192`` both give the same compute, collective and
+    exposed-collective seconds."""
+    from benchmark import reduce
+
+    path = os.path.join(REPO, "tests", "benchmark_suite", "data",
+                        "trace_gpt2-124m.s8192_2steps.textproto.gz")
+    space = X.XSpace()
+    for plane in reduce.load(path).planes:
+        xplane = X.XPlane(name=plane.name)
+        for line in plane.lines:
+            xplane.lines.append(X.XLine(name=line.name, events=[
+                X.XEvent(e.name, int(e.start_ns) * 1000,
+                         int(e.duration_ns) * 1000) for e in line.events]))
+        space.planes.append(xplane)
+    totals = A.attribute(space)["totals"]
+    (ops,) = reduce.device_ops(reduce.load(path)).values()
+    compute = reduce.total(reduce.merge(
+        [[op.start, op.end] for op in reduce.leaves(ops)
+         if reduce.collective_kind(op) is None]))
+    assert compute > 4e9 and totals["steps"] == 1
+    assert totals["compute_s"] == pytest.approx(compute * 1e-9, abs=1e-6)
+    assert totals["comm_s"] == pytest.approx(
+        reduce.total(reduce.collective_intervals(ops)) * 1e-9, abs=1e-6)
+    assert totals["comm_exposed_s"] == pytest.approx(
+        reduce.exposed_collective_ns(ops) * 1e-9, abs=1e-6)
+    assert totals["comm_s"] > 0.1 > totals["comm_exposed_s"] > 0
+
+
 def test_attribute_no_steps_synthesizes_window():
     dev = _plane(
         "/device:TPU:0",

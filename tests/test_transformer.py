@@ -184,3 +184,32 @@ def test_moe_under_pp_raises():
                             n_layers=4, d_ff=64, max_seq=64, moe_every=2)
     with pytest.raises(Exception):
         _train(cfg, mesh, steps=1)
+
+
+def test_the_compiled_step_names_its_parts():
+    """docs/perf.md: the scopes of the flagship step reach the compiled
+    program's ``op_name``s — attention and the loss head in both passes
+    (JAX writes ``jvp(..)`` and ``transpose(jvp(..))`` around them), the
+    gradient reduction and the optimizer bare, after the gradients."""
+    import re
+
+    mesh = make_mesh(dp=2, pp=1, tp=1, sp=1, devices=jax.devices()[:2])
+    params = shard_params(init_params(np.random.RandomState(0), CFG,
+                                      ep=2), CFG, mesh)
+    opt = optax.adam(1e-2)
+    text = make_train_step(CFG, mesh, opt).lower(
+        params, opt.init(params), *_data(mesh)).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+
+    def shown(*parts):
+        return any(all(p in n for p in parts) for n in names)
+
+    for scope in ("hvd_attn", "hvd_loss_head"):
+        assert shown("jvp(", scope) and shown("transpose(jvp(", scope), scope
+    assert shown("hvd_grad_reduce/psum")
+    assert shown("hvd_optimizer/")
+    for n in names:      # neither is inside the differentiated function
+        if "hvd_grad_reduce" in n or "hvd_optimizer" in n:
+            assert "jvp(" not in n, n
+    assert shown("hvd_loss_head", "log_softmax")
+    assert not shown("hvd_attn", "hvd_loss_head")
