@@ -272,7 +272,7 @@ def kernel_flash(rehearsal: bool, bh: int, seq: int, d: int,
     rng = np.random.RandomState(0)
     q, k, v, dout = (jnp.asarray(rng.randn(bh, seq, d), jnp.bfloat16)
                      for _ in range(4))
-    bq, bk = _block_sizes(seq, seq)
+    bq, bk = _block_sizes(seq, seq, d, q.dtype.itemsize)
     m0 = jnp.full((bh, seq), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((bh, seq), jnp.float32)
     o0 = jnp.zeros((bh, seq, d), jnp.float32)

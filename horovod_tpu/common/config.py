@@ -296,8 +296,8 @@ _register("attn_xla_score_bytes", Knob(
 _register("attn_block_q", Knob(
     "HOROVOD_ATTN_BLOCK_Q", 0, int,
     cli="--attn-block-q", config_key="attention.block_q",
-    help="Pallas attention Q tile size (0 = auto: largest MXU-friendly "
-         "divisor of the chunk, preferring 128). Bench/tuning hook for "
+    help="Pallas attention Q tile size (0 = auto: the largest of 1024, "
+         "512, ... 8 dividing the chunk). Bench/tuning hook for "
          "the on-chip tile sweep; must divide the local sequence "
          "chunk, else auto applies."))
 _register("attn_pallas_bwd", Knob(

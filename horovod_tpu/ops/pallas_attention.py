@@ -4,11 +4,16 @@ The hot op of ring attention (SURVEY §5.7 — a new TPU capability, absent
 from the reference): one online-softmax accumulation of a local Q chunk
 against one KV block, carrying the running (max, denominator, numerator)
 state between ring steps so `lax.ppermute` KV rotation overlaps the MXU
-work.  The kernel tiles Q×K into MXU-sized blocks, keeps softmax state
-in fp32 VMEM scratch across the innermost K-grid dimension, and applies
-block-level causal masking from *global* sequence offsets (the carried
-state is what makes it composable with the ring — a plain fused
-attention kernel could not resume from a previous block's state).
+work.  The kernel tiles Q×K into blocks as large as the chunk allows (up
+to 1024×1024, ``ring_attention._pick_block``: a grid step costs about
+half a microsecond whatever its tile, so small tiles are all fixed
+cost), keeps softmax state in fp32 VMEM scratch across the innermost
+K-grid dimension, and applies block-level causal masking from *global*
+sequence offsets (the carried state is what makes it composable with
+the ring — a plain fused attention kernel could not resume from a
+previous block's state).  A tile pair the causal mask hides whole is
+neither computed nor fetched, and only the pairs the diagonal crosses
+build the mask (:func:`causal_tile_counts` says how many of each).
 
 Compiled by Mosaic on a TPU backend; interpreted elsewhere
 (``common.platform.pallas_interpret``), so the same kernel code is
@@ -38,6 +43,113 @@ _NEG_INF = float("-inf")
 _M_LANE = 0
 _L_LANE = 64
 
+# Tiles are sized so that a kernel asks for at most about half of the
+# 128 MiB of VMEM a v5e (or v4, v6e) TensorCore has.
+VMEM_BUDGET = 64 << 20
+
+
+def tile_vmem_bytes(bq: int, bk: int, d: int, itemsize: int) -> int:
+    """What one grid step of the hungriest of the three kernels (dK/dV)
+    may hold in VMEM at a (bq, bk) tile, head size ``d`` and
+    ``itemsize``-byte operands: the kernels' ``vmem_limit_bytes``, and
+    what ``ring_attention`` keeps under :data:`VMEM_BUDGET` when it
+    picks tiles.  Held to what Mosaic allocates when it compiles the
+    kernels for a v5e (PR 25: 10 MiB at 1024×1024, d 64, bf16, where
+    this says 18; 36 at 2048×2048 where this says 61; 23 at 1024×1024,
+    d 256, f32 where this says 35)."""
+    n = max(bq, bk)
+    # Mosaic streams the elementwise chain between the products: what
+    # stays is two f32 (bq, bk) tiles (scores, dP) and one in the
+    # operand dtype for the next product (measured: 9 bytes an element)
+    tiles = bq * bk * (2 * 4 + itemsize)
+    # q, dO, k, v; the packed row state; two f32 blocks in or out (o, or
+    # dk and dv) — each double-buffered by the pipeline
+    blocks = 2 * (4 * n * d * itemsize + n * 128 * 4 + 2 * n * d * 4)
+    scratch = 2 * n * 128 * 4 + 2 * n * d * 4
+    return (tiles + blocks + scratch) * 5 // 4     # a quarter of headroom
+
+
+def _tile_live(q_start, k_start, bq: int):
+    """A (Q tile, K tile) pair is live iff the causal mask on global
+    positions leaves it any probability: its first key is no later
+    than its last query.  Python ints or traced scalars."""
+    return k_start <= q_start + (bq - 1)
+
+
+def _tile_diagonal(q_start, k_start, bk: int):
+    """The pair needs the mask iff its last key is later than its first
+    query; a live pair that does not lies wholly in the past.  (A dead
+    pair always "needs" it.)"""
+    return k_start + (bk - 1) > q_start
+
+
+def causal_tile_counts(lq: int, lk: int, bq: int, bk: int,
+                       q_offset: int = 0, k_offset: int = 0):
+    """``(grid, live, diagonal)`` tile pairs of one head's causal call:
+    how many grid steps there are, how many do any work, and how many
+    of those build the mask.  Seq 8192 in 1024×1024 tiles: 64 / 36 / 8;
+    in 128×128 tiles 4,096 / 2,080 / 64."""
+    grid = live = diagonal = 0
+    for q_start in range(q_offset, q_offset + lq, bq):
+        for k_start in range(k_offset, k_offset + lk, bk):
+            grid += 1
+            if _tile_live(q_start, k_start, bq):
+                live += 1
+                diagonal += _tile_diagonal(q_start, k_start, bk)
+    return grid, live, diagonal
+
+
+def _on_live_tile(off_ref, iq, ik, bq: int, bk: int, causal: bool, body):
+    """Run ``body(mask)`` for tile pair (iq, ik): not at all where the
+    causal mask hides the whole pair, with the (bq, bk) bool mask where
+    the diagonal crosses it, and with ``None`` where nothing is hidden
+    (no score is -inf there, so the body drops its guards too)."""
+    if not causal:
+        body(None)
+        return
+    q_start = off_ref[0] + iq * bq
+    k_start = off_ref[1] + ik * bk
+    diagonal = _tile_diagonal(q_start, k_start, bk)
+
+    @pl.when(_tile_live(q_start, k_start, bq) & diagonal)
+    def _():
+        qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        body(qpos >= kpos)
+
+    @pl.when(jnp.logical_not(diagonal))        # implies live
+    def _():
+        body(None)
+
+
+def _q_major_maps(bq: int, bk: int, causal: bool):
+    """Index maps ``(q_row, kv_row)`` of the (B*H, nq, nk) grid the
+    forward and dQ kernels share.  The K/V map clamps ``ik`` to the Q
+    row's last live tile, so that the dead steps after it name the
+    block already in VMEM and fetch nothing."""
+    def q_row(b, iq, ik, off_ref):
+        return b, iq, 0
+
+    def kv_row(b, iq, ik, off_ref):
+        if causal:
+            last_q = off_ref[0] - off_ref[1] + (iq + 1) * bq - 1
+            ik = jnp.minimum(ik, jax.lax.div(jnp.maximum(last_q, 0), bk))
+        return b, ik, 0
+
+    return q_row, kv_row
+
+
+def _compiler_params(bq: int, bk: int, d: int, dtype):
+    # the two outer dimensions are independent work items, only the
+    # innermost carries scratch state — telling Mosaic lets it overlap
+    # DMA with MXU work across grid steps instead of serializing the
+    # whole grid
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        # never less than Mosaic's own default, which small tiles had
+        vmem_limit_bytes=max(16 << 20, tile_vmem_bytes(
+            bq, bk, d, jnp.dtype(dtype).itemsize)))
+
 
 def _flash_step_kernel(off_ref, q_ref, k_ref, v_ref, mli_ref, oi_ref,
                        mlo_ref, oo_ref, m_s, l_s, acc,
@@ -46,7 +158,9 @@ def _flash_step_kernel(off_ref, q_ref, k_ref, v_ref, mli_ref, oi_ref,
     carries across the K blocks of one Q block.  The packed m|l HBM
     tile is unpacked into lane-replicated VMEM scratch on entry and
     repacked on exit, so the per-iteration math matches the classic
-    two-buffer layout while HBM sees a single state buffer."""
+    two-buffer layout while HBM sees a single state buffer.  Entry and
+    exit run on every row, also one whose every tile is dead: it hands
+    the carried state through unchanged."""
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -57,35 +171,33 @@ def _flash_step_kernel(off_ref, q_ref, k_ref, v_ref, mli_ref, oi_ref,
         l_s[:, :] = ml[:, _L_LANE][:, None] + jnp.zeros_like(l_s)
         acc[:, :] = oi_ref[0].astype(jnp.float32)
 
-    q = q_ref[0]                                   # (bq, d)
-    k = k_ref[0]                                   # (bk, d)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale  # (bq, bk)
+    def accumulate(mask):
+        s = jax.lax.dot_general(
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # (bq, bk)
+        if mask is not None:
+            s = jnp.where(mask, s, _NEG_INF)
+        m_prev = m_s[:, 0]                             # (bq,)
+        l_prev = l_s[:, 0]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        # Fully-masked rows keep m == -inf; exp against a finite
+        # stand-in.
+        m_safe = (m_new if mask is None
+                  else jnp.where(jnp.isfinite(m_new), m_new, 0.0))
+        p = jnp.exp(s - m_safe[:, None])
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
+        alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe),
+                          0.0)
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1)
+        pv = jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)        # (bq, d)
+        m_s[:, :] = m_new[:, None] + jnp.zeros_like(m_s)
+        l_s[:, :] = l_new[:, None] + jnp.zeros_like(l_s)
+        acc[:, :] = acc[:, :] * alpha[:, None] + pv
 
-    if causal:
-        q_start = off_ref[0] + pl.program_id(1) * bq
-        k_start = off_ref[1] + ik * bk
-        qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        s = jnp.where(qpos >= kpos, s, _NEG_INF)
-
-    m_prev = m_s[:, 0]                             # (bq,)
-    l_prev = l_s[:, 0]
-    m_cur = jnp.max(s, axis=-1)
-    m_new = jnp.maximum(m_prev, m_cur)
-    # Fully-masked rows keep m == -inf; exp against a finite stand-in.
-    m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-    p = jnp.exp(s - m_safe[:, None])
-    p = jnp.where(jnp.isfinite(s), p, 0.0)
-    alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1)
-    pv = jax.lax.dot_general(
-        p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)        # (bq, d)
-    m_s[:, :] = m_new[:, None] + jnp.zeros_like(m_s)
-    l_s[:, :] = l_new[:, None] + jnp.zeros_like(l_s)
-    acc[:, :] = acc[:, :] * alpha[:, None] + pv
+    _on_live_tile(off_ref, pl.program_id(1), ik, bq, bk, causal, accumulate)
 
     @pl.when(ik == nk - 1)
     def _():
@@ -94,15 +206,20 @@ def _flash_step_kernel(off_ref, q_ref, k_ref, v_ref, mli_ref, oi_ref,
         oo_ref[0] = acc[:, :].astype(oo_ref.dtype)
 
 
-def _flash_block_step_impl(q, k, v, m, l, o, q_offset, k_offset,
-                           causal, block_q, block_k, interpret):
-    bh, lq, d = q.shape
-    _, lk, _ = k.shape
+def _tiles(block_q, block_k, lq, lk):
     bq = min(block_q, lq)
     bk = min(block_k, lk)
     if lq % bq or lk % bk:
         raise ValueError(f"block sizes ({bq}, {bk}) must divide the "
                          f"sequence chunks ({lq}, {lk})")
+    return bq, bk
+
+
+def _flash_block_step_impl(q, k, v, m, l, o, q_offset, k_offset,
+                           causal, block_q, block_k, interpret):
+    bh, lq, d = q.shape
+    _, lk, _ = k.shape
+    bq, bk = _tiles(block_q, block_k, lq, lk)
     interpret = pallas_interpret(interpret)
     scale = 1.0 / (d ** 0.5)
     offs = jnp.asarray([q_offset, k_offset], jnp.int32)
@@ -113,49 +230,72 @@ def _flash_block_step_impl(q, k, v, m, l, o, q_offset, k_offset,
 
     kernel = functools.partial(_flash_step_kernel, causal=causal,
                                scale=scale, bq=bq, bk=bk)
-    grid = (bh, lq // bq, lk // bk)
+    q_row, kv_row = _q_major_maps(bq, bk, causal)
+
     mlo, oo = pl.pallas_call(
         kernel,
         name="hvd_flash_fwd",
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),               # offsets
-            pl.BlockSpec((1, bq, d), lambda b, iq, ik: (b, iq, 0)),   # q
-            pl.BlockSpec((1, bk, d), lambda b, iq, ik: (b, ik, 0)),   # k
-            pl.BlockSpec((1, bk, d), lambda b, iq, ik: (b, ik, 0)),   # v
-            pl.BlockSpec((1, bq, 128), lambda b, iq, ik: (b, iq, 0)),  # m|l
-            pl.BlockSpec((1, bq, d), lambda b, iq, ik: (b, iq, 0)),   # o
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, 128), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, bq, d), lambda b, iq, ik: (b, iq, 0)),
-        ],
+        # the offsets are prefetched scalars: the K/V index map reads
+        # them to skip the fetch of dead tiles
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, lq // bq, lk // bk),
+            in_specs=[
+                pl.BlockSpec((1, bq, d), q_row),      # q
+                pl.BlockSpec((1, bk, d), kv_row),     # k
+                pl.BlockSpec((1, bk, d), kv_row),     # v
+                pl.BlockSpec((1, bq, 128), q_row),    # m|l
+                pl.BlockSpec((1, bq, d), q_row),      # o
+            ],
+            out_specs=[
+                pl.BlockSpec((1, bq, 128), q_row),
+                pl.BlockSpec((1, bq, d), q_row),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, 128), jnp.float32),   # running max
+                pltpu.VMEM((bq, 128), jnp.float32),   # running denominator
+                pltpu.VMEM((bq, d), jnp.float32),     # numerator accumulator
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((bh, lq, 128), jnp.float32),
             jax.ShapeDtypeStruct((bh, lq, d), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, 128), jnp.float32),   # running max
-            pltpu.VMEM((bq, 128), jnp.float32),   # running denominator
-            pltpu.VMEM((bq, d), jnp.float32),     # numerator accumulator
-        ],
-        # b/iq are independent work items, only the K dimension carries
-        # scratch state — telling Mosaic lets it overlap DMA with MXU
-        # work across grid steps instead of serializing the whole grid.
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params(bq, bk, d, q.dtype),
         interpret=interpret,
     )(offs, q, k, v, ml, o)
     return mlo[..., _M_LANE], mlo[..., _L_LANE], oo
+
+
+def _recomputed_p_ds(q, k, v, do, ld, mask, scale):
+    """One tile's softmax probabilities from the saved per-row LSE, and
+    dS = P ∘ (dP − delta) · scale — what both backward kernels start
+    from.  The full score matrix is never materialized (the whole point
+    vs the XLA-remat VJP)."""
+    lse = ld[:, _M_LANE]
+    delta = ld[:, _L_LANE]
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale  # (bq, bk)
+    if mask is None:
+        # every key of the tile is visible to every row: lse is finite
+        p = jnp.exp(s - lse[:, None])
+    else:
+        # fully-masked rows carry lse = -inf
+        seen = jnp.isfinite(lse)
+        p = jnp.where(mask & seen[:, None],
+                      jnp.exp(s - jnp.where(seen, lse, 0.0)[:, None]), 0.0)
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)        # (bq, bk)
+    return p, p * (dp - delta[:, None]) * scale
 
 
 def _flash_bwd_dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
                          dq_ref, dq_acc, *, causal: bool, scale: float,
                          bq: int, bk: int):
     """dQ backward: grid (B*H, nq, nk), nk innermost so dq_acc carries
-    across the K blocks of one Q block.  Scores are recomputed per
-    (bq, bk) tile from the saved per-row LSE — the full score matrix is
-    never materialized (the whole point vs the XLA-remat VJP)."""
+    across the K blocks of one Q block (zero for a row whose every
+    tile is dead)."""
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -163,34 +303,15 @@ def _flash_bwd_dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
     def _():
         dq_acc[:, :] = jnp.zeros_like(dq_acc)
 
-    q = q_ref[0]                                   # (bq, d)
-    k = k_ref[0]                                   # (bk, d)
-    v = v_ref[0]                                   # (bk, d)
-    do = do_ref[0]                                 # (bq, d)
-    ld = ld_ref[0]                                 # (bq, 128) lse|delta
-    lse = ld[:, _M_LANE]
-    delta = ld[:, _L_LANE]
+    def accumulate(mask):
+        k = k_ref[0]                                   # (bk, d)
+        _, ds = _recomputed_p_ds(q_ref[0], k, v_ref[0], do_ref[0],
+                                 ld_ref[0], mask, scale)
+        dq_acc[:, :] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)        # (bq, d)
 
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale  # (bq, bk)
-    if causal:
-        q_start = off_ref[0] + pl.program_id(1) * bq
-        k_start = off_ref[1] + ik * bk
-        qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        s = jnp.where(qpos >= kpos, s, _NEG_INF)
-    # p = softmax row = exp(s - lse); fully-masked rows carry lse=-inf
-    p = jnp.where(jnp.isfinite(s) & jnp.isfinite(lse)[:, None],
-                  jnp.exp(s - jnp.where(jnp.isfinite(lse), lse,
-                                        0.0)[:, None]), 0.0)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)        # (bq, bk)
-    ds = p * (dp - delta[:, None]) * scale
-    dq_acc[:, :] += jax.lax.dot_general(
-        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)        # (bq, d)
+    _on_live_tile(off_ref, pl.program_id(1), ik, bq, bk, causal, accumulate)
 
     @pl.when(ik == nk - 1)
     def _():
@@ -210,36 +331,19 @@ def _flash_bwd_dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
         dk_acc[:, :] = jnp.zeros_like(dk_acc)
         dv_acc[:, :] = jnp.zeros_like(dv_acc)
 
-    q = q_ref[0]                                   # (bq, d)
-    k = k_ref[0]                                   # (bk, d)
-    v = v_ref[0]                                   # (bk, d)
-    do = do_ref[0]                                 # (bq, d)
-    ld = ld_ref[0]
-    lse = ld[:, _M_LANE]
-    delta = ld[:, _L_LANE]
+    def accumulate(mask):
+        q = q_ref[0]                                   # (bq, d)
+        do = do_ref[0]                                 # (bq, d)
+        p, ds = _recomputed_p_ds(q, k_ref[0], v_ref[0], do, ld_ref[0],
+                                 mask, scale)
+        dv_acc[:, :] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)        # (bk, d)
+        dk_acc[:, :] += jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)        # (bk, d)
 
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale  # (bq, bk)
-    if causal:
-        q_start = off_ref[0] + iq * bq
-        k_start = off_ref[1] + pl.program_id(1) * bk
-        qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        s = jnp.where(qpos >= kpos, s, _NEG_INF)
-    p = jnp.where(jnp.isfinite(s) & jnp.isfinite(lse)[:, None],
-                  jnp.exp(s - jnp.where(jnp.isfinite(lse), lse,
-                                        0.0)[:, None]), 0.0)
-    dv_acc[:, :] += jax.lax.dot_general(
-        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)        # (bk, d)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)        # (bq, bk)
-    ds = p * (dp - delta[:, None]) * scale
-    dk_acc[:, :] += jax.lax.dot_general(
-        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)        # (bk, d)
+    _on_live_tile(off_ref, iq, pl.program_id(1), bq, bk, causal, accumulate)
 
     @pl.when(iq == nq - 1)
     def _():
@@ -269,34 +373,32 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_offset, k_offset, *,
     """
     bh, lq, d = q.shape
     _, lk, _ = k.shape
-    bq = min(block_q, lq)
-    bk = min(block_k, lk)
-    if lq % bq or lk % bk:
-        raise ValueError(f"block sizes ({bq}, {bk}) must divide the "
-                         f"sequence chunks ({lq}, {lk})")
+    bq, bk = _tiles(block_q, block_k, lq, lk)
     interpret = pallas_interpret(interpret)
     scale = 1.0 / (d ** 0.5)
     offs = jnp.asarray([q_offset, k_offset], jnp.int32)
     ld = _pack_ld(lse, delta, bh, lq)
     kernel = functools.partial(_flash_bwd_dq_kernel, causal=causal,
                                scale=scale, bq=bq, bk=bk)
+    q_row, kv_row = _q_major_maps(bq, bk, causal)
+
     return pl.pallas_call(
         kernel,
         name="hvd_flash_bwd_dq",
-        grid=(bh, lq // bq, lk // bk),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, bq, d), lambda b, iq, ik: (b, iq, 0)),   # q
-            pl.BlockSpec((1, bk, d), lambda b, iq, ik: (b, ik, 0)),   # k
-            pl.BlockSpec((1, bk, d), lambda b, iq, ik: (b, ik, 0)),   # v
-            pl.BlockSpec((1, bq, d), lambda b, iq, ik: (b, iq, 0)),   # do
-            pl.BlockSpec((1, bq, 128), lambda b, iq, ik: (b, iq, 0)),  # ld
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, iq, ik: (b, iq, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, lq // bq, lk // bk),
+            in_specs=[
+                pl.BlockSpec((1, bq, d), q_row),      # q
+                pl.BlockSpec((1, bk, d), kv_row),     # k
+                pl.BlockSpec((1, bk, d), kv_row),     # v
+                pl.BlockSpec((1, bq, d), q_row),      # do
+                pl.BlockSpec((1, bq, 128), q_row),    # ld
+            ],
+            out_specs=pl.BlockSpec((1, bq, d), q_row),
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((bh, lq, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params(bq, bk, d, q.dtype),
         interpret=interpret,
     )(offs, q, k, v, do, ld)
 
@@ -312,41 +414,51 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, q_offset, k_offset, *,
     """
     bh, lq, d = q.shape
     _, lk, _ = k.shape
-    bq = min(block_q, lq)
-    bk = min(block_k, lk)
-    if lq % bq or lk % bk:
-        raise ValueError(f"block sizes ({bq}, {bk}) must divide the "
-                         f"sequence chunks ({lq}, {lk})")
+    bq, bk = _tiles(block_q, block_k, lq, lk)
     interpret = pallas_interpret(interpret)
     scale = 1.0 / (d ** 0.5)
     offs = jnp.asarray([q_offset, k_offset], jnp.int32)
     ld = _pack_ld(lse, delta, bh, lq)
     kernel = functools.partial(_flash_bwd_dkv_kernel, causal=causal,
                                scale=scale, bq=bq, bk=bk)
+    nq = lq // bq
+
+    def q_row(b, ik, iq, off_ref):
+        # iq clamped up to the K column's first live tile: the dead
+        # steps before it fetch that tile's blocks once, and no others
+        if causal:
+            first_k = off_ref[1] - off_ref[0] + ik * bk
+            first = jax.lax.div(jnp.maximum(first_k, 0), bq)
+            iq = jnp.maximum(iq, jnp.minimum(first, nq - 1))
+        return b, iq, 0
+
+    def kv_row(b, ik, iq, off_ref):
+        return b, ik, 0
+
     return pl.pallas_call(
         kernel,
         name="hvd_flash_bwd_dkv",
-        grid=(bh, lk // bk, lq // bq),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, bq, d), lambda b, ik, iq: (b, iq, 0)),   # q
-            pl.BlockSpec((1, bk, d), lambda b, ik, iq: (b, ik, 0)),   # k
-            pl.BlockSpec((1, bk, d), lambda b, ik, iq: (b, ik, 0)),   # v
-            pl.BlockSpec((1, bq, d), lambda b, ik, iq: (b, iq, 0)),   # do
-            pl.BlockSpec((1, bq, 128), lambda b, ik, iq: (b, iq, 0)),  # ld
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda b, ik, iq: (b, ik, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, ik, iq: (b, ik, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, lk // bk, nq),
+            in_specs=[
+                pl.BlockSpec((1, bq, d), q_row),      # q
+                pl.BlockSpec((1, bk, d), kv_row),     # k
+                pl.BlockSpec((1, bk, d), kv_row),     # v
+                pl.BlockSpec((1, bq, d), q_row),      # do
+                pl.BlockSpec((1, bq, 128), q_row),    # ld
+            ],
+            out_specs=[
+                pl.BlockSpec((1, bk, d), kv_row),
+                pl.BlockSpec((1, bk, d), kv_row),
+            ],
+            scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                            pltpu.VMEM((bk, d), jnp.float32)]),
         out_shape=[
             jax.ShapeDtypeStruct((bh, lk, d), jnp.float32),
             jax.ShapeDtypeStruct((bh, lk, d), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params(bq, bk, d, q.dtype),
         interpret=interpret,
     )(offs, q, k, v, do, ld)
 

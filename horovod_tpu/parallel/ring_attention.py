@@ -69,22 +69,28 @@ def xla_block_step(q, k, v, m, l, o, q_offset, k_offset, *,
 _warned_untiled: set = set()
 
 
-def _pick_block(n: int, preferred: int = 128) -> int | None:
-    """Largest MXU-friendly block size dividing n (None if there is
-    none — ``ring_attention`` then raises for an explicit
-    ``impl="pallas"`` and logs once for an automatic pick)."""
-    for c in (preferred, 64, 32, 16, 8):
-        if c <= n and n % c == 0:
-            return c
-    return None
+_TILE_LADDER = (1024, 512, 256, 128, 64, 32, 16, 8)
 
 
-def _block_sizes(lc: int, lk: int):
-    """(block_q, block_k) for the Pallas kernel: forced by the
-    HOROVOD_ATTN_BLOCK_Q/K knobs when they divide the chunk (the
-    on-chip tile-sweep hook), else the auto pick.  Returns (None, _)
-    when no aligned tiling exists for the Q chunk."""
+def _pick_block(n: int) -> int | None:
+    """Largest tile on the ladder dividing n — up to 1024, because a
+    grid step of the kernels costs about half a microsecond whatever
+    its tile (``PERF.md``, PR 25).  None if there is none —
+    ``ring_attention`` then raises for an explicit ``impl="pallas"``
+    and logs once for an automatic pick."""
+    return next((c for c in _TILE_LADDER if n % c == 0), None)
+
+
+def _block_sizes(lc: int, lk: int, d: int, itemsize: int):
+    """(block_q, block_k) for the Pallas kernel at head size ``d`` and
+    ``itemsize``-byte operands: forced by the HOROVOD_ATTN_BLOCK_Q/K
+    knobs when they divide the chunk (the on-chip tile-sweep hook),
+    else the auto pick, stepped down the ladder (K first) while the
+    kernels would ask for more than ``VMEM_BUDGET``.  Returns
+    (None, _) when no aligned tiling exists for the Q chunk."""
     from horovod_tpu.common import config as _config
+    from horovod_tpu.ops.pallas_attention import (VMEM_BUDGET,
+                                                  tile_vmem_bytes)
 
     def one(n, knob):
         forced = _config.get(knob)
@@ -98,7 +104,16 @@ def _block_sizes(lc: int, lk: int):
                 f"dividing chunk {n}; using auto tile size")
         return _pick_block(n)
 
-    return one(lc, "attn_block_q"), one(lk, "attn_block_k")
+    bq, bk = one(lc, "attn_block_q"), one(lk, "attn_block_k")
+    if _config.get("attn_block_q") or _config.get("attn_block_k"):
+        return bq, bk   # a forced size wins; Mosaic says if it is too much
+    while (bq and bk and max(bq, bk) > 8
+           and tile_vmem_bytes(bq, bk, d, itemsize) > VMEM_BUDGET):
+        if bk >= bq:
+            bk //= 2
+        else:
+            bq //= 2
+    return bq, bk
 
 
 def auto_impl(batch: int, heads: int, seq_q: int,
@@ -258,7 +273,8 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
                          f"got {impl!r}")
 
     if impl == "pallas":
-        bq, bk = _block_sizes(lc, lc)  # ring KV blocks are lc long too
+        # ring KV blocks are lc long too
+        bq, bk = _block_sizes(lc, lc, d, q.dtype.itemsize)
         if bq is None or bk is None:
             msg = (f"sequence chunk {lc} has no tile size the Pallas "
                    "attention kernel can use (a multiple of 8 dividing "

@@ -299,6 +299,119 @@ def test_pallas_bwd_knob_remat_matches_kernel(monkeypatch):
                                    rtol=1e-3, atol=1e-4)
 
 
+@pytest.mark.parametrize("chunk, tile", [(8192, 1024), (1024, 1024),
+                                         (512, 512), (384, 128),
+                                         (136, 8), (12, None)])
+def test_auto_tile_is_the_largest_on_the_ladder(chunk, tile):
+    """Tiles come from the shape, up to 1024: a grid step costs the
+    same whatever its tile."""
+    from horovod_tpu.parallel.ring_attention import (_block_sizes,
+                                                     _pick_block)
+
+    assert _pick_block(chunk) == tile
+    assert _block_sizes(chunk, chunk, 64, 2) == (tile, tile)
+
+
+def test_auto_tile_steps_down_to_fit_vmem():
+    """A head so wide that 1024x1024 would ask for more than half of
+    VMEM gets a smaller K tile, then a smaller Q tile; what the kernels
+    ask Mosaic for follows the tile."""
+    from horovod_tpu.ops.pallas_attention import (VMEM_BUDGET,
+                                                  tile_vmem_bytes)
+    from horovod_tpu.parallel.ring_attention import _block_sizes
+
+    assert tile_vmem_bytes(1024, 1024, 64, 2) < VMEM_BUDGET // 3
+    assert _block_sizes(8192, 8192, 256, 4) == (1024, 1024)
+    for d, tiles in ((768, (1024, 512)), (1024, (512, 512))):
+        assert _block_sizes(8192, 8192, d, 4) == tiles
+        assert tile_vmem_bytes(*tiles, d, 4) <= VMEM_BUDGET
+
+
+def test_causal_tile_counts_match_a_count_over_positions():
+    """grid / live / diagonal tile pairs as the kernels' predicates
+    decide them, against the mask itself: a pair is live iff any
+    (query, key) position in it is visible and diagonal iff, being
+    live, any is hidden."""
+    from horovod_tpu.ops.pallas_attention import causal_tile_counts
+
+    def brute(lq, lk, bq, bk, qo, ko):
+        visible = ((qo + np.arange(lq))[:, None]
+                   >= (ko + np.arange(lk))[None, :])
+        tiles = visible.reshape(lq // bq, bq, lk // bk, bk)
+        some, every = tiles.any(axis=(1, 3)), tiles.all(axis=(1, 3))
+        return some.size, int(some.sum()), int((some & ~every).sum())
+
+    for case in [(128, 128, 32, 16, 0, 0), (128, 128, 16, 32, 0, 0),
+                 (128, 128, 32, 16, 128, 0), (128, 128, 16, 32, 0, 128),
+                 (64, 128, 16, 64, 40, 8), (96, 64, 8, 32, 0, 24)]:
+        assert causal_tile_counts(*case) == brute(*case), case
+    # the benchmark's long cell, per head: today's tiles and the parent's
+    assert causal_tile_counts(8192, 8192, 1024, 1024) == (64, 36, 8)
+    assert causal_tile_counts(8192, 8192, 128, 128) == (4096, 2080, 64)
+
+
+@pytest.mark.parametrize("q_offset, k_offset",
+                         [(0, 0), (128, 0), (0, 128)],
+                         ids=["diagonal", "past", "future"])
+def test_kernels_match_xla_step_over_block_positions(q_offset, k_offset):
+    """The three kernels against ``xla_block_step`` and its ``jax.vjp``
+    with rectangular tiles, on a KV block that holds the diagonal
+    (masked, unmasked and skipped tile pairs), one wholly in the past
+    (no pair builds a mask) and one wholly in the future (every pair
+    skipped: the carried state comes back unchanged and the gradients
+    are exactly zero)."""
+    from horovod_tpu.ops.pallas_attention import (flash_bwd_dkv,
+                                                  flash_bwd_dq)
+    from horovod_tpu.parallel.ring_attention import xla_block_step
+
+    q, k, v = (_pack(x) for x in _qkv(11, l=128))
+    rng = np.random.RandomState(12)
+    bh, lq, d = q.shape
+    # a carried state from an earlier block, as in a ring's later steps
+    m0 = jnp.asarray(rng.randn(bh, lq), jnp.float32)
+    l0 = jnp.asarray(rng.rand(bh, lq) + 0.5, jnp.float32)
+    o0 = jnp.asarray(rng.randn(bh, lq, d), jnp.float32)
+    dout = jnp.asarray(rng.randn(bh, lq, d), jnp.float32) * 0.1
+
+    def normalized(step):
+        def fn(q_, k_, v_):
+            m, l, o = step(q_, k_, v_)
+            return o / l[..., None]
+        return fn
+
+    xla = lambda q_, k_, v_: xla_block_step(
+        q_, k_, v_, m0, l0, o0, q_offset, k_offset, causal=True)
+    em, el, eo = xla(q, k, v)
+    eout, vjp = jax.vjp(normalized(xla), q, k, v)
+    edq, edk, edv = vjp(dout)
+    # the carried o0 is a constant of the reference: take it out of
+    # delta, as the ring's backward sees only this block's products
+    lse = em + jnp.log(el)
+    delta = jnp.sum(dout * eout, axis=-1)
+    future = k_offset > q_offset + lq - 1
+
+    for bq, bk in ((32, 16), (16, 32)):
+        tiles = dict(causal=True, block_q=bq, block_k=bk, interpret=True)
+        m, l, o = flash_block_step(q, k, v, m0, l0, o0, q_offset,
+                                   k_offset, **tiles)
+        dq = flash_bwd_dq(q, k, v, dout, lse, delta, q_offset, k_offset,
+                          **tiles)
+        dk, dv = flash_bwd_dkv(q, k, v, dout, lse, delta, q_offset,
+                               k_offset, **tiles)
+        if future:
+            for got, want in ((m, m0), (l, l0), (o, o0)):
+                np.testing.assert_array_equal(np.asarray(got),
+                                              np.asarray(want))
+            for g in (dq, dk, dv):
+                assert not np.asarray(g).any()
+        for got, want in ((m, em), (l, el), (o, eo)):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=2e-4, atol=2e-5)
+        for got, want in ((dq, edq), (dk, edk), (dv, edv)):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=2e-3, atol=2e-4)
+
+
 def test_kernel_compiles_through_mosaic_on_tpu():
     """Guards the non-interpret lowering path: BlockSpec/scratch layout
     changes that only break Mosaic (not interpret mode) must fail CI on
