@@ -48,9 +48,10 @@ def main() -> None:
     p.add_argument("--steps", type=int, default=10)
     p.add_argument("--d-model", type=int, default=128)
     p.add_argument("--n-layers", type=int, default=2)
-    p.add_argument("--moe-every", type=int, default=0,
-                   help="insert an expert-parallel MoE block every k "
-                        "layers (0 = dense)")
+    p.add_argument("--experts", type=int, default=0,
+                   help="experts a dp rank holds in every layer after "
+                        "the first (dropless sigmoid top-2 over dp x "
+                        "this many, plus a shared expert; 0 = dense)")
     p.add_argument("--pp-schedule", default="gpipe",
                    choices=["gpipe", "interleaved"],
                    help="pipeline schedule when pp > 1 (interleaved = "
@@ -94,7 +95,10 @@ def main() -> None:
         n_heads=max(4, 2 * tp), head_dim=args.d_model // 4,
         n_layers=args.n_layers * pp * args.pp_virtual,
         d_ff=4 * args.d_model, max_seq=args.seq,
-        moe_every=args.moe_every, experts_per_rank=2,
+        **(dict(mlp="swiglu", n_experts=dp * args.experts,
+                experts_held=args.experts, experts_per_token=2,
+                d_expert=args.d_model, shared_experts=1, n_dense_layers=1)
+           if args.experts else {}),
         pp_microbatches=2 if pp > 1 else 1,
         pp_schedule=args.pp_schedule, pp_virtual=args.pp_virtual)
     mesh = make_mesh(**axes, devices=devices[:n])

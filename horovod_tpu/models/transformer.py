@@ -14,8 +14,17 @@ one train step:
     (:mod:`horovod_tpu.parallel.ring_attention`).
   * **pp** — layer stack split into stages, GPipe microbatching
     (:mod:`horovod_tpu.parallel.pipeline`) when the ``pp`` axis > 1.
-  * **ep** — optional Switch-MoE MLP with experts sharded over the
-    ``dp`` axis (:mod:`horovod_tpu.parallel.moe`).
+  * **ep** — expert layers (dropless sigmoid top-k, a share of the
+    experts held on each rank) with the experts sharded over the ``dp``
+    axis (:mod:`horovod_tpu.parallel.moe`).
+
+The block is chosen by the configuration, per layer: fused-QKV heads
+with learned positions or latent attention with rotary positions; a
+GELU MLP, SwiGLU, or — after ``n_dense_layers`` leading dense layers —
+the expert layer; a tied or an untied head; an optional multi-token
+prediction module (:mod:`horovod_tpu.models.blocks`, imported only
+where a configuration asks for one of these).  The defaults are the
+GPT-2 block.
 
 Everything is bf16 matmuls with fp32 accumulation/norms — MXU-native.
 """
@@ -31,7 +40,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from horovod_tpu.parallel.moe import moe_layer
 from horovod_tpu.parallel.pipeline import gpipe, interleaved_pipeline
 from horovod_tpu.parallel.ring_attention import ring_attention
 from horovod_tpu.parallel.sharding import (copy_to_tp, grad_reduce_axes,
@@ -52,9 +60,37 @@ class TransformerConfig:
     # attention block-step impl: None = auto (pallas on TPU, xla
     # elsewhere); "xla" | "pallas" to force
     attn_impl: str | None = None
-    # MoE (ep over the dp axis); 0 disables
-    moe_every: int = 0
-    experts_per_rank: int = 2
+    # the block's kinds; the defaults are the GPT-2 block
+    attention: str = "mha"   # "mha": fused QKV of head_dim, learned
+    #                          positions; "mla": latent attention, rotary
+    mlp: str = "gelu"        # the dense layers' MLP: "gelu" | "swiglu"
+    tied_head: bool = True   # logits through embed.T; else a head matrix
+    # recompute each block in the backward pass (pp = 1; pp_remat is the
+    # pipeline's): a block keeps only its input
+    remat: bool = False
+    # latent attention ("mla"; head_dim is not used)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    rope_theta: float = 10000.0
+    # expert layers (ep over the dp axis): with n_experts > 0 every
+    # layer after the first n_dense_layers routes each token to
+    # experts_per_token of n_experts; a rank holds experts_held of them,
+    # rank r those from r * experts_held on, and computes that share of
+    # the layer
+    n_experts: int = 0
+    experts_held: int = 0
+    experts_per_token: int = 0
+    d_expert: int = 0
+    shared_experts: int = 0  # a shared expert of this many x d_expert
+    routed_scale: float = 1.0
+    n_dense_layers: int = 0
+    # multi-token prediction: one module predicting token i + 2, its
+    # cross entropy weighted mtp_lambda (depth 0 = none, 1)
+    mtp_depth: int = 0
+    mtp_lambda: float = 0.3
     pp_microbatches: int = 2  # microbatches per pipeline stage when pp>1
     # pipeline schedule when pp>1: "gpipe" (fill-drain) or "interleaved"
     # (Megatron virtual stages, pp_virtual chunks per rank — bubble
@@ -77,10 +113,45 @@ class TransformerConfig:
                 "pp_virtual > 1 requires pp_schedule='interleaved'")
         if self.pp_virtual < 1:
             raise ValueError(f"pp_virtual must be >= 1: {self.pp_virtual}")
+        if self.attention not in ("mha", "mla"):
+            raise ValueError(f"attention must be 'mha' or 'mla', got "
+                             f"{self.attention!r}")
+        if self.mlp not in ("gelu", "swiglu"):
+            raise ValueError(f"mlp must be 'gelu' or 'swiglu', got "
+                             f"{self.mlp!r}")
+        if self.n_experts and self.mlp != "swiglu":
+            raise ValueError("expert layers are SwiGLU: n_experts > 0 "
+                             "needs mlp='swiglu'")
+        if self.mtp_depth not in (0, 1):
+            raise ValueError(f"mtp_depth must be 0 or 1: {self.mtp_depth}")
+        if self.mtp_depth and not (self.n_experts
+                                   and self.attention == "mla"):
+            raise ValueError("the MTP module is a latent-attention expert "
+                             "block: it needs attention='mla' and "
+                             "n_experts > 0")
 
     @property
     def compute_dtype(self):
         return jnp.dtype(self.dtype)
+
+    @property
+    def n_dense(self) -> int:
+        """Layers with a dense MLP: all of them without experts."""
+        return (min(self.n_dense_layers, self.n_layers) if self.n_experts
+                else self.n_layers)
+
+    @property
+    def n_expert_layers(self) -> int:
+        return self.n_layers - self.n_dense
+
+    def is_expert_layer(self, layer: int) -> bool:
+        return layer >= self.n_dense
+
+    @property
+    def gpt2_block(self) -> bool:
+        """Every kind at its default: nothing of ``models/blocks.py``."""
+        return (self.attention == "mha" and self.mlp == "gelu"
+                and self.tied_head and not self.mtp_depth)
 
 
 def init_params(rng: np.random.RandomState, cfg: TransformerConfig,
@@ -93,56 +164,63 @@ def init_params(rng: np.random.RandomState, cfg: TransformerConfig,
     def norm(*shape, scale):
         return (rng.randn(*shape) * scale).astype(np.float32)
 
-    p = {
-        "embed": norm(cfg.vocab, dm, scale=0.02),
-        "pos": norm(cfg.max_seq, dm, scale=0.02),
-        "ln_f": np.ones(dm, np.float32),
-        "layers": {
+    # A tied embedding is the head too and takes a head's small scale.
+    # An untied one is drawn at the scale of what the blocks add to the
+    # stream (fan-in scaled matrices write about 1 a token): at 0.02 the
+    # first attention's causal mean, about 1 / sqrt(position), swamps
+    # the token's own row, the tokens of a sequence collapse onto one
+    # direction and every one of them picks the same experts (PERF.md
+    # section 6, PR 28).
+    p = {"embed": norm(cfg.vocab, dm, scale=0.02 if cfg.tied_head else 1.0)}
+    if cfg.attention == "mha":
+        p["pos"] = norm(cfg.max_seq, dm, scale=0.02)
+        layers = {
             "wqkv": norm(nl, dm, 3 * nh * hd, scale=dm ** -0.5),
             "wo": norm(nl, nh * hd, dm, scale=(nh * hd) ** -0.5),
-            "w1": norm(nl, dm, ff, scale=dm ** -0.5),
-            "w2": norm(nl, ff, dm, scale=ff ** -0.5),
-            "ln1": np.ones((nl, dm), np.float32),
-            "ln2": np.ones((nl, dm), np.float32),
-        },
-    }
-    if cfg.moe_every:
-        n_moe = sum(1 for i in range(nl) if (i + 1) % cfg.moe_every == 0)
-        e = ep * cfg.experts_per_rank
-        p["moe"] = {
-            "router": norm(n_moe, dm, e, scale=dm ** -0.5),
-            "w_in": norm(n_moe, e, dm, ff, scale=dm ** -0.5),
-            "w_out": norm(n_moe, e, ff, dm, scale=ff ** -0.5),
         }
+    else:
+        from horovod_tpu.models import blocks
+
+        layers = blocks.init_mla(norm, cfg, nl)
+    if cfg.mlp == "gelu":
+        layers["w1"] = norm(nl, dm, ff, scale=dm ** -0.5)
+        layers["w2"] = norm(nl, ff, dm, scale=ff ** -0.5)
+    layers["ln1"] = np.ones((nl, dm), np.float32)
+    layers["ln2"] = np.ones((nl, dm), np.float32)
+    p["ln_f"] = np.ones(dm, np.float32)
+    p["layers"] = layers
+    if not cfg.gpt2_block:
+        from horovod_tpu.models import blocks
+
+        p.update(blocks.init_extra(norm, cfg, ep))
     return jax.tree_util.tree_map(jnp.asarray, p)
 
 
 def param_specs(cfg: TransformerConfig):
     """PartitionSpecs for shard_map in_specs: tp shards the
-    column/row-parallel matrices; MoE experts shard over dp (=ep)."""
+    column/row-parallel matrices; experts shard over dp (=ep)."""
     from jax.sharding import PartitionSpec as P
 
-    specs = {
-        "embed": P(),
-        "pos": P(),
-        "ln_f": P(),
-        # layer stacks shard over pp (each stage holds only its layers)
-        # and tp (column/row parallel matrices)
-        "layers": {
-            "wqkv": P("pp", None, "tp"),
-            "wo": P("pp", "tp", None),
-            "w1": P("pp", None, "tp"),
-            "w2": P("pp", "tp", None),
-            "ln1": P("pp"),
-            "ln2": P("pp"),
-        },
-    }
-    if cfg.moe_every:
-        specs["moe"] = {
-            "router": P(),
-            "w_in": P(None, "dp"),
-            "w_out": P(None, "dp"),
-        }
+    # layer stacks shard over pp (each stage holds only its layers)
+    # and tp (column/row parallel matrices)
+    specs = {"embed": P(), "ln_f": P()}
+    if cfg.attention == "mha":
+        specs["pos"] = P()
+        layers = {"wqkv": P("pp", None, "tp"), "wo": P("pp", "tp", None)}
+    else:
+        from horovod_tpu.models import blocks
+
+        layers = blocks.mla_specs("pp")
+    if cfg.mlp == "gelu":
+        layers["w1"] = P("pp", None, "tp")
+        layers["w2"] = P("pp", "tp", None)
+    layers["ln1"] = P("pp")
+    layers["ln2"] = P("pp")
+    specs["layers"] = layers
+    if not cfg.gpt2_block:
+        from horovod_tpu.models import blocks
+
+        specs.update(blocks.extra_specs(cfg))
     return specs
 
 
@@ -152,77 +230,89 @@ def _rmsnorm(x, g):
     return ((x32 / rms) * g).astype(x.dtype)
 
 
-def _block(cfg: TransformerConfig, lp, x, moe_params=None):
-    """One transformer block, per-device view.  x: (b, lc, dm)."""
+def _block(cfg: TransformerConfig, lp, x, positions=None, ffn=None,
+           ffn_weights=None):
+    """One transformer block, per-device view.  x: (b, lc, dm).
+    ``ffn`` (``models/blocks.py``: SwiGLU or the expert layer, with its
+    weights) replaces the stack's own GELU MLP; ``positions`` are the
+    rotary ones of latent attention.  Returns ``(x, pairs)``: the
+    expert layer's pairs per held expert, else ``None``."""
     b, lc, dm = x.shape
     cd = cfg.compute_dtype
-    tp = lax.axis_size("tp")
-    nh_local = cfg.n_heads // tp
 
     h = _rmsnorm(x, lp["ln1"])
-    h = copy_to_tp(h, "tp")  # Megatron "f": bwd sums shard contributions
-    qkv = (h.astype(cd) @ lp["wqkv"].astype(cd))
-    qkv = qkv.reshape(b, lc, 3, nh_local, cfg.head_dim)
-    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-    with jax.named_scope("hvd_attn"):
-        attn = ring_attention(q, k, v, "sp", causal=True,
-                              impl=cfg.attn_impl)
-    attn = attn.reshape(b, lc, nh_local * cfg.head_dim)
-    proj = (attn.astype(cd) @ lp["wo"].astype(cd)).astype(jnp.float32)
-    proj = reduce_from_tp(proj, "tp")  # Megatron "g": row-parallel reduce
+    if cfg.attention == "mla":
+        from horovod_tpu.models import blocks
+
+        with jax.named_scope("hvd_mla"):
+            proj = blocks.mla(cfg, lp, h, positions)
+    else:
+        tp = lax.axis_size("tp")
+        nh_local = cfg.n_heads // tp
+        h = copy_to_tp(h, "tp")  # Megatron "f": bwd sums shard contributions
+        qkv = (h.astype(cd) @ lp["wqkv"].astype(cd))
+        qkv = qkv.reshape(b, lc, 3, nh_local, cfg.head_dim)
+        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        with jax.named_scope("hvd_attn"):
+            attn = ring_attention(q, k, v, "sp", causal=True,
+                                  impl=cfg.attn_impl)
+        attn = attn.reshape(b, lc, nh_local * cfg.head_dim)
+        proj = (attn.astype(cd) @ lp["wo"].astype(cd)).astype(jnp.float32)
+        proj = reduce_from_tp(proj, "tp")  # Megatron "g": row-parallel reduce
     x = x + proj.astype(x.dtype)
 
     h = _rmsnorm(x, lp["ln2"])
-    if moe_params is not None:
-        tokens = h.reshape(b * lc, dm)
-        out, aux = moe_layer(tokens, moe_params["router"],
-                             moe_params["w_in"], moe_params["w_out"],
-                             axis_name="dp")
-        mlp = out.reshape(b, lc, dm).astype(jnp.float32)
+    if ffn is not None:
+        mlp, pairs = ffn(cfg, ffn_weights, h)
     else:
         h = copy_to_tp(h, "tp")
         ff = jax.nn.gelu((h.astype(cd) @ lp["w1"].astype(cd))
                          .astype(jnp.float32)).astype(cd)
         mlp = (ff @ lp["w2"].astype(cd)).astype(jnp.float32)
         mlp = reduce_from_tp(mlp, "tp")
-        aux = jnp.float32(0.0)
+        pairs = None
     x = x + mlp.astype(x.dtype)
-    return x, aux
+    return x, pairs
 
 
-def forward(params, tokens, cfg: TransformerConfig):
-    """Per-device forward inside shard_map over ('dp','pp','tp','sp').
-
-    tokens: (b_local, lc_local) int32.  Returns (logits fp32
-    (b, lc, vocab), aux_loss).
-    """
+def _stack(params, tokens, cfg: TransformerConfig):
+    """Per-device embedding and layer stack inside shard_map over
+    ('dp','pp','tp','sp').  tokens: (b_local, lc_local) int32.  Returns
+    ``(x (b, lc, dm) before the final norm, positions (lc,) global,
+    [pairs of each expert layer])``."""
     cd = cfg.compute_dtype
     sp_idx = lax.axis_index("sp")
     nstages = lax.axis_size("pp")
     b, lc = tokens.shape
     pos = sp_idx * lc + jnp.arange(lc)
-    x = (params["embed"][tokens] + params["pos"][pos]).astype(cd)
+    if cfg.attention == "mha":
+        x = (params["embed"][tokens] + params["pos"][pos]).astype(cd)
+    else:
+        x = params["embed"][tokens].astype(cd)
 
     layers = params["layers"]
-    moe = params.get("moe")
     local_layers = layers["ln1"].shape[0]  # n_layers / pp per stage
+    pairs = []
+    block = _remat_block if cfg.remat else _block
 
     if nstages == 1:
-        aux = jnp.float32(0.0)
         for i in range(local_layers):
             lp = jax.tree_util.tree_map(lambda a: a[i], layers)
-            mp = None
-            if moe is not None and (i + 1) % cfg.moe_every == 0:
-                idx = sum(1 for j in range(i + 1)
-                          if (j + 1) % cfg.moe_every == 0) - 1
-                mp = jax.tree_util.tree_map(lambda a: a[idx], moe)
-            x, a = _block(cfg, lp, x, mp)
-            aux = aux + a
+            if cfg.mlp == "gelu":
+                ffn = weights = None
+            else:
+                from horovod_tpu.models import blocks
+
+                ffn, weights = blocks.ffn_of(cfg, params, i)
+            x, routed = block(cfg, lp, x, pos, ffn, weights)
+            if routed is not None:
+                pairs.append(routed)
     else:
-        if moe is not None:
+        if cfg.mlp != "gelu" or cfg.attention != "mha":
             raise NotImplementedError(
-                "MoE layers under pipeline parallelism are not supported "
-                "yet; use moe_every=0 when pp > 1.")
+                "latent attention, SwiGLU and expert layers under "
+                "pipeline parallelism are not supported yet; pp > 1 "
+                "runs the GPT-2 block (attention='mha', mlp='gelu').")
 
         m = cfg.pp_microbatches
         micro = x.reshape(m, b // m, lc, cfg.d_model)
@@ -255,17 +345,57 @@ def forward(params, tokens, cfg: TransformerConfig):
 
             x = gpipe(stage_fn, None, micro, "pp", remat=cfg.pp_remat)
         x = x.reshape(b, lc, cfg.d_model)
-        aux = jnp.float32(0.0)
+    return x, pos, pairs
 
-    x = _rmsnorm(x, params["ln_f"])
+
+# Recomputed in the backward pass where the configuration asks for it
+# (``remat``): a block, or a loss head, then keeps only its inputs.  The
+# plain functions are called directly otherwise: every Python frame
+# between ``loss_fn`` and an operation is paid for again by each trace
+# of the step (PERF.md section 6, PR 28: two frames more were 2 s of the
+# GPT-2 cells' set-up on the chip's host).
+_remat_block = jax.checkpoint(_block, static_argnums=(0, 4))
+
+
+def _logits(cfg: TransformerConfig, x, gain, table):
+    """Final norm and f32 logits through ``table`` (``embed``,
+    transposed, or the untied head), (b, lc, vocab)."""
+    cd = cfg.compute_dtype
+    x = _rmsnorm(x, gain)
     with jax.named_scope("hvd_loss_head"):
-        logits = (x.astype(cd)
-                  @ params["embed"].astype(cd).T).astype(jnp.float32)
-    return logits, aux
+        return (x.astype(cd)
+                @ (table.astype(cd).T if cfg.tied_head
+                   else table.astype(cd))).astype(jnp.float32)
 
 
-def loss_fn(params, tokens, targets, cfg: TransformerConfig):
-    """LOCAL slice of the global-mean cross entropy.
+def _head_nll(cfg: TransformerConfig, x, gain, table, targets):
+    """The targets' negative log likelihood under :func:`_logits`,
+    (b, lc)."""
+    logits = _logits(cfg, x, gain, table)
+    with jax.named_scope("hvd_loss_head"):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        return -jnp.take_along_axis(logp, targets[..., None],
+                                    axis=-1)[..., 0]
+
+
+_remat_head_nll = jax.checkpoint(_head_nll, static_argnums=(0,))
+
+
+def forward(params, tokens, cfg: TransformerConfig):
+    """Per-device forward inside shard_map over ('dp','pp','tp','sp').
+
+    tokens: (b_local, lc_local) int32.  Returns (logits fp32
+    (b, lc, vocab), [pairs per held expert of each expert layer]).
+    """
+    x, _, pairs = _stack(params, tokens, cfg)
+    table = params["embed"] if cfg.tied_head else params["head"]
+    return _logits(cfg, x, params["ln_f"], table), pairs
+
+
+def loss_fn(params, tokens, targets, cfg: TransformerConfig,
+            with_routing: bool = False):
+    """LOCAL slice of the global-mean cross entropy (plus
+    ``mtp_lambda`` times the MTP module's, where there is one).
 
     Deliberately psum-free: local token-loss sum divided by the GLOBAL
     token count (a static number), so that one explicit psum of the
@@ -273,15 +403,54 @@ def loss_fn(params, tokens, targets, cfg: TransformerConfig):
     psum inside the differentiated loss would double-count — psum
     transposes to psum, inflating gradients by the data-axis size.
     Report the global loss by psumming this value outside the grad.
+
+    ``with_routing`` returns ``(loss, pairs)``: for every expert layer
+    (the MTP module's last) the (token, expert) pairs each held expert
+    computed, (layers, held) int32 (:data:`loss_and_routing`).
     """
-    logits, aux = forward(params, tokens, cfg)
-    with jax.named_scope("hvd_loss_head"):
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None],
-                                   axis=-1)[..., 0]
+    x, pos, pairs = _stack(params, tokens, cfg)
+    head_nll = _remat_head_nll if cfg.remat else _head_nll
+    table = params["embed"] if cfg.tied_head else params["head"]
+    nll = head_nll(cfg, x, params["ln_f"], table, targets)
     data_ranks = lax.axis_size("dp") * lax.axis_size("sp")
     global_tokens = jnp.float32(nll.size) * data_ranks
-    return jnp.sum(nll) / global_tokens + 0.01 * aux / data_ranks
+    loss = jnp.sum(nll) / global_tokens
+    if cfg.mtp_depth:
+        from horovod_tpu.models import blocks
+
+        block = _remat_block if cfg.remat else _block
+        extra, routed = blocks.mtp_loss(
+            cfg, params, x, targets,
+            lambda lp, w, h: block(cfg, lp, h, pos, blocks.expert_ffn, w),
+            lambda h, gain, nxt: head_nll(cfg, h, gain, table, nxt))
+        loss = loss + cfg.mtp_lambda * extra
+        pairs.append(routed)
+    if not with_routing:
+        return loss
+    return loss, (jnp.stack(pairs) if pairs
+                  else jnp.zeros((0, 0), jnp.int32))
+
+
+# ``jax.value_and_grad(loss_and_routing, has_aux=True)`` gives the loss,
+# the routing and the gradients from one program.
+loss_and_routing = functools.partial(loss_fn, with_routing=True)
+
+
+def record_routing(cfg: TransformerConfig, pairs, tokens: int) -> list:
+    """Write what a batch's routing sent the held experts to the flight
+    ring: one ``hvd_moe_route`` record an expert layer (the MTP
+    module's last) with ``pairs`` — a row of :func:`loss_and_routing`'s
+    second value, summed over ``sp`` and in expert order over the
+    ``dp`` ranks — ``tokens`` (in the batch), ``top_k`` and ``dropped``,
+    which the dropless layer keeps at 0.  Returns the records."""
+    from horovod_tpu.runtime import flight
+
+    records = [dict(layer=i, pairs=[int(n) for n in row], tokens=int(tokens),
+                    top_k=cfg.experts_per_token, dropped=0)
+               for i, row in enumerate(np.asarray(pairs))]
+    for record in records:
+        flight.record("hvd_moe_route", **record)
+    return records
 
 
 def make_train_step(cfg: TransformerConfig, mesh, optimizer,

@@ -48,9 +48,11 @@ _L_LANE = 64
 VMEM_BUDGET = 64 << 20
 
 
-def tile_vmem_bytes(bq: int, bk: int, d: int, itemsize: int) -> int:
+def tile_vmem_bytes(bq: int, bk: int, d: int, itemsize: int,
+                    dv: int | None = None) -> int:
     """What one grid step of the hungriest of the three kernels (dK/dV)
-    may hold in VMEM at a (bq, bk) tile, head size ``d`` and
+    may hold in VMEM at a (bq, bk) tile, q/k head size ``d``, v head
+    size ``dv`` (``d`` where not given) and
     ``itemsize``-byte operands: the kernels' ``vmem_limit_bytes``, and
     what ``ring_attention`` keeps under :data:`VMEM_BUDGET` when it
     picks tiles.  Held to what Mosaic allocates when it compiles the
@@ -58,14 +60,15 @@ def tile_vmem_bytes(bq: int, bk: int, d: int, itemsize: int) -> int:
     this says 18; 36 at 2048×2048 where this says 61; 23 at 1024×1024,
     d 256, f32 where this says 35)."""
     n = max(bq, bk)
+    dd = d + (d if dv is None else dv)      # a q/k-sized and a v-sized block
     # Mosaic streams the elementwise chain between the products: what
     # stays is two f32 (bq, bk) tiles (scores, dP) and one in the
     # operand dtype for the next product (measured: 9 bytes an element)
     tiles = bq * bk * (2 * 4 + itemsize)
     # q, dO, k, v; the packed row state; two f32 blocks in or out (o, or
     # dk and dv) — each double-buffered by the pipeline
-    blocks = 2 * (4 * n * d * itemsize + n * 128 * 4 + 2 * n * d * 4)
-    scratch = 2 * n * 128 * 4 + 2 * n * d * 4
+    blocks = 2 * (2 * n * dd * itemsize + n * 128 * 4 + n * dd * 4)
+    scratch = 2 * n * 128 * 4 + n * dd * 4
     return (tiles + blocks + scratch) * 5 // 4     # a quarter of headroom
 
 
@@ -139,7 +142,7 @@ def _q_major_maps(bq: int, bk: int, causal: bool):
     return q_row, kv_row
 
 
-def _compiler_params(bq: int, bk: int, d: int, dtype):
+def _compiler_params(bq: int, bk: int, d: int, dv: int, dtype):
     # the two outer dimensions are independent work items, only the
     # innermost carries scratch state — telling Mosaic lets it overlap
     # DMA with MXU work across grid steps instead of serializing the
@@ -148,7 +151,7 @@ def _compiler_params(bq: int, bk: int, d: int, dtype):
         dimension_semantics=("parallel", "parallel", "arbitrary"),
         # never less than Mosaic's own default, which small tiles had
         vmem_limit_bytes=max(16 << 20, tile_vmem_bytes(
-            bq, bk, d, jnp.dtype(dtype).itemsize)))
+            bq, bk, d, jnp.dtype(dtype).itemsize, dv)))
 
 
 def _flash_step_kernel(off_ref, q_ref, k_ref, v_ref, mli_ref, oi_ref,
@@ -192,7 +195,7 @@ def _flash_step_kernel(off_ref, q_ref, k_ref, v_ref, mli_ref, oi_ref,
         l_new = l_prev * alpha + jnp.sum(p, axis=-1)
         pv = jax.lax.dot_general(
             p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)        # (bq, d)
+            preferred_element_type=jnp.float32)        # (bq, dv)
         m_s[:, :] = m_new[:, None] + jnp.zeros_like(m_s)
         l_s[:, :] = l_new[:, None] + jnp.zeros_like(l_s)
         acc[:, :] = acc[:, :] * alpha[:, None] + pv
@@ -218,7 +221,7 @@ def _tiles(block_q, block_k, lq, lk):
 def _flash_block_step_impl(q, k, v, m, l, o, q_offset, k_offset,
                            causal, block_q, block_k, interpret):
     bh, lq, d = q.shape
-    _, lk, _ = k.shape
+    _, lk, dv = v.shape
     bq, bk = _tiles(block_q, block_k, lq, lk)
     interpret = pallas_interpret(interpret)
     scale = 1.0 / (d ** 0.5)
@@ -243,24 +246,24 @@ def _flash_block_step_impl(q, k, v, m, l, o, q_offset, k_offset,
             in_specs=[
                 pl.BlockSpec((1, bq, d), q_row),      # q
                 pl.BlockSpec((1, bk, d), kv_row),     # k
-                pl.BlockSpec((1, bk, d), kv_row),     # v
+                pl.BlockSpec((1, bk, dv), kv_row),    # v
                 pl.BlockSpec((1, bq, 128), q_row),    # m|l
-                pl.BlockSpec((1, bq, d), q_row),      # o
+                pl.BlockSpec((1, bq, dv), q_row),     # o
             ],
             out_specs=[
                 pl.BlockSpec((1, bq, 128), q_row),
-                pl.BlockSpec((1, bq, d), q_row),
+                pl.BlockSpec((1, bq, dv), q_row),
             ],
             scratch_shapes=[
                 pltpu.VMEM((bq, 128), jnp.float32),   # running max
                 pltpu.VMEM((bq, 128), jnp.float32),   # running denominator
-                pltpu.VMEM((bq, d), jnp.float32),     # numerator accumulator
+                pltpu.VMEM((bq, dv), jnp.float32),    # numerator accumulator
             ]),
         out_shape=[
             jax.ShapeDtypeStruct((bh, lq, 128), jnp.float32),
-            jax.ShapeDtypeStruct((bh, lq, d), jnp.float32),
+            jax.ShapeDtypeStruct((bh, lq, dv), jnp.float32),
         ],
-        compiler_params=_compiler_params(bq, bk, d, q.dtype),
+        compiler_params=_compiler_params(bq, bk, d, dv, q.dtype),
         interpret=interpret,
     )(offs, q, k, v, ml, o)
     return mlo[..., _M_LANE], mlo[..., _L_LANE], oo
@@ -333,12 +336,12 @@ def _flash_bwd_dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
 
     def accumulate(mask):
         q = q_ref[0]                                   # (bq, d)
-        do = do_ref[0]                                 # (bq, d)
+        do = do_ref[0]                                 # (bq, dv)
         p, ds = _recomputed_p_ds(q, k_ref[0], v_ref[0], do, ld_ref[0],
                                  mask, scale)
         dv_acc[:, :] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)        # (bk, d)
+            preferred_element_type=jnp.float32)        # (bk, dv)
         dk_acc[:, :] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)        # (bk, d)
@@ -365,14 +368,15 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_offset, k_offset, *,
                  block_k: int = 128, interpret: bool | None = None):
     """Flash-attention dQ for one (local Q, one KV block) pair.
 
-    q: (BH, Lq, D); k/v: (BH, Lk, D); do: (BH, Lq, D) upstream grad in
+    q: (BH, Lq, D); k: (BH, Lk, D); v: (BH, Lk, Dv); do: (BH, Lq, Dv)
+    upstream grad in
     the matmul dtype; lse: (BH, Lq) fp32 saved log-sum-exp rows
     (m + log l from the forward); delta: (BH, Lq) fp32 rowsum(dO * O).
     Returns fp32 (BH, Lq, D) — the dQ contribution of this KV block
     (sum over ring steps at the caller).
     """
     bh, lq, d = q.shape
-    _, lk, _ = k.shape
+    _, lk, dv = v.shape
     bq, bk = _tiles(block_q, block_k, lq, lk)
     interpret = pallas_interpret(interpret)
     scale = 1.0 / (d ** 0.5)
@@ -391,14 +395,14 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_offset, k_offset, *,
             in_specs=[
                 pl.BlockSpec((1, bq, d), q_row),      # q
                 pl.BlockSpec((1, bk, d), kv_row),     # k
-                pl.BlockSpec((1, bk, d), kv_row),     # v
-                pl.BlockSpec((1, bq, d), q_row),      # do
+                pl.BlockSpec((1, bk, dv), kv_row),    # v
+                pl.BlockSpec((1, bq, dv), q_row),     # do
                 pl.BlockSpec((1, bq, 128), q_row),    # ld
             ],
             out_specs=pl.BlockSpec((1, bq, d), q_row),
             scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((bh, lq, d), jnp.float32),
-        compiler_params=_compiler_params(bq, bk, d, q.dtype),
+        compiler_params=_compiler_params(bq, bk, d, dv, q.dtype),
         interpret=interpret,
     )(offs, q, k, v, do, ld)
 
@@ -409,11 +413,11 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, q_offset, k_offset, *,
     """Flash-attention (dK, dV) for one (local Q, one KV block) pair.
 
     Same contract as :func:`flash_bwd_dq`; returns fp32
-    ((BH, Lk, D), (BH, Lk, D)) — this Q chunk's contribution to the
+    ((BH, Lk, D), (BH, Lk, Dv)) — this Q chunk's contribution to the
     block's dK/dV (ring callers accumulate while rotating).
     """
     bh, lq, d = q.shape
-    _, lk, _ = k.shape
+    _, lk, dv = v.shape
     bq, bk = _tiles(block_q, block_k, lq, lk)
     interpret = pallas_interpret(interpret)
     scale = 1.0 / (d ** 0.5)
@@ -444,21 +448,21 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, q_offset, k_offset, *,
             in_specs=[
                 pl.BlockSpec((1, bq, d), q_row),      # q
                 pl.BlockSpec((1, bk, d), kv_row),     # k
-                pl.BlockSpec((1, bk, d), kv_row),     # v
-                pl.BlockSpec((1, bq, d), q_row),      # do
+                pl.BlockSpec((1, bk, dv), kv_row),    # v
+                pl.BlockSpec((1, bq, dv), q_row),     # do
                 pl.BlockSpec((1, bq, 128), q_row),    # ld
             ],
             out_specs=[
                 pl.BlockSpec((1, bk, d), kv_row),
-                pl.BlockSpec((1, bk, d), kv_row),
+                pl.BlockSpec((1, bk, dv), kv_row),
             ],
             scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                            pltpu.VMEM((bk, d), jnp.float32)]),
+                            pltpu.VMEM((bk, dv), jnp.float32)]),
         out_shape=[
             jax.ShapeDtypeStruct((bh, lk, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, lk, d), jnp.float32),
+            jax.ShapeDtypeStruct((bh, lk, dv), jnp.float32),
         ],
-        compiler_params=_compiler_params(bq, bk, d, q.dtype),
+        compiler_params=_compiler_params(bq, bk, d, dv, q.dtype),
         interpret=interpret,
     )(offs, q, k, v, do, ld)
 
@@ -505,8 +509,10 @@ def flash_block_step(q, k, v, m, l, o, q_offset, k_offset, *,
     """One ring-attention accumulation: attend local Q against one KV
     block, updating carried online-softmax state.
 
-    q: (BH, Lq, D); k, v: (BH, Lk, D); m, l: (BH, Lq) fp32 running
-    max / denominator; o: (BH, Lq, D) fp32 unnormalized numerator.
+    q: (BH, Lq, D); k: (BH, Lk, D); v: (BH, Lk, Dv), Dv any size (a
+    latent-attention head has 192 for q/k and 128 for v); m, l:
+    (BH, Lq) fp32 running max / denominator; o: (BH, Lq, Dv) fp32
+    unnormalized numerator.
     q_offset / k_offset: global positions of q[:,0]/k[:,0] (traced OK).
     Returns updated (m, l, o).  Differentiable: the backward pass is
     the XLA online-softmax step's VJP over the saved inputs.

@@ -1,12 +1,30 @@
-"""Expert parallelism: Switch-style top-1 mixture-of-experts with the
-expert dimension sharded over an ``ep`` mesh axis.
+"""The expert layer: one chip's share of a fine-grained mixture of
+experts, dropless.
 
-Absent from the reference (SURVEY §2.7); TPU extension.  Token dispatch
-follows the Mesh-TensorFlow/Switch einsum formulation: a (tokens,
-experts, capacity) one-hot dispatch tensor turns routing into two
-einsums (MXU work, no gathers), and a pair of `lax.all_to_all`s moves
-token blocks between the ranks that own each expert — the canonical
-EP collective (SURVEY §2.7 "EP all-to-all").
+Absent from the reference (SURVEY §2.7); TPU extension.  The layer is
+told which experts it holds (``first``, and as many as its weights have
+rows), routes every token over **all** ``E`` experts — sigmoid scores,
+the top ``k`` of score + correction bias, the selected scores
+renormalised and scaled (the DeepSeek-V3 ``noaux_tc`` rule) — and adds
+up what its own experts give for the (token, expert) pairs sent to
+them.  A shared expert, where the weights have one, is computed whole
+for every token.  What absent experts would have added is left out:
+the shares of all holders add up to the whole layer
+(``tests/test_pipeline_moe.py``).
+
+No capacity and no dropped pair.  The pairs are sorted by expert, the
+held ones first, and taken in chunks of ``CHUNK_ROWS`` rows: a gather
+of the rows, three grouped products over the experts held
+(``lax.ragged_dot``, SwiGLU), a scatter-add back onto the tokens.  A
+loop with a trip count read from the routing runs as many chunks as the
+held pairs fill, so work and memory follow the pairs that exist and not
+the worst case (``tokens x k`` rows when every token picks held
+experts), and any routing, however skewed, is computed in full.  The
+backward pass is the same loop over the chunks' own ``jax.vjp``.
+
+One chip: no exchange.  Over an ``ep`` mesh axis the same share runs
+between an all-gather of the tokens and a reduce-scatter of the partial
+results.
 """
 
 from __future__ import annotations
@@ -17,86 +35,213 @@ from jax import lax
 
 from horovod_tpu.common.types import HorovodTpuError
 
+CHUNK_ROWS = 16384
 
-def moe_layer(x, router_w, w_in, w_out, axis_name: str = "ep",
-              capacity_factor: float = 1.25):
-    """Top-1 (Switch) MoE over sharded experts.
 
-    x: (T, d) local tokens; router_w: (d, E) with E total experts;
-    w_in: (E_local, d, ff), w_out: (E_local, ff, d) — this rank's expert
-    weights, E = ep_size * E_local.
-    Returns (out (T, d), aux_loss scalar) — aux is the Switch
-    load-balancing loss.
+def route(x, router_w, bias, top_k: int, scale: float):
+    """``(ids (T, k) int32, weights (T, k) f32)``: sigmoid scores over
+    all experts in float32, the top ``k`` of ``score + bias`` (the bias
+    selects and takes no gradient), weights ``scale * s_i / (sum of the
+    selected s + 1e-20)``."""
+    with jax.named_scope("hvd_moe_route"):
+        scores = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), router_w.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST))
+        _, ids = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        picked = jnp.take_along_axis(scores, ids, axis=-1)
+        weights = scale * picked / (
+            jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    return ids, weights
+
+
+def swiglu(x, w):
+    """``(silu(x W_gate) * (x W_up)) W_down`` with f32 accumulation;
+    the shared expert and the dense layers' MLP."""
+    gate = jnp.dot(x, w["w_gate"], preferred_element_type=jnp.float32)
+    up = jnp.dot(x, w["w_up"], preferred_element_type=jnp.float32)
+    hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
+    return jnp.dot(hidden, w["w_down"], preferred_element_type=jnp.float32)
+
+
+def pairs_per_expert(ids, first, held: int):
+    """How many pairs :func:`route` sent to each of the ``held`` experts
+    from ``first`` on: (held,) int32.  Every one is computed; none is
+    dropped."""
+    local = ids.reshape(-1, 1) - first
+    return jnp.sum(local == jnp.arange(held, dtype=local.dtype), axis=0,
+                   dtype=jnp.int32)
+
+
+def _plan(ids, first, held: int):
+    """The (token, slot) pairs in expert order, held experts first, cut
+    into chunks.  Returns ``(order, starts (held,), ends (held,))``:
+    pair ``order[r]`` sits in row ``r``; expert ``first + e`` owns rows
+    ``starts[e] .. ends[e]``; rows from ``ends[-1]`` on go to experts
+    held elsewhere.  ``order`` is padded to a whole number of chunks
+    (the padding lies past every pair and is never reached)."""
+    local = ids.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    pad = -order.size % _chunk_rows(ids)
+    if pad:
+        order = jnp.concatenate([order, jnp.zeros(pad, order.dtype)])
+    counts = pairs_per_expert(ids, first, held)
+    ends = jnp.cumsum(counts)
+    return order, ends - counts, ends
+
+
+def _chunk_rows(ids) -> int:
+    return min(CHUNK_ROWS, ids.size)
+
+
+def _chunk(x, w, weights, plan, index, rows: int):
+    """Rows ``index * rows ..`` of the sorted pairs: ``(tokens (rows,),
+    out (rows, d) f32)``, the weighted outputs of the held experts for
+    those pairs, zero in rows past the last held pair."""
+    order, starts, ends = plan
+    top_k = weights.shape[1]
+    start = index * rows
+    pairs = lax.dynamic_slice_in_dim(order, start, rows)
+    tokens = pairs // top_k
+    live = (start + jnp.arange(rows, dtype=jnp.int32)) < ends[-1]
+    sizes = (jnp.clip(ends - start, 0, rows)
+             - jnp.clip(starts - start, 0, rows))
+    xs = jnp.where(live[:, None], x[tokens], 0)
+
+    def grouped(a, b):
+        return lax.ragged_dot(a, b, sizes,
+                              preferred_element_type=jnp.float32)
+
+    hidden = (jax.nn.silu(grouped(xs, w["w_gate"]))
+              * grouped(xs, w["w_up"])).astype(x.dtype)
+    out = grouped(hidden, w["w_down"])
+    scale = weights.reshape(-1)[pairs]
+    return tokens, jnp.where(live[:, None], out * scale[:, None], 0.0)
+
+
+def _live_chunks(plan, rows: int):
+    return (plan[2][-1] + rows - 1) // rows
+
+
+@jax.custom_vjp
+def expert_share(x, w, ids, weights, first):
+    """``sum_i w_i E_i(x)`` over the pairs whose expert is one of the
+    ``held`` from ``first`` on: (T, d) f32.  ``x``: (T, d) in the
+    compute dtype; ``w``: ``{"w_gate", "w_up": (held, d, f), "w_down":
+    (held, f, d)}`` in the compute dtype; ``ids``, ``weights``: (T, k)
+    from :func:`route`; ``first``: an int or a traced scalar."""
+    return _expert_share_fwd(x, w, ids, weights, first)[0]
+
+
+def _expert_share_fwd(x, w, ids, weights, first):
+    rows = _chunk_rows(ids)
+    plan = _plan(ids, first, w["w_gate"].shape[0])
+
+    def body(index, out):
+        tokens, part = _chunk(x, w, weights, plan, index, rows)
+        return out.at[tokens].add(part)
+
+    out = lax.fori_loop(0, _live_chunks(plan, rows), body,
+                        jnp.zeros(x.shape, jnp.float32))
+    return out, (x, w, weights, plan)
+
+
+def _expert_share_bwd(res, dout):
+    """The forward's loop again, each chunk through its own
+    ``jax.vjp``: nothing of a chunk outlives its turn."""
+    x, w, weights, plan = res
+    rows = _chunk_rows(weights)
+
+    def body(index, grads):
+        dx, dw, dweights = grads
+        (tokens, _), vjp = jax.vjp(
+            lambda x_, w_, weights_: _chunk(x_, w_, weights_, plan, index,
+                                            rows), x, w, weights)
+        gx, gw, gweights = vjp((jnp.zeros(tokens.shape, jax.dtypes.float0),
+                                dout[tokens]))
+        return (dx + gx.astype(jnp.float32),
+                jax.tree_util.tree_map(
+                    lambda a, g: a + g.astype(jnp.float32), dw, gw),
+                dweights + gweights)
+
+    zeros = (jnp.zeros(x.shape, jnp.float32),
+             jax.tree_util.tree_map(
+                 lambda a: jnp.zeros(a.shape, jnp.float32), w),
+             jnp.zeros_like(weights))
+    dx, dw, dweights = lax.fori_loop(0, _live_chunks(plan, rows), body,
+                                     zeros)
+    return (dx.astype(x.dtype),
+            jax.tree_util.tree_map(lambda g, a: g.astype(a.dtype), dw, w),
+            None, dweights, None)
+
+
+expert_share.defvjp(_expert_share_fwd, _expert_share_bwd)
+
+
+def moe_layer(x, params, *, top_k: int, scale: float, first=0,
+              axis_name: str | None = None):
+    """One expert layer on (T, d) tokens in the compute dtype.
+
+    ``params``: ``router`` (d, E) and ``bias`` (E,) over all ``E``
+    experts; ``experts`` ``{"w_gate", "w_up": (held, d, f), "w_down":
+    (held, f, d)}``, the experts ``first .. first + held`` of the ``E``;
+    ``shared`` (optional) one SwiGLU's weights.  Over ``axis_name`` (an
+    ``ep`` mesh axis of size > 1) rank ``r`` holds the experts from
+    ``first + r * held`` on: the tokens are gathered, every rank
+    computes its share for all of them, and a reduce-scatter returns
+    to each rank its own tokens' sum.  Returns ``(out (T, d) f32,
+    pairs (held,) int32)``: the routed part plus the shared expert, and
+    the pairs each held expert computed (of the gathered tokens).
     """
-    ep = lax.axis_size(axis_name)
-    t, d = x.shape
-    e_local = w_in.shape[0]
-    e = ep * e_local
-    if router_w.shape[1] != e:
+    cd = x.dtype
+    experts = jax.tree_util.tree_map(lambda a: a.astype(cd),
+                                     params["experts"])
+    held = experts["w_gate"].shape[0]
+    n_experts = params["router"].shape[1]
+    ep = 1 if axis_name is None else lax.axis_size(axis_name)
+    if ep * held > n_experts:
         raise HorovodTpuError(
-            f"router width {router_w.shape[1]} != experts {e}")
-    cap = int(max(1, (t / e) * capacity_factor))
-
-    logits = (x @ router_w).astype(jnp.float32)           # (T, E)
-    gates = jax.nn.softmax(logits, axis=-1)
-    expert_idx = jnp.argmax(gates, axis=-1)               # (T,)
-    onehot = jax.nn.one_hot(expert_idx, e, dtype=jnp.float32)  # (T, E)
-    gate = jnp.sum(gates * onehot, axis=-1)               # (T,)
-
-    # Switch aux loss: E * sum_e fraction_tokens_e * mean_prob_e
-    density = jnp.mean(onehot, axis=0)
-    density_proxy = jnp.mean(gates, axis=0)
-    aux = e * jnp.sum(density * density_proxy)
-
-    # position of each token within its expert; drop beyond capacity
-    pos = jnp.cumsum(onehot, axis=0) * onehot             # 1-based
-    keep = (pos > 0) & (pos <= cap)
-    pos0 = jnp.clip(pos - 1, 0, cap - 1).astype(jnp.int32)
-    dispatch = (keep.astype(jnp.float32)[..., None]
-                * jax.nn.one_hot(pos0, cap, dtype=jnp.float32))  # (T,E,C)
-    combine = dispatch * gate[:, None, None]
-
-    xin = x.astype(jnp.float32)
-    expert_in = jnp.einsum("tec,td->ecd", dispatch, xin)  # (E, C, d)
-    # ship expert blocks to their owner ranks
-    expert_in = expert_in.reshape(ep, e_local, cap, d)
-    expert_in = lax.all_to_all(expert_in, axis_name, split_axis=0,
-                               concat_axis=0, tiled=False)
-    # (ep_src, E_local, C, d): tokens from every rank for local experts
-    expert_in = expert_in.astype(x.dtype)
-
-    def ffn(xe, wi, wo):                                  # (src,C,d)
-        h = jax.nn.gelu(jnp.einsum("scd,df->scf", xe, wi))
-        return jnp.einsum("scf,fd->scd", h, wo)
-
-    expert_out = jax.vmap(ffn, in_axes=(1, 0, 0), out_axes=1)(
-        expert_in, w_in, w_out)                           # (src, E_local, C, d)
-
-    back = lax.all_to_all(expert_out.astype(jnp.float32), axis_name,
-                          split_axis=0, concat_axis=0, tiled=False)
-    back = back.reshape(e, cap, d)                        # (E, C, d) at source
-    out = jnp.einsum("tec,ecd->td", combine, back)
-    return out.astype(x.dtype), aux.astype(jnp.float32)
+            f"{ep} ranks x {held} experts held exceed the router's "
+            f"{n_experts}")
+    tokens = x
+    if ep > 1:
+        tokens = lax.all_gather(x, axis_name, axis=0, tiled=True)
+        first = first + lax.axis_index(axis_name) * held
+    ids, weights = route(tokens, params["router"], params["bias"], top_k,
+                         scale)
+    with jax.named_scope("hvd_moe_experts"):
+        out = expert_share(tokens, experts, ids, weights, first)
+    if ep > 1:
+        out = lax.psum_scatter(out, axis_name, scatter_dimension=0,
+                               tiled=True)
+    if "shared" in params:
+        with jax.named_scope("hvd_moe_shared"):
+            out = out + swiglu(x, jax.tree_util.tree_map(
+                lambda a: a.astype(cd), params["shared"]))
+    return out, lax.stop_gradient(pairs_per_expert(ids, first, held))
 
 
-def moe_reference(x, router_w, w_in_full, w_out_full,
-                  capacity_factor: float = 1.25):
-    """Single-device golden model (all experts local) for tests."""
-    e = router_w.shape[1]
-    t = x.shape[0]
-    cap = int(max(1, (t / e) * capacity_factor))
-    logits = (x @ router_w).astype(jnp.float32)
-    gates = jax.nn.softmax(logits, axis=-1)
-    idx = jnp.argmax(gates, axis=-1)
-    onehot = jax.nn.one_hot(idx, e, dtype=jnp.float32)
-    gate = jnp.sum(gates * onehot, axis=-1)
-    pos = jnp.cumsum(onehot, axis=0) * onehot
-    keep = (pos > 0) & (pos <= cap)
-    out = jnp.zeros_like(x, dtype=jnp.float32)
-    for token in range(t):
-        ei = int(idx[token])
-        if not bool(keep[token, ei]):
-            continue
-        h = jax.nn.gelu(x[token] @ w_in_full[ei])
-        out = out.at[token].set((h @ w_out_full[ei]) * gate[token])
-    return out.astype(x.dtype)
+def moe_reference(x, params, *, top_k: int, scale: float, first: int = 0,
+                  held: int | None = None):
+    """Plain golden model for tests: float32, a Python loop over the
+    experts ``first .. first + held`` (all of ``params["experts"]``
+    where ``held`` is not given), every expert on every token under a
+    mask.  The shared expert is added where ``params`` has one."""
+    scores = jax.nn.sigmoid(x @ params["router"])
+    ids = jnp.argsort(-(scores + params["bias"]), axis=-1,
+                      stable=True)[:, :top_k]
+    picked = jnp.take_along_axis(scores, ids, axis=-1)
+    weights = scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)
+    experts = params["experts"]
+    held = experts["w_gate"].shape[0] if held is None else held
+    out = jnp.zeros_like(x)
+    for e in range(held):
+        w = {name: a[e] for name, a in experts.items()}
+        gate = jnp.sum(jnp.where(ids == first + e, weights, 0.0), axis=-1)
+        out = out + gate[:, None] * (
+            (jax.nn.silu(x @ w["w_gate"]) * (x @ w["w_up"])) @ w["w_down"])
+    if "shared" in params:
+        s = params["shared"]
+        out = out + (jax.nn.silu(x @ s["w_gate"])
+                     * (x @ s["w_up"])) @ s["w_down"]
+    return out

@@ -41,8 +41,9 @@ def xla_block_step(q, k, v, m, l, o, q_offset, k_offset, *,
                    causal: bool):
     """One online-softmax accumulation in the packed layout.
 
-    q: (BH, Lq, D); k/v: (BH, Lk, D); m/l: (BH, Lq) fp32 running
-    max/denominator; o: (BH, Lq, D) fp32 unnormalized numerator.
+    q: (BH, Lq, D); k: (BH, Lk, D); v: (BH, Lk, Dv), Dv any size;
+    m/l: (BH, Lq) fp32 running max/denominator; o: (BH, Lq, Dv) fp32
+    unnormalized numerator.
     q_offset/k_offset: global positions of q[:, 0] / k[:, 0].
     Matmuls stay in the input dtype (bf16-friendly), softmax state fp32.
     """
@@ -81,8 +82,10 @@ def _pick_block(n: int) -> int | None:
     return next((c for c in _TILE_LADDER if n % c == 0), None)
 
 
-def _block_sizes(lc: int, lk: int, d: int, itemsize: int):
-    """(block_q, block_k) for the Pallas kernel at head size ``d`` and
+def _block_sizes(lc: int, lk: int, d: int, itemsize: int,
+                 dv: int | None = None):
+    """(block_q, block_k) for the Pallas kernel at q/k head size ``d``,
+    v head size ``dv`` (``d`` where not given) and
     ``itemsize``-byte operands: forced by the HOROVOD_ATTN_BLOCK_Q/K
     knobs when they divide the chunk (the on-chip tile-sweep hook),
     else the auto pick, stepped down the ladder (K first) while the
@@ -108,7 +111,7 @@ def _block_sizes(lc: int, lk: int, d: int, itemsize: int):
     if _config.get("attn_block_q") or _config.get("attn_block_k"):
         return bq, bk   # a forced size wins; Mosaic says if it is too much
     while (bq and bk and max(bq, bk) > 8
-           and tile_vmem_bytes(bq, bk, d, itemsize) > VMEM_BUDGET):
+           and tile_vmem_bytes(bq, bk, d, itemsize, dv) > VMEM_BUDGET):
         if bk >= bq:
             bk //= 2
         else:
@@ -135,7 +138,8 @@ def auto_impl(batch: int, heads: int, seq_q: int,
 def _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, bq, bk):
     """Pallas ring forward, returning (normalized fp32 out, lse).
 
-    qp/kp/vp: packed (B*H, Lc, D).  lse = m + log(l) per row — the one
+    qp/kp: packed (B*H, Lc, D), vp: (B*H, Lc, Dv).  lse = m + log(l)
+    per row — the one
     O(L) residual the saved-LSE backward needs (fully-masked rows keep
     lse = -inf).
     """
@@ -143,10 +147,10 @@ def _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, bq, bk):
 
     sp = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
-    bh, lc, d = qp.shape
+    bh, lc, _ = qp.shape
     m0 = jnp.full((bh, lc), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((bh, lc), jnp.float32)
-    o0 = jnp.zeros((bh, lc, d), jnp.float32)
+    o0 = jnp.zeros((bh, lc, vp.shape[-1]), jnp.float32)
     rot = [(i, (i + 1) % sp) for i in range(sp)]
 
     def step(j, carry):
@@ -221,8 +225,9 @@ def _ring_flash_bwd(axis_name, causal, bq, bk, res, dout):
         return dq, kj, vj, dkj, dvj
 
     z = jnp.zeros((bh, lc, d), jnp.float32)
+    zv = z if vp.shape[-1] == d else jnp.zeros(vp.shape, jnp.float32)
     dq, _, _, dk, dv = lax.fori_loop(
-        0, sp, step, (z, kp, vp, z, z))
+        0, sp, step, (z, kp, vp, z, zv))
     return dq.astype(qp.dtype), dk.astype(kp.dtype), dv.astype(vp.dtype)
 
 
@@ -233,8 +238,9 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
                    impl: str | None = None, layout: str = "contiguous"):
     """Multi-head attention with the sequence sharded over ``axis_name``.
 
-    q, k, v: (B, Lc, H, D) — the local sequence chunk (global L = Lc * sp).
-    Returns (B, Lc, H, D).  Must run inside shard_map/pjit with
+    q, k: (B, Lc, H, D), v: (B, Lc, H, Dv) — the local sequence chunk
+    (global L = Lc * sp); Dv may differ from D (latent attention: 192
+    and 128).  Returns (B, Lc, H, Dv).  Must run inside shard_map/pjit with
     ``axis_name`` a mesh axis; with axis size 1 it degrades to plain
     blockwise attention.  ``impl``: "pallas" | "xla" | None (auto:
     pallas on TPU, xla elsewhere).
@@ -263,6 +269,7 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
     sp = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, lc, h, d = q.shape
+    dv = v.shape[-1]
 
     asked = impl is not None
     if impl is None:
@@ -274,7 +281,7 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
 
     if impl == "pallas":
         # ring KV blocks are lc long too
-        bq, bk = _block_sizes(lc, lc, d, q.dtype.itemsize)
+        bq, bk = _block_sizes(lc, lc, d, q.dtype.itemsize, dv)
         if bq is None or bk is None:
             msg = (f"sequence chunk {lc} has no tile size the Pallas "
                    "attention kernel can use (a multiple of 8 dividing "
@@ -293,9 +300,9 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
             # hand-written flash backward kernels, O(L) residuals.
             qp = q.transpose(0, 2, 1, 3).reshape(b * h, lc, d)
             kp = k.transpose(0, 2, 1, 3).reshape(b * h, lc, d)
-            vp = v.transpose(0, 2, 1, 3).reshape(b * h, lc, d)
+            vp = v.transpose(0, 2, 1, 3).reshape(b * h, lc, dv)
             out = _ring_flash(qp, kp, vp, axis_name, causal, bq, bk)
-            out = out.reshape(b, h, lc, d).transpose(0, 2, 1, 3)
+            out = out.reshape(b, h, lc, dv).transpose(0, 2, 1, 3)
             return out.astype(q.dtype)
 
         # "remat": per-step custom VJP whose backward is the XLA block
@@ -313,10 +320,10 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
 
     qp = q.transpose(0, 2, 1, 3).reshape(b * h, lc, d)
     kp = k.transpose(0, 2, 1, 3).reshape(b * h, lc, d)
-    vp = v.transpose(0, 2, 1, 3).reshape(b * h, lc, d)
+    vp = v.transpose(0, 2, 1, 3).reshape(b * h, lc, dv)
     m0 = jnp.full((b * h, lc), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((b * h, lc), jnp.float32)
-    o0 = jnp.zeros((b * h, lc, d), jnp.float32)
+    o0 = jnp.zeros((b * h, lc, dv), jnp.float32)
     rot = [(i, (i + 1) % sp) for i in range(sp)]
 
     def step(j, carry):
@@ -334,7 +341,7 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
 
     m, l, o, _, _ = lax.fori_loop(0, sp, step, (m0, l0, o0, kp, vp))
     l = jnp.where(l == 0.0, 1.0, l)
-    out = (o / l[..., None]).reshape(b, h, lc, d).transpose(0, 2, 1, 3)
+    out = (o / l[..., None]).reshape(b, h, lc, dv).transpose(0, 2, 1, 3)
     return out.astype(q.dtype)
 
 

@@ -466,3 +466,119 @@ def test_each_kernel_is_lowered_under_its_own_name(kernel):
     assert "tpu_custom_call" in text
     assert set(re.findall(r'kernel_name = "(\w+)"', text)) == {kernel}
     assert f"{kernel}/pallas_call" in text      # and in the op_name
+
+
+# ---------------------------------------------------------------------------
+# A v head size other than the q/k head size (latent attention: 192 / 128)
+# ---------------------------------------------------------------------------
+
+
+def _latent_qkv(seed, bh=3, l=64, d=24, dv=16):
+    rng = np.random.RandomState(seed)
+    return (jnp.asarray(rng.randn(bh, l, d), jnp.float32),
+            jnp.asarray(rng.randn(bh, l, d), jnp.float32),
+            jnp.asarray(rng.randn(bh, l, dv), jnp.float32))
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("kernel", ["fwd", "bwd_dq", "bwd_dkv"])
+def test_kernels_take_a_v_head_size_of_their_own(kernel, causal):
+    """Each of the three kernels at ``d_v != d_qk`` against
+    ``xla_block_step`` and its ``jax.vjp``, in the interpreter, with a
+    carried state and rectangular tiles.  Tolerances as at equal head
+    sizes: f32 on both sides, the kernels' tile order of the sums
+    apart."""
+    from horovod_tpu.ops.pallas_attention import (flash_bwd_dkv,
+                                                  flash_bwd_dq)
+    from horovod_tpu.parallel.ring_attention import xla_block_step
+
+    q, k, v = _latent_qkv(21)
+    bh, lq, d = q.shape
+    dv = v.shape[-1]
+    rng = np.random.RandomState(22)
+    m0 = jnp.asarray(rng.randn(bh, lq), jnp.float32)
+    l0 = jnp.asarray(rng.rand(bh, lq) + 0.5, jnp.float32)
+    o0 = jnp.asarray(rng.randn(bh, lq, dv), jnp.float32)
+    dout = jnp.asarray(rng.randn(bh, lq, dv), jnp.float32) * 0.1
+
+    def xla(q_, k_, v_):
+        return xla_block_step(q_, k_, v_, m0, l0, o0, 0, 0, causal=causal)
+
+    def normalized(q_, k_, v_):
+        m, l, o = xla(q_, k_, v_)
+        return o / l[..., None]
+
+    em, el, eo = xla(q, k, v)
+    assert eo.shape == (bh, lq, dv)
+    eout, vjp = jax.vjp(normalized, q, k, v)
+    want = dict(zip(("dq", "dk", "dv"), vjp(dout)))
+    lse, delta = em + jnp.log(el), jnp.sum(dout * eout, axis=-1)
+    tiles = dict(causal=causal, block_q=16, block_k=32, interpret=True)
+    if kernel == "fwd":
+        got = flash_block_step(q, k, v, m0, l0, o0, 0, 0, **tiles)
+        for g, w in zip(got, (em, el, eo)):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=2e-4, atol=2e-5)
+        return
+    if kernel == "bwd_dq":
+        got = {"dq": flash_bwd_dq(q, k, v, dout, lse, delta, 0, 0, **tiles)}
+        assert got["dq"].shape == (bh, lq, d)
+    else:
+        dk, dv_ = flash_bwd_dkv(q, k, v, dout, lse, delta, 0, 0, **tiles)
+        assert dk.shape == k.shape and dv_.shape == v.shape
+        got = {"dk": dk, "dv": dv_}
+    for name, g in got.items():
+        np.testing.assert_allclose(np.asarray(g), np.asarray(want[name]),
+                                   rtol=2e-3, atol=2e-4)
+
+
+def test_ring_attention_at_two_head_sizes_both_impls():
+    """``ring_attention`` over sp = 4 with q/k heads of 24 and v heads
+    of 16: the Pallas ring and its saved-LSE backward against the XLA
+    ring, values and the gradients of all three inputs."""
+    mesh = Mesh(np.array(jax.devices()[:4]), ("sp",))
+    rng = np.random.RandomState(23)
+    b, l, h, d, dv = 2, 64, 2, 24, 16
+    q, k = (jnp.asarray(rng.randn(b, l, h, d), jnp.float32)
+            for _ in range(2))
+    v = jnp.asarray(rng.randn(b, l, h, dv), jnp.float32)
+
+    def grads(impl):
+        def per_device(q_, k_, v_):
+            def loss(q__, k__, v__):
+                out = ring_attention(q__, k__, v__, "sp", causal=True,
+                                     impl=impl)
+                assert out.shape == q__.shape[:3] + (dv,)
+                return jnp.sum(out ** 2), out
+            (_, out), g = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                             has_aux=True)(q_, k_, v_)
+            return (out,) + g
+
+        spec = P(None, "sp")
+        return jax.jit(shard_map(per_device, mesh=mesh, check_vma=False,
+                                 in_specs=(spec,) * 3,
+                                 out_specs=(spec,) * 4))(q, k, v)
+
+    for got, want in zip(grads("pallas"), grads("xla")):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-3, atol=2e-4)
+
+
+def test_vmem_estimate_at_equal_head_sizes_is_the_old_one():
+    """``dv`` left out, or equal to ``d``, asks Mosaic for exactly what
+    the kernels asked before they took a v head size (the GPT-2 cells'
+    compiled steps stay the parent's); 192 / 128 at 1024x1024 bf16
+    stays under the budget."""
+    from horovod_tpu.ops.pallas_attention import (VMEM_BUDGET,
+                                                  tile_vmem_bytes)
+    from horovod_tpu.parallel.ring_attention import _block_sizes
+
+    for d, itemsize in ((64, 2), (128, 2), (256, 4)):
+        n = 1024
+        old = ((n * n * (8 + itemsize)
+                + 2 * (4 * n * d * itemsize + n * 128 * 4 + 2 * n * d * 4)
+                + 2 * n * 128 * 4 + 2 * n * d * 4) * 5 // 4)
+        assert tile_vmem_bytes(n, n, d, itemsize) == old
+        assert tile_vmem_bytes(n, n, d, itemsize, d) == old
+    assert tile_vmem_bytes(1024, 1024, 192, 2, 128) < VMEM_BUDGET
+    assert _block_sizes(8192, 8192, 192, 2, 128) == (1024, 1024)

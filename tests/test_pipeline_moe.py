@@ -11,7 +11,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from horovod_tpu.parallel.moe import moe_layer, moe_reference
+from horovod_tpu.parallel import moe
 from horovod_tpu.parallel.pipeline import (gpipe, interleaved_schedule,
                                            interleaved_stage_split,
                                            pipeline)
@@ -250,6 +250,7 @@ EP = 8
 T, DIM, FFH = 32, 8, 16
 E_LOCAL = 2
 E = EP * E_LOCAL
+TOP_K, SCALE = 4, 2.5
 
 
 @pytest.fixture(scope="module")
@@ -257,52 +258,149 @@ def ep_mesh():
     return Mesh(np.array(jax.devices()[:EP]), ("ep",))
 
 
-def test_moe_matches_reference(ep_mesh):
-    rng = np.random.RandomState(2)
-    router = jnp.asarray(rng.randn(DIM, E).astype(np.float32)) * 0.5
-    w_in = jnp.asarray(rng.randn(E, DIM, FFH).astype(np.float32)) * 0.3
-    w_out = jnp.asarray(rng.randn(E, FFH, DIM).astype(np.float32)) * 0.3
-    x = jnp.asarray(rng.randn(EP, T, DIM).astype(np.float32))
-
-    def per_rank(xb, wi, wo):
-        out, aux = moe_layer(xb[0], router, wi, wo, "ep",
-                             capacity_factor=1.5)
-        return out[None], aux.reshape(1)
-
-    fn = jax.jit(shard_map(per_rank, mesh=ep_mesh, check_vma=False,
-                           in_specs=(P("ep"), P("ep"), P("ep")),
-                           out_specs=(P("ep"), P("ep"))))
-    out, aux = fn(x, w_in, w_out)
-    out = np.asarray(out)
-    assert np.isfinite(np.asarray(aux)).all()
-
-    # Golden: per-rank routing/capacity is local, expert math global.
-    for r in range(EP):
-        ref = moe_reference(x[r], router, w_in, w_out,
-                            capacity_factor=1.5)
-        np.testing.assert_allclose(out[r], np.asarray(ref), rtol=1e-4,
-                                   atol=1e-5)
+def _layer_params(seed, first=0, held=E, shared=True):
+    """A layer's weights, float32: the router and bias over all ``E``
+    experts, the experts ``first .. first + held``, the shared one."""
+    rng = np.random.RandomState(seed)
+    p = {"router": rng.randn(DIM, E) * 0.5, "bias": rng.randn(E) * 0.1,
+         "experts": {"w_gate": rng.randn(E, DIM, FFH) * 0.3,
+                     "w_up": rng.randn(E, DIM, FFH) * 0.3,
+                     "w_down": rng.randn(E, FFH, DIM) * 0.3},
+         "shared": {"w_gate": rng.randn(DIM, FFH) * 0.3,
+                    "w_up": rng.randn(DIM, FFH) * 0.3,
+                    "w_down": rng.randn(FFH, DIM) * 0.3}}
+    p["experts"] = {name: a[first:first + held]
+                    for name, a in p["experts"].items()}
+    if not shared:
+        del p["shared"]
+    return jax.tree_util.tree_map(lambda a: jnp.asarray(a, jnp.float32), p)
 
 
-def test_moe_grads_flow(ep_mesh):
-    rng = np.random.RandomState(3)
-    router = jnp.asarray(rng.randn(DIM, E).astype(np.float32)) * 0.5
-    w_in = jnp.asarray(rng.randn(E, DIM, FFH).astype(np.float32)) * 0.3
-    w_out = jnp.asarray(rng.randn(E, FFH, DIM).astype(np.float32)) * 0.3
-    x = jnp.asarray(rng.randn(EP, T, DIM).astype(np.float32))
+_SPECS = {"router": P(), "bias": P(),
+          "experts": {"w_gate": P("ep"), "w_up": P("ep"), "w_down": P("ep")},
+          "shared": {"w_gate": P(), "w_up": P(), "w_down": P()}}
 
-    def per_rank(xb, wi, wo):
-        def loss(wi_, wo_):
-            out, aux = moe_layer(xb[0], router, wi_, wo_, "ep")
-            return jnp.sum(out ** 2) + 0.01 * aux
 
-        gi, go = jax.grad(loss, argnums=(0, 1))(wi, wo)
-        return gi, go
+def test_moe_matches_reference(ep_mesh, monkeypatch):
+    """Over ``ep`` = 8, two experts a rank: tokens gathered, each rank's
+    share, a reduce-scatter back.  Every rank's tokens get what the
+    plain reference gives for the whole layer (all 16 experts and the
+    shared one), in chunks of 16 rows so that a rank loops more than
+    once; every pair is computed (the counts add up to tokens x k)."""
+    monkeypatch.setattr(moe, "CHUNK_ROWS", 16)
+    params = _layer_params(2)
+    x = jnp.asarray(np.random.RandomState(3).randn(EP, T, DIM), jnp.float32)
 
-    fn = jax.jit(shard_map(per_rank, mesh=ep_mesh, check_vma=False,
-                           in_specs=(P("ep"), P("ep"), P("ep")),
-                           out_specs=(P("ep"), P("ep"))))
-    gi, go = fn(x, w_in, w_out)
-    assert np.isfinite(np.asarray(gi)).all()
-    assert np.abs(np.asarray(gi)).max() > 0
-    assert np.isfinite(np.asarray(go)).all()
+    def per_rank(xb, p):
+        out, pairs = moe.moe_layer(xb[0], p, top_k=TOP_K, scale=SCALE,
+                                   axis_name="ep")
+        return out[None], pairs
+
+    out, pairs = jax.jit(shard_map(
+        per_rank, mesh=ep_mesh, check_vma=False,
+        in_specs=(P("ep"), _SPECS), out_specs=(P("ep"), P("ep"))))(x, params)
+    assert int(pairs.sum()) == EP * T * TOP_K
+    ref = moe.moe_reference(x.reshape(EP * T, DIM), params, top_k=TOP_K,
+                            scale=SCALE)
+    # float32 on both sides; the chunks' order of the sums apart
+    np.testing.assert_allclose(np.asarray(out).reshape(EP * T, DIM),
+                               np.asarray(ref), rtol=1e-4, atol=1e-5)
+
+
+def test_moe_grads_flow(ep_mesh, monkeypatch):
+    """The gradients of tokens, router, held experts and shared expert
+    through the exchange and the chunk loop's own backward pass are the
+    plain reference's; the selection bias gets none."""
+    monkeypatch.setattr(moe, "CHUNK_ROWS", 16)
+    params = _layer_params(4)
+    x = jnp.asarray(np.random.RandomState(5).randn(EP, T, DIM), jnp.float32)
+
+    def per_rank(xb, p):
+        def loss(x_, p_):
+            out, _ = moe.moe_layer(x_, p_, top_k=TOP_K, scale=SCALE,
+                                   axis_name="ep")
+            return jnp.sum(out ** 2)
+
+        gx, gp = jax.grad(loss, argnums=(0, 1))(xb[0], p)
+        # a replicated weight's gradient is the sum over the ranks, as
+        # make_train_step reduces it; an expert's stays with its holder
+        gp = {name: (g if name == "experts" else jax.lax.psum(g, "ep"))
+              for name, g in gp.items()}
+        return gx[None], gp
+
+    gx, gp = jax.jit(shard_map(
+        per_rank, mesh=ep_mesh, check_vma=False,
+        in_specs=(P("ep"), _SPECS), out_specs=(P("ep"), _SPECS)))(x, params)
+    rx, rp = jax.grad(
+        lambda x_, p_: jnp.sum(moe.moe_reference(
+            x_, p_, top_k=TOP_K, scale=SCALE) ** 2),
+        argnums=(0, 1))(x.reshape(EP * T, DIM), params)
+    assert not np.asarray(gp["bias"]).any()
+    assert np.abs(np.asarray(gp["experts"]["w_gate"])).max() > 0
+    got = jax.tree_util.tree_leaves((gx.reshape(EP * T, DIM), gp))
+    want = jax.tree_util.tree_leaves((rx, rp))
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4,
+                                   atol=2e-5)
+
+
+def test_the_shares_of_a_layer_add_up_to_the_layer():
+    """One chip at a time, no exchange: the 16 shares of a 16-expert
+    layer (one expert each, told which), with the shared expert, which
+    every chip computes alike, counted once, add up to what the uncut
+    reference gives; so do 4 shares of 4.  What a share leaves out is
+    exactly the other experts' part."""
+    x = jnp.asarray(np.random.RandomState(6).randn(T, DIM), jnp.float32)
+    whole = _layer_params(7)
+    ref = moe.moe_reference(x, whole, top_k=TOP_K, scale=SCALE)
+    shared = moe.swiglu(x, whole["shared"])
+    for held in (1, 4):
+        total, sent = shared, 0
+        for first in range(0, E, held):
+            out, pairs = moe.moe_layer(
+                x, _layer_params(7, first, held, shared=False),
+                top_k=TOP_K, scale=SCALE, first=first)
+            part = moe.moe_reference(
+                x, _layer_params(7, first, held, shared=False),
+                top_k=TOP_K, scale=SCALE, first=first)
+            np.testing.assert_allclose(np.asarray(out), np.asarray(part),
+                                       rtol=1e-4, atol=1e-5)
+            total, sent = total + out, sent + int(pairs.sum())
+        assert sent == T * TOP_K            # no pair dropped, none twice
+        np.testing.assert_allclose(np.asarray(total), np.asarray(ref),
+                                   rtol=1e-4, atol=1e-5)
+
+
+def test_routing_is_dropless_under_any_skew():
+    """A router that sends every token to the same k experts: the held
+    ones compute tokens x k pairs, more than a chunk, none dropped."""
+    rng = np.random.RandomState(8)
+    params = _layer_params(9, shared=False)
+    params["router"] = jnp.zeros((DIM, E))
+    params["bias"] = jnp.asarray(
+        np.where(np.arange(E) < TOP_K, 1.0, 0.0), jnp.float32)
+    x = jnp.asarray(rng.randn(3 * T, DIM), jnp.float32)
+    patch = pytest.MonkeyPatch()
+    patch.setattr(moe, "CHUNK_ROWS", 32)
+    try:
+        out, pairs = moe.moe_layer(x, params, top_k=TOP_K, scale=SCALE)
+    finally:
+        patch.undo()
+    assert pairs.tolist() == [3 * T] * TOP_K + [0] * (E - TOP_K)
+    ref = moe.moe_reference(x, params, top_k=TOP_K, scale=SCALE)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-4,
+                               atol=1e-5)
+
+
+def test_more_ranks_than_the_router_has_experts_raises(ep_mesh):
+    params = _layer_params(10)      # 16 experts a rank x 8 ranks > 16
+
+    def per_rank(xb, p):
+        return moe.moe_layer(xb[0], p, top_k=TOP_K, scale=SCALE,
+                             axis_name="ep")[0][None]
+
+    replicated = jax.tree_util.tree_map(lambda _: P(), params)
+    with pytest.raises(Exception, match="exceed the router"):
+        jax.jit(shard_map(per_rank, mesh=ep_mesh, check_vma=False,
+                          in_specs=(P("ep"), replicated),
+                          out_specs=P("ep")))(jnp.zeros((EP, T, DIM)), params)

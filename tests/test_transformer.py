@@ -118,10 +118,14 @@ def test_pipeline_interleaved_matches_gpipe():
 @pytest.mark.slow  # tier-1 runtime trim: heaviest cold-compile/subprocess tests;
 # ci.sh's full (unfiltered) suite still runs them
 def test_moe_expert_parallel_training():
+    """The GPT-2 attention block with SwiGLU and expert layers after a
+    leading dense one, 8 experts over dp = ep = 4."""
     mesh = make_mesh(dp=4, pp=1, tp=1, sp=2)
     cfg = TransformerConfig(vocab=64, d_model=32, n_heads=4, head_dim=8,
-                            n_layers=4, d_ff=64, max_seq=64,
-                            moe_every=2, experts_per_rank=2)
+                            n_layers=4, d_ff=64, max_seq=64, mlp="swiglu",
+                            n_experts=8, experts_held=2,
+                            experts_per_token=2, d_expert=16,
+                            shared_experts=1, n_dense_layers=1)
     losses = _train(cfg, mesh)
     assert np.isfinite(losses).all(), losses
     assert losses[-1] < losses[0] - 0.1, losses
@@ -179,11 +183,104 @@ def test_bad_pp_schedule_config_raises():
 
 
 def test_moe_under_pp_raises():
+    """Expert layers (and the other kinds of ``models/blocks.py``) have
+    no pipeline path yet: refused, not run wrong."""
     mesh = make_mesh(dp=2, pp=2, tp=1, sp=2)
     cfg = TransformerConfig(vocab=64, d_model=32, n_heads=4, head_dim=8,
-                            n_layers=4, d_ff=64, max_seq=64, moe_every=2)
-    with pytest.raises(Exception):
+                            n_layers=4, d_ff=64, max_seq=64, mlp="swiglu",
+                            n_experts=4, experts_held=2,
+                            experts_per_token=2, d_expert=16,
+                            n_dense_layers=1)
+    with pytest.raises(NotImplementedError, match="pipeline"):
         _train(cfg, mesh, steps=1)
+
+
+def test_bad_kind_config_raises():
+    base = dict(vocab=64, d_model=32, n_heads=4, head_dim=8,
+                n_layers=4, d_ff=64, max_seq=64)
+    with pytest.raises(ValueError):
+        TransformerConfig(**base, attention="gqa")
+    with pytest.raises(ValueError):
+        TransformerConfig(**base, mlp="relu")
+    with pytest.raises(ValueError):       # expert layers are SwiGLU
+        TransformerConfig(**base, n_experts=4)
+    with pytest.raises(ValueError):       # the MTP block is an expert block
+        TransformerConfig(**base, mtp_depth=1)
+
+
+# A small model of every new kind: latent attention with a rotary key
+# shared by the heads, a leading dense SwiGLU layer, expert layers with a
+# shared expert, an untied head, the MTP module, blocks recomputed.
+LATENT = TransformerConfig(
+    vocab=64, d_model=32, n_heads=4, n_layers=3, d_ff=48, max_seq=64,
+    attention="mla", mlp="swiglu", tied_head=False, remat=True,
+    q_lora_rank=24, kv_lora_rank=16, qk_nope_dim=8, qk_rope_dim=4,
+    v_head_dim=8, n_experts=8, experts_held=8, experts_per_token=3,
+    d_expert=16, shared_experts=1, routed_scale=2.5, n_dense_layers=1,
+    mtp_depth=1, dtype="float32")
+
+
+def _train_latent(mesh, steps=3):
+    import dataclasses
+
+    ep = mesh.shape["dp"]
+    cfg = dataclasses.replace(LATENT, experts_held=LATENT.n_experts // ep)
+    params = shard_params(init_params(np.random.RandomState(0), cfg, ep=ep),
+                          cfg, mesh)
+    opt = optax.adam(1e-2)
+    opt_state = opt.init(params)
+    step = make_train_step(cfg, mesh, opt)
+    ids = np.random.RandomState(1).randint(0, cfg.vocab, (8, 33))
+    sh = NamedSharding(mesh, P("dp", "sp"))
+    tokens = jax.device_put(jnp.asarray(ids[:, :-1], jnp.int32), sh)
+    targets = jax.device_put(jnp.asarray(ids[:, 1:], jnp.int32), sh)
+    losses = []
+    for _ in range(steps):
+        params, opt_state, loss = step(params, opt_state, tokens, targets)
+        losses.append(float(loss))
+    return losses
+
+
+def test_latent_expert_model_layouts_agree():
+    """The same model and data on one device and over dp (= ep: the
+    experts' exchange) x tp (heads and SwiGLU split, the shared rotary
+    key's gradient summed) x sp (ring attention at 12 / 8 head sizes,
+    global rotary positions, the MTP's token from the next chunk): the
+    same losses, in float32 to rounding.  So the share test is also the
+    test of ep > 1 inside the model."""
+    one = _train_latent(make_mesh(dp=1, pp=1, tp=1, sp=1,
+                                  devices=jax.devices()[:1]))
+    assert one[-1] < one[0] - 0.1, one
+    np.testing.assert_allclose(
+        _train_latent(make_mesh(dp=2, pp=1, tp=2, sp=2)), one, rtol=2e-5)
+
+
+def test_gpt2_shape_builds_and_traces_nothing_of_the_new_kinds(monkeypatch):
+    """A GPT-2-shaped configuration has the parameter tree it always
+    had — no expert, rotary, latent or MTP parameter — and tracing its
+    step calls nothing of ``models/blocks.py`` or ``parallel/moe.py``:
+    its set-up path does not pay for the kinds it does not use."""
+    from horovod_tpu.models import blocks
+    from horovod_tpu.parallel import moe
+
+    assert CFG.gpt2_block
+    params = init_params(np.random.RandomState(0), CFG)
+    assert set(params) == {"embed", "pos", "ln_f", "layers"}
+    assert set(params["layers"]) == {"wqkv", "wo", "w1", "w2", "ln1", "ln2"}
+
+    def refuse(*_, **__):
+        raise AssertionError("a GPT-2-shaped step traced a new kind")
+
+    for module in (blocks, moe):
+        for name, value in vars(module).items():
+            if callable(value) and getattr(value, "__module__",
+                                           None) == module.__name__:
+                monkeypatch.setattr(module, name, refuse)
+    mesh = make_mesh(dp=2, pp=1, tp=1, sp=1, devices=jax.devices()[:2])
+    placed = shard_params(params, CFG, mesh)
+    opt = optax.adam(1e-2)
+    make_train_step(CFG, mesh, opt).lower(placed, opt.init(placed),
+                                          *_data(mesh))
 
 
 def test_the_compiled_step_names_its_parts():
@@ -213,3 +310,54 @@ def test_the_compiled_step_names_its_parts():
             assert "jvp(" not in n, n
     assert shown("hvd_loss_head", "log_softmax")
     assert not shown("hvd_attn", "hvd_loss_head")
+
+
+def test_the_new_kinds_name_their_parts_and_record_their_routing():
+    """docs/perf.md: ``hvd_mla`` (with ``hvd_attn`` inside), ``hvd_moe``
+    (with ``hvd_moe_route`` / ``_experts`` / ``_shared`` inside) and
+    ``hvd_mtp`` reach the compiled step's ``op_name``s in both passes,
+    the recomputed forward among the backward's; ``record_routing``
+    writes one flight record an expert layer, the MTP module's last,
+    whose pairs add up to tokens x k with the whole router held."""
+    import re
+
+    from jax import shard_map
+
+    from horovod_tpu.models.transformer import (loss_and_routing,
+                                                param_specs, record_routing)
+    from horovod_tpu.runtime import flight
+
+    mesh = make_mesh(dp=1, pp=1, tp=1, sp=1, devices=jax.devices()[:1])
+    params = shard_params(init_params(np.random.RandomState(0), LATENT),
+                          LATENT, mesh)
+    opt = optax.adam(1e-2)
+    tokens, targets = _data(mesh, batch=2)
+    text = make_train_step(LATENT, mesh, opt).lower(
+        params, opt.init(params), tokens, targets).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+
+    def shown(*parts):
+        return any(all(p in n for p in parts) for n in names)
+
+    for scope in ("hvd_mla", "hvd_moe", "hvd_mtp"):
+        assert shown("jvp(", scope) and shown("transpose(jvp(", scope), scope
+    assert shown("hvd_mla/hvd_attn")
+    for inner in ("hvd_moe_route", "hvd_moe_experts", "hvd_moe_shared"):
+        assert shown("hvd_moe/" + inner), inner
+    assert shown("hvd_mtp", "hvd_mla") and shown("hvd_mtp", "hvd_loss_head")
+    assert shown("checkpoint") or shown("remat")      # blocks recomputed
+
+    flight.reset()
+    data = P("dp", "sp")
+    pairs = jax.jit(shard_map(
+        lambda p, tok, tgt: loss_and_routing(p, tok, tgt, LATENT)[1],
+        mesh=mesh, check_vma=False, in_specs=(param_specs(LATENT), data, data),
+        out_specs=P(None, "dp")))(params, tokens, targets)
+    records = record_routing(LATENT, pairs, tokens.size)
+    ring = [e for e in flight.recorder().snapshot()
+            if e["kind"] == "hvd_moe_route"]
+    assert [e["layer"] for e in ring] == [0, 1, 2] == [
+        r["layer"] for r in records]
+    for event in ring:
+        assert event["dropped"] == 0 and len(event["pairs"]) == 8
+        assert sum(event["pairs"]) == tokens.size * LATENT.experts_per_token
