@@ -15,6 +15,15 @@ previous block's state).  A tile pair the causal mask hides whole is
 neither computed nor fetched, and only the pairs the diagonal crosses
 build the mask (:func:`causal_tile_counts` says how many of each).
 
+The state goes through HBM only between ring steps.  At the ends of the
+ring the kernel does the state's work where the state is, in VMEM
+(:func:`flash_fwd_step`): the first step is handed no (m, l, o) and
+starts its scratch at (-inf, 0, 0); the last step writes o / l and
+lse = m + log l instead of the state.  The backward kernels keep fp32
+accumulators in VMEM and write the type the caller asks for.  A ring of
+one step — every cell of the benchmark — has only ends: three kernel
+calls, no state, no fp32 gradient in HBM.
+
 Compiled by Mosaic on a TPU backend; interpreted elsewhere
 (``common.platform.pallas_interpret``), so the same kernel code is
 exercised by the CPU test mesh.
@@ -154,25 +163,38 @@ def _compiler_params(bq: int, bk: int, d: int, dv: int, dtype):
             bq, bk, d, jnp.dtype(dtype).itemsize, dv)))
 
 
-def _flash_step_kernel(off_ref, q_ref, k_ref, v_ref, mli_ref, oi_ref,
-                       mlo_ref, oo_ref, m_s, l_s, acc,
-                       *, causal: bool, scale: float, bq: int, bk: int):
+def _flash_step_kernel(off_ref, q_ref, k_ref, v_ref, *refs, causal: bool,
+                       scale: float, bq: int, bk: int, first: bool,
+                       last: bool):
     """Grid: (B*H, nq, nk) — nk innermost so (m_s, l_s, acc) scratch
-    carries across the K blocks of one Q block.  The packed m|l HBM
-    tile is unpacked into lane-replicated VMEM scratch on entry and
-    repacked on exit, so the per-iteration math matches the classic
-    two-buffer layout while HBM sees a single state buffer.  Entry and
+    carries across the K blocks of one Q block.  ``refs`` are the
+    carried state in (packed m|l, o; none on a ring's ``first`` step,
+    where scratch starts at -inf, 0, 0), the results and the scratch.
+    A middle step's results are the state out: m_s and l_s repacked
+    into one tile, so HBM sees a single state buffer, and the
+    unnormalized numerator.  The ``last`` step's are the row's lse =
+    m + log l in every lane of the tile (-inf for a row that saw no
+    key) and the normalized o / l (0 for such a row), in fp32 and, if
+    the caller asked, rounded to another type beside it.  Entry and
     exit run on every row, also one whose every tile is dead: it hands
     the carried state through unchanged."""
+    if not first:
+        mli_ref, oi_ref, *refs = refs
+    stat_ref, *o_refs, m_s, l_s, acc = refs
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
 
     @pl.when(ik == 0)
     def _():
-        ml = mli_ref[0]
-        m_s[:, :] = ml[:, _M_LANE][:, None] + jnp.zeros_like(m_s)
-        l_s[:, :] = ml[:, _L_LANE][:, None] + jnp.zeros_like(l_s)
-        acc[:, :] = oi_ref[0].astype(jnp.float32)
+        if first:
+            m_s[:, :] = jnp.full_like(m_s, _NEG_INF)
+            l_s[:, :] = jnp.zeros_like(l_s)
+            acc[:, :] = jnp.zeros_like(acc)
+        else:
+            ml = mli_ref[0]
+            m_s[:, :] = ml[:, _M_LANE][:, None] + jnp.zeros_like(m_s)
+            l_s[:, :] = ml[:, _L_LANE][:, None] + jnp.zeros_like(l_s)
+            acc[:, :] = oi_ref[0].astype(jnp.float32)
 
     def accumulate(mask):
         s = jax.lax.dot_general(
@@ -204,9 +226,20 @@ def _flash_step_kernel(off_ref, q_ref, k_ref, v_ref, mli_ref, oi_ref,
 
     @pl.when(ik == nk - 1)
     def _():
-        mlo_ref[0] = jnp.concatenate(
-            [m_s[:, :_L_LANE], l_s[:, _L_LANE:]], axis=1)
-        oo_ref[0] = acc[:, :].astype(oo_ref.dtype)
+        if last:
+            l = l_s[:, :]                   # lane-replicated, as m_s
+            seen = l > 0.0
+            stat_ref[0] = jnp.where(
+                seen, m_s[:, :] + jnp.log(jnp.where(seen, l, 1.0)),
+                _NEG_INF)
+            den = l_s[:, 0]
+            out = acc[:, :] / jnp.where(den == 0.0, 1.0, den)[:, None]
+            for o_ref in o_refs:
+                o_ref[0] = out.astype(o_ref.dtype)
+        else:
+            stat_ref[0] = jnp.concatenate(
+                [m_s[:, :_L_LANE], l_s[:, _L_LANE:]], axis=1)
+            o_refs[0][0] = acc[:, :]
 
 
 def _tiles(block_q, block_k, lq, lk):
@@ -218,24 +251,77 @@ def _tiles(block_q, block_k, lq, lk):
     return bq, bk
 
 
-def _flash_block_step_impl(q, k, v, m, l, o, q_offset, k_offset,
-                           causal, block_q, block_k, interpret):
+def _pack_rows(a, b, bh, lq):
+    """Two per-row f32 scalars (m|l for the forward kernel, lse|delta
+    for the backward kernels) as one (BH, Lq, 128) tile buffer: ``a``
+    in lanes 0..63, ``b`` in lanes 64..127."""
+    return jnp.concatenate(
+        [jnp.broadcast_to(a[..., None], (bh, lq, _L_LANE)),
+         jnp.broadcast_to(b[..., None], (bh, lq, 128 - _L_LANE))],
+        axis=-1)
+
+
+# The three entry points are jitted so that a model of n layers traces
+# and lowers each kernel once a shape, not n times (gpt2-124m.s8192's
+# warm set-up on the chip's host: 45-53 s without; PERF.md, PR 29), and
+# inlined, so that the caller's program holds the kernels and no call:
+# with a call left in it, XLA kept other buffers in VMEM around the
+# kernels and the expert cell's compiled step gave NaN (PERF.md,
+# section 7).
+_entry_point = functools.partial(jax.jit, inline=True)
+
+
+@_entry_point(static_argnames=(
+    "causal", "block_q", "block_k", "last", "interpret"))
+def flash_fwd_step(q, k, v, state, q_offset, k_offset, *,
+                   causal: bool = True, block_q: int = 128,
+                   block_k: int = 128, last: bool = False,
+                   interpret: bool | None = None):
+    """One step of a ring's forward pass: attend local Q against one
+    KV block.  Shapes as :func:`flash_block_step`.
+
+    ``state``: the carried ``(m, l, o)``, or None on the ring's first
+    step — the kernel then starts from (-inf, 0, 0) in scratch and
+    reads no state from HBM.  Returns the updated ``(m, l, o)``; on the
+    ``last`` step instead ``(out, lse, out_q)``: the normalized
+    (BH, Lq, Dv) fp32 result o / l (0 for a row that saw no key), the
+    (BH, Lq) fp32 lse = m + log l (-inf for such a row), and ``out``
+    rounded to q's type (``out`` itself where that is fp32), all
+    computed where the state is, in VMEM.  A one-step ring is first
+    and last at once and its state never exists in HBM.  Forward only.
+    """
     bh, lq, d = q.shape
     _, lk, dv = v.shape
     bq, bk = _tiles(block_q, block_k, lq, lk)
     interpret = pallas_interpret(interpret)
     scale = 1.0 / (d ** 0.5)
     offs = jnp.asarray([q_offset, k_offset], jnp.int32)
-    ml = jnp.concatenate(
-        [jnp.broadcast_to(m[..., None], (bh, lq, _L_LANE)),
-         jnp.broadcast_to(l[..., None], (bh, lq, 128 - _L_LANE))],
-        axis=-1)
+    first = state is None
 
     kernel = functools.partial(_flash_step_kernel, causal=causal,
-                               scale=scale, bq=bq, bk=bk)
+                               scale=scale, bq=bq, bk=bk, first=first,
+                               last=last)
     q_row, kv_row = _q_major_maps(bq, bk, causal)
+    operands = [q, k, v]
+    in_specs = [
+        pl.BlockSpec((1, bq, d), q_row),      # q
+        pl.BlockSpec((1, bk, d), kv_row),     # k
+        pl.BlockSpec((1, bk, dv), kv_row),    # v
+    ]
+    if not first:
+        m, l, o = state
+        operands += [_pack_rows(m, l, bh, lq), o]
+        in_specs += [
+            pl.BlockSpec((1, bq, 128), q_row),    # m|l
+            pl.BlockSpec((1, bq, dv), q_row),     # o
+        ]
 
-    mlo, oo = pl.pallas_call(
+    o_block = pl.BlockSpec((1, bq, dv), q_row)
+    o_shapes = [jax.ShapeDtypeStruct((bh, lq, dv), jnp.float32)]
+    if last and q.dtype != jnp.float32:
+        o_shapes.append(jax.ShapeDtypeStruct((bh, lq, dv), q.dtype))
+
+    stat, *o = pl.pallas_call(
         kernel,
         name="hvd_flash_fwd",
         # the offsets are prefetched scalars: the K/V index map reads
@@ -243,30 +329,23 @@ def _flash_block_step_impl(q, k, v, m, l, o, q_offset, k_offset,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, lq // bq, lk // bk),
-            in_specs=[
-                pl.BlockSpec((1, bq, d), q_row),      # q
-                pl.BlockSpec((1, bk, d), kv_row),     # k
-                pl.BlockSpec((1, bk, dv), kv_row),    # v
-                pl.BlockSpec((1, bq, 128), q_row),    # m|l
-                pl.BlockSpec((1, bq, dv), q_row),     # o
-            ],
-            out_specs=[
-                pl.BlockSpec((1, bq, 128), q_row),
-                pl.BlockSpec((1, bq, dv), q_row),
-            ],
+            in_specs=in_specs,
+            # m|l and o, or lse and o / l
+            out_specs=[pl.BlockSpec((1, bq, 128), q_row)]
+            + [o_block] * len(o_shapes),
             scratch_shapes=[
                 pltpu.VMEM((bq, 128), jnp.float32),   # running max
                 pltpu.VMEM((bq, 128), jnp.float32),   # running denominator
                 pltpu.VMEM((bq, dv), jnp.float32),    # numerator accumulator
             ]),
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, lq, 128), jnp.float32),
-            jax.ShapeDtypeStruct((bh, lq, dv), jnp.float32),
-        ],
+        out_shape=[jax.ShapeDtypeStruct((bh, lq, 128), jnp.float32)]
+        + o_shapes,
         compiler_params=_compiler_params(bq, bk, d, dv, q.dtype),
         interpret=interpret,
-    )(offs, q, k, v, ml, o)
-    return mlo[..., _M_LANE], mlo[..., _L_LANE], oo
+    )(offs, *operands)
+    if last:
+        return o[0], stat[..., _M_LANE], o[-1]
+    return stat[..., _M_LANE], stat[..., _L_LANE], o[0]
 
 
 def _recomputed_p_ds(q, k, v, do, ld, mask, scale):
@@ -318,7 +397,7 @@ def _flash_bwd_dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
 
     @pl.when(ik == nk - 1)
     def _():
-        dq_ref[0] = dq_acc[:, :]
+        dq_ref[0] = dq_acc[:, :].astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
@@ -350,30 +429,26 @@ def _flash_bwd_dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
 
     @pl.when(iq == nq - 1)
     def _():
-        dk_ref[0] = dk_acc[:, :]
-        dv_ref[0] = dv_acc[:, :]
+        dk_ref[0] = dk_acc[:, :].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:, :].astype(dv_ref.dtype)
 
 
-def _pack_ld(lse, delta, bh, lq):
-    """Pack per-row lse|delta into one (BH, Lq, 128) f32 tile buffer —
-    same single-state-buffer trick as the forward's m|l packing."""
-    return jnp.concatenate(
-        [jnp.broadcast_to(lse[..., None], (bh, lq, _L_LANE)),
-         jnp.broadcast_to(delta[..., None], (bh, lq, 128 - _L_LANE))],
-        axis=-1)
-
-
+@_entry_point(static_argnames=(
+    "causal", "block_q", "block_k", "out_dtype", "interpret"))
 def flash_bwd_dq(q, k, v, do, lse, delta, q_offset, k_offset, *,
                  causal: bool = True, block_q: int = 128,
-                 block_k: int = 128, interpret: bool | None = None):
+                 block_k: int = 128, out_dtype=jnp.float32,
+                 interpret: bool | None = None):
     """Flash-attention dQ for one (local Q, one KV block) pair.
 
     q: (BH, Lq, D); k: (BH, Lk, D); v: (BH, Lk, Dv); do: (BH, Lq, Dv)
     upstream grad in
     the matmul dtype; lse: (BH, Lq) fp32 saved log-sum-exp rows
     (m + log l from the forward); delta: (BH, Lq) fp32 rowsum(dO * O).
-    Returns fp32 (BH, Lq, D) — the dQ contribution of this KV block
-    (sum over ring steps at the caller).
+    Returns (BH, Lq, D) in ``out_dtype`` — the dQ contribution of this
+    KV block: fp32 where the caller sums over ring steps, the operands'
+    type in a one-step ring (the accumulator is fp32 in VMEM either
+    way and is rounded once, as it is written out).
     """
     bh, lq, d = q.shape
     _, lk, dv = v.shape
@@ -381,7 +456,7 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_offset, k_offset, *,
     interpret = pallas_interpret(interpret)
     scale = 1.0 / (d ** 0.5)
     offs = jnp.asarray([q_offset, k_offset], jnp.int32)
-    ld = _pack_ld(lse, delta, bh, lq)
+    ld = _pack_rows(lse, delta, bh, lq)
     kernel = functools.partial(_flash_bwd_dq_kernel, causal=causal,
                                scale=scale, bq=bq, bk=bk)
     q_row, kv_row = _q_major_maps(bq, bk, causal)
@@ -401,20 +476,24 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_offset, k_offset, *,
             ],
             out_specs=pl.BlockSpec((1, bq, d), q_row),
             scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((bh, lq, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((bh, lq, d), out_dtype),
         compiler_params=_compiler_params(bq, bk, d, dv, q.dtype),
         interpret=interpret,
     )(offs, q, k, v, do, ld)
 
 
+@_entry_point(static_argnames=(
+    "causal", "block_q", "block_k", "out_dtype", "interpret"))
 def flash_bwd_dkv(q, k, v, do, lse, delta, q_offset, k_offset, *,
                   causal: bool = True, block_q: int = 128,
-                  block_k: int = 128, interpret: bool | None = None):
+                  block_k: int = 128, out_dtype=jnp.float32,
+                  interpret: bool | None = None):
     """Flash-attention (dK, dV) for one (local Q, one KV block) pair.
 
-    Same contract as :func:`flash_bwd_dq`; returns fp32
-    ((BH, Lk, D), (BH, Lk, Dv)) — this Q chunk's contribution to the
-    block's dK/dV (ring callers accumulate while rotating).
+    Same contract as :func:`flash_bwd_dq`; returns
+    ((BH, Lk, D), (BH, Lk, Dv)) in ``out_dtype`` — this Q chunk's
+    contribution to the block's dK/dV (callers in a longer ring
+    accumulate in fp32 while rotating).
     """
     bh, lq, d = q.shape
     _, lk, dv = v.shape
@@ -422,7 +501,7 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, q_offset, k_offset, *,
     interpret = pallas_interpret(interpret)
     scale = 1.0 / (d ** 0.5)
     offs = jnp.asarray([q_offset, k_offset], jnp.int32)
-    ld = _pack_ld(lse, delta, bh, lq)
+    ld = _pack_rows(lse, delta, bh, lq)
     kernel = functools.partial(_flash_bwd_dkv_kernel, causal=causal,
                                scale=scale, bq=bq, bk=bk)
     nq = lq // bq
@@ -459,15 +538,16 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, q_offset, k_offset, *,
             scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                             pltpu.VMEM((bk, dv), jnp.float32)]),
         out_shape=[
-            jax.ShapeDtypeStruct((bh, lk, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, lk, dv), jnp.float32),
+            jax.ShapeDtypeStruct((bh, lk, d), out_dtype),
+            jax.ShapeDtypeStruct((bh, lk, dv), out_dtype),
         ],
         compiler_params=_compiler_params(bq, bk, d, dv, q.dtype),
         interpret=interpret,
     )(offs, q, k, v, do, ld)
 
 
-# The block step below is forward-only; its VJP is the XLA block
+# The carried step below (state in, state out: a middle step of the
+# ring) is forward-only; its VJP is the XLA block
 # step's (same math, rematerialized from the inputs).  It remains the
 # ``attn_pallas_bwd="remat"`` escape hatch; the default pallas path now
 # runs the ring-level saved-LSE VJP in ring_attention, whose backward
@@ -477,14 +557,16 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, q_offset, k_offset, *,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
 def _flash_block_step_diff(q, k, v, m, l, o, q_offset, k_offset,
                            causal, block_q, block_k, interpret):
-    return _flash_block_step_impl(q, k, v, m, l, o, q_offset, k_offset,
-                                  causal, block_q, block_k, interpret)
+    return flash_fwd_step(q, k, v, (m, l, o), q_offset, k_offset,
+                          causal=causal, block_q=block_q, block_k=block_k,
+                          interpret=interpret)
 
 
 def _flash_fwd(q, k, v, m, l, o, q_offset, k_offset,
                causal, block_q, block_k, interpret):
-    out = _flash_block_step_impl(q, k, v, m, l, o, q_offset, k_offset,
-                                 causal, block_q, block_k, interpret)
+    out = flash_fwd_step(q, k, v, (m, l, o), q_offset, k_offset,
+                         causal=causal, block_q=block_q, block_k=block_k,
+                         interpret=interpret)
     return out, (q, k, v, m, l, o, q_offset, k_offset)
 
 

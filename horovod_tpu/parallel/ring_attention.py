@@ -24,6 +24,17 @@ with dK/dV accumulators rotating alongside KV — no O(Lq·Lk) score
 block is ever materialized in either direction
 (``HOROVOD_ATTN_PALLAS_BWD=remat`` selects the previous XLA-remat
 block-step VJP for on-chip A/B).
+
+What the Pallas ring's ends do.  The softmax state (m, l, o) and the
+fp32 dK/dV accumulators exist in HBM only between steps: the first
+forward step starts the state in the kernel and the last finishes it
+there (``out`` and ``lse`` come out of the kernel, ``out`` also in the
+operands' type); the first backward step's contributions are the
+accumulators; K and V stop rotating once the last step holds its block,
+and only dK and dV make the ``sp``-th rotation that brings each block's
+gradient home.  A ring of one (``sp`` = 1, a Python int at trace time)
+has only ends: one forward call, the two backward kernels writing the
+operands' type, no loop, no ``ppermute``, nothing carried.
 """
 
 from __future__ import annotations
@@ -125,8 +136,12 @@ def auto_impl(batch: int, heads: int, seq_q: int,
     of this shape on TPU.  Shared with ``bench.py``'s crossover
     side-measure so its labels can never drift from the product
     decision.  The XLA step materializes fp32 scores plus an fp32
-    softmax transient, hence 8 bytes per score element.  Where the
-    two impls actually cross over on the chip is not measured."""
+    softmax transient, hence 8 bytes per score element.  The
+    threshold is older than the kernels it chooses between: on a v5e
+    one call's forward and backward at (16, 1024, 12, 64), which it
+    sends to XLA, takes 6.2 ms through the kernels and 7.7 through
+    XLA (``PERF.md``, PR 29); seq 2048 and 4096 are not measured, and
+    moving it takes a benchmark cell on each side first."""
     from horovod_tpu.common import config as _config
 
     seq_k = seq_q if seq_k is None else seq_k
@@ -135,60 +150,77 @@ def auto_impl(batch: int, heads: int, seq_q: int,
             else "pallas")
 
 
+def _ring_offsets(j, axis_name, lc, causal):
+    """Global positions of the local Q chunk and of ring step j's KV
+    block.  They feed only the causal mask: a non-causal trace holds no
+    axis_index chain, and neither does a ring of one."""
+    sp = lax.axis_size(axis_name)
+    if not causal or sp == 1:
+        return 0, 0
+    idx = lax.axis_index(axis_name)
+    return idx * lc, ((idx - j) % sp) * lc
+
+
+def _ring_rotate(axis_name, *blocks):
+    sp = lax.axis_size(axis_name)
+    rot = [(i, (i + 1) % sp) for i in range(sp)]
+    return tuple(lax.ppermute(x, axis_name, rot) for x in blocks)
+
+
 def _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, bq, bk):
-    """Pallas ring forward, returning (normalized fp32 out, lse).
+    """Pallas ring forward, returning (normalized fp32 out, lse, out in
+    the operands' type).
 
     qp/kp: packed (B*H, Lc, D), vp: (B*H, Lc, Dv).  lse = m + log(l)
     per row — the one
     O(L) residual the saved-LSE backward needs (fully-masked rows keep
-    lse = -inf).
+    lse = -inf).  The softmax state goes through HBM only between
+    steps: the first step's kernel starts it, the last step's finishes
+    it, and KV stops rotating once the last step holds its block.  A
+    ring of one is one kernel call and nothing else.
     """
-    from horovod_tpu.ops.pallas_attention import flash_block_step
+    from horovod_tpu.ops.pallas_attention import flash_fwd_step
 
     sp = lax.axis_size(axis_name)
-    idx = lax.axis_index(axis_name)
-    bh, lc, _ = qp.shape
-    m0 = jnp.full((bh, lc), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((bh, lc), jnp.float32)
-    o0 = jnp.zeros((bh, lc, vp.shape[-1]), jnp.float32)
-    rot = [(i, (i + 1) % sp) for i in range(sp)]
+    lc = qp.shape[1]
 
-    def step(j, carry):
-        m, l, o, kj, vj = carry
-        # Global offsets feed only the causal mask; keep the
-        # axis_index chain out of the non-causal trace entirely.
-        qo, ko = (idx * lc, ((idx - j) % sp) * lc) if causal else (0, 0)
-        m, l, o = flash_block_step(qp, kj, vj, m, l, o, qo, ko,
-                                   causal=causal, block_q=bq,
-                                   block_k=bk)
-        kj = lax.ppermute(kj, axis_name, rot)
-        vj = lax.ppermute(vj, axis_name, rot)
-        return m, l, o, kj, vj
+    def step(j, state, kj, vj, last=False):
+        qo, ko = _ring_offsets(j, axis_name, lc, causal)
+        return flash_fwd_step(qp, kj, vj, state, qo, ko, causal=causal,
+                              block_q=bq, block_k=bk, last=last)
 
-    m, l, o, _, _ = lax.fori_loop(0, sp, step, (m0, l0, o0, kp, vp))
-    lse = jnp.where(l > 0.0, m + jnp.log(jnp.where(l > 0.0, l, 1.0)),
-                    -jnp.inf)
-    l = jnp.where(l == 0.0, 1.0, l)
-    return o / l[..., None], lse
+    if sp == 1:
+        return step(0, None, kp, vp, last=True)
+
+    def middle(j, carry):
+        *state, kj, vj = carry
+        return (*step(j, state, kj, vj), *_ring_rotate(axis_name, kj, vj))
+
+    carry = (*step(0, None, kp, vp), *_ring_rotate(axis_name, kp, vp))
+    *state, kj, vj = lax.fori_loop(1, sp - 1, middle, carry)
+    return step(sp - 1, state, kj, vj, last=True)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _ring_flash(qp, kp, vp, axis_name, causal, bq, bk):
     """Differentiable Pallas ring attention on packed (B*H, Lc, D)
-    operands: forward saves only (q, k, v, out, lse); backward is a
+    operands, returning (B*H, Lc, Dv) in their type: forward saves only
+    (q, k, v, out, lse), ``out`` in fp32 (``delta`` = rowsum(dO ∘ out)
+    reads it); backward is a
     second ring pass over the saved-LSE flash backward kernels
     (:func:`horovod_tpu.ops.pallas_attention.flash_bwd_dq` / ``_dkv``),
     with dK/dV accumulators rotating alongside KV so each block's
-    gradient arrives home after the full cycle.  Nothing O(Lq·Lk) is
+    gradient arrives home after the full cycle (in a ring of one it is
+    home already: nothing rotates).  Nothing O(Lq·Lk) is
     ever materialized — unlike the previous XLA-remat VJP, whose fp32
     score block OOM'd v5e HBM at (seq 4096, batch 4)."""
-    out, _ = _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, bq, bk)
-    return out
+    return _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, bq, bk)[2]
 
 
 def _ring_flash_fwd(qp, kp, vp, axis_name, causal, bq, bk):
-    out, lse = _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, bq, bk)
-    return out, (qp, kp, vp, out, lse)
+    out, lse, out_q = _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal,
+                                           bq, bk)
+    return out_q, (qp, kp, vp, out, lse)
 
 
 def _ring_flash_bwd(axis_name, causal, bq, bk, res, dout):
@@ -197,38 +229,43 @@ def _ring_flash_bwd(axis_name, causal, bq, bk, res, dout):
 
     qp, kp, vp, out, lse = res
     sp = lax.axis_size(axis_name)
-    idx = lax.axis_index(axis_name)
-    bh, lc, d = qp.shape
-    dout = dout.astype(jnp.float32)
-    delta = jnp.sum(dout * out, axis=-1)       # (BH, Lc) fp32
-    do_mm = dout.astype(qp.dtype)              # matmul dtype (bf16-safe)
-    rot = [(i, (i + 1) % sp) for i in range(sp)]
+    lc = qp.shape[1]
+    # dout comes in the operands' type, the products' (bf16-safe); out
+    # and delta are fp32
+    delta = jnp.sum(dout.astype(jnp.float32) * out, axis=-1)   # (BH, Lc)
 
-    def step(j, carry):
+    def step(j, kj, vj, out_dtype=jnp.float32):
+        """This step's (dQ, dK, dV) contributions."""
+        qo, ko = _ring_offsets(j, axis_name, lc, causal)
+        tiles = dict(causal=causal, block_q=bq, block_k=bk,
+                     out_dtype=out_dtype)
+        return (flash_bwd_dq(qp, kj, vj, dout, lse, delta, qo, ko, **tiles),
+                *flash_bwd_dkv(qp, kj, vj, dout, lse, delta, qo, ko,
+                               **tiles))
+
+    if sp == 1:
+        # every block is at home and nothing is summed over steps: the
+        # kernels round their fp32 accumulators once, as they write
+        dq, dk, dv = step(0, kp, vp, qp.dtype)
+        return dq, dk.astype(kp.dtype), dv.astype(vp.dtype)
+
+    def middle(j, carry):
         dq, kj, vj, dkj, dvj = carry
-        # Offsets drive only causal masking (see fwd step note).
-        qo, ko = (idx * lc, ((idx - j) % sp) * lc) if causal else (0, 0)
-        dq = dq + flash_bwd_dq(qp, kj, vj, do_mm, lse, delta,
-                               qo, ko, causal=causal,
-                               block_q=bq, block_k=bk)
-        dk_p, dv_p = flash_bwd_dkv(qp, kj, vj, do_mm, lse, delta,
-                                   qo, ko, causal=causal,
-                                   block_q=bq, block_k=bk)
-        dkj = dkj + dk_p
-        dvj = dvj + dv_p
-        # KV and its gradient accumulators rotate together; after sp
-        # steps both are back at the block's home rank.
-        kj = lax.ppermute(kj, axis_name, rot)
-        vj = lax.ppermute(vj, axis_name, rot)
-        dkj = lax.ppermute(dkj, axis_name, rot)
-        dvj = lax.ppermute(dvj, axis_name, rot)
-        return dq, kj, vj, dkj, dvj
+        dq_p, dk_p, dv_p = step(j, kj, vj)
+        # KV and its gradient accumulators rotate together
+        return (dq + dq_p, *_ring_rotate(axis_name, kj, vj, dkj + dk_p,
+                                         dvj + dv_p))
 
-    z = jnp.zeros((bh, lc, d), jnp.float32)
-    zv = z if vp.shape[-1] == d else jnp.zeros(vp.shape, jnp.float32)
-    dq, _, _, dk, dv = lax.fori_loop(
-        0, sp, step, (z, kp, vp, z, zv))
-    return dq.astype(qp.dtype), dk.astype(kp.dtype), dv.astype(vp.dtype)
+    # the first step's contributions are the accumulators
+    dq, dk, dv = step(0, kp, vp)
+    dq, kj, vj, dkj, dvj = lax.fori_loop(
+        1, sp - 1, middle, (dq, *_ring_rotate(axis_name, kp, vp, dk, dv)))
+    dq_p, dk_p, dv_p = step(sp - 1, kj, vj)
+    # KV stays where the last step used it; its gradient's sp-th
+    # rotation brings each block's home
+    dk, dv = _ring_rotate(axis_name, dkj + dk_p, dvj + dv_p)
+    return ((dq + dq_p).astype(qp.dtype), dk.astype(kp.dtype),
+            dv.astype(vp.dtype))
 
 
 _ring_flash.defvjp(_ring_flash_fwd, _ring_flash_bwd)
@@ -302,8 +339,7 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
             kp = k.transpose(0, 2, 1, 3).reshape(b * h, lc, d)
             vp = v.transpose(0, 2, 1, 3).reshape(b * h, lc, dv)
             out = _ring_flash(qp, kp, vp, axis_name, causal, bq, bk)
-            out = out.reshape(b, h, lc, dv).transpose(0, 2, 1, 3)
-            return out.astype(q.dtype)
+            return out.reshape(b, h, lc, dv).transpose(0, 2, 1, 3)
 
         # "remat": per-step custom VJP whose backward is the XLA block
         # step's (full fp32 score block per ring step) — kept for

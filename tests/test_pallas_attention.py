@@ -582,3 +582,201 @@ def test_vmem_estimate_at_equal_head_sizes_is_the_old_one():
         assert tile_vmem_bytes(n, n, d, itemsize, d) == old
     assert tile_vmem_bytes(1024, 1024, 192, 2, 128) < VMEM_BUDGET
     assert _block_sizes(8192, 8192, 192, 2, 128) == (1024, 1024)
+
+
+# ---------------------------------------------------------------------------
+# The ends of the ring: the forward kernel starts and finishes the softmax
+# itself, the backward kernels write the operands' type (a one-step ring
+# has only ends)
+# ---------------------------------------------------------------------------
+
+
+def _ring_of_one(seed, d, dv, dtype, bh=2, l=64):
+    rng = np.random.RandomState(seed)
+    return (jnp.asarray(rng.randn(bh, l, d), dtype),
+            jnp.asarray(rng.randn(bh, l, d), dtype),
+            jnp.asarray(rng.randn(bh, l, dv), dtype),
+            jnp.asarray(rng.randn(bh, l, dv) * 0.1, dtype))
+
+
+def _carried_then_epilogue(q, k, v, q_offset, k_offset, **tiles):
+    """What a one-step ring ran before its kernel could start and
+    finish: (-inf, 0, 0) handed in through HBM, the carried kernel, and
+    the normalization and lse in XLA."""
+    bh, lq, _ = q.shape
+    m, l, o = flash_block_step(
+        q, k, v, jnp.full((bh, lq), -jnp.inf, jnp.float32),
+        jnp.zeros((bh, lq), jnp.float32),
+        jnp.zeros((bh, lq, v.shape[-1]), jnp.float32),
+        q_offset, k_offset, **tiles)
+    lse = jnp.where(l > 0.0, m + jnp.log(jnp.where(l > 0.0, l, 1.0)),
+                    -jnp.inf)
+    return o / jnp.where(l == 0.0, 1.0, l)[..., None], lse
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("d, dv", [(64, 64), (192, 128)])
+@pytest.mark.parametrize("causal", [True, False])
+def test_first_and_last_step_at_once_is_carried_step_and_epilogue(
+        causal, d, dv, dtype):
+    """No state in, ``out`` and ``lse`` out: the same numbers as the
+    carried kernel followed by the epilogue, to one ulp of fp32 (the
+    division and the log are the kernel's now), and the result in the
+    operands' type is that fp32 ``out`` rounded once."""
+    from horovod_tpu.ops.pallas_attention import flash_fwd_step
+
+    q, k, v, _ = _ring_of_one(31, d, dv, dtype)
+    tiles = dict(causal=causal, block_q=32, block_k=16, interpret=True)
+    want_out, want_lse = _carried_then_epilogue(q, k, v, 0, 0, **tiles)
+    out, lse, out_q = flash_fwd_step(q, k, v, None, 0, 0, last=True,
+                                     **tiles)
+    assert out.dtype == lse.dtype == jnp.float32 and out_q.dtype == dtype
+    np.testing.assert_array_max_ulp(np.asarray(out), np.asarray(want_out),
+                                    maxulp=1)
+    np.testing.assert_array_max_ulp(np.asarray(lse), np.asarray(want_lse),
+                                    maxulp=1)
+    np.testing.assert_array_equal(
+        np.asarray(out_q.astype(jnp.float32)),
+        np.asarray(out.astype(dtype).astype(jnp.float32)))
+
+
+def test_first_step_then_last_step_compose():
+    """A ring of two, by hand: the first step takes no state and hands
+    one on, the last takes it and finishes — dense attention over both
+    KV halves."""
+    from horovod_tpu.ops.pallas_attention import flash_fwd_step
+
+    q, k, v = (_pack(x) for x in _qkv(32))
+    half = L // 2
+    tiles = dict(causal=True, block_q=32, block_k=16, interpret=True)
+    state = flash_fwd_step(q, k[:, :half], v[:, :half], None, 0, 0, **tiles)
+    out, lse, _ = flash_fwd_step(q, k[:, half:], v[:, half:], state, 0,
+                                 half, last=True, **tiles)
+    want_out, want_lse = _carried_then_epilogue(q, k, v, 0, 0, **tiles)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want_out),
+                               rtol=2e-6, atol=2e-7)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                               rtol=2e-6)
+
+
+def test_a_row_that_sees_no_key():
+    """A KV block that starts 16 positions after the Q chunk: the first
+    16 rows see no key.  The finishing kernel gives them lse = -inf and
+    out = 0 (not 0 / 0), and they add exactly nothing to any
+    gradient."""
+    from horovod_tpu.ops.pallas_attention import (flash_bwd_dkv,
+                                                  flash_bwd_dq,
+                                                  flash_fwd_step)
+
+    q, k, v, dout = _ring_of_one(33, 24, 16, jnp.float32)
+    blind = 16
+    tiles = dict(causal=True, block_q=32, block_k=16, interpret=True)
+    out, lse, _ = flash_fwd_step(q, k, v, None, 0, blind, last=True,
+                                 **tiles)
+    want_out, want_lse = _carried_then_epilogue(q, k, v, 0, blind, **tiles)
+    assert np.all(np.asarray(lse[:, :blind]) == -np.inf)
+    assert not np.asarray(out[:, :blind]).any()
+    assert np.isfinite(np.asarray(lse[:, blind:])).all()
+    np.testing.assert_array_max_ulp(np.asarray(out), np.asarray(want_out),
+                                    maxulp=1)
+    np.testing.assert_array_equal(np.asarray(lse[:, :blind]),
+                                  np.asarray(want_lse[:, :blind]))
+
+    def grads(dout):
+        delta = jnp.sum(dout * out, axis=-1)
+        return (flash_bwd_dq(q, k, v, dout, lse, delta, 0, blind, **tiles),
+                *flash_bwd_dkv(q, k, v, dout, lse, delta, 0, blind,
+                               **tiles))
+
+    dq, dk, dv = grads(dout)
+    assert not np.asarray(dq[:, :blind]).any()
+    assert np.asarray(dq[:, blind:]).any()
+    # dK and dV with the blind rows' dO taken away: bit for bit the same
+    for got, want in zip((dk, dv), grads(dout.at[:, :blind].set(0.0))[1:]):
+        assert np.isfinite(np.asarray(got)).all()
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("d, dv", [(64, 64), (192, 128)])
+def test_bwd_kernels_write_the_operands_type(d, dv):
+    """``out_dtype=bfloat16``: the fp32 accumulators rounded once as
+    they are written — the numbers the fp32 result gives when cast."""
+    from horovod_tpu.ops.pallas_attention import (flash_bwd_dkv,
+                                                  flash_bwd_dq,
+                                                  flash_fwd_step)
+
+    q, k, v, dout = _ring_of_one(34, d, dv, jnp.bfloat16)
+    tiles = dict(causal=True, block_q=16, block_k=32, interpret=True)
+    out, lse, _ = flash_fwd_step(q, k, v, None, 0, 0, last=True, **tiles)
+    delta = jnp.sum(dout.astype(jnp.float32) * out, axis=-1)
+    args = (q, k, v, dout, lse, delta, 0, 0)
+    wide = (flash_bwd_dq(*args, **tiles), *flash_bwd_dkv(*args, **tiles))
+    narrow = (flash_bwd_dq(*args, out_dtype=jnp.bfloat16, **tiles),
+              *flash_bwd_dkv(*args, out_dtype=jnp.bfloat16, **tiles))
+    for w, n, like in zip(wide, narrow, (q, k, v)):
+        assert w.dtype == jnp.float32 and n.dtype == jnp.bfloat16
+        assert n.shape == like.shape and np.asarray(w).any()
+        np.testing.assert_array_equal(
+            np.asarray(n.astype(jnp.float32)),
+            np.asarray(w.astype(jnp.bfloat16).astype(jnp.float32)))
+
+
+def _primitives(jaxpr):
+    """Every primitive's name in a jaxpr and in the jaxprs its
+    equations hold (shard_map, custom_vjp, pallas_call, cond...)."""
+    from jax.extend import core as jcore
+
+    def inner(value):
+        if isinstance(value, jcore.ClosedJaxpr):
+            yield value.jaxpr
+        elif isinstance(value, jcore.Jaxpr):
+            yield value
+        elif isinstance(value, (tuple, list)):
+            for item in value:
+                yield from inner(item)
+
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        for value in eqn.params.values():
+            for sub in inner(value):
+                names.extend(_primitives(sub))
+    return names
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_a_ring_of_one_rotates_nothing_and_loops_over_nothing(dtype):
+    """``jax.grad`` through ``ring_attention(impl="pallas")`` on a
+    one-device ``sp`` axis: three kernel calls, no ``ppermute`` (not of
+    K and V, not of dK and dV) and no loop; values and gradients are
+    the dense reference's."""
+    mesh = Mesh(np.array(jax.devices()[:1]), ("sp",))
+    q, k, v = (x.astype(dtype) for x in _qkv(35))
+
+    def loss(a, b_, c, attention):
+        return jnp.sum(attention(a, b_, c).astype(jnp.float32) ** 2)
+
+    ring = lambda a, b_, c: ring_attention(a, b_, c, "sp", causal=True,
+                                           impl="pallas")
+    fn = shard_map(
+        lambda a, b_, c: jax.value_and_grad(loss, argnums=(0, 1, 2))(
+            a, b_, c, ring),
+        mesh=mesh, check_vma=False, in_specs=(P(None, "sp"),) * 3,
+        out_specs=(P(), (P(None, "sp"),) * 3))
+    names = _primitives(jax.make_jaxpr(fn)(q, k, v).jaxpr)
+    assert names.count("pallas_call") == 3, names
+    assert not {"ppermute", "while", "scan"} & set(names), names
+
+    value, grads = jax.jit(fn)(q, k, v)
+    want_value, want_grads = jax.value_and_grad(loss, argnums=(0, 1, 2))(
+        q, k, v, lambda a, b_, c: reference_attention(a, b_, c, True))
+    tol = (dict(rtol=2e-3, atol=2e-4) if dtype == jnp.float32
+           else dict(rtol=5e-2, atol=5e-2))
+    np.testing.assert_allclose(float(value), float(want_value),
+                               rtol=tol["rtol"])
+    for got, want in zip(grads, want_grads):
+        assert got.dtype == dtype
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32), **tol)
