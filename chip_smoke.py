@@ -263,9 +263,9 @@ def kernel_flash(rehearsal: bool, bh: int, seq: int, d: int,
     import jax.numpy as jnp
     import numpy as np
 
-    from horovod_tpu.ops.pallas_attention import (flash_block_step,
-                                                  flash_bwd_dkv,
-                                                  flash_bwd_dq)
+    from horovod_tpu.ops.pallas_attention import (flash_bwd_dkv,
+                                                  flash_bwd_dq,
+                                                  flash_fwd_step)
     from horovod_tpu.parallel.ring_attention import (_block_sizes,
                                                      xla_block_step)
 
@@ -282,8 +282,8 @@ def kernel_flash(rehearsal: bool, bh: int, seq: int, d: int,
         lse = m + jnp.log(l)          # causal: every row sees >= 1 key
         return o / l[..., None], lse
 
-    fwd = jax.jit(lambda q, k, v: norm(*flash_block_step(
-        q, k, v, m0, l0, o0, 0, 0, causal=True, block_q=bq,
+    fwd = jax.jit(lambda q, k, v: norm(*flash_fwd_step(
+        q, k, v, (m0, l0, o0), 0, 0, causal=True, block_q=bq,
         block_k=bk))).lower(q, k, v).compile()
     compiled_with_mosaic(fwd, True, rehearsal)
     out, lse = fwd(q, k, v)

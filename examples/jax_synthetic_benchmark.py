@@ -10,7 +10,7 @@ Run::
 
 The train step is the framework's compiled data-parallel path: a
 shard_map over the world mesh with the DistributedOptimizer's traced
-psum — identical to ``bench.py`` (the driver's measured workload).
+psum.
 """
 
 try:

@@ -12,7 +12,7 @@ mechanizes those three invariant families as static-analysis passes:
   schedule shape) replacing the per-test regexes;
 * :mod:`~horovod_tpu.analysis.knob_lint` — AST cross-referencing of the
   knob registry against raw env reads, the round-0 handshake vector,
-  the program/AOT cache keys, the launcher/bench CLI surfaces, and the
+  the program/AOT cache keys, the launcher's CLI surface, and the
   docs;
 * :mod:`~horovod_tpu.analysis.concurrency_lint` — a lock-acquisition
   graph over ``runtime/``, ``run/`` and ``common/`` reporting

@@ -24,9 +24,8 @@ This pass mechanizes the cross-references:
   shape no program).
 * ``KNOB-AOT-KEY`` — the AOT cache must key on ``round0_cfg()``
   itself (one agreement surface by construction).
-* ``KNOB-CLI-REGISTRY`` / ``KNOB-BENCH-DRIFT`` — the launcher builds
-  its flags from the registry; bench.py must not invent env names the
-  registry does not know.
+* ``KNOB-CLI-REGISTRY`` — the launcher builds its flags from the
+  registry.
 * ``KNOB-DOC-MISSING`` — every registered knob has a doc row.
 
 Everything here is AST-based: no module UNDER LINT is imported (the
@@ -40,24 +39,10 @@ from __future__ import annotations
 
 import ast
 import os
-import re
 from dataclasses import dataclass, field
 
 from horovod_tpu.analysis.findings import Finding
 
-# Env names that are deliberately NOT registry knobs: launcher-assigned
-# process identity / cross-process coordination values.  They are still
-# flagged when read raw inside the package (the allowlist carries the
-# per-file justification); this set only exempts them from the bench
-# CLI-drift rule, where mentioning them is not "inventing a knob".
-COORDINATION_ENV = frozenset({
-    "HOROVOD_RANK", "HOROVOD_SIZE", "HOROVOD_LOCAL_RANK",
-    "HOROVOD_LOCAL_SIZE", "HOROVOD_CROSS_RANK", "HOROVOD_CROSS_SIZE",
-    "HOROVOD_TPU_RANK", "HOROVOD_HOSTNAMES", "HOROVOD_SECRET_KEY",
-    "HOROVOD_ELASTIC_JOINER", "HOROVOD_ELASTIC_UID",
-    "HOROVOD_ELASTIC_NP", "HOROVOD_RESTART_ATTEMPT",
-    "HOROVOD_RESUME_STEP", "HOROVOD_RUNFUNC_NO_SHARED_FS",
-})
 # Help-text phrases that claim cross-rank agreement; the handshake
 # vector and these markers must agree in both directions.
 HANDSHAKE_MARKERS = ("round-0 handshake", "must agree on every rank")
@@ -68,8 +53,7 @@ DATA_PLANE_MODULES = ("ops/xla_exec.py", "ops/collectives.py",
                       "ops/overlap.py", "ops/compression.py",
                       "ops/quantization.py")
 
-_CONFIG_ALIASES = {"config", "_config", "_bconfig"}
-_ENV_RE = re.compile(r"HOROVOD_[A-Z0-9_]+")
+_CONFIG_ALIASES = {"config", "_config"}
 
 
 def _f(rule, loc, msg, hint="", severity="error") -> Finding:
@@ -349,7 +333,6 @@ def _registry_rules(root: str) -> list:
     findings = []
     knobs = _cfg.knobs()
     knob_names = frozenset(knobs)
-    env_to_name = {k.env: n for n, k in knobs.items()}
 
     mods = _Modules(root, [
         "horovod_tpu/runtime/controller.py",
@@ -454,29 +437,7 @@ def _registry_rules(root: str) -> list:
             "build knob flags from the registry (run/launcher.py "
             "parser loop)"))
 
-    # (8) bench.py must not invent env names.
-    bench = os.path.join(root, "bench.py")
-    if os.path.exists(bench):
-        with open(bench) as f:
-            tree = ast.parse(f.read(), filename="bench.py")
-        seen = {}
-        for node in ast.walk(tree):
-            if isinstance(node, ast.Constant) and isinstance(node.value,
-                                                             str):
-                for env in _ENV_RE.findall(node.value):
-                    seen.setdefault(env, node.lineno)
-        for env, lineno in sorted(seen.items()):
-            if env in env_to_name or env in COORDINATION_ENV:
-                continue
-            findings.append(_f(
-                "KNOB-BENCH-DRIFT", f"bench.py:{lineno}",
-                f"bench references {env}, which is neither a "
-                "registered knob nor a known coordination "
-                "var — the PR 10 unregistered-knob drift class",
-                "register the knob in common/config.py (or add it to "
-                "knob_lint's coordination set with a rationale)"))
-
-    # (9) every registered knob has a doc row.
+    # (8) every registered knob has a doc row.
     docs_text = _docs_corpus(root)
     for name, k in sorted(knobs.items()):
         if k.env not in docs_text:
@@ -487,9 +448,9 @@ def _registry_rules(root: str) -> list:
                 "add a row to the relevant doc's knob table",
                 severity="warning"))
 
-    # (10) every registered knob has a READER: some string in the
-    # package (outside config.py) or bench.py names either the knob or
-    # its env var — via config.get("name"), a dynamic-helper call
+    # (9) every registered knob has a READER: some string in the
+    # package (outside config.py) names either the knob or its env
+    # var — via config.get("name"), a dynamic-helper call
     # site, or a justified raw env read.  A knob nothing reads is
     # documentation fiction with a CLI flag (HOROVOD_EAGER_PAD_POW2
     # shipped exactly that way and survived 11 PRs).
@@ -499,7 +460,7 @@ def _registry_rules(root: str) -> list:
             findings.append(_f(
                 "KNOB-DEAD", "horovod_tpu/common/config.py:registry",
                 f"registered knob '{name}' ({k.env}) has no reader "
-                "anywhere in the package or bench.py — its CLI flag "
+                "anywhere in the package — its CLI flag "
                 "and doc row promise behavior that does not exist",
                 "wire the knob up or delete the registration",
                 severity="warning"))
@@ -507,14 +468,11 @@ def _registry_rules(root: str) -> list:
 
 
 def _referenced_strings(root: str) -> set:
-    """Every string constant in the package (minus config.py) and
-    bench.py — the read-evidence corpus for KNOB-DEAD."""
+    """Every string constant in the package (minus config.py) — the
+    read-evidence corpus for KNOB-DEAD."""
     out: set = set()
     paths = [p for p in _package_files(os.path.join(root, "horovod_tpu"))
              if not p.replace(os.sep, "/").endswith("common/config.py")]
-    bench = os.path.join(root, "bench.py")
-    if os.path.exists(bench):
-        paths.append(bench)
     for path in paths:
         try:
             with open(path) as f:

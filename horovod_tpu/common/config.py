@@ -287,31 +287,6 @@ _register("timeline_mark_cycles", Knob(
     "HOROVOD_TIMELINE_MARK_CYCLES", False, _parse_bool,
     cli="--timeline-mark-cycles", config_key="profiling.timeline_mark_cycles",
     help="Emit background-cycle markers into the timeline."))
-_register("attn_xla_score_bytes", Knob(
-    "HOROVOD_ATTN_XLA_SCORE_BYTES", 4 << 30, int,
-    cli="--attn-xla-score-bytes", config_key="attention.xla_score_bytes",
-    help="Ring attention auto-impl threshold: per-ring-step fp32 "
-         "score+softmax bytes up to which XLA's fused attention is "
-         "used; beyond it the streaming Pallas kernel takes over."))
-_register("attn_block_q", Knob(
-    "HOROVOD_ATTN_BLOCK_Q", 0, int,
-    cli="--attn-block-q", config_key="attention.block_q",
-    help="Pallas attention Q tile size (0 = auto: the largest of 1024, "
-         "512, ... 8 dividing the chunk). Bench/tuning hook for "
-         "the on-chip tile sweep; must divide the local sequence "
-         "chunk, else auto applies."))
-_register("attn_pallas_bwd", Knob(
-    "HOROVOD_ATTN_PALLAS_BWD", "kernel", str,
-    cli="--attn-pallas-bwd", config_key="attention.pallas_bwd",
-    help="Backward strategy for the Pallas ring-attention impl: "
-         "'kernel' (default — saved-LSE flash backward kernels, O(L) "
-         "residuals) or 'remat' (XLA block-step VJP rematerializing "
-         "the fp32 score block per ring step; A/B hook)."))
-_register("attn_block_k", Knob(
-    "HOROVOD_ATTN_BLOCK_K", 0, int,
-    cli="--attn-block-k", config_key="attention.block_k",
-    help="Pallas attention K tile size (0 = auto, see "
-         "--attn-block-q)."))
 _register("jax_profiler", Knob(
     "HOROVOD_TIMELINE_JAX_PROFILER", "", str,
     cli="--jax-profiler-dir", config_key="profiling.jax_profiler_dir",

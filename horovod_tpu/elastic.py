@@ -60,7 +60,7 @@ from horovod_tpu.common import logging as _log
 from horovod_tpu.common.types import HorovodTpuError, RanksDownError
 from horovod_tpu.runtime import flight as _flight
 
-# Module state: generation statistics (bench extras read these) and the
+# Module state: generation statistics and the
 # lazily-created rendezvous transport.  ``_transport_factory`` is the
 # test hook: single-process tests drive the whole admission protocol
 # over an in-memory fake wire.
@@ -100,7 +100,7 @@ def generation() -> int:
 
 
 def stats() -> dict:
-    """Re-form statistics for observability (bench extras): count, last
+    """Re-form statistics for observability: count, last
     and total re-form latency, ranks lost, ranks grown back."""
     out = dict(_stats)
     out["generation"] = generation()

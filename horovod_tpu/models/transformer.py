@@ -504,8 +504,7 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer,
                         out_specs=(pspecs, P()))
 
     # Donating params/opt_state lets XLA update weights in place
-    # instead of allocating fresh buffers every step (same move as the
-    # bench ResNet step, +~2% measured there); callers follow the
+    # instead of allocating fresh buffers every step; callers follow the
     # params, opt_state, loss = step(params, opt_state, ...) reassign
     # pattern, so the invalidated buffers are never re-read.
     @functools.partial(jax.jit, donate_argnums=(0, 1))

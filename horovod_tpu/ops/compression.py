@@ -334,9 +334,8 @@ def fused_wire_bytes(n_elems: int, itemsize: int, modes, *, block: int,
     the overlap chain uses (``n // k`` plus one extra element for the
     first ``n % k`` buckets), each share counted under ITS mode by
     :func:`payload_wire_bytes`.  The single accounting the autotuner's
-    scoring, the ``hvd_data_wire_bytes_total`` metric and bench's
-    analytic ``*_wire_compression_ratio`` all share — so they can
-    never disagree about the achieved byte cut."""
+    scoring and the ``hvd_data_wire_bytes_total`` metric share — so
+    they can never disagree about the achieved byte cut."""
     n_elems = max(int(n_elems), 0)
     modes = list(modes) or ["none"]
     k = len(modes)

@@ -1,4 +1,11 @@
-"""Pallas TPU kernel: blockwise (flash) attention accumulation step.
+"""Pallas TPU kernels: the flash-attention forward step and the two
+saved-LSE backward kernels.
+
+Three entry points (:func:`flash_fwd_step`, :func:`flash_bwd_dq`,
+:func:`flash_bwd_dkv`), none differentiable by itself: the one caller,
+``parallel/ring_attention``'s ring-level VJP, pairs them, and hands
+them tiles it derives from the chunk length and :data:`VMEM_BUDGET`.
+This module imports nothing from ``parallel/``.
 
 The hot op of ring attention (SURVEY §5.7 — a new TPU capability, absent
 from the reference): one online-softmax accumulation of a local Q chunk
@@ -278,17 +285,22 @@ def flash_fwd_step(q, k, v, state, q_offset, k_offset, *,
                    block_k: int = 128, last: bool = False,
                    interpret: bool | None = None):
     """One step of a ring's forward pass: attend local Q against one
-    KV block.  Shapes as :func:`flash_block_step`.
+    KV block.
 
-    ``state``: the carried ``(m, l, o)``, or None on the ring's first
-    step — the kernel then starts from (-inf, 0, 0) in scratch and
-    reads no state from HBM.  Returns the updated ``(m, l, o)``; on the
-    ``last`` step instead ``(out, lse, out_q)``: the normalized
-    (BH, Lq, Dv) fp32 result o / l (0 for a row that saw no key), the
-    (BH, Lq) fp32 lse = m + log l (-inf for such a row), and ``out``
-    rounded to q's type (``out`` itself where that is fp32), all
-    computed where the state is, in VMEM.  A one-step ring is first
-    and last at once and its state never exists in HBM.  Forward only.
+    q: (BH, Lq, D); k: (BH, Lk, D); v: (BH, Lk, Dv), Dv any size (a
+    latent-attention head has 192 for q/k and 128 for v).
+    q_offset / k_offset: global positions of q[:,0]/k[:,0] (traced OK).
+    ``state``: the carried ``(m, l, o)`` (m, l: (BH, Lq) fp32 running
+    max / denominator; o: (BH, Lq, Dv) fp32 unnormalized numerator),
+    or None on the ring's first step — the kernel then starts from
+    (-inf, 0, 0) in scratch and reads no state from HBM.  Returns the
+    updated ``(m, l, o)``; on the ``last`` step instead
+    ``(out, lse, out_q)``: the normalized (BH, Lq, Dv) fp32 result
+    o / l (0 for a row that saw no key), the (BH, Lq) fp32
+    lse = m + log l (-inf for such a row), and ``out`` rounded to q's
+    type (``out`` itself where that is fp32), all computed where the
+    state is, in VMEM.  A one-step ring is first and last at once and
+    its state never exists in HBM.  Forward only.
     """
     bh, lq, d = q.shape
     _, lk, dv = v.shape
@@ -351,8 +363,7 @@ def flash_fwd_step(q, k, v, state, q_offset, k_offset, *,
 def _recomputed_p_ds(q, k, v, do, ld, mask, scale):
     """One tile's softmax probabilities from the saved per-row LSE, and
     dS = P ∘ (dP − delta) · scale — what both backward kernels start
-    from.  The full score matrix is never materialized (the whole point
-    vs the XLA-remat VJP)."""
+    from.  The full score matrix is never materialized."""
     lse = ld[:, _M_LANE]
     delta = ld[:, _L_LANE]
     s = jax.lax.dot_general(
@@ -544,62 +555,3 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, q_offset, k_offset, *,
         compiler_params=_compiler_params(bq, bk, d, dv, q.dtype),
         interpret=interpret,
     )(offs, q, k, v, do, ld)
-
-
-# The carried step below (state in, state out: a middle step of the
-# ring) is forward-only; its VJP is the XLA block
-# step's (same math, rematerialized from the inputs).  It remains the
-# ``attn_pallas_bwd="remat"`` escape hatch; the default pallas path now
-# runs the ring-level saved-LSE VJP in ring_attention, whose backward
-# is the two hand-written kernels above (no full score materialization
-# — the XLA-remat VJP needed the whole fp32 score block per ring step,
-# which OOM'd HBM at (seq 4096, b 4) on v5e).
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
-def _flash_block_step_diff(q, k, v, m, l, o, q_offset, k_offset,
-                           causal, block_q, block_k, interpret):
-    return flash_fwd_step(q, k, v, (m, l, o), q_offset, k_offset,
-                          causal=causal, block_q=block_q, block_k=block_k,
-                          interpret=interpret)
-
-
-def _flash_fwd(q, k, v, m, l, o, q_offset, k_offset,
-               causal, block_q, block_k, interpret):
-    out = flash_fwd_step(q, k, v, (m, l, o), q_offset, k_offset,
-                         causal=causal, block_q=block_q, block_k=block_k,
-                         interpret=interpret)
-    return out, (q, k, v, m, l, o, q_offset, k_offset)
-
-
-def _flash_bwd(causal, block_q, block_k, interpret, res, ct):
-    from horovod_tpu.parallel.ring_attention import xla_block_step
-
-    q, k, v, m, l, o, q_offset, k_offset = res
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_, m_, l_, o_: xla_block_step(
-            q_, k_, v_, m_, l_, o_, q_offset, k_offset, causal=causal),
-        q, k, v, m, l, o)
-    dq, dk, dv, dm, dl, do = vjp(ct)
-    return dq, dk, dv, dm, dl, do, None, None
-
-
-_flash_block_step_diff.defvjp(_flash_fwd, _flash_bwd)
-
-
-def flash_block_step(q, k, v, m, l, o, q_offset, k_offset, *,
-                     causal: bool = True, block_q: int = 128,
-                     block_k: int = 128, interpret: bool | None = None):
-    """One ring-attention accumulation: attend local Q against one KV
-    block, updating carried online-softmax state.
-
-    q: (BH, Lq, D); k: (BH, Lk, D); v: (BH, Lk, Dv), Dv any size (a
-    latent-attention head has 192 for q/k and 128 for v); m, l:
-    (BH, Lq) fp32 running max / denominator; o: (BH, Lq, Dv) fp32
-    unnormalized numerator.
-    q_offset / k_offset: global positions of q[:,0]/k[:,0] (traced OK).
-    Returns updated (m, l, o).  Differentiable: the backward pass is
-    the XLA online-softmax step's VJP over the saved inputs.
-    """
-    return _flash_block_step_diff(q, k, v, m, l, o,
-                                  jnp.asarray(q_offset, jnp.int32),
-                                  jnp.asarray(k_offset, jnp.int32),
-                                  causal, block_q, block_k, interpret)

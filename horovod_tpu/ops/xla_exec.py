@@ -54,7 +54,7 @@ def _hier_admissibility():
     ranks and ranks are host-contiguous, so row ``r`` of the world mesh
     sits at ``(r // local, r % local)`` of the 2-level mesh.
     ``HOROVOD_HIERARCHICAL_LOCAL_SIZE`` overrides the detected local
-    group size (test/bench hook).  Shared with the autotuner
+    group size (test hook).  Shared with the autotuner
     (`hier_possible`) so it never tunes a dimension this gate would
     ignore."""
     st = _basics.state()

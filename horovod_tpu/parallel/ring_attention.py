@@ -9,21 +9,21 @@ rotation overlaps with the attention compute of the previous block, so
 ICI transfer hides behind the MXU (Liu et al., "Ring Attention with
 Blockwise Transformers", and the jax-ml scaling-book collective recipe).
 
-One ring driver, two block-step implementations with the same packed
-(B*H, L, D) signature: ``impl="xla"`` is the pure-JAX online-softmax
-step (XLA fuses it well — the safe fallback everywhere), and
+Two paths over the same packed (B*H, L, D) operands, chosen from the
+shape alone: ``impl="xla"`` is the pure-JAX online-softmax step
+(XLA fuses it well — the safe fallback everywhere), and
 ``impl="pallas"`` is the hand-tiled flash kernel
 (:mod:`horovod_tpu.ops.pallas_attention`) that keeps softmax state in
-VMEM scratch and feeds the MXU with aligned blocks.  The default picks
-by score-block size on TPU (:func:`auto_impl`); a chunk length with no
-aligned divisor raises when pallas was asked for and logs once when
-the pick was automatic.  The pallas path is differentiable through a ring-level custom
-VJP: the forward saves only (q, k, v, out, lse) and the backward is a
-second ring pass over hand-written saved-LSE flash backward kernels,
-with dK/dV accumulators rotating alongside KV — no O(Lq·Lk) score
-block is ever materialized in either direction
-(``HOROVOD_ATTN_PALLAS_BWD=remat`` selects the previous XLA-remat
-block-step VJP for on-chip A/B).
+VMEM scratch and feeds the MXU with aligned blocks, its tiles picked
+from the chunk length and the VMEM budget (:func:`_block_sizes`).  The
+default picks by score-block size on TPU (:func:`auto_impl`); a chunk
+length with no aligned divisor raises when pallas was asked for and
+logs once when the pick was automatic.  The pallas path is
+differentiable through a ring-level custom VJP: the forward saves only
+(q, k, v, out, lse) and the backward is a second ring pass over
+hand-written saved-LSE flash backward kernels, with dK/dV accumulators
+rotating alongside KV — no O(Lq·Lk) score block is ever materialized
+in either direction.
 
 What the Pallas ring's ends do.  The softmax state (m, l, o) and the
 fp32 dK/dV accumulators exist in HBM only between steps: the first
@@ -97,30 +97,14 @@ def _block_sizes(lc: int, lk: int, d: int, itemsize: int,
                  dv: int | None = None):
     """(block_q, block_k) for the Pallas kernel at q/k head size ``d``,
     v head size ``dv`` (``d`` where not given) and
-    ``itemsize``-byte operands: forced by the HOROVOD_ATTN_BLOCK_Q/K
-    knobs when they divide the chunk (the on-chip tile-sweep hook),
-    else the auto pick, stepped down the ladder (K first) while the
-    kernels would ask for more than ``VMEM_BUDGET``.  Returns
-    (None, _) when no aligned tiling exists for the Q chunk."""
-    from horovod_tpu.common import config as _config
+    ``itemsize``-byte operands: the largest tile on the ladder for
+    each side, stepped down the ladder (K first) while the kernels
+    would ask for more than ``VMEM_BUDGET``.  Returns (None, _) when
+    no aligned tiling exists for the Q chunk."""
     from horovod_tpu.ops.pallas_attention import (VMEM_BUDGET,
                                                   tile_vmem_bytes)
 
-    def one(n, knob):
-        forced = _config.get(knob)
-        # sublane-aligned (f32 tile rows come in 8s on TPU) and a
-        # divisor of the chunk; anything else falls back to auto
-        if forced > 0 and forced % 8 == 0 and n % forced == 0:
-            return forced
-        if forced:
-            _log.warning(
-                f"{knob}={forced} is not a positive multiple of 8 "
-                f"dividing chunk {n}; using auto tile size")
-        return _pick_block(n)
-
-    bq, bk = one(lc, "attn_block_q"), one(lk, "attn_block_k")
-    if _config.get("attn_block_q") or _config.get("attn_block_k"):
-        return bq, bk   # a forced size wins; Mosaic says if it is too much
+    bq, bk = _pick_block(lc), _pick_block(lk)
     while (bq and bk and max(bq, bk) > 8
            and tile_vmem_bytes(bq, bk, d, itemsize, dv) > VMEM_BUDGET):
         if bk >= bq:
@@ -130,24 +114,24 @@ def _block_sizes(lc: int, lk: int, d: int, itemsize: int,
     return bq, bk
 
 
+# One ring step's fp32 score and softmax bytes up to which auto_impl
+# picks XLA.  The threshold is older than the kernels it chooses
+# between: on a v5e one call's forward and backward at
+# (16, 1024, 12, 64), which it sends to XLA, takes 6.2 ms through the
+# kernels and 7.7 through XLA (``PERF.md``, PR 29); seq 2048 and 4096
+# are not measured, and moving it waits for a benchmark cell on each
+# side (``ROADMAP.md`` S4 a).
+XLA_SCORE_BYTES = 4 << 30
+
+
 def auto_impl(batch: int, heads: int, seq_q: int,
               seq_k: int | None = None) -> str:
     """Which attention impl the auto heuristic picks for one ring step
-    of this shape on TPU.  Shared with ``bench.py``'s crossover
-    side-measure so its labels can never drift from the product
-    decision.  The XLA step materializes fp32 scores plus an fp32
-    softmax transient, hence 8 bytes per score element.  The
-    threshold is older than the kernels it chooses between: on a v5e
-    one call's forward and backward at (16, 1024, 12, 64), which it
-    sends to XLA, takes 6.2 ms through the kernels and 7.7 through
-    XLA (``PERF.md``, PR 29); seq 2048 and 4096 are not measured, and
-    moving it takes a benchmark cell on each side first."""
-    from horovod_tpu.common import config as _config
-
+    of this shape on TPU.  The XLA step materializes fp32 scores plus
+    an fp32 softmax transient, hence 8 bytes per score element."""
     seq_k = seq_q if seq_k is None else seq_k
     score_bytes = 8 * batch * heads * seq_q * seq_k
-    return ("xla" if score_bytes <= _config.get("attn_xla_score_bytes")
-            else "pallas")
+    return "xla" if score_bytes <= XLA_SCORE_BYTES else "pallas"
 
 
 def _ring_offsets(j, axis_name, lc, causal):
@@ -212,8 +196,7 @@ def _ring_flash(qp, kp, vp, axis_name, causal, bq, bk):
     with dK/dV accumulators rotating alongside KV so each block's
     gradient arrives home after the full cycle (in a ring of one it is
     home already: nothing rotates).  Nothing O(Lq·Lk) is
-    ever materialized — unlike the previous XLA-remat VJP, whose fp32
-    score block OOM'd v5e HBM at (seq 4096, batch 4)."""
+    ever materialized."""
     return _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, bq, bk)[2]
 
 
@@ -330,29 +313,13 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
                 _log.warning(msg + "; using the XLA block step")
             impl = "xla"
     if impl == "pallas":
-        from horovod_tpu.common import config as _config
-
-        if _config.get("attn_pallas_bwd") != "remat":
-            # Default: ring-level saved-LSE VJP — backward runs the
-            # hand-written flash backward kernels, O(L) residuals.
-            qp = q.transpose(0, 2, 1, 3).reshape(b * h, lc, d)
-            kp = k.transpose(0, 2, 1, 3).reshape(b * h, lc, d)
-            vp = v.transpose(0, 2, 1, 3).reshape(b * h, lc, dv)
-            out = _ring_flash(qp, kp, vp, axis_name, causal, bq, bk)
-            return out.reshape(b, h, lc, dv).transpose(0, 2, 1, 3)
-
-        # "remat": per-step custom VJP whose backward is the XLA block
-        # step's (full fp32 score block per ring step) — kept for
-        # on-chip A/B against the kernel backward.
-        from horovod_tpu.ops.pallas_attention import flash_block_step
-
-        def step_fn(qp, kj, vj, m, l, o, qo, ko):
-            return flash_block_step(qp, kj, vj, m, l, o, qo, ko,
-                                    causal=causal, block_q=bq, block_k=bk)
-    else:
-        def step_fn(qp, kj, vj, m, l, o, qo, ko):
-            return xla_block_step(qp, kj, vj, m, l, o, qo, ko,
-                                  causal=causal)
+        # ring-level saved-LSE VJP — backward runs the hand-written
+        # flash backward kernels, O(L) residuals
+        qp = q.transpose(0, 2, 1, 3).reshape(b * h, lc, d)
+        kp = k.transpose(0, 2, 1, 3).reshape(b * h, lc, d)
+        vp = v.transpose(0, 2, 1, 3).reshape(b * h, lc, dv)
+        out = _ring_flash(qp, kp, vp, axis_name, causal, bq, bk)
+        return out.reshape(b, h, lc, dv).transpose(0, 2, 1, 3)
 
     qp = q.transpose(0, 2, 1, 3).reshape(b * h, lc, d)
     kp = k.transpose(0, 2, 1, 3).reshape(b * h, lc, d)
@@ -369,7 +336,8 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
         # that mask, so the non-causal trace skips the axis_index
         # chain (see _ring_flash_fwd_impl).
         qo, ko = (idx * lc, ((idx - j) % sp) * lc) if causal else (0, 0)
-        m, l, o = step_fn(qp, kj, vj, m, l, o, qo, ko)
+        m, l, o = xla_block_step(qp, kj, vj, m, l, o, qo, ko,
+                                 causal=causal)
         # Rotate KV around the ring (overlaps next block's compute).
         kj = lax.ppermute(kj, axis_name, rot)
         vj = lax.ppermute(vj, axis_name, rot)

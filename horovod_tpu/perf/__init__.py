@@ -16,9 +16,8 @@ schedules label their buckets with ``hvd_overlap_rs/math/ag<k>`` /
 * :mod:`horovod_tpu.perf.capture` — sampled continuous capture
   (``HOROVOD_PROFILE_EVERY_N_STEPS``) feeding the
   ``hvd_device_*`` / ``hvd_mfu`` gauges of the PR 6 metrics plane;
-* :mod:`horovod_tpu.perf.report` / :mod:`horovod_tpu.perf.compare` —
-  ``python -m horovod_tpu.perf report <dir>`` and the noise-aware
-  ``bench.py --compare`` regression gate;
+* :mod:`horovod_tpu.perf.report` —
+  ``python -m horovod_tpu.perf report <dir>``;
 * :mod:`horovod_tpu.perf.goodput` — the wall-clock ledger: every
   second of a run classified into exclusive phases (init / compile /
   input_wait / compute / comm_exposed / checkpoint / reform /
@@ -41,7 +40,6 @@ from horovod_tpu.perf.capture import (
     set_step_flops,
     stop_and_analyze,
 )
-from horovod_tpu.perf.compare import build_baseline, compare_result
 from horovod_tpu.perf.goodput import (
     FleetGoodput,
     GoodputLedger,
@@ -55,8 +53,6 @@ __all__ = [
     "GoodputLedger",
     "analyze_dir",
     "attribute",
-    "build_baseline",
-    "compare_result",
     "drain",
     "fleet_report",
     "format_report",

@@ -1,20 +1,14 @@
-"""CLI: ``python -m horovod_tpu.perf {report,baseline,compare,goodput}``.
+"""CLI: ``python -m horovod_tpu.perf {report,goodput,health}``.
 
 ``report <dir>``    — device-truth attribution for every capture under
                       a profile directory (``--json`` for machines).
-``baseline ...``    — aggregate bench result JSONs into a noise-aware
-                      baseline (per-metric mean/σ/direction).
-``compare r b``     — gate an existing bench result against a baseline
-                      (exit 3 on regression — the same gate
-                      ``bench.py --compare`` applies to a fresh run).
 ``goodput <path>``  — wall-clock attribution table per rank and
-                      fleet-wide from goodput ledger dumps, a bench
-                      result, or a live ``/metrics.json`` endpoint
-                      (docs/goodput.md).
+                      fleet-wide from goodput ledger dumps or a live
+                      ``/metrics.json`` endpoint (docs/goodput.md).
 ``health <path>``   — per-rank training-health table (grad norm, loss,
                       nonfinite culprit attribution, sentinel alerts)
-                      from health dumps, a bench result, or a live
-                      ``/metrics.json`` endpoint (docs/health.md).
+                      from health dumps or a live ``/metrics.json``
+                      endpoint (docs/health.md).
 See docs/perf.md.
 """
 
@@ -28,8 +22,8 @@ import sys
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
         prog="python -m horovod_tpu.perf",
-        description="Device-truth perf observatory: xplane reports and "
-                    "the bench regression gate (docs/perf.md).")
+        description="Device-truth perf observatory: xplane, goodput "
+                    "and health reports (docs/perf.md).")
     sub = p.add_subparsers(dest="cmd", required=True)
 
     r = sub.add_parser("report", help="analyze captures under a "
@@ -42,24 +36,6 @@ def build_parser() -> argparse.ArgumentParser:
                    help="flops per step (enables MFU when the capture "
                         "has no recorded hint)")
 
-    b = sub.add_parser("baseline", help="build a regression-gate "
-                                        "baseline from bench results")
-    b.add_argument("results", nargs="+",
-                   help="bench result JSON files (one line each)")
-    b.add_argument("-o", "--output", required=True)
-    b.add_argument("--note", default="")
-
-    c = sub.add_parser("compare", help="gate a bench result against a "
-                                       "baseline (exit 3 on regression)")
-    c.add_argument("result", help="bench result JSON")
-    c.add_argument("baseline", help="baseline JSON (from `baseline`)")
-    c.add_argument("--nsigma", type=float, default=3.0)
-    c.add_argument("--json", action="store_true")
-    c.add_argument("--inject", default="",
-                   help="metric=factor[,metric=factor...] multipliers "
-                        "applied before gating — CI hook proving the "
-                        "gate trips")
-
     g = sub.add_parser(
         "goodput",
         help="wall-clock attribution per rank + fleet "
@@ -67,9 +43,9 @@ def build_parser() -> argparse.ArgumentParser:
     g.add_argument("path",
                    help="a directory of goodput-*.json ledger dumps "
                         "(HOROVOD_GOODPUT_DIR / the flight dir), a "
-                        "single dump or bench-result JSON, or a live "
-                        "rank endpoint URL (http://host:port — "
-                        "/metrics.json is fetched)")
+                        "single dump, or a live rank endpoint URL "
+                        "(http://host:port — /metrics.json is "
+                        "fetched)")
     g.add_argument("--json", action="store_true",
                    help="machine-readable output")
     g.add_argument("--slo", type=float, default=None,
@@ -82,16 +58,15 @@ def build_parser() -> argparse.ArgumentParser:
     h.add_argument("path",
                    help="a directory of health-*.json dumps "
                         "(HOROVOD_HEALTH_DIR / the flight dir), a "
-                        "single dump or bench-result JSON, or a live "
-                        "rank endpoint URL (http://host:port — "
-                        "/metrics.json is fetched)")
+                        "single dump, or a live rank endpoint URL "
+                        "(http://host:port — /metrics.json is "
+                        "fetched)")
     h.add_argument("--json", action="store_true",
                    help="machine-readable output")
     return p
 
 
 def main(argv=None) -> int:
-    from horovod_tpu.perf import compare as _cmp
     from horovod_tpu.perf import report as _report
 
     args = build_parser().parse_args(argv)
@@ -123,38 +98,12 @@ def main(argv=None) -> int:
         else:
             print(_goodput.format_report(rep))
         return 0 if rep["ranks"] else 1
-    if args.cmd == "report":
-        rep = _report.analyze_dir(args.dir, flops_per_step=args.flops)
-        if args.json:
-            print(json.dumps(rep))
-        else:
-            print(_report.format_report(rep))
-        return 0 if rep["captures"] else 1
-    if args.cmd == "baseline":
-        results = [_cmp.load_json(p) for p in args.results]
-        baseline = _cmp.build_baseline(results, note=args.note)
-        with open(args.output, "w") as f:
-            json.dump(baseline, f, indent=1, sort_keys=True)
-        print(f"wrote {args.output}: {len(baseline['metrics'])} gated "
-              f"metric(s) from {len(results)} run(s)")
-        return 0
-    # compare — a broken gate input (missing/corrupt JSON) exits 3
-    # like a regression: CI misconfiguration must fail the build, not
-    # traceback with an unrelated status (same contract as bench.py).
-    try:
-        result = _cmp.load_json(args.result)
-        baseline = _cmp.load_json(args.baseline)
-        cmp = _cmp.compare_result(result, baseline, nsigma=args.nsigma,
-                                  inject=_cmp.parse_inject(args.inject))
-    except Exception as exc:
-        print(f"perf gate broken ({args.result} vs {args.baseline}): "
-              f"{exc!r}", file=sys.stderr)
-        return 3
+    rep = _report.analyze_dir(args.dir, flops_per_step=args.flops)
     if args.json:
-        print(json.dumps(cmp))
+        print(json.dumps(rep))
     else:
-        print(_cmp.format_compare(cmp, args.baseline))
-    return 0 if cmp["ok"] else 3
+        print(_report.format_report(rep))
+    return 0 if rep["captures"] else 1
 
 
 if __name__ == "__main__":

@@ -8,15 +8,14 @@ numbers every wire-efficiency claim in this repo actually needs
   events (``step_num`` stat);
 * per-step **device** comm seconds split into *hidden under math* vs
   *exposed* — the true overlap efficiency of the PR 5/7 bucket
-  schedules, measured as interval intersection instead of the
-  host-side two-run subtraction ``bench.py`` records;
+  schedules, measured as interval intersection;
 * per-collective device seconds by kind (all-reduce, all-gather,
   reduce-scatter, collective-permute, all-to-all);
 * per-scope seconds for the framework's named buckets
   (``hvd_overlap_rs/math/ag<k>``, ``hvd_zero2_rs<k>``,
   ``hvd_zero3_ag<k>``, ...);
 * MFU when a flops-per-step hint is available (XLA ``cost_analysis``
-  flops, supplied by bench or the capture hook) against the chip's
+  flops, supplied through the capture hook) against the chip's
   peak (spec-sheet table below; an unknown device raises).
 
 Works on TPU device planes and on the CPU backend's host-plane XLA
@@ -33,8 +32,7 @@ from horovod_tpu.perf import xplane as _xp
 # bf16 peak FLOP/s per chip, keyed by a tag found in jax's
 # ``device_kind`` (lower-cased, spaces removed; "TPU v5 lite" is what a
 # v5e announces).  Source: Google Cloud TPU documentation, the "System
-# architecture" page of each generation.  The one table in the repo:
-# bench.py imports it.
+# architecture" page of each generation.
 _PEAK_FLOPS = [
     ("v6", 918e12), ("v5p", 459e12), ("v5lite", 197e12), ("v5e", 197e12),
     ("v5", 459e12), ("v4", 275e12), ("v3", 123e12), ("v2", 46e12),

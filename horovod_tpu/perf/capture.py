@@ -13,7 +13,7 @@ registry:
   much of it the overlap/ZeRO schedules actually hid under math;
 * ``hvd_device_comm_kind_seconds{kind=...}`` — per-collective split;
 * ``hvd_mfu`` — when a flops-per-step hint is registered
-  (:func:`set_step_flops`, stamped by bench's cost analysis), on an
+  (:func:`set_step_flops`), on an
   accelerator (the spec table's peak; a CPU run reports none).
 
 The gauges ride the KV snapshot publisher to the launcher's fleet
@@ -27,7 +27,7 @@ Design constraints:
 * every hook is advisory: a capture/analysis failure increments a
   counter and never takes a training step down;
 * analysis runs off-thread; :func:`drain` joins outstanding analyzers
-  (bench calls it before stamping extras so results are deterministic).
+  (so that a reader of the gauges sees deterministic results).
 """
 
 from __future__ import annotations
@@ -246,8 +246,8 @@ def stop_and_analyze(token: dict) -> None:
 
 
 def drain(timeout_s: float = 30.0) -> None:
-    """Join outstanding analyzer threads (bounded).  Bench calls this
-    before reading :func:`last_analysis` / the gauges into extras."""
+    """Join outstanding analyzer threads (bounded): call it before
+    reading :func:`last_analysis` or the gauges."""
     deadline = time.monotonic() + timeout_s
     with _lock:
         threads = list(_state["threads"])

@@ -11,7 +11,7 @@ into exclusive phases:
 
 * ``init``        — framework/runtime bring-up (``hvd.init()``);
 * ``compile``     — program materialization: model trace+XLA compile
-  (bench warmup spans), negotiated-program builds (the PR 11
+  (warmup spans), negotiated-program builds (the PR 11
   ``hvd_compile_seconds_total`` cold/warm counters), cost analysis;
 * ``input_wait``  — the training thread starved on the input pipeline
   (the ``hvd.data_wait()`` span / iterator-wrapper hook — the
@@ -46,10 +46,7 @@ Surfaces:
   attribution table per rank and fleet-wide (``--json`` for machines);
 * per-rank JSON dumps (``goodput-r<k>-g<g>.json``) on shutdown/abort
   next to the flight-recorder dumps, plus a ``goodput`` event on every
-  flight ring dump;
-* bench extras (``goodput_ratio``, the phase breakdown,
-  ``dominant_bottleneck``) so the PR 9 regression gate can fail a
-  build on a goodput drop.
+  flight ring dump.
 
 Import discipline: stdlib + the stdlib-only runtime modules (config,
 logging, metrics) — no jax anywhere in this module, enforced by the
@@ -762,9 +759,9 @@ class FleetGoodput:
 
 
 def _snapshot_from_obj(obj: dict) -> list:
-    """Ledger snapshots out of one parsed JSON object of any supported
-    shape: a raw ledger dump, a bench result (extras.goodput), or a
-    metrics /metrics.json snapshot."""
+    """Ledger snapshots out of one parsed JSON object of either
+    supported shape: a raw ledger dump or a metrics /metrics.json
+    snapshot."""
     if not isinstance(obj, dict):
         return []
     if "phases" in obj and "elapsed_s" in obj:
@@ -772,27 +769,13 @@ def _snapshot_from_obj(obj: dict) -> list:
     if "metrics" in obj and "meta" in obj:
         led = from_metrics_snapshot(obj)
         return [led] if led else []
-    extra = obj.get("extra") or {}
-    gp = extra.get("goodput")
-    if isinstance(gp, dict):
-        phases = {k[:-2]: float(v) for k, v in gp.items()
-                  if k.endswith("_s") and k[:-2] in PHASES}
-        return [{
-            "elapsed_s": float(gp.get("elapsed_s", 0.0)),
-            "phases": phases,
-            "unattributed_s": float(gp.get("unattributed_s", 0.0)),
-            "unattributed_ratio": float(gp.get("unattributed_ratio",
-                                               0.0)),
-            "goodput_ratio": float(extra.get("goodput_ratio", 0.0)),
-            "rank": 0,
-        }]
     return []
 
 
 def load_snapshots(path: str) -> list:
     """Collect per-rank ledger snapshots from ``path``: a directory of
-    ``goodput-*.json`` dumps, a single JSON file (dump / bench result /
-    metrics snapshot), or a live ``http(s)://`` metrics endpoint
+    ``goodput-*.json`` dumps, a single JSON file (dump / metrics
+    snapshot), or a live ``http(s)://`` metrics endpoint
     (``/metrics.json`` is appended when the URL names a bare host)."""
     snaps: list = []
     if path.startswith(("http://", "https://")):
@@ -903,6 +886,5 @@ def format_report(report: dict) -> str:
             + ")")
     if not report.get("ranks"):
         lines.append("no goodput ledgers found (expected goodput-*.json "
-                     "dumps, a bench result with extras.goodput, or a "
-                     "/metrics.json snapshot)")
+                     "dumps or a /metrics.json snapshot)")
     return "\n".join(lines)

@@ -348,7 +348,7 @@ def compile_or_load(program_key, build, args):
 
 
 def stats() -> dict:
-    """Counter snapshot for bench extras / tests."""
+    """Counter snapshot for the re-form report and tests."""
     return {
         "hits": int(_M_HITS.total()),
         "misses": int(_M_MISSES.total()),

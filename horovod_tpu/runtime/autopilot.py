@@ -494,7 +494,7 @@ class Autopilot:
             pass
 
     def stats(self) -> dict:
-        """Counts for bench extras / drill outputs."""
+        """Counts for drill outputs."""
         by_rule: dict[str, int] = {}
         by_outcome: dict[str, int] = {}
         for a in self.actions:

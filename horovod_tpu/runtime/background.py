@@ -447,8 +447,8 @@ class BackgroundRuntime:
             self.pm.record_bytes(wire_b, logical_b)
         # axis=local: ICI-scoped local-SGD inner reductions; axis=cross:
         # anything whose bytes cross slices over DCN (docs/local-sgd.md
-        # — the bench's *_dcn_bytes_per_step extras read the cross
-        # series, so the >= H x reduction is measured, not claimed).
+        # — the cross series is what the >= H x reduction is counted
+        # on).
         scope = _scope_of(resp)
         _M_WIRE_BYTES.inc(wire_b, kind=resp.kind,
                           axis="local" if scope == "local" else "cross")

@@ -380,8 +380,7 @@ def dump_on_failure(reason: str, flush_metrics: bool = True) -> str | None:
     # that is exactly when the attribution matters most.  sys.modules
     # lookup + signal-path skip for the same handler-safety reasons as
     # in dump() (coordinated aborts run on ordinary threads and keep
-    # the ledger dump; a SIGTERM'd bench stamps its ledger from its
-    # own SystemExit path instead).
+    # the ledger dump).
     try:
         _goodput = (None if _in_signal_handler
                     else sys.modules.get("horovod_tpu.perf.goodput"))

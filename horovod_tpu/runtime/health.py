@@ -478,7 +478,7 @@ def reset() -> None:
 def observe_loss(value: float, step: int | None = None) -> None:
     """Feed the real loss trajectory to the health plane — the
     divergence sentinel's and the compression guardrail's primary
-    signal.  Host-side and cheap; call it once per step (bench does)."""
+    signal.  Host-side and cheap; call it once per step."""
     monitor().observe_loss(value, step=step)
 
 
@@ -900,28 +900,10 @@ def from_metrics_snapshot(snap: dict) -> dict | None:
     return out
 
 
-def _snapshot_from_bench(obj: dict) -> dict | None:
-    extra = (obj or {}).get("extra") or {}
-    if "health_alerts" not in extra and "nonfinite_steps" not in extra:
-        return None
-    return {"meta": {"rank": 0, "size": 1, "generation": 0,
-                     "reason": "bench_result"},
-            "last_loss": None,
-            "last_grad_norm": extra.get("grad_norm_final"),
-            # bench records verdict EVENTS, not element counts — keep
-            # the semantics distinct (format_report labels them apart)
-            "nonfinite_events": extra.get("nonfinite_steps", 0),
-            "culprits": [], "update_ratio": {},
-            "active_alerts": extra.get("health_active_alerts") or [],
-            "skipped_steps": extra.get("health_skipped_steps", 0),
-            "alerts_total": extra.get("health_alerts", 0)}
-
-
 def load_snapshots(path: str) -> list:
     """Per-rank health snapshots from: a directory of health-*.json
-    dumps (deduped to each rank's newest generation), a single dump or
-    bench-result JSON, or a live endpoint URL (``/metrics.json`` is
-    fetched)."""
+    dumps (deduped to each rank's newest generation), a single dump,
+    or a live endpoint URL (``/metrics.json`` is fetched)."""
     if path.startswith(("http://", "https://")):
         from urllib.request import urlopen
 
@@ -951,9 +933,6 @@ def load_snapshots(path: str) -> list:
                 (best[r] for r in sorted(best))]
     with open(path) as f:
         obj = json.load(f)
-    if "metric" in obj and "extra" in obj:  # bench result line
-        snap = _snapshot_from_bench(obj)
-        return [snap] if snap else []
     if "metrics" in obj and "meta" in obj:  # metrics snapshot
         snap = from_metrics_snapshot(obj)
         return [snap] if snap else []
@@ -992,11 +971,7 @@ def format_report(report: dict) -> str:
         loss = s.get("last_loss")
         alerts = s.get("active_alerts") or []
         gn_s = f"{gn:.4g}" if isinstance(gn, (int, float)) else "-"
-        if "nonfinite_elems" in s:
-            nf_s = f"nonfinite {float(s.get('nonfinite_elems') or 0):g}"
-        else:  # bench artifacts record verdict events, not elements
-            nf_s = (f"nonfinite_events "
-                    f"{float(s.get('nonfinite_events', 0) or 0):g}")
+        nf_s = f"nonfinite {float(s.get('nonfinite_elems') or 0):g}"
         lines.append(
             f"  rank {meta.get('rank', '?')} g{meta.get('generation', 0)}"
             f": loss {loss if loss is not None else '-'}"

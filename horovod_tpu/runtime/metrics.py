@@ -7,8 +7,7 @@ staleness, re-forms, compressed-vs-logical bytes — was visible only as
 scattered log lines.  This module is the registry those subsystems
 write into and the three surfaces that read it:
 
-* ``hvd.metrics()`` — a nested snapshot dict (programmatic access,
-  bench extras);
+* ``hvd.metrics()`` — a nested snapshot dict (programmatic access);
 * a per-rank Prometheus-text HTTP endpoint
   (``HOROVOD_METRICS_PORT`` + rank, off by default);
 * launcher-side aggregation: every rank publishes periodic JSON

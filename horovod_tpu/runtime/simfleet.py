@@ -449,7 +449,7 @@ class SimFleet:
 
 
 # ---------------------------------------------------------------------------
-# Canned scenarios (ci.sh `simfleet` stage, bench --sim-ranks, docs recipe)
+# Canned scenarios (ci.sh `simfleet` stage, docs recipe)
 # ---------------------------------------------------------------------------
 
 

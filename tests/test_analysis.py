@@ -227,7 +227,7 @@ def test_knob_fixture_tree_flagged_and_twin_passes(tmp_path):
 
 def test_knob_dead_rule_flags_readerless_knob(monkeypatch):
     """KNOB-DEAD regression (the HOROVOD_EAGER_PAD_POW2 class): a
-    registered knob no string in the package or bench.py names is
+    registered knob no string in the package names is
     documentation fiction with a CLI flag — register a fake one and
     the rule must flag exactly it."""
     from horovod_tpu.common import config as _cfg

@@ -4,21 +4,17 @@ The acceptance contract of the attribution layer: phases are exclusive
 and conserve wall-clock (they sum to elapsed, ``unattributed`` being
 the exact remainder), the honesty bucket stays bounded and nameable,
 the fleet merge names the dominant bottleneck with per-rank evidence,
-the SLO burn alert fires, and partial/aborted runs keep their
-accounting.
+and the SLO burn alert fires.
 """
 
 import json
 import os
-import socket
-import subprocess
-import sys
 import time
 
 import pytest
 
 from horovod_tpu.perf import goodput as gp
-from test_multiprocess import REPO, run_ranks
+from test_multiprocess import run_ranks
 
 
 @pytest.fixture(autouse=True)
@@ -426,6 +422,45 @@ def test_dump_and_cli_report_round_trip(tmp_path, capsys):
     capsys.readouterr()
 
 
+@pytest.mark.parametrize("form", ["ledger_dump", "metrics_snapshot",
+                                  "bench_artifact"])
+def test_cli_reads_one_file_of_each_form_it_knows(tmp_path, capsys, form):
+    """``perf goodput <file>``: one ledger dump and one ``/metrics.json``
+    snapshot are each a rank's ledger.  A ``bench.py`` result line with
+    ``extra.goodput`` was a third form until PR 31 removed the script:
+    it holds no ledger now, and nothing to report exits 1."""
+    from horovod_tpu.perf.__main__ import main as perf_main
+    from horovod_tpu.runtime import metrics as M
+
+    clock = _fake_clock()
+    led = gp.GoodputLedger(clock=clock)
+    led.start()
+    clock.advance(10.0)
+    led.observe("compile", 5.0)
+    led.observe_step(2.5, compute=2.5, comm_exposed=0.0)
+    if form == "ledger_dump":
+        obj = led.snapshot()
+    elif form == "metrics_snapshot":
+        led.publish()
+        obj = {"meta": {"rank": 0, "host": "h", "time": time.time()},
+               "metrics": M.registry().snapshot()}
+    else:
+        obj = {"metric": "m", "value": 1.0, "extra": {
+            "goodput_ratio": 0.25,
+            "goodput": {"compile_s": 5.0, "compute_s": 2.5,
+                        "unattributed_s": 2.5, "elapsed_s": 10.0}}}
+    path = tmp_path / "one.json"
+    path.write_text(json.dumps(obj))
+    rc = perf_main(["goodput", str(path), "--json"])
+    rep = json.loads(capsys.readouterr().out)
+    if form == "bench_artifact":
+        assert rc == 1 and rep["ranks"] == []
+        return
+    assert rc == 0
+    assert rep["ranks"][0]["phases"]["compile"] == pytest.approx(5.0)
+    assert rep["dominant_bottleneck"]["phase"] == "compile"
+
+
 def test_load_snapshots_dedupes_per_generation_dumps(tmp_path):
     """Regression: every elastic re-form's teardown dumps the SAME
     rank's cumulative ledger under a new generation — loading a dump
@@ -497,25 +532,6 @@ def test_flight_dump_carries_goodput_event(tmp_path):
     flight.reset()
 
 
-def test_bench_result_extras_feed_cli(tmp_path, capsys):
-    from horovod_tpu.perf.__main__ import main as perf_main
-
-    result = {"metric": "m", "value": 1.0, "extra": {
-        "goodput_ratio": 0.25,
-        "goodput": {"init_s": 1.0, "compile_s": 5.0, "compute_s": 2.5,
-                    "input_wait_s": 0.5, "comm_exposed_s": 0.0,
-                    "checkpoint_s": 0.0, "reform_s": 0.0,
-                    "unattributed_s": 1.0, "elapsed_s": 10.0,
-                    "unattributed_ratio": 0.1}}}
-    p = tmp_path / "bench.json"
-    p.write_text(json.dumps(result))
-    rc = perf_main(["goodput", str(p), "--json"])
-    rep = json.loads(capsys.readouterr().out)
-    assert rc == 0
-    assert rep["ranks"][0]["phases"]["compile"] == pytest.approx(5.0)
-    assert rep["dominant_bottleneck"]["phase"] == "compile"
-
-
 # ---------------------------------------------------------------------------
 # 2-proc acceptance: the fleet report names the straggler's phase+rank
 # ---------------------------------------------------------------------------
@@ -564,87 +580,3 @@ def test_2proc_delay_fault_names_rank1_comm_exposed():
     assert by_rank[1]["phases"]["comm_exposed"] \
         > by_rank[0]["phases"]["comm_exposed"], by_rank
     assert by_rank[1]["phases"]["comm_exposed"] > 0.8
-
-
-# ---------------------------------------------------------------------------
-# Bench smoke: a fault-killed run still stamps its partial ledger
-# ---------------------------------------------------------------------------
-
-
-def _free_port() -> int:
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
-
-
-@pytest.mark.multiprocess
-@pytest.mark.slow  # ~60 s 2-proc bench with an injected death
-def test_bench_partial_run_keeps_goodput_ledger(tmp_path):
-    """Satellite: a bench run ending by abort (die:rank1 fault) still
-    stamps the partial goodput ledger into extras — phase accounting
-    must survive exactly the runs where it matters."""
-    port = _free_port()
-    procs = []
-    for r in range(2):
-        env = dict(os.environ)
-        env.update({
-            "HOROVOD_PLATFORM": "cpu",
-            "JAX_PLATFORMS": "cpu",
-            "HOROVOD_RANK": str(r),
-            "HOROVOD_SIZE": "2",
-            "HOROVOD_LOCAL_RANK": str(r),
-            "HOROVOD_LOCAL_SIZE": "2",
-            "HOROVOD_COORDINATOR_ADDR": f"localhost:{port}",
-            "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
-            "PYTHONPATH": REPO + os.pathsep + env.get("PYTHONPATH", ""),
-            # eager section only: the negotiated data plane raises
-            # RanksDownError promptly when the peer dies (an in-trace
-            # model step would ride out the slow gloo deadline instead)
-            "BENCH_MODELS": "none",
-            "BENCH_EAGER": "1",
-            # round1: rank 1 dies at the first DATA round, after the
-            # round-0 handshake completed — plain die:rank1 could fire
-            # before rank 1 ever published a heartbeat, leaving rank 0
-            # to ride out the handshake wire deadline instead of the
-            # prompt staleness abort
-            "HOROVOD_FAULT_SPEC": "die:rank1:round1",
-            "HOROVOD_HEARTBEAT_INTERVAL": "0.5",
-            "HOROVOD_HEARTBEAT_TIMEOUT_SECONDS": "5",
-        })
-        procs.append(subprocess.Popen(
-            [sys.executable, os.path.join(REPO, "bench.py")],
-            env=env, cwd=str(tmp_path), stdout=subprocess.PIPE,
-            stderr=subprocess.STDOUT, text=True))
-    outs = []
-    for r, p in enumerate(procs):
-        try:
-            out, _ = p.communicate(timeout=180)
-        except subprocess.TimeoutExpired:
-            for q in procs:
-                q.kill()
-            raise AssertionError(f"bench rank {r} timed out")
-        outs.append(out)
-    assert procs[1].returncode == 137, outs[1][-1000:]
-    result = None
-    for line in reversed(outs[0].strip().splitlines()):
-        try:
-            obj = json.loads(line)
-        except ValueError:
-            continue
-        if isinstance(obj, dict) and "metric" in obj:
-            result = obj
-            break
-    assert result is not None, outs[0][-2000:]
-    extra = result["extra"]
-    # the run is partial (no headline) but the ledger survived
-    assert result["value"] is None
-    good = extra.get("goodput")
-    assert good and good["elapsed_s"] > 0, extra
-    assert "goodput_ratio" in extra
-    total = (sum(v for k, v in good.items()
-                 if k.endswith("_s") and k not in ("elapsed_s",
-                                                   "unattributed_s"))
-             + good["unattributed_s"])
-    assert total == pytest.approx(good["elapsed_s"], rel=0.03), good

@@ -533,6 +533,30 @@ def test_dump_load_report_cli_round_trip(tmp_path, monkeypatch):
     assert out["culprits"][0]["rank"] == 1
 
 
+@pytest.mark.parametrize("form", ["dump", "metrics_snapshot"])
+def test_report_reads_one_file_of_each_form_it_knows(tmp_path, form):
+    """``perf health <file>``: a single ``health-*.json`` dump and a
+    single ``/metrics.json`` snapshot each give one rank's row with its
+    nonfinite element count (the third form, a ``bench.py`` result
+    line, went with the script in PR 31)."""
+    H.publish_verdict(np.array([[1.0, 4.0, 2.0, 7.0]]), idx=None,
+                      groups=("bfloat16",))
+    H.observe_loss(0.5)
+    if form == "dump":
+        obj = H.monitor().snapshot()
+    else:
+        obj = M.metrics()
+    path = tmp_path / "one.json"
+    path.write_text(json.dumps(obj))
+    rep = H.load_report(str(path))
+    assert len(rep["ranks"]) == 1
+    assert rep["culprits"] == [{"rank": 1, "group": "bfloat16",
+                                "count": 7.0}]
+    text = H.format_report(rep)
+    assert "nonfinite 7" in text and "nonfinite_events" not in text
+    assert "rank 1 / bfloat16" in text
+
+
 def test_from_metrics_snapshot():
     H.publish_verdict(np.array([[1.0, 4.0, 2.0, 7.0]]), idx=None,
                       groups=("bfloat16",))

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from horovod_tpu.ops.pallas_attention import flash_block_step
+from horovod_tpu.ops.pallas_attention import flash_fwd_step
 from horovod_tpu.parallel.ring_attention import (reference_attention,
                                                  ring_attention)
 
@@ -39,8 +39,8 @@ def test_single_step_matches_dense(causal):
     m = jnp.full(qp.shape[:2], -jnp.inf, jnp.float32)
     l = jnp.zeros(qp.shape[:2], jnp.float32)
     o = jnp.zeros(qp.shape, jnp.float32)
-    m, l, o = flash_block_step(qp, kp, vp, m, l, o, 0, 0, causal=causal,
-                               block_q=32, block_k=32, interpret=True)
+    m, l, o = flash_fwd_step(qp, kp, vp, (m, l, o), 0, 0, causal=causal,
+                             block_q=32, block_k=32, interpret=True)
     l = jnp.where(l == 0.0, 1.0, l)
     out = _unpack(o / l[..., None], B, H)
     expected = reference_attention(q, k, v, causal=causal)
@@ -59,12 +59,12 @@ def test_carried_state_composes_across_kv_chunks(causal):
     l = jnp.zeros(qp.shape[:2], jnp.float32)
     o = jnp.zeros(qp.shape, jnp.float32)
     # NB: q_offset=0 with k chunks at global offsets 0 and half
-    m, l, o = flash_block_step(qp, kp[:, :half], vp[:, :half], m, l, o,
-                               0, 0, causal=causal, block_q=32, block_k=16,
-                               interpret=True)
-    m, l, o = flash_block_step(qp, kp[:, half:], vp[:, half:], m, l, o,
-                               0, half, causal=causal, block_q=32,
-                               block_k=16, interpret=True)
+    m, l, o = flash_fwd_step(qp, kp[:, :half], vp[:, :half], (m, l, o),
+                             0, 0, causal=causal, block_q=32, block_k=16,
+                             interpret=True)
+    m, l, o = flash_fwd_step(qp, kp[:, half:], vp[:, half:], (m, l, o),
+                             0, half, causal=causal, block_q=32,
+                             block_k=16, interpret=True)
     l = jnp.where(l == 0.0, 1.0, l)
     out = _unpack(o / l[..., None], B, H)
     expected = reference_attention(q, k, v, causal=causal)
@@ -78,8 +78,8 @@ def test_block_shape_validation():
     m = jnp.zeros(qp.shape[:2], jnp.float32)
     o = jnp.zeros(qp.shape, jnp.float32)
     with pytest.raises(ValueError, match="divide"):
-        flash_block_step(qp, kp, vp, m, m, o, 0, 0, block_q=48,
-                         interpret=True)
+        flash_fwd_step(qp, kp, vp, (m, m, o), 0, 0, block_q=48,
+                       interpret=True)
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -117,33 +117,6 @@ def test_ring_attention_impls_agree_bfloat16():
 
     np.testing.assert_allclose(run("pallas"), run("xla"), rtol=2e-2,
                                atol=2e-2)
-
-
-def test_forced_tile_sizes_stay_correct(monkeypatch):
-    """HOROVOD_ATTN_BLOCK_Q/K (the on-chip tile-sweep hook) force the
-    kernel's tiling; results must not change.  A non-dividing forced
-    size falls back to auto with a warning, still correct."""
-    sp = 2
-    mesh = Mesh(np.array(jax.devices()[:sp]), ("sp",))
-    q, k, v = _qkv(5)
-    expected = reference_attention(q, k, v, causal=True)
-
-    def run():
-        fn = jax.jit(shard_map(
-            lambda a, b_, c: ring_attention(a, b_, c, "sp", causal=True,
-                                            impl="pallas"),
-            mesh=mesh, check_vma=False,
-            in_specs=(P(None, "sp"), P(None, "sp"), P(None, "sp")),
-            out_specs=P(None, "sp")))
-        return np.asarray(fn(q, k, v))
-
-    monkeypatch.setenv("HOROVOD_ATTN_BLOCK_Q", "16")
-    monkeypatch.setenv("HOROVOD_ATTN_BLOCK_K", "32")
-    np.testing.assert_allclose(run(), np.asarray(expected), rtol=2e-4,
-                               atol=2e-5)
-    monkeypatch.setenv("HOROVOD_ATTN_BLOCK_Q", "999")  # no divisor
-    np.testing.assert_allclose(run(), np.asarray(expected), rtol=2e-4,
-                               atol=2e-5)
 
 
 def test_impl_validation():
@@ -200,8 +173,7 @@ def test_grad_through_pallas_ring():
 def test_bwd_kernels_match_dense_vjp(causal):
     """flash_bwd_dq/dkv (saved-LSE backward kernels) vs the dense
     reference attention's autodiff on one full block."""
-    from horovod_tpu.ops.pallas_attention import (flash_block_step,
-                                                  flash_bwd_dkv,
+    from horovod_tpu.ops.pallas_attention import (flash_bwd_dkv,
                                                   flash_bwd_dq)
 
     q, k, v = _qkv(7)
@@ -209,8 +181,8 @@ def test_bwd_kernels_match_dense_vjp(causal):
     m = jnp.full(qp.shape[:2], -jnp.inf, jnp.float32)
     l = jnp.zeros(qp.shape[:2], jnp.float32)
     o = jnp.zeros(qp.shape, jnp.float32)
-    m, l, o = flash_block_step(qp, kp, vp, m, l, o, 0, 0, causal=causal,
-                               block_q=32, block_k=16, interpret=True)
+    m, l, o = flash_fwd_step(qp, kp, vp, (m, l, o), 0, 0, causal=causal,
+                             block_q=32, block_k=16, interpret=True)
     lse = jnp.where(l > 0, m + jnp.log(jnp.where(l > 0, l, 1.0)), -jnp.inf)
     lsafe = jnp.where(l == 0.0, 1.0, l)
     out = o / lsafe[..., None]
@@ -274,29 +246,77 @@ def test_ring_kernel_bwd_matches_dense_grads(causal):
                                    rtol=2e-3, atol=2e-4)
 
 
-def test_pallas_bwd_knob_remat_matches_kernel(monkeypatch):
-    """HOROVOD_ATTN_PALLAS_BWD=remat (the XLA-remat A/B hook) must
-    produce the same gradients as the default kernel backward."""
-    sp = 2
-    mesh = Mesh(np.array(jax.devices()[:sp]), ("sp",))
-    q, k, v = _qkv(10)
+@pytest.mark.parametrize("batch, heads, seq, impl", [
+    (16, 12, 1024, "xla"),       # gpt2-124m.s1024
+    (8, 12, 2048, "xla"),        # R0's gpt2-124m.s2048: 3 GiB of scores
+    (4, 12, 4096, "pallas"),     # 6 GiB
+    (2, 12, 8192, "pallas"),     # gpt2-124m.s8192
+    (2, 32, 8192, "pallas"),     # joyai-llm-flash.s8192.epshare
+    (8, 16, 2048, "xla"),        # 8 * 2**29 bytes: the threshold itself
+    (8, 16, 2056, "pallas"),     # the next sublane-aligned length
+])
+def test_auto_impl_picks_by_the_score_block(batch, heads, seq, impl):
+    """8 bytes a score element (f32 scores and an f32 softmax
+    transient) against ``XLA_SCORE_BYTES``: the three LM cells' shapes
+    and both sides of the boundary."""
+    from horovod_tpu.parallel import ring_attention as ra
 
-    def grads():
-        def loss(a, b_, c):
-            o = ring_attention(a, b_, c, "sp", causal=True, impl="pallas")
-            return jnp.sum(o ** 2)
-        return jax.jit(shard_map(
-            lambda a, b_, c: jax.grad(loss, argnums=(0, 1, 2))(a, b_, c),
-            mesh=mesh, check_vma=False,
-            in_specs=(P(None, "sp"),) * 3,
-            out_specs=(P(None, "sp"),) * 3))(q, k, v)
+    assert ra.XLA_SCORE_BYTES == 4 << 30
+    assert ra.auto_impl(batch, heads, seq) == impl
+    assert ra.auto_impl(batch, heads, seq, seq) == impl
 
-    g_kernel = grads()
-    monkeypatch.setenv("HOROVOD_ATTN_PALLAS_BWD", "remat")
-    g_remat = grads()
-    for a, b_ in zip(g_kernel, g_remat):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
-                                   rtol=1e-3, atol=1e-4)
+
+def _lowered_ring(impl):
+    """Forward and backward of a two-chip causal ring at a small shape,
+    as lowered text (Pallas kernels in interpret mode)."""
+    mesh = Mesh(np.array(jax.devices()[:2]), ("sp",))
+    spec = P(None, "sp")
+
+    def loss(a, b_, c):
+        return jnp.sum(ring_attention(a, b_, c, "sp", causal=True,
+                                      impl=impl) ** 2)
+
+    return jax.jit(shard_map(
+        jax.grad(loss, argnums=(0, 1, 2)), mesh=mesh, check_vma=False,
+        in_specs=(spec,) * 3, out_specs=(spec,) * 3)).lower(
+            *_qkv(6)).as_text()
+
+
+_REMOVED_KNOBS = {
+    "HOROVOD_ATTN_BLOCK_Q": ("attn_block_q", "8"),
+    "HOROVOD_ATTN_BLOCK_K": ("attn_block_k", "8"),
+    "HOROVOD_ATTN_PALLAS_BWD": ("attn_pallas_bwd", "remat"),
+    "HOROVOD_ATTN_XLA_SCORE_BYTES": ("attn_xla_score_bytes", "0"),
+}
+
+
+@pytest.fixture(scope="module")
+def clean_ring_texts():
+    with pytest.MonkeyPatch.context() as patch:
+        for env in _REMOVED_KNOBS:
+            patch.delenv(env, raising=False)
+        return {impl: _lowered_ring(impl) for impl in ("pallas", "xla")}
+
+
+@pytest.mark.parametrize("env", sorted(_REMOVED_KNOBS))
+def test_a_removed_attention_knob_is_not_read(monkeypatch, clean_ring_texts,
+                                              env):
+    """The four switches the pre-harness A/B apparatus swept are gone:
+    set to a value that used to change the program (8-wide tiles, the
+    XLA-remat backward, a threshold of nothing), each leaves the lowered
+    ring and ``auto_impl``'s pick as they are, and the registry does not
+    know the name."""
+    from horovod_tpu.common import config
+    from horovod_tpu.parallel.ring_attention import auto_impl
+
+    key, value = _REMOVED_KNOBS[env]
+    monkeypatch.setenv(env, value)
+    for impl, text in clean_ring_texts.items():
+        assert _lowered_ring(impl) == text, impl
+    assert auto_impl(16, 12, 1024) == "xla"
+    with pytest.raises(KeyError):
+        config.get(key)
+    assert env not in {k.env for k in config.knobs().values()}
 
 
 @pytest.mark.parametrize("chunk, tile", [(8192, 1024), (1024, 1024),
@@ -392,8 +412,8 @@ def test_kernels_match_xla_step_over_block_positions(q_offset, k_offset):
 
     for bq, bk in ((32, 16), (16, 32)):
         tiles = dict(causal=True, block_q=bq, block_k=bk, interpret=True)
-        m, l, o = flash_block_step(q, k, v, m0, l0, o0, q_offset,
-                                   k_offset, **tiles)
+        m, l, o = flash_fwd_step(q, k, v, (m0, l0, o0), q_offset,
+                                 k_offset, **tiles)
         dq = flash_bwd_dq(q, k, v, dout, lse, delta, q_offset, k_offset,
                           **tiles)
         dk, dv = flash_bwd_dkv(q, k, v, dout, lse, delta, q_offset,
@@ -428,8 +448,8 @@ def test_kernel_compiles_through_mosaic_on_tpu():
     m = jnp.full((bh, l), -np.inf, jnp.float32)
     den = jnp.zeros((bh, l), jnp.float32)
     o = jnp.zeros((bh, l, d), jnp.float32)
-    m2, l2, o2 = flash_block_step(q, k, v, m, den, o, 0, 0,
-                                  interpret=False)
+    m2, l2, o2 = flash_fwd_step(q, k, v, (m, den, o), 0, 0,
+                                interpret=False)
     out = np.asarray(o2 / np.asarray(l2)[..., None])
     s = np.einsum("bqd,bkd->bqk", np.asarray(q),
                   np.asarray(k)) / np.sqrt(d)
@@ -456,7 +476,7 @@ def test_each_kernel_is_lowered_under_its_own_name(kernel):
     row = jax.ShapeDtypeStruct((bh, l), jnp.float32)
     acc = jax.ShapeDtypeStruct((bh, l, d), jnp.float32)
     calls = {
-        "hvd_flash_fwd": (pa.flash_block_step, (x, x, x, row, row, acc)),
+        "hvd_flash_fwd": (pa.flash_fwd_step, (x, x, x, (row, row, acc))),
         "hvd_flash_bwd_dq": (pa.flash_bwd_dq, (x, x, x, x, row, row)),
         "hvd_flash_bwd_dkv": (pa.flash_bwd_dkv, (x, x, x, x, row, row)),
     }
@@ -515,7 +535,7 @@ def test_kernels_take_a_v_head_size_of_their_own(kernel, causal):
     lse, delta = em + jnp.log(el), jnp.sum(dout * eout, axis=-1)
     tiles = dict(causal=causal, block_q=16, block_k=32, interpret=True)
     if kernel == "fwd":
-        got = flash_block_step(q, k, v, m0, l0, o0, 0, 0, **tiles)
+        got = flash_fwd_step(q, k, v, (m0, l0, o0), 0, 0, **tiles)
         for g, w in zip(got, (em, el, eo)):
             np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                        rtol=2e-4, atol=2e-5)
@@ -604,10 +624,10 @@ def _carried_then_epilogue(q, k, v, q_offset, k_offset, **tiles):
     finish: (-inf, 0, 0) handed in through HBM, the carried kernel, and
     the normalization and lse in XLA."""
     bh, lq, _ = q.shape
-    m, l, o = flash_block_step(
-        q, k, v, jnp.full((bh, lq), -jnp.inf, jnp.float32),
-        jnp.zeros((bh, lq), jnp.float32),
-        jnp.zeros((bh, lq, v.shape[-1]), jnp.float32),
+    m, l, o = flash_fwd_step(
+        q, k, v, (jnp.full((bh, lq), -jnp.inf, jnp.float32),
+                  jnp.zeros((bh, lq), jnp.float32),
+                  jnp.zeros((bh, lq, v.shape[-1]), jnp.float32)),
         q_offset, k_offset, **tiles)
     lse = jnp.where(l > 0.0, m + jnp.log(jnp.where(l > 0.0, l, 1.0)),
                     -jnp.inf)
@@ -624,8 +644,6 @@ def test_first_and_last_step_at_once_is_carried_step_and_epilogue(
     carried kernel followed by the epilogue, to one ulp of fp32 (the
     division and the log are the kernel's now), and the result in the
     operands' type is that fp32 ``out`` rounded once."""
-    from horovod_tpu.ops.pallas_attention import flash_fwd_step
-
     q, k, v, _ = _ring_of_one(31, d, dv, dtype)
     tiles = dict(causal=causal, block_q=32, block_k=16, interpret=True)
     want_out, want_lse = _carried_then_epilogue(q, k, v, 0, 0, **tiles)
@@ -645,8 +663,6 @@ def test_first_step_then_last_step_compose():
     """A ring of two, by hand: the first step takes no state and hands
     one on, the last takes it and finishes — dense attention over both
     KV halves."""
-    from horovod_tpu.ops.pallas_attention import flash_fwd_step
-
     q, k, v = (_pack(x) for x in _qkv(32))
     half = L // 2
     tiles = dict(causal=True, block_q=32, block_k=16, interpret=True)

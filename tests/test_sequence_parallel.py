@@ -160,24 +160,23 @@ def test_ring_attention_bf16(mesh):
 
 @pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.parametrize("sp", [1, 2, 4])
-def test_pallas_ring_gives_what_the_carried_ring_gave(sp, causal,
-                                                      monkeypatch):
+def test_pallas_ring_gives_what_the_carried_ring_gave(sp, causal):
     """The Pallas ring's first step takes no state, its last step
     finishes the softmax in the kernel, and K and V stay where the last
     step used them (at sp = 1 nothing rotates at all).  Values: those of
     the ring that carries its state through every step and normalizes
-    in XLA (``HOROVOD_ATTN_PALLAS_BWD=remat`` still runs that forward),
+    in XLA (``impl="xla"``: the same recurrence in ``xla_block_step``),
     to an ulp.  Gradients of all three inputs: the dense reference's,
     dK and dV home after ``sp`` rotations."""
     mesh = Mesh(np.array(jax.devices()[:sp]), ("sp",))
     q, k, v = _qkv(11)
     spec = P(None, "sp")
 
-    def run():
+    def run(impl):
         def per_device(a, b_, c):
             def loss(a_, b__, c_):
                 out = ring_attention(a_, b__, c_, "sp", causal=causal,
-                                     impl="pallas")
+                                     impl=impl)
                 return jnp.sum(out ** 2), out
             (_, out), grads = jax.value_and_grad(
                 loss, argnums=(0, 1, 2), has_aux=True)(a, b_, c)
@@ -186,9 +185,8 @@ def test_pallas_ring_gives_what_the_carried_ring_gave(sp, causal,
                                  in_specs=(spec,) * 3,
                                  out_specs=(spec,) * 4))(q, k, v)
 
-    out, *grads = run()
-    monkeypatch.setenv("HOROVOD_ATTN_PALLAS_BWD", "remat")
-    carried_out, *_ = run()
+    out, *grads = run("pallas")
+    carried_out, *_ = run("xla")
     np.testing.assert_array_max_ulp(np.asarray(out), np.asarray(carried_out),
                                     maxulp=1)
 
