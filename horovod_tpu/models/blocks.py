@@ -189,7 +189,7 @@ def mla(cfg: TransformerConfig, lp, h, positions):
          jnp.broadcast_to(k_rope[:, :, None, :], (b, lc, nh, dr))], axis=-1)
     with jax.named_scope("hvd_attn"):
         attn = ring_attention(q, k, kv[..., dn:], "sp", causal=True,
-                              impl=cfg.attn_impl)
+                              impl=cfg.attn_impl, recomputed=cfg.remat)
     proj = (attn.reshape(b, lc, nh * dv).astype(cd)
             @ lp["wo"].astype(cd)).astype(jnp.float32)
     return reduce_from_tp(proj, "tp")

@@ -41,7 +41,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from horovod_tpu.parallel.pipeline import gpipe, interleaved_pipeline
-from horovod_tpu.parallel.ring_attention import ring_attention
+from horovod_tpu.parallel.ring_attention import KEPT_NAMES, ring_attention
 from horovod_tpu.parallel.sharding import (copy_to_tp, grad_reduce_axes,
                                            reduce_from_tp,
                                            tree_map_with_specs)
@@ -66,7 +66,9 @@ class TransformerConfig:
     mlp: str = "gelu"        # the dense layers' MLP: "gelu" | "swiglu"
     tied_head: bool = True   # logits through embed.T; else a head matrix
     # recompute each block in the backward pass (pp = 1; pp_remat is the
-    # pipeline's): a block keeps only its input
+    # pipeline's): a block keeps its input and, through the Pallas
+    # attention path, the kernel's fp32 result and its row statistics
+    # (ring_attention.KEPT_NAMES), so the forward kernel is not run again
     remat: bool = False
     # latent attention ("mla"; head_dim is not used)
     q_lora_rank: int = 0
@@ -255,7 +257,7 @@ def _block(cfg: TransformerConfig, lp, x, positions=None, ffn=None,
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         with jax.named_scope("hvd_attn"):
             attn = ring_attention(q, k, v, "sp", causal=True,
-                                  impl=cfg.attn_impl)
+                                  impl=cfg.attn_impl, recomputed=cfg.remat)
         attn = attn.reshape(b, lc, nh_local * cfg.head_dim)
         proj = (attn.astype(cd) @ lp["wo"].astype(cd)).astype(jnp.float32)
         proj = reduce_from_tp(proj, "tp")  # Megatron "g": row-parallel reduce
@@ -349,12 +351,17 @@ def _stack(params, tokens, cfg: TransformerConfig):
 
 
 # Recomputed in the backward pass where the configuration asks for it
-# (``remat``): a block, or a loss head, then keeps only its inputs.  The
-# plain functions are called directly otherwise: every Python frame
-# between ``loss_fn`` and an operation is paid for again by each trace
-# of the step (PERF.md section 6, PR 28: two frames more were 2 s of the
-# GPT-2 cells' set-up on the chip's host).
-_remat_block = jax.checkpoint(_block, static_argnums=(0, 4))
+# (``remat``): a loss head then keeps only its inputs, and a block its
+# input and what the attention kernel gave (fp32 ``out`` and ``lse``,
+# under ``ring_attention.KEPT_NAMES``), 270 MB a block in the expert
+# cell for a forward kernel that is not run a second time (PERF.md
+# section 6, PR 32).  The plain functions are called directly otherwise:
+# every Python frame between ``loss_fn`` and an operation is paid for
+# again by each trace of the step (PERF.md section 6, PR 28: two frames
+# more were 2 s of the GPT-2 cells' set-up on the chip's host).
+_remat_block = jax.checkpoint(
+    _block, static_argnums=(0, 4),
+    policy=jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES))
 
 
 def _logits(cfg: TransformerConfig, x, gain, table):

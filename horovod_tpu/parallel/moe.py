@@ -109,14 +109,18 @@ def _chunk(x, w, weights, plan, index, rows: int):
     xs = jnp.where(live[:, None], x[tokens], 0)
 
     def grouped(a, b):
-        return lax.ragged_dot(a, b, sizes,
-                              preferred_element_type=jnp.float32)
+        # a row past the last held pair belongs to no group, and on the
+        # TPU neither the product nor its transpose writes it: what
+        # stands there is what the buffer held, a NaN now and then, and
+        # 0 x NaN is NaN (PERF.md section 6, PR 32).  Selected away,
+        # here and, by this select's own transpose, in the backward pass.
+        return jnp.where(live[:, None], lax.ragged_dot(
+            a, b, sizes, preferred_element_type=jnp.float32), 0.0)
 
     hidden = (jax.nn.silu(grouped(xs, w["w_gate"]))
               * grouped(xs, w["w_up"])).astype(x.dtype)
-    out = grouped(hidden, w["w_down"])
     scale = weights.reshape(-1)[pairs]
-    return tokens, jnp.where(live[:, None], out * scale[:, None], 0.0)
+    return tokens, grouped(hidden, w["w_down"]) * scale[:, None]
 
 
 def _live_chunks(plan, rows: int):

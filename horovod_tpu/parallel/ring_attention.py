@@ -35,6 +35,13 @@ and only dK and dV make the ``sp``-th rotation that brings each block's
 gradient home.  A ring of one (``sp`` = 1, a Python int at trace time)
 has only ends: one forward call, the two backward kernels writing the
 operands' type, no loop, no ``ppermute``, nothing carried.
+
+What a recomputed block keeps.  Told that its caller is ``recomputed``,
+the forward rule names its residuals ``out`` and ``lse``
+(:data:`KEPT_NAMES`), so a ``jax.checkpoint`` whose policy saves those
+names (``transformer._remat_block``) keeps them from the forward pass,
+and takes what it returns from the kept ``out``: the replay in the
+backward pass then holds no forward kernel at all.
 """
 
 from __future__ import annotations
@@ -44,8 +51,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from horovod_tpu.common import logging as _log
+
+# The forward rule's residuals ``out`` (fp32) and ``lse`` under
+# ``jax.ad_checkpoint.checkpoint_name``, given where the caller's block
+# is recomputed: what that block's policy keeps beside its input.
+KEPT_NAMES = ("hvd_attn_out", "hvd_attn_lse")
 
 
 def xla_block_step(q, k, v, m, l, o, q_offset, k_offset, *,
@@ -185,8 +198,8 @@ def _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, bq, bk):
     return step(sp - 1, state, kj, vj, last=True)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _ring_flash(qp, kp, vp, axis_name, causal, bq, bk):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _ring_flash(qp, kp, vp, axis_name, causal, bq, bk, recomputed):
     """Differentiable Pallas ring attention on packed (B*H, Lc, D)
     operands, returning (B*H, Lc, Dv) in their type: forward saves only
     (q, k, v, out, lse), ``out`` in fp32 (``delta`` = rowsum(dO ∘ out)
@@ -196,17 +209,29 @@ def _ring_flash(qp, kp, vp, axis_name, causal, bq, bk):
     with dK/dV accumulators rotating alongside KV so each block's
     gradient arrives home after the full cycle (in a ring of one it is
     home already: nothing rotates).  Nothing O(Lq·Lk) is
-    ever materialized."""
+    ever materialized.
+
+    ``recomputed`` says the call stands in a block that is run again in
+    the backward pass under a policy that keeps :data:`KEPT_NAMES`.  The
+    forward rule then gives ``out`` and ``lse`` those names and returns
+    the kept ``out`` rounded to the operands' type, which is what the
+    kernel's own third result is, bit for bit: the replay is left with
+    nothing that reads the kernel and runs none.  Both halves are
+    needed: with the rule returning the kernel's result the replay
+    runs the kernel for it, names or no names."""
     return _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, bq, bk)[2]
 
 
-def _ring_flash_fwd(qp, kp, vp, axis_name, causal, bq, bk):
+def _ring_flash_fwd(qp, kp, vp, axis_name, causal, bq, bk, recomputed):
     out, lse, out_q = _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal,
                                            bq, bk)
+    if recomputed:
+        out, lse = map(checkpoint_name, (out, lse), KEPT_NAMES)
+        out_q = out.astype(qp.dtype)
     return out_q, (qp, kp, vp, out, lse)
 
 
-def _ring_flash_bwd(axis_name, causal, bq, bk, res, dout):
+def _ring_flash_bwd(axis_name, causal, bq, bk, recomputed, res, dout):
     from horovod_tpu.ops.pallas_attention import (flash_bwd_dkv,
                                                   flash_bwd_dq)
 
@@ -255,7 +280,8 @@ _ring_flash.defvjp(_ring_flash_fwd, _ring_flash_bwd)
 
 
 def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
-                   impl: str | None = None, layout: str = "contiguous"):
+                   impl: str | None = None, layout: str = "contiguous",
+                   recomputed: bool = False):
     """Multi-head attention with the sequence sharded over ``axis_name``.
 
     q, k: (B, Lc, H, D), v: (B, Lc, H, Dv) — the local sequence chunk
@@ -263,7 +289,11 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
     and 128).  Returns (B, Lc, H, Dv).  Must run inside shard_map/pjit with
     ``axis_name`` a mesh axis; with axis size 1 it degrades to plain
     blockwise attention.  ``impl``: "pallas" | "xla" | None (auto:
-    pallas on TPU, xla elsewhere).
+    pallas on TPU, xla elsewhere).  ``recomputed``: the caller's block
+    is run again in the backward pass under a policy that keeps
+    :data:`KEPT_NAMES` (the Pallas path's ``out`` and ``lse``; see
+    :func:`_ring_flash`).  The XLA path has no names: a recomputed block
+    keeps only its input there.
 
     ``layout``: how the global sequence maps onto ranks.
 
@@ -318,7 +348,7 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
         qp = q.transpose(0, 2, 1, 3).reshape(b * h, lc, d)
         kp = k.transpose(0, 2, 1, 3).reshape(b * h, lc, d)
         vp = v.transpose(0, 2, 1, 3).reshape(b * h, lc, dv)
-        out = _ring_flash(qp, kp, vp, axis_name, causal, bq, bk)
+        out = _ring_flash(qp, kp, vp, axis_name, causal, bq, bk, recomputed)
         return out.reshape(b, h, lc, dv).transpose(0, 2, 1, 3)
 
     qp = q.transpose(0, 2, 1, 3).reshape(b * h, lc, d)

@@ -404,3 +404,48 @@ def test_more_ranks_than_the_router_has_experts_raises(ep_mesh):
         jax.jit(shard_map(per_rank, mesh=ep_mesh, check_vma=False,
                           in_specs=(P("ep"), replicated),
                           out_specs=P("ep")))(jnp.zeros((EP, T, DIM)), params)
+
+
+def _leaves_rows_unwritten(real):
+    """``lax.ragged_dot`` as the TPU runs it: a row of the left operand
+    that belongs to no group is not written, in the product and in the
+    left operand's gradient.  NaN stands for what the buffer held."""
+    def product(a, b, sizes, **kw):
+        @jax.custom_vjp
+        def prod(a, b, sizes):
+            dead = jnp.arange(a.shape[0]) >= jnp.sum(sizes)
+            return jnp.where(dead[:, None], jnp.nan, real(a, b, sizes, **kw))
+
+        def bwd(res, ct):
+            a, b, sizes = res
+            da, db = jax.vjp(lambda a, b: real(a, b, sizes, **kw), a, b)[1](ct)
+            dead = jnp.arange(a.shape[0]) >= jnp.sum(sizes)
+            return (jnp.where(dead[:, None], jnp.nan, da), db,
+                    np.zeros(sizes.shape, jax.dtypes.float0))
+
+        prod.defvjp(lambda a, b, sizes: (prod(a, b, sizes), (a, b, sizes)),
+                    bwd)
+        return prod(a, b, sizes)
+
+    return product
+
+
+def test_rows_that_belong_to_no_group_are_never_read(monkeypatch):
+    """A share that holds 4 of 16 experts: most rows of its one chunk
+    lie past the last held pair.  With those rows of every grouped
+    product and of its left gradient poisoned, the share and all its
+    gradients are what they are unpoisoned: finite, and equal."""
+    params = _layer_params(11, first=4, held=4, shared=False)
+    x = jnp.asarray(np.random.RandomState(12).randn(T, DIM), jnp.float32)
+
+    def loss(x_, p_):
+        out, _ = moe.moe_layer(x_, p_, top_k=TOP_K, scale=SCALE, first=4)
+        return jnp.sum(out ** 2)
+
+    want = jax.value_and_grad(loss, argnums=(0, 1))(x, params)
+    monkeypatch.setattr(jax.lax, "ragged_dot",
+                        _leaves_rows_unwritten(jax.lax.ragged_dot))
+    got = jax.value_and_grad(loss, argnums=(0, 1))(x, params)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

@@ -361,3 +361,74 @@ def test_the_new_kinds_name_their_parts_and_record_their_routing():
     for event in ring:
         assert event["dropped"] == 0 and len(event["pairs"]) == 8
         assert sum(event["pairs"]) == tokens.size * LATENT.experts_per_token
+
+
+def _loss_and_grads(cfg):
+    """``(kernel calls, named values, loss, gradients)`` of the
+    per-device loss on one device: the first two counted in the jaxpr
+    of the gradient, the replayed blocks inside it."""
+    from jax import shard_map
+    from test_pallas_attention import _primitives
+
+    from horovod_tpu.models.transformer import loss_fn, param_specs
+
+    mesh = make_mesh(dp=1, pp=1, tp=1, sp=1, devices=jax.devices()[:1])
+    params = shard_params(init_params(np.random.RandomState(0), cfg), cfg,
+                          mesh)
+    ids = np.random.RandomState(1).randint(0, cfg.vocab, (2, 33))
+    sh = NamedSharding(mesh, P("dp", "sp"))
+    tokens = jax.device_put(jnp.asarray(ids[:, :-1], jnp.int32), sh)
+    targets = jax.device_put(jnp.asarray(ids[:, 1:], jnp.int32), sh)
+    specs, data = param_specs(cfg), P("dp", "sp")
+    fn = jax.jit(shard_map(
+        lambda p, tok, tgt: jax.value_and_grad(loss_fn)(p, tok, tgt, cfg),
+        mesh=mesh, check_vma=False, in_specs=(specs, data, data),
+        out_specs=(P(), specs)))
+    names = _primitives(jax.make_jaxpr(fn)(params, tokens, targets).jaxpr)
+    return (names.count("pallas_call"), names.count("name"),
+            *fn(params, tokens, targets))
+
+
+# rel. l2 of remat=True's gradient against remat=False's, all leaves
+# together and the worst leaf.  float32 is equal to rounding.  In
+# bfloat16 the CPU compiler fuses the replayed block's converts
+# otherwise; measured 0.0098 / 0.016 here, and 0.068 / 0.22 with the
+# parent's policy-free checkpoint, whose replay ran the kernel again
+# (CHANGES.md, PR 32).
+_REMAT_APART = {"float32": (1e-6, 1e-6), "bfloat16": (2e-2, 5e-2)}
+
+
+@pytest.mark.parametrize("kind,impl,dtype", [
+    ("mha", "pallas", "float32"), ("latent", "pallas", "float32"),
+    ("mha", "pallas", "bfloat16"), ("latent", "pallas", "bfloat16"),
+    ("mha", "xla", "float32"), ("latent", "xla", "float32")])
+def test_a_recomputed_block_keeps_what_its_attention_kernel_gave(
+        kind, impl, dtype):
+    """``remat=True`` against ``remat=False``, kernels interpreted: the
+    same loss and gradients, and through the Pallas path the gradient's
+    jaxpr holds three kernel calls a block (the GPT-2 block x 2; layer
+    0, two expert layers and the MTP module's): one forward and the two
+    backward, no second forward, because the replayed block takes
+    ``out`` and ``lse`` from what the policy kept.  The XLA path names
+    nothing, so the policy keeps nothing there and a recomputed block
+    keeps only its input."""
+    import dataclasses
+
+    base, blocks = {"mha": (dataclasses.replace(CFG, n_layers=2), 2),
+                    "latent": (LATENT, 4)}[kind]
+    base = dataclasses.replace(base, attn_impl=impl, dtype=dtype)
+    plain = _loss_and_grads(dataclasses.replace(base, remat=False))
+    kept = _loss_and_grads(dataclasses.replace(base, remat=True))
+
+    on_path = impl == "pallas"
+    assert plain[:2] == (3 * blocks * on_path, 0)
+    assert kept[:2] == (3 * blocks * on_path, 2 * blocks * on_path)
+    assert float(kept[2]) == float(plain[2])
+    pairs = [(np.asarray(a, np.float32), np.asarray(b, np.float32))
+             for a, b in zip(jax.tree_util.tree_leaves(plain[3]),
+                             jax.tree_util.tree_leaves(kept[3]))]
+    whole, leaf = _REMAT_APART[dtype]
+    assert (sum(np.sum((a - b) ** 2) for a, b in pairs)
+            <= whole ** 2 * sum(np.sum(a ** 2) for a, _ in pairs))
+    for a, b in pairs:
+        assert np.linalg.norm(a - b) <= leaf * np.linalg.norm(a)
