@@ -52,6 +52,12 @@ def main() -> None:
                    help="experts a dp rank holds in every layer after "
                         "the first (dropless sigmoid top-2 over dp x "
                         "this many, plus a shared expert; 0 = dense)")
+    p.add_argument("--layer-pattern", default="",
+                   help="one letter a layer, each layer ONE sub-layer: "
+                        "M a Mamba-2 mixer, * grouped-query attention "
+                        "without positions, E an expert layer of relu^2 "
+                        "experts (with --experts; e.g. MEM*E). Replaces "
+                        "--n-layers; dp only (no pp, tp or sp)")
     p.add_argument("--pp-schedule", default="gpipe",
                    choices=["gpipe", "interleaved"],
                    help="pipeline schedule when pp > 1 (interleaved = "
@@ -90,15 +96,27 @@ def main() -> None:
                          f"have {len(devices)} "
                          f"({devices[0].platform})")
 
+    if "E" in args.layer_pattern and not args.experts:
+        raise SystemExit("an E layer needs --experts")
+    experts = dict(n_experts=dp * args.experts, experts_held=args.experts,
+                   experts_per_token=2, d_expert=args.d_model,
+                   shared_experts=1) if args.experts else {}
+    if args.layer_pattern:
+        # a hybrid stack: 4 state-space heads in 2 groups, 2 key/value
+        # heads under the 4 query heads, two-matrix experts
+        kinds = dict(layer_pattern=args.layer_pattern, tied_head=False,
+                     n_kv_heads=2, ssm_heads=4,
+                     ssm_head_dim=2 * args.d_model // 4, ssm_groups=2,
+                     ssm_state=16, ssm_chunk=min(64, args.seq),
+                     expert_form="relu2", **experts)
+    else:
+        kinds = dict(mlp="swiglu", n_dense_layers=1,
+                     **experts) if experts else {}
     cfg = TransformerConfig(
         vocab=1024, d_model=args.d_model,
         n_heads=max(4, 2 * tp), head_dim=args.d_model // 4,
         n_layers=args.n_layers * pp * args.pp_virtual,
-        d_ff=4 * args.d_model, max_seq=args.seq,
-        **(dict(mlp="swiglu", n_experts=dp * args.experts,
-                experts_held=args.experts, experts_per_token=2,
-                d_expert=args.d_model, shared_experts=1, n_dense_layers=1)
-           if args.experts else {}),
+        d_ff=4 * args.d_model, max_seq=args.seq, **kinds,
         pp_microbatches=2 if pp > 1 else 1,
         pp_schedule=args.pp_schedule, pp_virtual=args.pp_virtual)
     mesh = make_mesh(**axes, devices=devices[:n])

@@ -1,7 +1,11 @@
 """The block kinds of the flagship transformer beyond the GPT-2 block:
 latent attention with rotary positions, SwiGLU, the expert layer, an
 untied head and the multi-token-prediction module — what the
-DeepSeek-V3 family of configurations is made of.
+DeepSeek-V3 family of configurations is made of — and the layers of a
+``layer_pattern``, each ONE pre-normed sub-layer with one residual: a
+Mamba-2 mixer on a chunked scan, grouped-query attention without
+positions, the expert layer alone — what the hybrid state-space /
+attention / expert configurations (``nemotron_h``) are made of.
 
 Imported by :mod:`horovod_tpu.models.transformer` only where a
 ``TransformerConfig`` asks for one of them; a GPT-2-shaped configuration
@@ -16,6 +20,13 @@ Parameters (beside ``embed``, ``ln_f`` and the ``layers`` stack of
             shared{w_gate w_up w_down}                experts (layer, held * ep, ..)
     head    (d_model, vocab)                          untied
     mtp     eh_proj ln_e ln_h ln_f layers{..} moe{..} one block, stacks of one
+
+Under a layer pattern (beside ``embed``, ``ln_f``, ``head``), a row a
+layer of the kind, in the pattern's order::
+
+    ssm     ln w_in conv_w conv_b dt_bias a_log d norm w_out      "M"
+    attn    ln wq wk wv wo                                        "*"
+    moe     ln router bias experts{..} shared{..}                 "E"
 """
 
 from __future__ import annotations
@@ -27,7 +38,7 @@ from jax import lax
 
 from horovod_tpu.models.transformer import TransformerConfig, _rmsnorm
 from horovod_tpu.parallel import moe
-from horovod_tpu.parallel.ring_attention import ring_attention
+from horovod_tpu.parallel.ring_attention import KEPT_NAMES, ring_attention
 from horovod_tpu.parallel.sharding import copy_to_tp, reduce_from_tp
 
 
@@ -61,15 +72,25 @@ def _init_swiglu(norm, lead: tuple, dm: int, ff: int) -> dict:
             "w_down": norm(*lead, ff, dm, scale=ff ** -0.5)}
 
 
+def _init_expert(norm, cfg: TransformerConfig, lead: tuple, ff: int) -> dict:
+    """An expert of ``cfg.expert_form``: the three matrices of SwiGLU,
+    or ``w_up`` and ``w_down`` of a relu^2 expert (``parallel/moe.py``
+    reads the form from which are there)."""
+    w = _init_swiglu(norm, lead, cfg.d_model, ff)
+    if cfg.expert_form == "relu2":
+        del w["w_gate"]
+    return w
+
+
 def _init_moe(norm, cfg: TransformerConfig, n: int, ep: int) -> dict:
     dm = cfg.d_model
     p = {"router": norm(n, dm, cfg.n_experts, scale=dm ** -0.5),
          # the selection bias: a buffer, drawn once and never updated
          "bias": norm(n, cfg.n_experts, scale=0.01),
-         "experts": _init_swiglu(norm, (n, ep * cfg.experts_held), dm,
+         "experts": _init_expert(norm, cfg, (n, ep * cfg.experts_held),
                                  cfg.d_expert)}
     if cfg.shared_experts:
-        p["shared"] = _init_swiglu(norm, (n,), dm,
+        p["shared"] = _init_expert(norm, cfg, (n,),
                                    cfg.shared_experts * cfg.d_expert)
     return p
 
@@ -109,11 +130,12 @@ def mla_specs(lead):
 def _moe_specs(cfg: TransformerConfig) -> dict:
     from jax.sharding import PartitionSpec as P
 
-    held = {"w_gate": P(None, "dp"), "w_up": P(None, "dp"),
-            "w_down": P(None, "dp")}
-    specs = {"router": P(), "bias": P(), "experts": held}
+    names = (("w_gate",) if cfg.expert_form == "swiglu" else ()) + (
+        "w_up", "w_down")
+    specs = {"router": P(), "bias": P(),
+             "experts": dict.fromkeys(names, P(None, "dp"))}
     if cfg.shared_experts:
-        specs["shared"] = {"w_gate": P(), "w_up": P(), "w_down": P()}
+        specs["shared"] = dict.fromkeys(names, P())
     return specs
 
 
@@ -173,9 +195,9 @@ def mla(cfg: TransformerConfig, lp, h, positions):
     nh = cfg.n_heads // lax.axis_size("tp")
     dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     h = h.astype(cd)
-    c_q = _rmsnorm(h @ lp["wq_a"].astype(cd), lp["q_norm"])
+    c_q = _rmsnorm(h @ lp["wq_a"].astype(cd), lp["q_norm"], cfg.norm_eps)
     kv = h @ lp["wkv_a"].astype(cd)
-    c_kv = _rmsnorm(kv[..., :cfg.kv_lora_rank], lp["kv_norm"])
+    c_kv = _rmsnorm(kv[..., :cfg.kv_lora_rank], lp["kv_norm"], cfg.norm_eps)
     k_rope = rotary(kv[..., cfg.kv_lora_rank:], positions, cfg.rope_theta)
     # Megatron "f": what follows is per head, the latents are replicated
     c_q, c_kv, k_rope = (copy_to_tp(a, "tp") for a in (c_q, c_kv, k_rope))
@@ -210,16 +232,17 @@ def dense_swiglu(cfg: TransformerConfig, w, h):
     return reduce_from_tp(out, "tp"), None
 
 
-def expert_ffn(cfg: TransformerConfig, w, h):
+def expert_ffn(cfg: TransformerConfig, w, h, count_all: bool = False):
     """The expert layer on the normalised stream; experts over ``dp``
     (= ep).  Returns ``(f32 output, pairs computed by each held
-    expert)``."""
+    expert)``; with ``count_all`` the pairs sent to each of all the
+    experts, alike on every ``dp`` rank."""
     b, lc, dm = h.shape
     with jax.named_scope("hvd_moe"):
         out, pairs = moe.moe_layer(
             h.reshape(b * lc, dm).astype(cfg.compute_dtype), w,
             top_k=cfg.experts_per_token, scale=cfg.routed_scale,
-            axis_name="dp")
+            axis_name="dp", count_all=count_all)
     return out.reshape(b, lc, dm), pairs
 
 
@@ -254,7 +277,8 @@ def mtp_loss(cfg: TransformerConfig, params, x, targets, block, head_nll):
     with jax.named_scope("hvd_mtp"):
         emb = params["embed"][targets].astype(cd)
         joined = jnp.concatenate(
-            [_rmsnorm(emb, mp["ln_e"]), _rmsnorm(x, mp["ln_h"])], axis=-1)
+            [_rmsnorm(emb, mp["ln_e"], cfg.norm_eps),
+             _rmsnorm(x, mp["ln_h"], cfg.norm_eps)], axis=-1)
         h = joined.astype(cd) @ mp["eh_proj"].astype(cd)
         lp = jax.tree_util.tree_map(lambda a: a[0], mp["layers"])
         w = jax.tree_util.tree_map(lambda a: a[0], mp["moe"])
@@ -271,3 +295,247 @@ def mtp_loss(cfg: TransformerConfig, params, x, targets, block, head_nll):
         count = b * lax.axis_size("dp") * (sp * lc - 1)
         loss = jnp.sum(jnp.where(seen[None, :], nll, 0.0)) / count
     return loss, pairs
+
+
+# ---------------------------------------------------------------------------
+# A layer pattern: one sub-layer a layer, the weights stacked per kind
+# ---------------------------------------------------------------------------
+
+# the stack that holds the weights of each kind of layer
+STACK_OF = {"M": "ssm", "*": "attn", "E": "moe"}
+# how the Mamba-2 reference code draws a state-space layer's own
+# parameters: the time step log-uniform in [DT_MIN, DT_MAX] and floored,
+# kept as what softplus maps onto it; the decay rate A uniform in
+# A_RANGE, kept as its logarithm; the skip D = 1
+DT_MIN, DT_MAX, DT_FLOOR = 1e-3, 1e-1, 1e-4
+A_RANGE = (1.0, 16.0)
+
+
+def _init_ssm(norm, rng, cfg: TransformerConfig, n: int) -> dict:
+    dm, heads = cfg.d_model, cfg.ssm_heads
+    inner = heads * cfg.ssm_head_dim
+    conv = inner + 2 * cfg.ssm_groups * cfg.ssm_state
+    # a framework's default for a convolution's weight and bias: uniform
+    # in +-1 / sqrt(taps); a normal of the same variance here
+    tap = (3 * cfg.ssm_conv) ** -0.5
+    p = {"ln": np.ones((n, dm), np.float32),
+         "w_in": norm(n, dm, inner + conv + heads, scale=dm ** -0.5),
+         "conv_w": norm(n, cfg.ssm_conv, conv, scale=tap),
+         "conv_b": norm(n, conv, scale=tap)}
+    dt = jnp.maximum(DT_MIN * (DT_MAX / DT_MIN) ** rng.rand(n, heads),
+                     DT_FLOOR)
+    p["dt_bias"] = (dt + jnp.log(-jnp.expm1(-dt))).astype(jnp.float32)
+    low, high = A_RANGE
+    p["a_log"] = jnp.log(low + (high - low) * rng.rand(n, heads)).astype(
+        jnp.float32)
+    p["d"] = np.ones((n, heads), np.float32)
+    p["norm"] = np.ones((n, inner), np.float32)
+    p["w_out"] = norm(n, inner, dm, scale=inner ** -0.5)
+    return p
+
+
+def _init_gqa(norm, cfg: TransformerConfig, n: int) -> dict:
+    dm, width = cfg.d_model, cfg.n_heads * cfg.head_dim
+    kv = (cfg.n_kv_heads or cfg.n_heads) * cfg.head_dim
+    return {"ln": np.ones((n, dm), np.float32),
+            "wq": norm(n, dm, width, scale=dm ** -0.5),
+            "wk": norm(n, dm, kv, scale=dm ** -0.5),
+            "wv": norm(n, dm, kv, scale=dm ** -0.5),
+            "wo": norm(n, width, dm, scale=width ** -0.5)}
+
+
+def init_pattern(norm, rng, cfg: TransformerConfig, ep: int) -> dict:
+    """Everything but ``embed``: a stack a kind the pattern holds, the
+    final norm and the untied head.  ``rng.rand`` draws the uniform
+    numbers of the state-space layers."""
+    dm, kinds = cfg.d_model, cfg.layer_pattern
+    p = {"ln_f": np.ones(dm, np.float32),
+         "head": norm(dm, cfg.vocab, scale=dm ** -0.5)}
+    if "M" in kinds:
+        p["ssm"] = _init_ssm(norm, rng, cfg, kinds.count("M"))
+    if "*" in kinds:
+        p["attn"] = _init_gqa(norm, cfg, kinds.count("*"))
+    if "E" in kinds:
+        n = kinds.count("E")
+        p["moe"] = {"ln": np.ones((n, dm), np.float32),
+                    **_init_moe(norm, cfg, n, ep)}
+    if cfg.rescale_depth:
+        # what a sub-layer writes to the stream shrinks with the depth
+        # the stream is drawn for, so that a token's own row outweighs
+        # what the mixers add of its neighbours (at fan-in scale alone
+        # the tokens of a sequence lean the same way and share experts:
+        # PERF.md section 6, PR 33)
+        down = cfg.rescale_depth ** -0.5
+        moe_ = p.get("moe", {})
+        for holder, name in ((p.get("ssm", {}), "w_out"),
+                             (p.get("attn", {}), "wo"),
+                             (moe_.get("experts", {}), "w_down"),
+                             (moe_.get("shared", {}), "w_down")):
+            if name in holder:
+                holder[name] = holder[name] * down
+    return p
+
+
+def pattern_specs(cfg: TransformerConfig) -> dict:
+    """Everything replicated but the held experts, which shard over
+    ``dp`` (= ep): no kind of a pattern runs under ``pp`` or ``tp``."""
+    from jax.sharding import PartitionSpec as P
+
+    names = {"ssm": ("ln", "w_in", "conv_w", "conv_b", "dt_bias", "a_log",
+                     "d", "norm", "w_out"),
+             "attn": ("ln", "wq", "wk", "wv", "wo")}
+    specs = {"head": P()}
+    for kind in set(cfg.layer_pattern):
+        stack = STACK_OF[kind]
+        specs[stack] = ({"ln": P(), **_moe_specs(cfg)} if kind == "E"
+                        else dict.fromkeys(names[stack], P()))
+    return specs
+
+
+def _whole_axes(what: str, reasons: dict) -> None:
+    """Raise for the first of the mesh axes ``reasons`` names that is
+    larger than 1."""
+    for axis, why in reasons.items():
+        if lax.axis_size(axis) > 1:
+            raise NotImplementedError(
+                f"{what} under {axis} > 1 is not supported: {why}")
+
+
+def causal_conv(xbc, weight, bias):
+    """A causal depthwise convolution over time as shifted
+    multiply-adds in float32.  ``xbc``: (b, lc, channels); ``weight``:
+    (taps, channels), tap ``k`` weighing the input ``taps - 1 - k`` steps
+    back (before the first step there is nothing); ``bias``:
+    (channels,)."""
+    taps, steps = weight.shape[0], xbc.shape[1]
+    earlier = jnp.pad(xbc, ((0, 0), (taps - 1, 0), (0, 0)))
+    out = bias.astype(jnp.float32)
+    for k in range(taps):
+        out = out + earlier[:, k:k + steps].astype(jnp.float32) * weight[k]
+    return out
+
+
+def mamba(cfg: TransformerConfig, lp, h):
+    """The Mamba-2 mixer on the normalised stream ``h`` (b, lc, dm):
+    one in-projection split into the gate ``z``, ``x | B | C`` and the
+    time steps; a causal depthwise convolution of ``ssm_conv`` taps and
+    SiLU over ``x | B | C``; ``dt = softplus(dt + dt_bias)`` and ``A =
+    -exp(a_log)`` in float32; the recurrence as a chunked scan
+    (:mod:`horovod_tpu.ops.ssm_scan`); ``y * silu(z)``, an RMSNorm over
+    each group's channels, the out-projection.  Returns ``(f32 output,
+    the least logarithm of a whole chunk's decay)``."""
+    from horovod_tpu.ops.ssm_scan import ssm_scan
+
+    _whole_axes("a state-space layer", {
+        "tp": "the in-projection's z | x B C | dt parts, the "
+              "convolution's channels and the groups' B and C are not "
+              "shared out over the tp ranks",
+        "sp": "a scan over a sequence split across chips needs the state "
+              "at each chip's first step, and the convolution its last "
+              "inputs, passed round the ring, which is not built"})
+    b, lc, _ = h.shape
+    cd = cfg.compute_dtype
+    heads, size = cfg.ssm_heads, cfg.ssm_head_dim
+    groups, state = cfg.ssm_groups, cfg.ssm_state
+    inner, bc = heads * size, groups * state
+    proj = h.astype(cd) @ lp["w_in"].astype(cd)
+    z, xbc, dt = jnp.split(proj, [inner, 2 * inner + 2 * bc], axis=-1)
+    xbc = jax.nn.silu(causal_conv(xbc, lp["conv_w"], lp["conv_b"])).astype(cd)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
+    y, least = ssm_scan(
+        xbc[..., :inner].reshape(b, lc, heads, size), dt,
+        -jnp.exp(lp["a_log"]),
+        xbc[..., inner:inner + bc].reshape(b, lc, groups, state),
+        xbc[..., inner + bc:].reshape(b, lc, groups, state), lp["d"],
+        cfg.ssm_chunk)
+    y = y.reshape(b, lc, inner) * jax.nn.silu(z.astype(jnp.float32))
+    y = y.reshape(b, lc, groups, inner // groups)
+    y = y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + cfg.norm_eps)
+    y = (y.reshape(b, lc, inner) * lp["norm"]).astype(cd)
+    return (y @ lp["w_out"].astype(cd)).astype(jnp.float32), least
+
+
+def _over_query_heads(t, times: int):
+    """(b, lc, key/value heads, d) -> (b, lc, query heads, d): each
+    key/value head ``times`` times in a row, so query head ``i`` meets
+    key/value head ``i // times``."""
+    return jnp.repeat(t, times, axis=2)
+
+
+def gqa(cfg: TransformerConfig, lp, h):
+    """Grouped-query attention on the normalised stream ``h``, no
+    positional encoding: ``n_heads`` query heads, query head ``i`` on
+    key/value head ``i // (n_heads / n_kv_heads)``.  ``k`` and ``v`` are
+    broadcast over their query heads before ``ring_attention``, whose
+    kernels read one head count; the sum over a group in the backward
+    pass is that broadcast's transpose.  Returns the f32 output
+    projection."""
+    _whole_axes("grouped-query attention", {
+        "tp": "the key/value heads, fewer than the query heads, are not "
+              "shared out over the tp ranks",
+        "sp": "k and v are broadcast over their query heads before the "
+              "ring, which would pass every key/value head round once "
+              "for each of its query heads"})
+    b, lc, _ = h.shape
+    cd = cfg.compute_dtype
+    nh, hd = cfg.n_heads, cfg.head_dim
+    nkv = cfg.n_kv_heads or nh
+    h = h.astype(cd)
+    q = (h @ lp["wq"].astype(cd)).reshape(b, lc, nh, hd)
+    k, v = (_over_query_heads(
+        (h @ lp[w].astype(cd)).reshape(b, lc, nkv, hd), nh // nkv)
+        for w in ("wk", "wv"))
+    with jax.named_scope("hvd_attn"):
+        attn = ring_attention(q, k, v, "sp", causal=True,
+                              impl=cfg.attn_impl, recomputed=cfg.remat)
+    return (attn.reshape(b, lc, nh * hd).astype(cd)
+            @ lp["wo"].astype(cd)).astype(jnp.float32)
+
+
+def pattern_layer(cfg: TransformerConfig, kind: str, lp, x):
+    """One layer of a pattern: ``x + f(RMSNorm(x))`` with ``f`` the
+    sub-layer of ``kind``.  Returns ``(x, report)``: the pairs an
+    expert layer's routing sent each of all its experts, a state-space
+    layer's least log-decay, else ``None``."""
+    h = _rmsnorm(x, lp["ln"], cfg.norm_eps)
+    if kind == "M":
+        with jax.named_scope("hvd_ssm"):
+            out, report = mamba(cfg, lp, h)
+    elif kind == "*":
+        out, report = gqa(cfg, lp, h), None
+    else:
+        out, report = expert_ffn(cfg, lp, h, count_all=True)
+    return x + out.astype(x.dtype), report
+
+
+# as ``transformer._remat_block``: a recomputed attention layer keeps
+# what its kernel gave, every other layer only its input
+_remat_layer = jax.checkpoint(
+    pattern_layer, static_argnums=(0, 1),
+    policy=jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES))
+
+
+def pattern_stack(cfg: TransformerConfig, params, x, pos):
+    """Walk the pattern: layer ``i`` is of kind ``layer_pattern[i]`` and
+    takes the next row of its kind's stack.  ``x``: the embedded tokens
+    (b, lc, dm).  Returns ``(x, pos, reports)`` as ``transformer._stack``
+    does, ``reports`` a dict ``{"loads": [an "E" layer's each],
+    "least_log_decay": [an "M" layer's each]}``."""
+    if lax.axis_size("pp") > 1:
+        raise NotImplementedError(
+            "a layer pattern under pp > 1 is not supported: its weights "
+            "are stacked per kind and not per layer, so a pp shard of a "
+            "stack is not a stage's layers; pp > 1 runs the GPT-2 block")
+    layer = _remat_layer if cfg.remat else pattern_layer
+    rows = dict.fromkeys(STACK_OF, 0)
+    reports = {"loads": [], "least_log_decay": []}
+    for kind in cfg.layer_pattern:
+        lp = jax.tree_util.tree_map(lambda a: a[rows[kind]],
+                                    params[STACK_OF[kind]])
+        rows[kind] += 1
+        x, report = layer(cfg, kind, lp, x)
+        if kind == "E":
+            reports["loads"].append(report)
+        elif kind == "M":
+            reports["least_log_decay"].append(report)
+    return x, pos, reports

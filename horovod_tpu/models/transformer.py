@@ -24,7 +24,10 @@ GELU MLP, SwiGLU, or — after ``n_dense_layers`` leading dense layers —
 the expert layer; a tied or an untied head; an optional multi-token
 prediction module (:mod:`horovod_tpu.models.blocks`, imported only
 where a configuration asks for one of these).  The defaults are the
-GPT-2 block.
+GPT-2 block.  With a ``layer_pattern`` a layer is instead ONE
+pre-normed sub-layer with one residual — a Mamba-2 mixer, grouped-query
+attention without positions, or an expert FFN alone — of the kind the
+pattern gives it, its weights stacked per kind (``blocks.pattern_stack``).
 
 Everything is bf16 matmuls with fp32 accumulation/norms — MXU-native.
 """
@@ -93,6 +96,32 @@ class TransformerConfig:
     # cross entropy weighted mtp_lambda (depth 0 = none, 1)
     mtp_depth: int = 0
     mtp_lambda: float = 0.3
+    # the experts' form, the shared expert's too: "swiglu" (gate, up,
+    # down) | "relu2" (up, down: W_down relu(W_up h)^2)
+    expert_form: str = "swiglu"
+    # the epsilon of every RMSNorm
+    norm_eps: float = 1e-6
+    # a layer is ONE pre-normed sub-layer with one residual, of the kind
+    # the pattern gives it (a string or a tuple, one letter a layer):
+    # "M" a Mamba-2 mixer, "*" grouped-query attention without
+    # positions, "E" the expert layer alone.  Empty: the uniform stack
+    # of attention + MLP blocks above.  n_layers is the pattern's length.
+    layer_pattern: tuple = ()
+    # the depth the residual stream is drawn for: every sub-layer's
+    # out-projection is divided by sqrt(rescale_depth) at initialisation
+    # (a pre-norm stack's rule; the published depth where fewer layers
+    # are held); 0 = fan-in scaling alone
+    rescale_depth: int = 0
+    n_kv_heads: int = 0      # "*": key/value heads (0 = n_heads)
+    # "M": ssm_heads heads of ssm_head_dim, B and C in ssm_groups groups
+    # of ssm_state, a causal depthwise convolution of ssm_conv taps,
+    # the scan in chunks of ssm_chunk steps
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
     pp_microbatches: int = 2  # microbatches per pipeline stage when pp>1
     # pipeline schedule when pp>1: "gpipe" (fill-drain) or "interleaved"
     # (Megatron virtual stages, pp_virtual chunks per rank — bubble
@@ -121,8 +150,16 @@ class TransformerConfig:
         if self.mlp not in ("gelu", "swiglu"):
             raise ValueError(f"mlp must be 'gelu' or 'swiglu', got "
                              f"{self.mlp!r}")
-        if self.n_experts and self.mlp != "swiglu":
-            raise ValueError("expert layers are SwiGLU: n_experts > 0 "
+        if self.expert_form not in ("swiglu", "relu2"):
+            raise ValueError(f"expert_form must be 'swiglu' or 'relu2', "
+                             f"got {self.expert_form!r}")
+        object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
+        if self.layer_pattern:
+            object.__setattr__(self, "n_layers", len(self.layer_pattern))
+            self._check_pattern()
+        elif self.n_experts and self.mlp != "swiglu":
+            raise ValueError("in the uniform stack the expert layers "
+                             "follow SwiGLU dense layers: n_experts > 0 "
                              "needs mlp='swiglu'")
         if self.mtp_depth not in (0, 1):
             raise ValueError(f"mtp_depth must be 0 or 1: {self.mtp_depth}")
@@ -132,18 +169,42 @@ class TransformerConfig:
                              "block: it needs attention='mla' and "
                              "n_experts > 0")
 
+    def _check_pattern(self):
+        kinds = set(self.layer_pattern)
+        if kinds - set("M*E"):
+            raise ValueError(f"layer_pattern holds 'M', '*' and 'E': "
+                             f"{sorted(kinds - set('M*E'))}")
+        if self.tied_head or self.mtp_depth:
+            raise ValueError("a layer pattern needs tied_head=False and "
+                             "mtp_depth=0")
+        if "E" in kinds and not self.n_experts:
+            raise ValueError("'E' layers need n_experts > 0")
+        if "*" in kinds and self.n_heads % (self.n_kv_heads or self.n_heads):
+            raise ValueError(f"n_heads {self.n_heads} is no multiple of "
+                             f"n_kv_heads {self.n_kv_heads}")
+        if "M" in kinds and (self.ssm_heads < 1 or self.ssm_head_dim < 1
+                             or self.ssm_state < 1
+                             or self.ssm_heads % self.ssm_groups):
+            raise ValueError("'M' layers need ssm_heads (a multiple of "
+                             "ssm_groups), ssm_head_dim and ssm_state")
+
     @property
     def compute_dtype(self):
         return jnp.dtype(self.dtype)
 
     @property
     def n_dense(self) -> int:
-        """Layers with a dense MLP: all of them without experts."""
+        """Layers with a dense MLP: all of them without experts, none
+        under a layer pattern."""
+        if self.layer_pattern:
+            return 0
         return (min(self.n_dense_layers, self.n_layers) if self.n_experts
                 else self.n_layers)
 
     @property
     def n_expert_layers(self) -> int:
+        if self.layer_pattern:
+            return self.layer_pattern.count("E")
         return self.n_layers - self.n_dense
 
     def is_expert_layer(self, layer: int) -> bool:
@@ -153,7 +214,8 @@ class TransformerConfig:
     def gpt2_block(self) -> bool:
         """Every kind at its default: nothing of ``models/blocks.py``."""
         return (self.attention == "mha" and self.mlp == "gelu"
-                and self.tied_head and not self.mtp_depth)
+                and self.tied_head and not self.mtp_depth
+                and not self.layer_pattern)
 
 
 def init_params(rng: np.random.RandomState, cfg: TransformerConfig,
@@ -174,6 +236,11 @@ def init_params(rng: np.random.RandomState, cfg: TransformerConfig,
     # direction and every one of them picks the same experts (PERF.md
     # section 6, PR 28).
     p = {"embed": norm(cfg.vocab, dm, scale=0.02 if cfg.tied_head else 1.0)}
+    if cfg.layer_pattern:
+        from horovod_tpu.models import blocks
+
+        p.update(blocks.init_pattern(norm, rng, cfg, ep))
+        return jax.tree_util.tree_map(jnp.asarray, p)
     if cfg.attention == "mha":
         p["pos"] = norm(cfg.max_seq, dm, scale=0.02)
         layers = {
@@ -206,6 +273,11 @@ def param_specs(cfg: TransformerConfig):
     # layer stacks shard over pp (each stage holds only its layers)
     # and tp (column/row parallel matrices)
     specs = {"embed": P(), "ln_f": P()}
+    if cfg.layer_pattern:
+        from horovod_tpu.models import blocks
+
+        specs.update(blocks.pattern_specs(cfg))
+        return specs
     if cfg.attention == "mha":
         specs["pos"] = P()
         layers = {"wqkv": P("pp", None, "tp"), "wo": P("pp", "tp", None)}
@@ -226,9 +298,9 @@ def param_specs(cfg: TransformerConfig):
     return specs
 
 
-def _rmsnorm(x, g):
+def _rmsnorm(x, g, eps: float = 1e-6):
     x32 = x.astype(jnp.float32)
-    rms = jnp.sqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + 1e-6)
+    rms = jnp.sqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
     return ((x32 / rms) * g).astype(x.dtype)
 
 
@@ -242,7 +314,7 @@ def _block(cfg: TransformerConfig, lp, x, positions=None, ffn=None,
     b, lc, dm = x.shape
     cd = cfg.compute_dtype
 
-    h = _rmsnorm(x, lp["ln1"])
+    h = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
     if cfg.attention == "mla":
         from horovod_tpu.models import blocks
 
@@ -263,7 +335,7 @@ def _block(cfg: TransformerConfig, lp, x, positions=None, ffn=None,
         proj = reduce_from_tp(proj, "tp")  # Megatron "g": row-parallel reduce
     x = x + proj.astype(x.dtype)
 
-    h = _rmsnorm(x, lp["ln2"])
+    h = _rmsnorm(x, lp["ln2"], cfg.norm_eps)
     if ffn is not None:
         mlp, pairs = ffn(cfg, ffn_weights, h)
     else:
@@ -287,6 +359,11 @@ def _stack(params, tokens, cfg: TransformerConfig):
     nstages = lax.axis_size("pp")
     b, lc = tokens.shape
     pos = sp_idx * lc + jnp.arange(lc)
+    if cfg.layer_pattern:
+        from horovod_tpu.models import blocks
+
+        return blocks.pattern_stack(cfg, params,
+                                    params["embed"][tokens].astype(cd), pos)
     if cfg.attention == "mha":
         x = (params["embed"][tokens] + params["pos"][pos]).astype(cd)
     else:
@@ -368,7 +445,7 @@ def _logits(cfg: TransformerConfig, x, gain, table):
     """Final norm and f32 logits through ``table`` (``embed``,
     transposed, or the untied head), (b, lc, vocab)."""
     cd = cfg.compute_dtype
-    x = _rmsnorm(x, gain)
+    x = _rmsnorm(x, gain, cfg.norm_eps)
     with jax.named_scope("hvd_loss_head"):
         return (x.astype(cd)
                 @ (table.astype(cd).T if cfg.tied_head
@@ -413,7 +490,14 @@ def loss_fn(params, tokens, targets, cfg: TransformerConfig,
 
     ``with_routing`` returns ``(loss, pairs)``: for every expert layer
     (the MTP module's last) the (token, expert) pairs each held expert
-    computed, (layers, held) int32 (:data:`loss_and_routing`).
+    computed, (layers, held) int32 (:data:`loss_and_routing`).  Under a
+    layer pattern the second value is a dict of what the kinds report,
+    a row a layer of the kind: ``loads`` (expert layers, n_experts)
+    int32, the pairs the routing sent each of all the experts, held
+    here or not (what ``moe.settle_bias`` balances; the held experts'
+    columns are the pairs computed); ``least_log_decay`` (state-space
+    layers,) float32, the least logarithm of a whole chunk's decay
+    (:func:`record_scan`).
     """
     x, pos, pairs = _stack(params, tokens, cfg)
     head_nll = _remat_head_nll if cfg.remat else _head_nll
@@ -434,6 +518,9 @@ def loss_fn(params, tokens, targets, cfg: TransformerConfig,
         pairs.append(routed)
     if not with_routing:
         return loss
+    if cfg.layer_pattern:
+        return loss, {kind: jnp.stack(rows) for kind, rows in pairs.items()
+                      if rows}
     return loss, (jnp.stack(pairs) if pairs
                   else jnp.zeros((0, 0), jnp.int32))
 
@@ -457,6 +544,28 @@ def record_routing(cfg: TransformerConfig, pairs, tokens: int) -> list:
                for i, row in enumerate(np.asarray(pairs))]
     for record in records:
         flight.record("hvd_moe_route", **record)
+    return records
+
+
+def record_scan(cfg: TransformerConfig, least_log_decay) -> list:
+    """Write one ``hvd_ssm_scan`` record a state-space layer to the
+    flight ring: the chunk's length, the chunks a sequence of
+    ``max_seq`` is cut into, heads and state size, and
+    ``least_log_decay`` — a row of :func:`loss_and_routing`'s
+    ``least_log_decay``, the least over the ``dp`` and ``sp`` ranks: the
+    least logarithm of a whole chunk's decay in that batch, which says
+    how near the scan's ``exp`` comes to underflow (float32's smallest
+    normal number is ``exp(-87.3)``; below it a chunk's incoming state
+    is simply forgotten).  Returns the records."""
+    from horovod_tpu.runtime import flight
+
+    records = [dict(layer=i, chunk=cfg.ssm_chunk,
+                    chunks=-(-cfg.max_seq // cfg.ssm_chunk),
+                    heads=cfg.ssm_heads, state=cfg.ssm_state,
+                    least_log_decay=float(least))
+               for i, least in enumerate(np.asarray(least_log_decay))]
+    for record in records:
+        flight.record("hvd_ssm_scan", **record)
     return records
 
 

@@ -14,13 +14,20 @@ the shares of all holders add up to the whole layer
 
 No capacity and no dropped pair.  The pairs are sorted by expert, the
 held ones first, and taken in chunks of ``CHUNK_ROWS`` rows: a gather
-of the rows, three grouped products over the experts held
-(``lax.ragged_dot``, SwiGLU), a scatter-add back onto the tokens.  A
+of the rows, the grouped products over the experts held
+(``lax.ragged_dot``: three for a SwiGLU expert, two for a relu^2 one),
+a scatter-add back onto the tokens.  A
 loop with a trip count read from the routing runs as many chunks as the
 held pairs fill, so work and memory follow the pairs that exist and not
 the worst case (``tokens x k`` rows when every token picks held
 experts), and any routing, however skewed, is computed in full.  The
 backward pass is the same loop over the chunks' own ``jax.vjp``.
+
+The experts' form is a property of the layer's weights: three matrices
+(``w_gate``, ``w_up``, ``w_down``) are SwiGLU, ``W_down (silu(W_gate x)
+* W_up x)``; two (``w_up``, ``w_down``) are ``W_down relu(W_up x)^2``.
+The shared expert, the backward rule (each chunk's own ``jax.vjp``) and
+:func:`moe_reference` read the same.
 
 One chip: no exchange.  Over an ``ep`` mesh axis the same share runs
 between an all-gather of the tokens and a reduce-scatter of the partial
@@ -61,6 +68,14 @@ def swiglu(x, w):
     up = jnp.dot(x, w["w_up"], preferred_element_type=jnp.float32)
     hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
     return jnp.dot(hidden, w["w_down"], preferred_element_type=jnp.float32)
+
+
+def relu2(x, w):
+    """``relu(x W_up)^2 W_down`` with f32 accumulation: the shared
+    expert of a two-matrix layer."""
+    up = jnp.dot(x, w["w_up"], preferred_element_type=jnp.float32)
+    return jnp.dot(jnp.square(jax.nn.relu(up)).astype(x.dtype), w["w_down"],
+                   preferred_element_type=jnp.float32)
 
 
 def pairs_per_expert(ids, first, held: int):
@@ -117,8 +132,12 @@ def _chunk(x, w, weights, plan, index, rows: int):
         return jnp.where(live[:, None], lax.ragged_dot(
             a, b, sizes, preferred_element_type=jnp.float32), 0.0)
 
-    hidden = (jax.nn.silu(grouped(xs, w["w_gate"]))
-              * grouped(xs, w["w_up"])).astype(x.dtype)
+    if "w_gate" in w:
+        hidden = (jax.nn.silu(grouped(xs, w["w_gate"]))
+                  * grouped(xs, w["w_up"])).astype(x.dtype)
+    else:
+        hidden = jnp.square(jax.nn.relu(grouped(xs, w["w_up"]))).astype(
+            x.dtype)
     scale = weights.reshape(-1)[pairs]
     return tokens, grouped(hidden, w["w_down"]) * scale[:, None]
 
@@ -132,14 +151,15 @@ def expert_share(x, w, ids, weights, first):
     """``sum_i w_i E_i(x)`` over the pairs whose expert is one of the
     ``held`` from ``first`` on: (T, d) f32.  ``x``: (T, d) in the
     compute dtype; ``w``: ``{"w_gate", "w_up": (held, d, f), "w_down":
-    (held, f, d)}`` in the compute dtype; ``ids``, ``weights``: (T, k)
+    (held, f, d)}`` in the compute dtype (no ``w_gate``: relu^2
+    experts); ``ids``, ``weights``: (T, k)
     from :func:`route`; ``first``: an int or a traced scalar."""
     return _expert_share_fwd(x, w, ids, weights, first)[0]
 
 
 def _expert_share_fwd(x, w, ids, weights, first):
     rows = _chunk_rows(ids)
-    plan = _plan(ids, first, w["w_gate"].shape[0])
+    plan = _plan(ids, first, w["w_down"].shape[0])
 
     def body(index, out):
         tokens, part = _chunk(x, w, weights, plan, index, rows)
@@ -182,25 +202,38 @@ def _expert_share_bwd(res, dout):
 expert_share.defvjp(_expert_share_fwd, _expert_share_bwd)
 
 
+def settle_bias(bias, loads, rate: float):
+    """One round of the selection bias's balance rule (the ``noaux_tc``
+    rule of the DeepSeek-V3 family): an expert that was sent more pairs
+    than the mean of its layer loses ``rate``, one that was sent fewer
+    gains it.  ``bias``, ``loads``: (..., E) over all experts."""
+    loads = loads.astype(jnp.float32)
+    return bias + rate * jnp.sign(
+        jnp.mean(loads, axis=-1, keepdims=True) - loads)
+
+
 def moe_layer(x, params, *, top_k: int, scale: float, first=0,
-              axis_name: str | None = None):
+              axis_name: str | None = None, count_all: bool = False):
     """One expert layer on (T, d) tokens in the compute dtype.
 
     ``params``: ``router`` (d, E) and ``bias`` (E,) over all ``E``
     experts; ``experts`` ``{"w_gate", "w_up": (held, d, f), "w_down":
-    (held, f, d)}``, the experts ``first .. first + held`` of the ``E``;
-    ``shared`` (optional) one SwiGLU's weights.  Over ``axis_name`` (an
+    (held, f, d)}``, the experts ``first .. first + held`` of the ``E``
+    (without ``w_gate`` they are relu^2 experts); ``shared`` (optional)
+    one expert's weights of either form.  Over ``axis_name`` (an
     ``ep`` mesh axis of size > 1) rank ``r`` holds the experts from
     ``first + r * held`` on: the tokens are gathered, every rank
     computes its share for all of them, and a reduce-scatter returns
     to each rank its own tokens' sum.  Returns ``(out (T, d) f32,
     pairs (held,) int32)``: the routed part plus the shared expert, and
-    the pairs each held expert computed (of the gathered tokens).
+    the pairs each held expert computed (of the gathered tokens); with
+    ``count_all`` the pairs the routing sent each of the ``E`` experts,
+    held here or not, (E,) int32.
     """
     cd = x.dtype
     experts = jax.tree_util.tree_map(lambda a: a.astype(cd),
                                      params["experts"])
-    held = experts["w_gate"].shape[0]
+    held = experts["w_down"].shape[0]
     n_experts = params["router"].shape[1]
     ep = 1 if axis_name is None else lax.axis_size(axis_name)
     if ep * held > n_experts:
@@ -219,9 +252,12 @@ def moe_layer(x, params, *, top_k: int, scale: float, first=0,
         out = lax.psum_scatter(out, axis_name, scatter_dimension=0,
                                tiled=True)
     if "shared" in params:
+        shared = swiglu if "w_gate" in params["shared"] else relu2
         with jax.named_scope("hvd_moe_shared"):
-            out = out + swiglu(x, jax.tree_util.tree_map(
+            out = out + shared(x, jax.tree_util.tree_map(
                 lambda a: a.astype(cd), params["shared"]))
+    if count_all:
+        return out, lax.stop_gradient(pairs_per_expert(ids, 0, n_experts))
     return out, lax.stop_gradient(pairs_per_expert(ids, first, held))
 
 
@@ -230,22 +266,26 @@ def moe_reference(x, params, *, top_k: int, scale: float, first: int = 0,
     """Plain golden model for tests: float32, a Python loop over the
     experts ``first .. first + held`` (all of ``params["experts"]``
     where ``held`` is not given), every expert on every token under a
-    mask.  The shared expert is added where ``params`` has one."""
+    mask.  The shared expert is added where ``params`` has one.  The
+    experts' form is read from the weights, as the layer reads it."""
+
+    def expert(w):
+        if "w_gate" in w:
+            return (jax.nn.silu(x @ w["w_gate"]) * (x @ w["w_up"])) @ w["w_down"]
+        return jnp.square(jax.nn.relu(x @ w["w_up"])) @ w["w_down"]
+
     scores = jax.nn.sigmoid(x @ params["router"])
     ids = jnp.argsort(-(scores + params["bias"]), axis=-1,
                       stable=True)[:, :top_k]
     picked = jnp.take_along_axis(scores, ids, axis=-1)
     weights = scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)
     experts = params["experts"]
-    held = experts["w_gate"].shape[0] if held is None else held
+    held = experts["w_down"].shape[0] if held is None else held
     out = jnp.zeros_like(x)
     for e in range(held):
-        w = {name: a[e] for name, a in experts.items()}
         gate = jnp.sum(jnp.where(ids == first + e, weights, 0.0), axis=-1)
-        out = out + gate[:, None] * (
-            (jax.nn.silu(x @ w["w_gate"]) * (x @ w["w_up"])) @ w["w_down"])
+        out = out + gate[:, None] * expert(
+            {name: a[e] for name, a in experts.items()})
     if "shared" in params:
-        s = params["shared"]
-        out = out + (jax.nn.silu(x @ s["w_gate"])
-                     * (x @ s["w_up"])) @ s["w_down"]
+        out = out + expert(params["shared"])
     return out

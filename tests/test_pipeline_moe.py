@@ -258,9 +258,13 @@ def ep_mesh():
     return Mesh(np.array(jax.devices()[:EP]), ("ep",))
 
 
-def _layer_params(seed, first=0, held=E, shared=True):
+FORMS = ("swiglu", "relu2")
+
+
+def _layer_params(seed, first=0, held=E, shared=True, form="swiglu"):
     """A layer's weights, float32: the router and bias over all ``E``
-    experts, the experts ``first .. first + held``, the shared one."""
+    experts, the experts ``first .. first + held``, the shared one.
+    ``form`` "relu2" leaves the gate matrices out: two-matrix experts."""
     rng = np.random.RandomState(seed)
     p = {"router": rng.randn(DIM, E) * 0.5, "bias": rng.randn(E) * 0.1,
          "experts": {"w_gate": rng.randn(E, DIM, FFH) * 0.3,
@@ -271,6 +275,8 @@ def _layer_params(seed, first=0, held=E, shared=True):
                     "w_down": rng.randn(FFH, DIM) * 0.3}}
     p["experts"] = {name: a[first:first + held]
                     for name, a in p["experts"].items()}
+    if form == "relu2":
+        del p["experts"]["w_gate"], p["shared"]["w_gate"]
     if not shared:
         del p["shared"]
     return jax.tree_util.tree_map(lambda a: jnp.asarray(a, jnp.float32), p)
@@ -344,25 +350,27 @@ def test_moe_grads_flow(ep_mesh, monkeypatch):
                                    atol=2e-5)
 
 
-def test_the_shares_of_a_layer_add_up_to_the_layer():
+@pytest.mark.parametrize("form", FORMS)
+def test_the_shares_of_a_layer_add_up_to_the_layer(form):
     """One chip at a time, no exchange: the 16 shares of a 16-expert
     layer (one expert each, told which), with the shared expert, which
     every chip computes alike, counted once, add up to what the uncut
     reference gives; so do 4 shares of 4.  What a share leaves out is
-    exactly the other experts' part."""
+    exactly the other experts' part.  SwiGLU experts and two-matrix
+    relu^2 ones: the layer and the reference read the form from the
+    weights."""
     x = jnp.asarray(np.random.RandomState(6).randn(T, DIM), jnp.float32)
-    whole = _layer_params(7)
+    whole = _layer_params(7, form=form)
     ref = moe.moe_reference(x, whole, top_k=TOP_K, scale=SCALE)
-    shared = moe.swiglu(x, whole["shared"])
+    shared = getattr(moe, form)(x, whole["shared"])
     for held in (1, 4):
         total, sent = shared, 0
         for first in range(0, E, held):
-            out, pairs = moe.moe_layer(
-                x, _layer_params(7, first, held, shared=False),
-                top_k=TOP_K, scale=SCALE, first=first)
-            part = moe.moe_reference(
-                x, _layer_params(7, first, held, shared=False),
-                top_k=TOP_K, scale=SCALE, first=first)
+            share = _layer_params(7, first, held, shared=False, form=form)
+            out, pairs = moe.moe_layer(x, share, top_k=TOP_K, scale=SCALE,
+                                       first=first)
+            part = moe.moe_reference(x, share, top_k=TOP_K, scale=SCALE,
+                                     first=first)
             np.testing.assert_allclose(np.asarray(out), np.asarray(part),
                                        rtol=1e-4, atol=1e-5)
             total, sent = total + out, sent + int(pairs.sum())
@@ -430,12 +438,14 @@ def _leaves_rows_unwritten(real):
     return product
 
 
-def test_rows_that_belong_to_no_group_are_never_read(monkeypatch):
+@pytest.mark.parametrize("form", FORMS)
+def test_rows_that_belong_to_no_group_are_never_read(monkeypatch, form):
     """A share that holds 4 of 16 experts: most rows of its one chunk
     lie past the last held pair.  With those rows of every grouped
     product and of its left gradient poisoned, the share and all its
-    gradients are what they are unpoisoned: finite, and equal."""
-    params = _layer_params(11, first=4, held=4, shared=False)
+    gradients are what they are unpoisoned: finite, and equal.  Both
+    forms of expert."""
+    params = _layer_params(11, first=4, held=4, shared=False, form=form)
     x = jnp.asarray(np.random.RandomState(12).randn(T, DIM), jnp.float32)
 
     def loss(x_, p_):
@@ -449,3 +459,44 @@ def test_rows_that_belong_to_no_group_are_never_read(monkeypatch):
     for g, w in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_count_all_gives_the_pairs_sent_to_every_expert():
+    """``count_all``: the second value is over all ``E`` experts, held
+    or not; its held columns are what the share computes, and it adds
+    up to tokens x k."""
+    params = _layer_params(13, first=4, held=4, shared=False)
+    x = jnp.asarray(np.random.RandomState(14).randn(T, DIM), jnp.float32)
+    out, held = moe.moe_layer(x, params, top_k=TOP_K, scale=SCALE, first=4)
+    same, loads = moe.moe_layer(x, params, top_k=TOP_K, scale=SCALE, first=4,
+                                count_all=True)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(same))
+    assert loads.shape == (E,) and int(loads.sum()) == T * TOP_K
+    np.testing.assert_array_equal(np.asarray(loads[4:8]), np.asarray(held))
+
+
+def test_the_balance_rule_evens_the_load_out():
+    """``settle_bias``: an expert sent more pairs than the mean loses
+    ``rate``, one sent fewer gains it, an expert at the mean keeps its
+    bias; over rounds a lopsided router's busiest expert comes down to
+    the mean (the bias selects only: the weights stay the scores')."""
+    bias = jnp.zeros((2, 4))
+    loads = jnp.asarray([[10, 2, 6, 6], [1, 1, 1, 21]], jnp.int32)
+    np.testing.assert_allclose(
+        np.asarray(moe.settle_bias(bias, loads, 0.5)),
+        [[-0.5, 0.5, 0.0, 0.0], [0.5, 0.5, 0.5, -0.5]])
+    params = _layer_params(15, shared=False)
+    params["router"] = params["router"].at[:, 0].mul(4.0)   # a favourite
+    x = jnp.asarray(np.random.RandomState(16).randn(8 * T, DIM), jnp.float32)
+
+    def loads_of(bias):
+        ids, _ = moe.route(x, params["router"], bias, TOP_K, SCALE)
+        return moe.pairs_per_expert(ids, 0, E)
+
+    bias = params["bias"]
+    before = loads_of(bias)
+    for _ in range(200):
+        bias = moe.settle_bias(bias, loads_of(bias), 0.01)
+    after = loads_of(bias)
+    assert float(before.max() / before.mean()) > 1.5
+    assert float(after.max() / after.mean()) < 1.15
