@@ -535,12 +535,21 @@ def record_routing(cfg: TransformerConfig, pairs, tokens: int) -> list:
     ring: one ``hvd_moe_route`` record an expert layer (the MTP
     module's last) with ``pairs`` — a row of :func:`loss_and_routing`'s
     second value, summed over ``sp`` and in expert order over the
-    ``dp`` ranks — ``tokens`` (in the batch), ``top_k`` and ``dropped``,
-    which the dropless layer keeps at 0.  Returns the records."""
+    ``dp`` ranks — ``tokens`` (in the batch), ``top_k``, ``dropped``,
+    which the dropless layer keeps at 0, ``chunk_rows``, the rows
+    ``moe.chunk_rows`` gives a chunk for that many tokens, and
+    ``chunks``, the trips the busiest rank's pairs take: ``sum(pairs) /
+    (chunks x chunk_rows)`` is the share of the rows run that held a
+    pair (one rank).  Returns the records."""
+    from horovod_tpu.parallel import moe
     from horovod_tpu.runtime import flight
 
+    rows = moe.chunk_rows(int(tokens) * cfg.experts_per_token,
+                          cfg.experts_held, cfg.n_experts)
     records = [dict(layer=i, pairs=[int(n) for n in row], tokens=int(tokens),
-                    top_k=cfg.experts_per_token, dropped=0)
+                    top_k=cfg.experts_per_token, dropped=0, chunk_rows=rows,
+                    chunks=int(-(-row.reshape(-1, cfg.experts_held)
+                                 .sum(axis=1).max() // rows)))
                for i, row in enumerate(np.asarray(pairs))]
     for record in records:
         flight.record("hvd_moe_route", **record)

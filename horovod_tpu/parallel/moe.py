@@ -13,15 +13,20 @@ the shares of all holders add up to the whole layer
 (``tests/test_pipeline_moe.py``).
 
 No capacity and no dropped pair.  The pairs are sorted by expert, the
-held ones first, and taken in chunks of ``CHUNK_ROWS`` rows: a gather
-of the rows, the grouped products over the experts held
-(``lax.ragged_dot``: three for a SwiGLU expert, two for a relu^2 one),
-a scatter-add back onto the tokens.  A
-loop with a trip count read from the routing runs as many chunks as the
-held pairs fill, so work and memory follow the pairs that exist and not
-the worst case (``tokens x k`` rows when every token picks held
-experts), and any routing, however skewed, is computed in full.  The
-backward pass is the same loop over the chunks' own ``jax.vjp``.
+held ones first, and taken in chunks: a gather of the rows, the grouped
+products over the experts held (``lax.ragged_dot``: three for a SwiGLU
+expert, two for a relu^2 one), a scatter-add back onto the tokens.  A
+chunk is sized from the shapes (:func:`chunk_rows`): ``CHUNK_ROWS``
+rows in as many equal parts as still hold, with a third to spare, the
+share of the ``tokens x k`` pairs that a balanced router sends the
+experts held.  Every row past the pairs is gathered, selected away and
+added back as zero; no load runs more rows than in chunks of
+``CHUNK_ROWS``, and a balanced one fewer.  A loop with a trip
+count read from the routing runs as many chunks as the held pairs
+fill, so work and memory follow the pairs that exist and not the worst
+case (``tokens x k`` rows when every token picks held experts), and
+any routing, however skewed, is computed in full.  The backward pass
+is the same loop over the chunks' own ``jax.vjp``.
 
 The experts' form is a property of the layer's weights: three matrices
 (``w_gate``, ``w_up``, ``w_down``) are SwiGLU, ``W_down (silu(W_gate x)
@@ -36,13 +41,30 @@ results.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from horovod_tpu.common.types import HorovodTpuError
 
+# the most rows a chunk has: what bounds its f32 temporaries
 CHUNK_ROWS = 16384
+
+
+def chunk_rows(pairs: int, held: int, n_experts: int) -> int:
+    """Rows of a chunk of ``pairs`` sorted (token, slot) pairs where
+    ``held`` of ``n_experts`` experts are held: ``CHUNK_ROWS`` rows (at
+    most ``pairs``) in as many equal parts as still hold the held
+    experts' expected share and a third.  A trip costs what some six
+    thousand rows cost, and a grouped product only the pairs it meets
+    (PERF.md section 6, PR 34), so a balanced layer is one trip; and
+    the parts of one whole chunk are its rows (rounded up), so a load
+    past a part runs no more rows than it would in whole chunks."""
+    whole = min(CHUNK_ROWS, pairs)
+    share = -(-4 * pairs * held // (3 * n_experts))
+    return -(-whole // max(1, whole // share))
 
 
 def route(x, router_w, bias, top_k: int, scale: float):
@@ -87,26 +109,22 @@ def pairs_per_expert(ids, first, held: int):
                    dtype=jnp.int32)
 
 
-def _plan(ids, first, held: int):
+def _plan(ids, first, held: int, rows: int):
     """The (token, slot) pairs in expert order, held experts first, cut
-    into chunks.  Returns ``(order, starts (held,), ends (held,))``:
-    pair ``order[r]`` sits in row ``r``; expert ``first + e`` owns rows
-    ``starts[e] .. ends[e]``; rows from ``ends[-1]`` on go to experts
-    held elsewhere.  ``order`` is padded to a whole number of chunks
+    into chunks of ``rows``.  Returns ``(order, starts (held,), ends
+    (held,))``: pair ``order[r]`` sits in row ``r``; expert ``first +
+    e`` owns rows ``starts[e] .. ends[e]``; rows from ``ends[-1]`` on
+    go to experts held elsewhere.  ``order`` is padded to a whole number of chunks
     (the padding lies past every pair and is never reached)."""
     local = ids.reshape(-1) - first
     key = jnp.where((local >= 0) & (local < held), local, held)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    pad = -order.size % _chunk_rows(ids)
+    pad = -order.size % rows
     if pad:
         order = jnp.concatenate([order, jnp.zeros(pad, order.dtype)])
     counts = pairs_per_expert(ids, first, held)
     ends = jnp.cumsum(counts)
     return order, ends - counts, ends
-
-
-def _chunk_rows(ids) -> int:
-    return min(CHUNK_ROWS, ids.size)
 
 
 def _chunk(x, w, weights, plan, index, rows: int):
@@ -146,20 +164,22 @@ def _live_chunks(plan, rows: int):
     return (plan[2][-1] + rows - 1) // rows
 
 
-@jax.custom_vjp
-def expert_share(x, w, ids, weights, first):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def expert_share(x, w, ids, weights, first, n_experts: int):
     """``sum_i w_i E_i(x)`` over the pairs whose expert is one of the
     ``held`` from ``first`` on: (T, d) f32.  ``x``: (T, d) in the
     compute dtype; ``w``: ``{"w_gate", "w_up": (held, d, f), "w_down":
     (held, f, d)}`` in the compute dtype (no ``w_gate``: relu^2
     experts); ``ids``, ``weights``: (T, k)
-    from :func:`route`; ``first``: an int or a traced scalar."""
-    return _expert_share_fwd(x, w, ids, weights, first)[0]
+    from :func:`route`; ``first``: an int or a traced scalar;
+    ``n_experts``: the router's width, which sizes a chunk."""
+    return _expert_share_fwd(x, w, ids, weights, first, n_experts)[0]
 
 
-def _expert_share_fwd(x, w, ids, weights, first):
-    rows = _chunk_rows(ids)
-    plan = _plan(ids, first, w["w_down"].shape[0])
+def _expert_share_fwd(x, w, ids, weights, first, n_experts):
+    held = w["w_down"].shape[0]
+    rows = chunk_rows(ids.size, held, n_experts)
+    plan = _plan(ids, first, held, rows)
 
     def body(index, out):
         tokens, part = _chunk(x, w, weights, plan, index, rows)
@@ -170,11 +190,11 @@ def _expert_share_fwd(x, w, ids, weights, first):
     return out, (x, w, weights, plan)
 
 
-def _expert_share_bwd(res, dout):
+def _expert_share_bwd(n_experts, res, dout):
     """The forward's loop again, each chunk through its own
     ``jax.vjp``: nothing of a chunk outlives its turn."""
     x, w, weights, plan = res
-    rows = _chunk_rows(weights)
+    rows = chunk_rows(weights.size, w["w_down"].shape[0], n_experts)
 
     def body(index, grads):
         dx, dw, dweights = grads
@@ -247,7 +267,7 @@ def moe_layer(x, params, *, top_k: int, scale: float, first=0,
     ids, weights = route(tokens, params["router"], params["bias"], top_k,
                          scale)
     with jax.named_scope("hvd_moe_experts"):
-        out = expert_share(tokens, experts, ids, weights, first)
+        out = expert_share(tokens, experts, ids, weights, first, n_experts)
     if ep > 1:
         out = lax.psum_scatter(out, axis_name, scatter_dimension=0,
                                tiled=True)

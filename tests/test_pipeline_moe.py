@@ -3,6 +3,10 @@ correctness on the 8-device mesh.  Both are TPU extensions beyond the
 reference (SURVEY §2.7); validated against single-device golden models.
 """
 
+import functools
+import json
+import pathlib
+
 import numpy as np
 import pytest
 
@@ -398,6 +402,95 @@ def test_routing_is_dropless_under_any_skew():
     ref = moe.moe_reference(x, params, top_k=TOP_K, scale=SCALE)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-4,
                                atol=1e-5)
+
+
+@pytest.mark.parametrize("form", FORMS)
+@pytest.mark.parametrize("case", ("larger", "equal", "divisor", "no_divisor"))
+def test_any_chunk_size_gives_the_one_chunk_result(monkeypatch, form, case):
+    """A share that holds 4 of 16 experts, its held pairs in chunks
+    larger than, equal to, a divisor of and no divisor of their number:
+    the loop runs ``ceil(held pairs / rows)`` trips in each pass (the
+    chunks are counted as they run), and the share and every gradient
+    are the one-chunk result to float32 rounding and the plain
+    reference's.  Both forms of expert."""
+    params = _layer_params(17, first=4, held=4, shared=False, form=form)
+    x = jnp.asarray(np.random.RandomState(19).randn(T, DIM), jnp.float32)
+
+    def loss(layer):
+        return jax.value_and_grad(
+            lambda x_, p_: jnp.sum(layer(x_, p_, top_k=TOP_K, scale=SCALE,
+                                         first=4) ** 2),
+            argnums=(0, 1))(x, params)
+
+    def share(*args, **kw):
+        return moe.moe_layer(*args, **kw)[0]
+
+    held = int(moe.moe_layer(x, params, top_k=TOP_K, scale=SCALE,
+                             first=4)[1].sum())
+    assert held % 2 == 0 and 6 <= held <= T * TOP_K - 3
+    rows = {"larger": held + 3, "equal": held, "divisor": held // 2,
+            "no_divisor": held // 2 + 1}[case]
+    assert held + 3 <= moe.chunk_rows(T * TOP_K, 4, E) == T * TOP_K // 2
+    one = loss(share)                   # one chunk holds all the held pairs
+    trips, real = [], moe._chunk
+
+    def counted(x_, w, weights, plan, index, rows_):
+        assert rows_ == rows
+        jax.debug.callback(lambda i: trips.append(int(i)), index)
+        return real(x_, w, weights, plan, index, rows_)
+
+    monkeypatch.setattr(moe, "CHUNK_ROWS", rows)
+    monkeypatch.setattr(moe, "_chunk", counted)
+    got = loss(share)
+    jax.effects_barrier()
+    assert sorted(trips) == sorted(2 * list(range(-(-held // rows))))
+    want = loss(functools.partial(moe.moe_reference, held=4))
+    for g, o, w in zip(*map(jax.tree_util.tree_leaves, (got, one, want))):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(o), rtol=1e-5,
+                                   atol=1e-6)
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4,
+                                   atol=2e-5)
+
+
+def _cell_shapes(config):
+    """``(tokens x k, held, router width)`` of a step of the benchmark's
+    expert cell of that configuration."""
+    root = pathlib.Path(__file__).parents[1] / "benchmark"
+    cfg = json.loads((root / "configs" / f"{config}.json").read_text())
+    job = json.loads((root / "traffic" / "s8192.epshare.json").read_text())
+    return (job["batch_per_chip"] * job["seq"] * cfg["num_experts_per_tok"],
+            cfg["n_routed_experts"], cfg["router_width"])
+
+
+def test_the_hybrid_cells_balanced_layer_is_one_trip_with_room():
+    """Arithmetic only, at the shapes of the benchmark's hybrid cell
+    (16,384 tokens a step, top-6 with 8 held of 128): the 6,144 pairs a
+    balanced router sends the held experts lie at least 15 % clear of a
+    trip boundary, so a seed's few per cent do not change the trips,
+    and one trip is half the 16,384 rows of a whole chunk."""
+    pairs, held, width = _cell_shapes("nemotron-twotower-30b-a3b")
+    expected = pairs * held // width
+    assert (pairs, expected) == (16384 * 6, 6144)
+    rows = moe.chunk_rows(pairs, held, width)
+    assert 1.15 * expected <= rows == 16384 // 2
+
+
+@pytest.mark.parametrize("config", ("nemotron-twotower-30b-a3b",
+                                    "joyai-llm-flash"))
+def test_no_load_runs_more_rows_than_whole_chunks_would(config):
+    """Arithmetic only, at the shapes of the benchmark's expert cells:
+    a chunk is ``CHUNK_ROWS`` rows in equal parts (2 and 1: the latent
+    cell's share and a third does not fit twice), so whatever the held
+    experts are sent, from nothing to every pair, the trips' rows are
+    at most those of whole chunks."""
+    pairs, held, width = _cell_shapes(config)
+    rows = moe.chunk_rows(pairs, held, width)
+    assert moe.CHUNK_ROWS % rows == 0
+    assert moe.CHUNK_ROWS // rows == {8: 2, 16: 1}[held]
+    load = np.arange(0, pairs + 1, 61)
+    run = -(-load // rows) * rows
+    whole = -(-load // moe.CHUNK_ROWS) * moe.CHUNK_ROWS
+    assert (run <= whole).all()
 
 
 def test_more_ranks_than_the_router_has_experts_raises(ep_mesh):

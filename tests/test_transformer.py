@@ -312,13 +312,15 @@ def test_the_compiled_step_names_its_parts():
     assert not shown("hvd_attn", "hvd_loss_head")
 
 
-def test_the_new_kinds_name_their_parts_and_record_their_routing():
+def test_the_new_kinds_name_their_parts_and_record_their_routing(monkeypatch):
     """docs/perf.md: ``hvd_mla`` (with ``hvd_attn`` inside), ``hvd_moe``
     (with ``hvd_moe_route`` / ``_experts`` / ``_shared`` inside) and
     ``hvd_mtp`` reach the compiled step's ``op_name``s in both passes,
     the recomputed forward among the backward's; ``record_routing``
     writes one flight record an expert layer, the MTP module's last,
-    whose pairs add up to tokens x k with the whole router held."""
+    whose pairs add up to tokens x k with the whole router held, and
+    which says how many rows a chunk had and how many chunks the pairs
+    took: fewer than one chunk's rows held no pair."""
     import re
 
     from jax import shard_map
@@ -361,6 +363,22 @@ def test_the_new_kinds_name_their_parts_and_record_their_routing():
     for event in ring:
         assert event["dropped"] == 0 and len(event["pairs"]) == 8
         assert sum(event["pairs"]) == tokens.size * LATENT.experts_per_token
+        run = event["chunks"] * event["chunk_rows"]
+        assert sum(event["pairs"]) <= run < (sum(event["pairs"])
+                                             + event["chunk_rows"])
+    # two ranks' halves of a router of 8, 5 tokens: the busiest rank's
+    # 9 pairs take 3 chunks of 4 rows; the rule's own rows otherwise
+    import dataclasses
+
+    from horovod_tpu.parallel import moe
+
+    cfg = dataclasses.replace(LATENT, experts_held=4)
+    (record,) = record_routing(cfg, [[1, 2, 0, 3, 4, 0, 5, 0]], 5)
+    assert (record["chunk_rows"], record["chunks"]) == (
+        moe.chunk_rows(15, 4, 8), 1)
+    monkeypatch.setattr(moe, "CHUNK_ROWS", 4)
+    (record,) = record_routing(cfg, [[1, 2, 0, 3, 4, 0, 5, 0]], 5)
+    assert (record["chunk_rows"], record["chunks"]) == (4, 3)
 
 
 def _loss_and_grads(cfg):
