@@ -56,7 +56,13 @@ def main() -> None:
                    help="one letter a layer, each layer ONE sub-layer: "
                         "M a Mamba-2 mixer, * grouped-query attention "
                         "without positions, E an expert layer of relu^2 "
-                        "experts (with --experts; e.g. MEM*E). Replaces "
+                        "experts (with --experts; e.g. MEM*E); S and G "
+                        "gated grouped-query attention with QK-norm, S "
+                        "under a sliding window of seq / 4 with rotary "
+                        "positions, G over the whole past with none, D "
+                        "a dense SwiGLU FFN; with S, G or D every "
+                        "sub-layer also gets a norm after it and the "
+                        "experts are SwiGLU (e.g. SDSESESEGE). Replaces "
                         "--n-layers; dp only (no pp, tp or sp)")
     p.add_argument("--pp-schedule", default="gpipe",
                    choices=["gpipe", "interleaved"],
@@ -109,6 +115,13 @@ def main() -> None:
                      ssm_head_dim=2 * args.d_model // 4, ssm_groups=2,
                      ssm_state=16, ssm_chunk=min(64, args.seq),
                      expert_form="relu2", **experts)
+        if set(args.layer_pattern) & set("SGD"):
+            # a window / full attention stack: a quarter of the sequence
+            # in the window, a norm after every sub-layer, the embedding
+            # times sqrt(d), SwiGLU experts
+            kinds.update(window=max(1, args.seq // 4), post_norm=True,
+                         embed_scale=args.d_model ** 0.5,
+                         expert_form="swiglu")
     else:
         kinds = dict(mlp="swiglu", n_dense_layers=1,
                      **experts) if experts else {}

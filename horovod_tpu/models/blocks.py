@@ -5,7 +5,11 @@ DeepSeek-V3 family of configurations is made of — and the layers of a
 ``layer_pattern``, each ONE pre-normed sub-layer with one residual: a
 Mamba-2 mixer on a chunked scan, grouped-query attention without
 positions, the expert layer alone — what the hybrid state-space /
-attention / expert configurations (``nemotron_h``) are made of.
+attention / expert configurations (``nemotron_h``) are made of — and
+gated grouped-query attention with QK-norm, under a sliding window with
+rotary positions or over the whole past with none, a dense SwiGLU FFN,
+and a second norm after a sub-layer — what the window / full attention
+expert configurations (``afmoe``) are made of.
 
 Imported by :mod:`horovod_tpu.models.transformer` only where a
 ``TransformerConfig`` asks for one of them; a GPT-2-shaped configuration
@@ -27,6 +31,12 @@ layer of the kind, in the pattern's order::
     ssm     ln w_in conv_w conv_b dt_bias a_log d norm w_out      "M"
     attn    ln wq wk wv wo                                        "*"
     moe     ln router bias experts{..} shared{..}                 "E"
+    swa     ln wq wk wv wg wo q_norm k_norm                       "S"
+    gattn   ln wq wk wv wg wo q_norm k_norm                       "G"
+    dense   ln w_gate w_up w_down                                 "D"
+
+and ``ln_post`` in every stack where the configuration has a
+``post_norm``.
 """
 
 from __future__ import annotations
@@ -35,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from horovod_tpu.models.transformer import TransformerConfig, _rmsnorm
 from horovod_tpu.parallel import moe
@@ -167,17 +178,22 @@ def extra_specs(cfg: TransformerConfig) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def rotary(x, positions, theta: float):
+def rotary(x, positions, theta: float, halves: bool = False):
     """Rotary embedding over interleaved pairs ``(x[2i], x[2i+1])`` of
-    the last axis, frequencies ``theta ** (-2i / d)``, no scaling.  x:
-    (b, l, d) or (b, l, h, d); positions: (l,) global.  Computed in
-    float32."""
+    the last axis — with ``halves`` over the pairs ``(x[i], x[i + d/2])``
+    of its two halves, the half-rotation layout — frequencies
+    ``theta ** (-2i / d)``, no scaling.  x: (b, l, d) or (b, l, h, d);
+    positions: (l,) global.  Computed in float32."""
     d = x.shape[-1]
     inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angle = positions.astype(jnp.float32)[:, None] * inv      # (l, d/2)
     if x.ndim == 4:
         angle = angle[:, None, :]
     cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if halves:
+        a, b = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+        return jnp.concatenate([a * cos - b * sin, a * sin + b * cos],
+                               axis=-1).astype(x.dtype)
     pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
     a, b = pairs[..., 0], pairs[..., 1]
     out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
@@ -302,7 +318,14 @@ def mtp_loss(cfg: TransformerConfig, params, x, targets, block, head_nll):
 # ---------------------------------------------------------------------------
 
 # the stack that holds the weights of each kind of layer
-STACK_OF = {"M": "ssm", "*": "attn", "E": "moe"}
+STACK_OF = {"M": "ssm", "*": "attn", "E": "moe", "S": "swa", "G": "gattn",
+            "D": "dense"}
+# the kinds that call ``ring_attention`` over ``n_heads`` query heads on
+# ``n_kv_heads`` key/value heads
+ATTENTION_KINDS = "*SG"
+# the f32 result of an expert layer that a post-norm reads, under
+# ``jax.ad_checkpoint.checkpoint_name``: kept by a recomputed layer
+KEPT_EXPERT_OUT = "hvd_moe_out"
 # how the Mamba-2 reference code draws a state-space layer's own
 # parameters: the time step log-uniform in [DT_MIN, DT_MAX] and floored,
 # kept as what softplus maps onto it; the decay rate A uniform in
@@ -344,6 +367,17 @@ def _init_gqa(norm, cfg: TransformerConfig, n: int) -> dict:
             "wo": norm(n, width, dm, scale=width ** -0.5)}
 
 
+def _init_gated_gqa(norm, cfg: TransformerConfig, n: int) -> dict:
+    """``_init_gqa`` with the output gate's matrix and the gains of the
+    two per-head norms, one of ``head_dim`` for all query heads and one
+    for all key heads."""
+    dm, width = cfg.d_model, cfg.n_heads * cfg.head_dim
+    return {**_init_gqa(norm, cfg, n),
+            "wg": norm(n, dm, width, scale=dm ** -0.5),
+            "q_norm": np.ones((n, cfg.head_dim), np.float32),
+            "k_norm": np.ones((n, cfg.head_dim), np.float32)}
+
+
 def init_pattern(norm, rng, cfg: TransformerConfig, ep: int) -> dict:
     """Everything but ``embed``: a stack a kind the pattern holds, the
     final norm and the untied head.  ``rng.rand`` draws the uniform
@@ -359,6 +393,17 @@ def init_pattern(norm, rng, cfg: TransformerConfig, ep: int) -> dict:
         n = kinds.count("E")
         p["moe"] = {"ln": np.ones((n, dm), np.float32),
                     **_init_moe(norm, cfg, n, ep)}
+    for kind in "SG":
+        if kind in kinds:
+            p[STACK_OF[kind]] = _init_gated_gqa(norm, cfg, kinds.count(kind))
+    if "D" in kinds:
+        n = kinds.count("D")
+        p["dense"] = {"ln": np.ones((n, dm), np.float32),
+                      **_init_swiglu(norm, (n,), dm, cfg.d_ff)}
+    if cfg.post_norm:
+        for kind in set(kinds):
+            p[STACK_OF[kind]]["ln_post"] = np.ones((kinds.count(kind), dm),
+                                                   np.float32)
     if cfg.rescale_depth:
         # what a sub-layer writes to the stream shrinks with the depth
         # the stream is drawn for, so that a token's own row outweighs
@@ -369,6 +414,9 @@ def init_pattern(norm, rng, cfg: TransformerConfig, ep: int) -> dict:
         moe_ = p.get("moe", {})
         for holder, name in ((p.get("ssm", {}), "w_out"),
                              (p.get("attn", {}), "wo"),
+                             (p.get("swa", {}), "wo"),
+                             (p.get("gattn", {}), "wo"),
+                             (p.get("dense", {}), "w_down"),
                              (moe_.get("experts", {}), "w_down"),
                              (moe_.get("shared", {}), "w_down")):
             if name in holder:
@@ -381,14 +429,19 @@ def pattern_specs(cfg: TransformerConfig) -> dict:
     ``dp`` (= ep): no kind of a pattern runs under ``pp`` or ``tp``."""
     from jax.sharding import PartitionSpec as P
 
+    gated = ("ln", "wq", "wk", "wv", "wg", "wo", "q_norm", "k_norm")
     names = {"ssm": ("ln", "w_in", "conv_w", "conv_b", "dt_bias", "a_log",
                      "d", "norm", "w_out"),
-             "attn": ("ln", "wq", "wk", "wv", "wo")}
+             "attn": ("ln", "wq", "wk", "wv", "wo"),
+             "swa": gated, "gattn": gated,
+             "dense": ("ln", "w_gate", "w_up", "w_down")}
     specs = {"head": P()}
     for kind in set(cfg.layer_pattern):
         stack = STACK_OF[kind]
         specs[stack] = ({"ln": P(), **_moe_specs(cfg)} if kind == "E"
                         else dict.fromkeys(names[stack], P()))
+        if cfg.post_norm:
+            specs[stack]["ln_post"] = P()
     return specs
 
 
@@ -492,33 +545,107 @@ def gqa(cfg: TransformerConfig, lp, h):
             @ lp["wo"].astype(cd)).astype(jnp.float32)
 
 
-def pattern_layer(cfg: TransformerConfig, kind: str, lp, x):
+def gated_gqa(cfg: TransformerConfig, lp, h, positions, sliding: bool):
+    """Gated grouped-query attention on the normalised stream ``h``:
+    :func:`gqa`'s heads with an RMSNorm over each head's ``q`` and ``k``
+    (one learned gain of ``head_dim`` for all query heads, one for all
+    key heads) and the result multiplied by ``sigmoid(h W_g)`` in float32
+    before the output projection.  ``sliding``: ``q`` and ``k`` take
+    rotary positions (all of ``head_dim``, the half-rotation layout) and
+    query ``i`` sees key ``j`` iff ``0 <= i - j < cfg.window``; else no
+    positions and the whole past.  Returns the f32 output projection."""
+    _whole_axes("gated grouped-query attention", {
+        "tp": "the key/value heads, fewer than the query heads, and the "
+              "gate's columns are not shared out over the tp ranks",
+        "sp": "k and v are broadcast over their query heads before the "
+              "ring, which would pass every key/value head round once "
+              "for each of its query heads, and a window's whole ring "
+              "steps are not skipped"})
+    b, lc, _ = h.shape
+    cd = cfg.compute_dtype
+    nh, hd = cfg.n_heads, cfg.head_dim
+    nkv = cfg.n_kv_heads or nh
+    h = h.astype(cd)
+    q = _rmsnorm((h @ lp["wq"].astype(cd)).reshape(b, lc, nh, hd),
+                 lp["q_norm"], cfg.norm_eps)
+    k = _rmsnorm((h @ lp["wk"].astype(cd)).reshape(b, lc, nkv, hd),
+                 lp["k_norm"], cfg.norm_eps)
+    v = (h @ lp["wv"].astype(cd)).reshape(b, lc, nkv, hd)
+    if sliding:
+        q, k = (rotary(t, positions, cfg.rope_theta, halves=True)
+                for t in (q, k))
+    k, v = (_over_query_heads(t, nh // nkv) for t in (k, v))
+    with jax.named_scope("hvd_attn"):
+        attn = ring_attention(q, k, v, "sp", causal=True,
+                              impl=cfg.attn_impl, recomputed=cfg.remat,
+                              window=cfg.window if sliding else None)
+    gate = jax.nn.sigmoid((h @ lp["wg"].astype(cd)).astype(jnp.float32))
+    attn = attn.reshape(b, lc, nh * hd).astype(jnp.float32) * gate
+    return (attn.astype(cd) @ lp["wo"].astype(cd)).astype(jnp.float32)
+
+
+def pattern_dense(cfg: TransformerConfig, lp, h):
+    """The dense SwiGLU FFN as a layer of a pattern, whose stacks are
+    whole on every chip.  Returns the f32 output."""
+    _whole_axes("a pattern's dense FFN", {
+        "tp": "a pattern's stacks are replicated, so its matrices are "
+              "not split column / row over the tp ranks"})
+    cd = cfg.compute_dtype
+    return moe.swiglu(h.astype(cd), {name: lp[name].astype(cd) for name in
+                                     ("w_gate", "w_up", "w_down")})
+
+
+def pattern_layer(cfg: TransformerConfig, kind: str, lp, x, positions):
     """One layer of a pattern: ``x + f(RMSNorm(x))`` with ``f`` the
-    sub-layer of ``kind``.  Returns ``(x, report)``: the pairs an
-    expert layer's routing sent each of all its experts, a state-space
-    layer's least log-decay, else ``None``."""
+    sub-layer of ``kind`` — ``x + RMSNorm(f(RMSNorm(x)))`` where the
+    configuration has a ``post_norm``.  ``positions``: (lc,) global, for
+    the kind that takes rotary ones.  Returns ``(x, report)``: the pairs
+    an expert layer's routing sent each of all its experts, a
+    state-space layer's least log-decay, else ``None``."""
     h = _rmsnorm(x, lp["ln"], cfg.norm_eps)
+    report = None
     if kind == "M":
         with jax.named_scope("hvd_ssm"):
             out, report = mamba(cfg, lp, h)
     elif kind == "*":
-        out, report = gqa(cfg, lp, h), None
+        out = gqa(cfg, lp, h)
+    elif kind == "S":
+        with jax.named_scope("hvd_swa"):
+            out = gated_gqa(cfg, lp, h, positions, sliding=True)
+    elif kind == "G":
+        with jax.named_scope("hvd_gattn"):
+            out = gated_gqa(cfg, lp, h, positions, sliding=False)
+    elif kind == "D":
+        out = pattern_dense(cfg, lp, h)
     else:
         out, report = expert_ffn(cfg, lp, h, count_all=True)
+        if cfg.post_norm:
+            out = checkpoint_name(out, KEPT_EXPERT_OUT)
+    if cfg.post_norm:
+        out = _rmsnorm(out, lp["ln_post"], cfg.norm_eps)
     return x + out.astype(x.dtype), report
 
 
 # as ``transformer._remat_block``: a recomputed attention layer keeps
-# what its kernel gave, every other layer only its input
+# what its kernel gave, every other layer only its input — and an expert
+# layer whose result goes through a norm before the residual add keeps
+# that result, 134 MB a layer of 16,384 tokens at d 2048: the norm's
+# backward pass reads it, and without the name the replay would run the
+# held experts' sort, gather, grouped products and scatter-add a second
+# time for it (15 grouped products a layer where 12 do; without a norm
+# after it nothing reads the result and the compiler drops that replay)
 _remat_layer = jax.checkpoint(
     pattern_layer, static_argnums=(0, 1),
-    policy=jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES))
+    policy=jax.checkpoint_policies.save_only_these_names(
+        *KEPT_NAMES, KEPT_EXPERT_OUT))
 
 
 def pattern_stack(cfg: TransformerConfig, params, x, pos):
     """Walk the pattern: layer ``i`` is of kind ``layer_pattern[i]`` and
-    takes the next row of its kind's stack.  ``x``: the embedded tokens
-    (b, lc, dm).  Returns ``(x, pos, reports)`` as ``transformer._stack``
+    takes the next row of its kind's stack.  ``x``: the tokens' rows of
+    the embedding (b, lc, dm), float32, which enter the stream times
+    ``embed_scale`` in the compute type; ``pos``: (lc,) global
+    positions.  Returns ``(x, pos, reports)`` as ``transformer._stack``
     does, ``reports`` a dict ``{"loads": [an "E" layer's each],
     "least_log_decay": [an "M" layer's each]}``."""
     if lax.axis_size("pp") > 1:
@@ -526,6 +653,9 @@ def pattern_stack(cfg: TransformerConfig, params, x, pos):
             "a layer pattern under pp > 1 is not supported: its weights "
             "are stacked per kind and not per layer, so a pp shard of a "
             "stack is not a stage's layers; pp > 1 runs the GPT-2 block")
+    if cfg.embed_scale != 1.0:
+        x = x * cfg.embed_scale
+    x = x.astype(cfg.compute_dtype)
     layer = _remat_layer if cfg.remat else pattern_layer
     rows = dict.fromkeys(STACK_OF, 0)
     reports = {"loads": [], "least_log_decay": []}
@@ -533,7 +663,7 @@ def pattern_stack(cfg: TransformerConfig, params, x, pos):
         lp = jax.tree_util.tree_map(lambda a: a[rows[kind]],
                                     params[STACK_OF[kind]])
         rows[kind] += 1
-        x, report = layer(cfg, kind, lp, x)
+        x, report = layer(cfg, kind, lp, x, pos)
         if kind == "E":
             reports["loads"].append(report)
         elif kind == "M":

@@ -26,8 +26,11 @@ prediction module (:mod:`horovod_tpu.models.blocks`, imported only
 where a configuration asks for one of these).  The defaults are the
 GPT-2 block.  With a ``layer_pattern`` a layer is instead ONE
 pre-normed sub-layer with one residual — a Mamba-2 mixer, grouped-query
-attention without positions, or an expert FFN alone — of the kind the
-pattern gives it, its weights stacked per kind (``blocks.pattern_stack``).
+attention without positions, gated grouped-query attention with QK-norm
+under a sliding window with rotary positions or full without, a dense
+SwiGLU FFN or an expert FFN alone — of the kind the pattern gives it,
+its weights stacked per kind (``blocks.pattern_stack``), with a second
+norm after the sub-layer where the configuration states one.
 
 Everything is bf16 matmuls with fp32 accumulation/norms — MXU-native.
 """
@@ -104,9 +107,21 @@ class TransformerConfig:
     # a layer is ONE pre-normed sub-layer with one residual, of the kind
     # the pattern gives it (a string or a tuple, one letter a layer):
     # "M" a Mamba-2 mixer, "*" grouped-query attention without
-    # positions, "E" the expert layer alone.  Empty: the uniform stack
-    # of attention + MLP blocks above.  n_layers is the pattern's length.
+    # positions, "E" the expert layer alone; "S" and "G" gated
+    # grouped-query attention with an RMSNorm on each head's q and k,
+    # "S" under the sliding window with rotary positions, "G" over the
+    # whole past with none; "D" a dense SwiGLU FFN of d_ff
+    # (blocks.STACK_OF is the table).  Empty: the uniform stack of
+    # attention + MLP blocks above.  n_layers is the pattern's length.
     layer_pattern: tuple = ()
+    # "S": query i sees key j iff 0 <= i - j < window
+    window: int = 0
+    # a pattern's layer is x + N_post(f(N_pre(x))): a second learned
+    # RMSNorm on what the sub-layer gives, before the residual add
+    post_norm: bool = False
+    # a pattern's embedding rows are multiplied by this as they enter
+    # the stream, and drawn at 1 / embed_scale
+    embed_scale: float = 1.0
     # the depth the residual stream is drawn for: every sub-layer's
     # out-projection is divided by sqrt(rescale_depth) at initialisation
     # (a pre-norm stack's rule; the published depth where fewer layers
@@ -170,18 +185,27 @@ class TransformerConfig:
                              "n_experts > 0")
 
     def _check_pattern(self):
+        """The pattern's kinds are ``blocks.STACK_OF``'s, and each kind
+        has the sizes it needs."""
+        from horovod_tpu.models.blocks import ATTENTION_KINDS, STACK_OF
+
         kinds = set(self.layer_pattern)
-        if kinds - set("M*E"):
-            raise ValueError(f"layer_pattern holds 'M', '*' and 'E': "
-                             f"{sorted(kinds - set('M*E'))}")
+        if kinds - set(STACK_OF):
+            raise ValueError(
+                f"layer_pattern holds {', '.join(map(repr, STACK_OF))}: "
+                f"{sorted(kinds - set(STACK_OF))}")
         if self.tied_head or self.mtp_depth:
             raise ValueError("a layer pattern needs tied_head=False and "
                              "mtp_depth=0")
         if "E" in kinds and not self.n_experts:
             raise ValueError("'E' layers need n_experts > 0")
-        if "*" in kinds and self.n_heads % (self.n_kv_heads or self.n_heads):
+        if kinds & set(ATTENTION_KINDS) and self.n_heads % (
+                self.n_kv_heads or self.n_heads):
             raise ValueError(f"n_heads {self.n_heads} is no multiple of "
                              f"n_kv_heads {self.n_kv_heads}")
+        if "S" in kinds and (self.window < 1 or self.head_dim % 2):
+            raise ValueError("'S' layers need window >= 1 and an even "
+                             "head_dim (rotary positions)")
         if "M" in kinds and (self.ssm_heads < 1 or self.ssm_head_dim < 1
                              or self.ssm_state < 1
                              or self.ssm_heads % self.ssm_groups):
@@ -235,7 +259,8 @@ def init_params(rng: np.random.RandomState, cfg: TransformerConfig,
     # the token's own row, the tokens of a sequence collapse onto one
     # direction and every one of them picks the same experts (PERF.md
     # section 6, PR 28).
-    p = {"embed": norm(cfg.vocab, dm, scale=0.02 if cfg.tied_head else 1.0)}
+    p = {"embed": norm(cfg.vocab, dm, scale=(0.02 if cfg.tied_head
+                                             else 1.0 / cfg.embed_scale))}
     if cfg.layer_pattern:
         from horovod_tpu.models import blocks
 
@@ -362,8 +387,8 @@ def _stack(params, tokens, cfg: TransformerConfig):
     if cfg.layer_pattern:
         from horovod_tpu.models import blocks
 
-        return blocks.pattern_stack(cfg, params,
-                                    params["embed"][tokens].astype(cd), pos)
+        return blocks.pattern_stack(cfg, params, params["embed"][tokens],
+                                    pos)
     if cfg.attention == "mha":
         x = (params["embed"][tokens] + params["pos"][pos]).astype(cd)
     else:
@@ -575,6 +600,43 @@ def record_scan(cfg: TransformerConfig, least_log_decay) -> list:
                for i, least in enumerate(np.asarray(least_log_decay))]
     for record in records:
         flight.record("hvd_ssm_scan", **record)
+    return records
+
+
+def record_attention(cfg: TransformerConfig, batch: int) -> list:
+    """Write one ``hvd_attn_window`` record an attention layer of the
+    pattern to the flight ring: ``layer``, ``layer_kind``, ``window``
+    (0 = none: the causal bound alone), ``seq`` (``max_seq``, one
+    chip's: ``sp`` 1), the path ``impl`` and the tiles ``block_q`` x
+    ``block_k`` that ``ring_attention`` picks for ``batch`` sequences,
+    and the ``grid`` / ``live`` / ``masked`` tile pairs of one head's
+    call at those tiles (``pallas_attention.causal_tile_counts``: the
+    steps there are, those that do any work, those that build a mask).
+    Returns the records."""
+    from horovod_tpu.models.blocks import ATTENTION_KINDS
+    from horovod_tpu.ops.pallas_attention import causal_tile_counts
+    from horovod_tpu.parallel.ring_attention import _block_sizes, auto_impl
+    from horovod_tpu.runtime import flight
+
+    seq = cfg.max_seq
+    impl = cfg.attn_impl or (auto_impl(batch, cfg.n_heads, seq)
+                             if jax.default_backend() == "tpu" else "xla")
+    bq, bk = _block_sizes(seq, seq, cfg.head_dim,
+                          cfg.compute_dtype.itemsize)
+    records = []
+    for layer, kind in enumerate(cfg.layer_pattern):
+        if kind not in ATTENTION_KINDS:
+            continue
+        window = cfg.window if kind == "S" else None
+        grid, live, masked = (causal_tile_counts(seq, seq, bq, bk,
+                                                 window=window)
+                              if bq and bk else (0, 0, 0))
+        records.append(dict(layer=layer, layer_kind=kind,
+                            window=window or 0, seq=seq, impl=impl,
+                            block_q=bq or 0, block_k=bk or 0, grid=grid,
+                            live=live, masked=masked))
+    for record in records:
+        flight.record("hvd_attn_window", **record)
     return records
 
 

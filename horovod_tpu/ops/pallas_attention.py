@@ -18,9 +18,17 @@ cost), keeps softmax state in fp32 VMEM scratch across the innermost
 K-grid dimension, and applies block-level causal masking from *global*
 sequence offsets (the carried state is what makes it composable with
 the ring — a plain fused attention kernel could not resume from a
-previous block's state).  A tile pair the causal mask hides whole is
-neither computed nor fetched, and only the pairs the diagonal crosses
-build the mask (:func:`causal_tile_counts` says how many of each).
+previous block's state).  A sliding ``window`` (a static Python int) is
+a second bound on the same predicate: query ``i`` sees key ``j`` iff
+``0 <= i - j < window``, the causal bound and the window's trailing
+edge.  A tile pair the mask hides whole — past the diagonal or behind
+the window — is neither computed nor fetched (the index maps clamp a
+row's dead steps onto its first or last live tile), and only the pairs
+the diagonal or the trailing edge crosses build the mask
+(:func:`causal_tile_counts` says how many of each).  A windowed call's
+kernels carry names of their own (``hvd_flash_fwd_win`` ...), so that a
+trace tells them from the plain calls; without a window the kernels
+trace what they traced before there was one.
 
 The state goes through HBM only between ring steps.  At the ends of the
 ring the kernel does the state's work where the state is, in VMEM
@@ -88,64 +96,95 @@ def tile_vmem_bytes(bq: int, bk: int, d: int, itemsize: int,
     return (tiles + blocks + scratch) * 5 // 4     # a quarter of headroom
 
 
-def _tile_live(q_start, k_start, bq: int):
-    """A (Q tile, K tile) pair is live iff the causal mask on global
-    positions leaves it any probability: its first key is no later
-    than its last query.  Python ints or traced scalars."""
-    return k_start <= q_start + (bq - 1)
+def _tile_live(q_start, k_start, bq: int, bk: int | None = None,
+               window: int | None = None):
+    """A (Q tile, K tile) pair is live iff the mask on global positions
+    leaves it any probability: its first key is no later than its last
+    query and, under a ``window``, its last key no further back than
+    ``window - 1`` from its first query.  Python ints or traced
+    scalars."""
+    live = k_start <= q_start + (bq - 1)
+    if window is not None:
+        live = live & (k_start + (bk - 1) >= q_start - (window - 1))
+    return live
 
 
 def _tile_diagonal(q_start, k_start, bk: int):
-    """The pair needs the mask iff its last key is later than its first
-    query; a live pair that does not lies wholly in the past.  (A dead
-    pair always "needs" it.)"""
+    """The pair needs the causal mask iff its last key is later than
+    its first query; a live pair that does not lies wholly in the past.
+    (A dead pair always "needs" it.)"""
     return k_start + (bk - 1) > q_start
 
 
+def _tile_trailing(q_start, k_start, bq: int, window: int):
+    """The pair needs the window's mask iff its first key lies further
+    back than ``window - 1`` from its last query: the window's trailing
+    edge crosses it (or, of a dead pair, lies past it)."""
+    return k_start < q_start + (bq - 1) - (window - 1)
+
+
 def causal_tile_counts(lq: int, lk: int, bq: int, bk: int,
-                       q_offset: int = 0, k_offset: int = 0):
-    """``(grid, live, diagonal)`` tile pairs of one head's causal call:
-    how many grid steps there are, how many do any work, and how many
-    of those build the mask.  Seq 8192 in 1024×1024 tiles: 64 / 36 / 8;
-    in 128×128 tiles 4,096 / 2,080 / 64."""
-    grid = live = diagonal = 0
+                       q_offset: int = 0, k_offset: int = 0,
+                       window: int | None = None):
+    """``(grid, live, masked)`` tile pairs of one head's causal call,
+    under a sliding ``window`` where one is given (query ``i`` sees key
+    ``j`` iff ``0 <= i - j < window``): how many grid steps there are,
+    how many do any work, and how many of those build the mask (the
+    diagonal crosses them, or the window's trailing edge).  Seq 8192 in
+    1024×1024 tiles: 64 / 36 / 8; in 128×128 tiles 4,096 / 2,080 / 64.
+    Seq 16,384 in 1024×1024 tiles: 256 / 136 / 16, and under a window
+    of 2,048 256 / 45 / 30."""
+    grid = live = masked = 0
     for q_start in range(q_offset, q_offset + lq, bq):
         for k_start in range(k_offset, k_offset + lk, bk):
             grid += 1
-            if _tile_live(q_start, k_start, bq):
+            if _tile_live(q_start, k_start, bq, bk, window):
                 live += 1
-                diagonal += _tile_diagonal(q_start, k_start, bk)
-    return grid, live, diagonal
+                masked += bool(
+                    _tile_diagonal(q_start, k_start, bk)
+                    or (window is not None
+                        and _tile_trailing(q_start, k_start, bq, window)))
+    return grid, live, masked
 
 
-def _on_live_tile(off_ref, iq, ik, bq: int, bk: int, causal: bool, body):
+def _on_live_tile(off_ref, iq, ik, bq: int, bk: int, causal: bool, body,
+                  window: int | None = None):
     """Run ``body(mask)`` for tile pair (iq, ik): not at all where the
-    causal mask hides the whole pair, with the (bq, bk) bool mask where
-    the diagonal crosses it, and with ``None`` where nothing is hidden
-    (no score is -inf there, so the body drops its guards too)."""
+    mask hides the whole pair, with the (bq, bk) bool mask where the
+    diagonal or the window's trailing edge crosses it, and with ``None``
+    where nothing is hidden (no score is -inf there, so the body drops
+    its guards too)."""
     if not causal:
         body(None)
         return
     q_start = off_ref[0] + iq * bq
     k_start = off_ref[1] + ik * bk
-    diagonal = _tile_diagonal(q_start, k_start, bk)
+    masked = _tile_diagonal(q_start, k_start, bk)
+    live = _tile_live(q_start, k_start, bq, bk, window)
+    if window is not None:
+        masked = masked | _tile_trailing(q_start, k_start, bq, window)
 
-    @pl.when(_tile_live(q_start, k_start, bq) & diagonal)
+    @pl.when(live & masked)
     def _():
         qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        body(qpos >= kpos)
+        seen = qpos >= kpos
+        if window is not None:
+            seen = seen & (qpos - kpos < window)
+        body(seen)
 
-    @pl.when(jnp.logical_not(diagonal))        # implies live
+    @pl.when(jnp.logical_not(masked))          # implies live
     def _():
         body(None)
 
 
-def _q_major_maps(bq: int, bk: int, causal: bool):
+def _q_major_maps(bq: int, bk: int, causal: bool, nk: int,
+                  window: int | None = None):
     """Index maps ``(q_row, kv_row)`` of the (B*H, nq, nk) grid the
     forward and dQ kernels share.  The K/V map clamps ``ik`` to the Q
-    row's last live tile, so that the dead steps after it name the
-    block already in VMEM and fetch nothing."""
+    row's last live tile and, under a ``window``, to its first, so that
+    the dead steps after and before them name a block that is or will
+    be in VMEM and fetch nothing of their own."""
     def q_row(b, iq, ik, off_ref):
         return b, iq, 0
 
@@ -153,9 +192,49 @@ def _q_major_maps(bq: int, bk: int, causal: bool):
         if causal:
             last_q = off_ref[0] - off_ref[1] + (iq + 1) * bq - 1
             ik = jnp.minimum(ik, jax.lax.div(jnp.maximum(last_q, 0), bk))
+        if window is not None:
+            # the first key the row's first query sees
+            first_q = off_ref[0] - off_ref[1] + iq * bq - (window - 1)
+            first = jax.lax.div(jnp.maximum(first_q, 0), bk)
+            ik = jnp.maximum(ik, jnp.minimum(first, nk - 1))
         return b, ik, 0
 
     return q_row, kv_row
+
+
+def _k_major_maps(bq: int, bk: int, causal: bool, nq: int,
+                  window: int | None = None):
+    """Index maps ``(q_row, kv_row)`` of the (B*H, nk, nq) grid of the
+    dK/dV kernel.  The Q map clamps ``iq`` up to the K column's first
+    live tile — the dead steps before it fetch that tile's blocks once,
+    and no others — and, under a ``window``, down to its last: the last
+    query that still sees the column's last key."""
+    def q_row(b, ik, iq, off_ref):
+        if window is not None:
+            last_k = (off_ref[1] - off_ref[0] + (ik + 1) * bk - 1
+                      + (window - 1))
+            iq = jnp.minimum(iq, jax.lax.div(jnp.maximum(last_k, 0), bq))
+        if causal:
+            first_k = off_ref[1] - off_ref[0] + ik * bk
+            first = jax.lax.div(jnp.maximum(first_k, 0), bq)
+            iq = jnp.maximum(iq, jnp.minimum(first, nq - 1))
+        return b, iq, 0
+
+    def kv_row(b, ik, iq, off_ref):
+        return b, ik, 0
+
+    return q_row, kv_row
+
+
+def _kernel_name(name: str, window: int | None) -> str:
+    return name if window is None else name + "_win"
+
+
+def _check_window(causal: bool, window: int | None) -> None:
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"a sliding window is a bound beside the causal "
+                         f"one: it needs causal=True and window >= 1, got "
+                         f"causal={causal}, window={window}")
 
 
 def _compiler_params(bq: int, bk: int, d: int, dv: int, dtype):
@@ -172,7 +251,7 @@ def _compiler_params(bq: int, bk: int, d: int, dv: int, dtype):
 
 def _flash_step_kernel(off_ref, q_ref, k_ref, v_ref, *refs, causal: bool,
                        scale: float, bq: int, bk: int, first: bool,
-                       last: bool):
+                       last: bool, window: int | None = None):
     """Grid: (B*H, nq, nk) — nk innermost so (m_s, l_s, acc) scratch
     carries across the K blocks of one Q block.  ``refs`` are the
     carried state in (packed m|l, o; none on a ring's ``first`` step,
@@ -229,7 +308,8 @@ def _flash_step_kernel(off_ref, q_ref, k_ref, v_ref, *refs, causal: bool,
         l_s[:, :] = l_new[:, None] + jnp.zeros_like(l_s)
         acc[:, :] = acc[:, :] * alpha[:, None] + pv
 
-    _on_live_tile(off_ref, pl.program_id(1), ik, bq, bk, causal, accumulate)
+    _on_live_tile(off_ref, pl.program_id(1), ik, bq, bk, causal, accumulate,
+                  window)
 
     @pl.when(ik == nk - 1)
     def _():
@@ -279,17 +359,20 @@ _entry_point = functools.partial(jax.jit, inline=True)
 
 
 @_entry_point(static_argnames=(
-    "causal", "block_q", "block_k", "last", "interpret"))
+    "causal", "block_q", "block_k", "last", "interpret", "window"))
 def flash_fwd_step(q, k, v, state, q_offset, k_offset, *,
                    causal: bool = True, block_q: int = 128,
                    block_k: int = 128, last: bool = False,
-                   interpret: bool | None = None):
+                   interpret: bool | None = None,
+                   window: int | None = None):
     """One step of a ring's forward pass: attend local Q against one
     KV block.
 
     q: (BH, Lq, D); k: (BH, Lk, D); v: (BH, Lk, Dv), Dv any size (a
     latent-attention head has 192 for q/k and 128 for v).
     q_offset / k_offset: global positions of q[:,0]/k[:,0] (traced OK).
+    ``window``: a static sliding window on those positions (query ``i``
+    sees key ``j`` iff ``0 <= i - j < window``), or None.
     ``state``: the carried ``(m, l, o)`` (m, l: (BH, Lq) fp32 running
     max / denominator; o: (BH, Lq, Dv) fp32 unnormalized numerator),
     or None on the ring's first step — the kernel then starts from
@@ -310,10 +393,11 @@ def flash_fwd_step(q, k, v, state, q_offset, k_offset, *,
     offs = jnp.asarray([q_offset, k_offset], jnp.int32)
     first = state is None
 
+    _check_window(causal, window)
     kernel = functools.partial(_flash_step_kernel, causal=causal,
                                scale=scale, bq=bq, bk=bk, first=first,
-                               last=last)
-    q_row, kv_row = _q_major_maps(bq, bk, causal)
+                               last=last, window=window)
+    q_row, kv_row = _q_major_maps(bq, bk, causal, lk // bk, window)
     operands = [q, k, v]
     in_specs = [
         pl.BlockSpec((1, bq, d), q_row),      # q
@@ -335,7 +419,7 @@ def flash_fwd_step(q, k, v, state, q_offset, k_offset, *,
 
     stat, *o = pl.pallas_call(
         kernel,
-        name="hvd_flash_fwd",
+        name=_kernel_name("hvd_flash_fwd", window),
         # the offsets are prefetched scalars: the K/V index map reads
         # them to skip the fetch of dead tiles
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -385,7 +469,7 @@ def _recomputed_p_ds(q, k, v, do, ld, mask, scale):
 
 def _flash_bwd_dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
                          dq_ref, dq_acc, *, causal: bool, scale: float,
-                         bq: int, bk: int):
+                         bq: int, bk: int, window: int | None = None):
     """dQ backward: grid (B*H, nq, nk), nk innermost so dq_acc carries
     across the K blocks of one Q block (zero for a row whose every
     tile is dead)."""
@@ -404,7 +488,8 @@ def _flash_bwd_dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)        # (bq, d)
 
-    _on_live_tile(off_ref, pl.program_id(1), ik, bq, bk, causal, accumulate)
+    _on_live_tile(off_ref, pl.program_id(1), ik, bq, bk, causal, accumulate,
+                  window)
 
     @pl.when(ik == nk - 1)
     def _():
@@ -413,7 +498,8 @@ def _flash_bwd_dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
 
 def _flash_bwd_dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, causal: bool,
-                          scale: float, bq: int, bk: int):
+                          scale: float, bq: int, bk: int,
+                          window: int | None = None):
     """dK/dV backward: grid (B*H, nk, nq), nq innermost so the dk/dv
     accumulators carry across the Q blocks of one KV block."""
     iq = pl.program_id(2)
@@ -436,7 +522,8 @@ def _flash_bwd_dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)        # (bk, d)
 
-    _on_live_tile(off_ref, iq, pl.program_id(1), bq, bk, causal, accumulate)
+    _on_live_tile(off_ref, iq, pl.program_id(1), bq, bk, causal, accumulate,
+                  window)
 
     @pl.when(iq == nq - 1)
     def _():
@@ -445,11 +532,12 @@ def _flash_bwd_dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
 
 
 @_entry_point(static_argnames=(
-    "causal", "block_q", "block_k", "out_dtype", "interpret"))
+    "causal", "block_q", "block_k", "out_dtype", "interpret", "window"))
 def flash_bwd_dq(q, k, v, do, lse, delta, q_offset, k_offset, *,
                  causal: bool = True, block_q: int = 128,
                  block_k: int = 128, out_dtype=jnp.float32,
-                 interpret: bool | None = None):
+                 interpret: bool | None = None,
+                 window: int | None = None):
     """Flash-attention dQ for one (local Q, one KV block) pair.
 
     q: (BH, Lq, D); k: (BH, Lk, D); v: (BH, Lk, Dv); do: (BH, Lq, Dv)
@@ -459,7 +547,8 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_offset, k_offset, *,
     Returns (BH, Lq, D) in ``out_dtype`` — the dQ contribution of this
     KV block: fp32 where the caller sums over ring steps, the operands'
     type in a one-step ring (the accumulator is fp32 in VMEM either
-    way and is rounded once, as it is written out).
+    way and is rounded once, as it is written out).  ``window``: as
+    :func:`flash_fwd_step`'s.
     """
     bh, lq, d = q.shape
     _, lk, dv = v.shape
@@ -468,13 +557,14 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_offset, k_offset, *,
     scale = 1.0 / (d ** 0.5)
     offs = jnp.asarray([q_offset, k_offset], jnp.int32)
     ld = _pack_rows(lse, delta, bh, lq)
+    _check_window(causal, window)
     kernel = functools.partial(_flash_bwd_dq_kernel, causal=causal,
-                               scale=scale, bq=bq, bk=bk)
-    q_row, kv_row = _q_major_maps(bq, bk, causal)
+                               scale=scale, bq=bq, bk=bk, window=window)
+    q_row, kv_row = _q_major_maps(bq, bk, causal, lk // bk, window)
 
     return pl.pallas_call(
         kernel,
-        name="hvd_flash_bwd_dq",
+        name=_kernel_name("hvd_flash_bwd_dq", window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, lq // bq, lk // bk),
@@ -494,11 +584,12 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_offset, k_offset, *,
 
 
 @_entry_point(static_argnames=(
-    "causal", "block_q", "block_k", "out_dtype", "interpret"))
+    "causal", "block_q", "block_k", "out_dtype", "interpret", "window"))
 def flash_bwd_dkv(q, k, v, do, lse, delta, q_offset, k_offset, *,
                   causal: bool = True, block_q: int = 128,
                   block_k: int = 128, out_dtype=jnp.float32,
-                  interpret: bool | None = None):
+                  interpret: bool | None = None,
+                  window: int | None = None):
     """Flash-attention (dK, dV) for one (local Q, one KV block) pair.
 
     Same contract as :func:`flash_bwd_dq`; returns
@@ -513,28 +604,17 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, q_offset, k_offset, *,
     scale = 1.0 / (d ** 0.5)
     offs = jnp.asarray([q_offset, k_offset], jnp.int32)
     ld = _pack_rows(lse, delta, bh, lq)
+    _check_window(causal, window)
     kernel = functools.partial(_flash_bwd_dkv_kernel, causal=causal,
-                               scale=scale, bq=bq, bk=bk)
-    nq = lq // bq
-
-    def q_row(b, ik, iq, off_ref):
-        # iq clamped up to the K column's first live tile: the dead
-        # steps before it fetch that tile's blocks once, and no others
-        if causal:
-            first_k = off_ref[1] - off_ref[0] + ik * bk
-            first = jax.lax.div(jnp.maximum(first_k, 0), bq)
-            iq = jnp.maximum(iq, jnp.minimum(first, nq - 1))
-        return b, iq, 0
-
-    def kv_row(b, ik, iq, off_ref):
-        return b, ik, 0
+                               scale=scale, bq=bq, bk=bk, window=window)
+    q_row, kv_row = _k_major_maps(bq, bk, causal, lq // bq, window)
 
     return pl.pallas_call(
         kernel,
-        name="hvd_flash_bwd_dkv",
+        name=_kernel_name("hvd_flash_bwd_dkv", window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, lk // bk, nq),
+            grid=(bh, lk // bk, lq // bq),
             in_specs=[
                 pl.BlockSpec((1, bq, d), q_row),      # q
                 pl.BlockSpec((1, bk, d), kv_row),     # k

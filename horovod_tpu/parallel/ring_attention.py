@@ -62,13 +62,15 @@ KEPT_NAMES = ("hvd_attn_out", "hvd_attn_lse")
 
 
 def xla_block_step(q, k, v, m, l, o, q_offset, k_offset, *,
-                   causal: bool):
+                   causal: bool, window: int | None = None):
     """One online-softmax accumulation in the packed layout.
 
     q: (BH, Lq, D); k: (BH, Lk, D); v: (BH, Lk, Dv), Dv any size;
     m/l: (BH, Lq) fp32 running max/denominator; o: (BH, Lq, Dv) fp32
     unnormalized numerator.
     q_offset/k_offset: global positions of q[:, 0] / k[:, 0].
+    ``window``: query ``i`` sees key ``j`` iff ``0 <= i - j < window``
+    (a second bound beside the causal one; None: the causal bound alone).
     Matmuls stay in the input dtype (bf16-friendly), softmax state fp32.
     """
     lq, lk = q.shape[1], k.shape[1]
@@ -77,7 +79,10 @@ def xla_block_step(q, k, v, m, l, o, q_offset, k_offset, *,
     if causal:
         qpos = q_offset + jnp.arange(lq)
         kpos = k_offset + jnp.arange(lk)
-        s = jnp.where(qpos[:, None] >= kpos[None, :], s, -jnp.inf)
+        seen = qpos[:, None] >= kpos[None, :]
+        if window is not None:
+            seen = seen & (qpos[:, None] - kpos[None, :] < window)
+        s = jnp.where(seen, s, -jnp.inf)
     m_cur = jnp.max(s, axis=-1)                      # (BH, Lq)
     m_new = jnp.maximum(m, m_cur)
     # guard fully-masked rows (max = -inf)
@@ -164,7 +169,8 @@ def _ring_rotate(axis_name, *blocks):
     return tuple(lax.ppermute(x, axis_name, rot) for x in blocks)
 
 
-def _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, bq, bk):
+def _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, bq, bk,
+                         window=None):
     """Pallas ring forward, returning (normalized fp32 out, lse, out in
     the operands' type).
 
@@ -184,7 +190,8 @@ def _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, bq, bk):
     def step(j, state, kj, vj, last=False):
         qo, ko = _ring_offsets(j, axis_name, lc, causal)
         return flash_fwd_step(qp, kj, vj, state, qo, ko, causal=causal,
-                              block_q=bq, block_k=bk, last=last)
+                              block_q=bq, block_k=bk, last=last,
+                              window=window)
 
     if sp == 1:
         return step(0, None, kp, vp, last=True)
@@ -198,8 +205,9 @@ def _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, bq, bk):
     return step(sp - 1, state, kj, vj, last=True)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _ring_flash(qp, kp, vp, axis_name, causal, bq, bk, recomputed):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _ring_flash(qp, kp, vp, axis_name, causal, bq, bk, recomputed,
+                window=None):
     """Differentiable Pallas ring attention on packed (B*H, Lc, D)
     operands, returning (B*H, Lc, Dv) in their type: forward saves only
     (q, k, v, out, lse), ``out`` in fp32 (``delta`` = rowsum(dO ∘ out)
@@ -218,20 +226,26 @@ def _ring_flash(qp, kp, vp, axis_name, causal, bq, bk, recomputed):
     kernel's own third result is, bit for bit: the replay is left with
     nothing that reads the kernel and runs none.  Both halves are
     needed: with the rule returning the kernel's result the replay
-    runs the kernel for it, names or no names."""
-    return _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, bq, bk)[2]
+    runs the kernel for it, names or no names.
+
+    ``window`` (static) is the sliding window on global positions that
+    all three kernels mask and skip tiles by, or None."""
+    return _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, bq, bk,
+                                window)[2]
 
 
-def _ring_flash_fwd(qp, kp, vp, axis_name, causal, bq, bk, recomputed):
+def _ring_flash_fwd(qp, kp, vp, axis_name, causal, bq, bk, recomputed,
+                    window):
     out, lse, out_q = _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal,
-                                           bq, bk)
+                                           bq, bk, window)
     if recomputed:
         out, lse = map(checkpoint_name, (out, lse), KEPT_NAMES)
         out_q = out.astype(qp.dtype)
     return out_q, (qp, kp, vp, out, lse)
 
 
-def _ring_flash_bwd(axis_name, causal, bq, bk, recomputed, res, dout):
+def _ring_flash_bwd(axis_name, causal, bq, bk, recomputed, window, res,
+                    dout):
     from horovod_tpu.ops.pallas_attention import (flash_bwd_dkv,
                                                   flash_bwd_dq)
 
@@ -246,7 +260,7 @@ def _ring_flash_bwd(axis_name, causal, bq, bk, recomputed, res, dout):
         """This step's (dQ, dK, dV) contributions."""
         qo, ko = _ring_offsets(j, axis_name, lc, causal)
         tiles = dict(causal=causal, block_q=bq, block_k=bk,
-                     out_dtype=out_dtype)
+                     out_dtype=out_dtype, window=window)
         return (flash_bwd_dq(qp, kj, vj, dout, lse, delta, qo, ko, **tiles),
                 *flash_bwd_dkv(qp, kj, vj, dout, lse, delta, qo, ko,
                                **tiles))
@@ -281,7 +295,7 @@ _ring_flash.defvjp(_ring_flash_fwd, _ring_flash_bwd)
 
 def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
                    impl: str | None = None, layout: str = "contiguous",
-                   recomputed: bool = False):
+                   recomputed: bool = False, window: int | None = None):
     """Multi-head attention with the sequence sharded over ``axis_name``.
 
     q, k: (B, Lc, H, D), v: (B, Lc, H, Dv) — the local sequence chunk
@@ -294,6 +308,15 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
     :data:`KEPT_NAMES` (the Pallas path's ``out`` and ``lse``; see
     :func:`_ring_flash`).  The XLA path has no names: a recomputed block
     keeps only its input there.
+
+    ``window``: a sliding window on global positions, a static Python
+    int: query ``i`` sees key ``j`` iff ``0 <= i - j < window`` (itself
+    and the ``window - 1`` before it), a second bound beside the causal
+    one, which it needs.  Both paths mask by it; the kernels also skip
+    and do not fetch the tile pairs it hides, and carry names of their
+    own (``hvd_flash_fwd_win`` ...).  Over a ring every step still
+    runs: whole steps behind the window are not skipped.  ``None``: the
+    causal bound alone, traced as before there was a window.
 
     ``layout``: how the global sequence maps onto ranks.
 
@@ -314,6 +337,11 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
     if layout not in ("contiguous", "zigzag"):
         raise ValueError(f"ring_attention layout must be 'contiguous' or "
                          f"'zigzag', got {layout!r}")
+    if window is not None and (not causal or window < 1
+                               or layout != "contiguous"):
+        raise ValueError("a sliding window needs causal=True, window >= 1 "
+                         f"and the contiguous layout, got causal={causal}, "
+                         f"window={window}, layout={layout!r}")
     if layout == "zigzag":
         return _ring_attention_zigzag(q, k, v, axis_name, causal)
     sp = lax.axis_size(axis_name)
@@ -348,7 +376,8 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
         qp = q.transpose(0, 2, 1, 3).reshape(b * h, lc, d)
         kp = k.transpose(0, 2, 1, 3).reshape(b * h, lc, d)
         vp = v.transpose(0, 2, 1, 3).reshape(b * h, lc, dv)
-        out = _ring_flash(qp, kp, vp, axis_name, causal, bq, bk, recomputed)
+        out = _ring_flash(qp, kp, vp, axis_name, causal, bq, bk, recomputed,
+                          window)
         return out.reshape(b, h, lc, dv).transpose(0, 2, 1, 3)
 
     qp = q.transpose(0, 2, 1, 3).reshape(b * h, lc, d)
@@ -367,7 +396,7 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
         # chain (see _ring_flash_fwd_impl).
         qo, ko = (idx * lc, ((idx - j) % sp) * lc) if causal else (0, 0)
         m, l, o = xla_block_step(qp, kj, vj, m, l, o, qo, ko,
-                                 causal=causal)
+                                 causal=causal, window=window)
         # Rotate KV around the ring (overlaps next block's compute).
         kj = lax.ppermute(kj, axis_name, rot)
         vj = lax.ppermute(vj, axis_name, rot)
@@ -380,11 +409,12 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
 
 
 def blockwise_attention(q, k, v, causal: bool = True,
-                        block_k: int = 512):
+                        block_k: int = 512, window: int | None = None):
     """Single-device flash-style attention: online softmax over KV
     blocks, O(L * block_k) memory instead of the O(L^2) score matrix.
     q/k/v: (B, L, H, D); returns (B, L, H, D).  The local building
-    block Ulysses runs after its head-scatter."""
+    block Ulysses runs after its head-scatter.  ``window``: as
+    :func:`ring_attention`'s (every block is still visited)."""
     b, l_, h, d = q.shape
     bk = min(block_k, l_)
     while l_ % bk:
@@ -403,7 +433,7 @@ def blockwise_attention(q, k, v, causal: bool = True,
         kj = lax.dynamic_slice_in_dim(kp, j * bk, bk, axis=1)
         vj = lax.dynamic_slice_in_dim(vp, j * bk, bk, axis=1)
         return xla_block_step(qp, kj, vj, m, l, o, 0, j * bk,
-                              causal=causal)
+                              causal=causal, window=window)
 
     m, l, o = lax.fori_loop(0, n_blocks, step, (m0, l0, o0))
     l = jnp.where(l == 0.0, 1.0, l)
@@ -521,12 +551,17 @@ def _ring_attention_zigzag(q, k, v, axis_name: str, causal: bool):
     return out.astype(q.dtype)
 
 
-def reference_attention(q, k, v, causal: bool = True):
-    """Dense single-device attention for tests: (B, L, H, D) global."""
+def reference_attention(q, k, v, causal: bool = True,
+                        window: int | None = None):
+    """Dense single-device attention for tests: (B, L, H, D) global.
+    ``window``: query ``i`` sees key ``j`` iff ``0 <= i - j < window``
+    (with ``causal``), the golden model of the kernels' second bound."""
     b, l_, h, d = q.shape
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) / (d ** 0.5)
     if causal:
         mask = jnp.tril(jnp.ones((l_, l_), bool))
+        if window is not None:
+            mask = mask & ~jnp.tril(jnp.ones((l_, l_), bool), -window)
         s = jnp.where(mask[None, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v).astype(q.dtype)

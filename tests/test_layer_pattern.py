@@ -185,16 +185,18 @@ def test_a_configuration_without_a_pattern_imports_nothing_of_it():
     assert proc.returncode == 0, proc.stderr[-2000:]
 
 
-def test_transformer_lm_example_takes_a_layer_pattern():
+@pytest.mark.parametrize("pattern", ["MEM*E", "SDSEGE"])
+def test_transformer_lm_example_takes_a_layer_pattern(pattern):
     """The user's entry point outside the harness: ``--layer-pattern``
-    with ``--experts`` on a dp mesh."""
+    with ``--experts`` on a dp mesh; the hybrid kinds, and the window /
+    full attention ones with a norm after every sub-layer."""
     env = dict(os.environ, HOROVOD_PLATFORM="cpu", HOROVOD_SIZE="1",
                XLA_FLAGS="--xla_force_host_platform_device_count=2",
                PYTHONPATH=REPO + os.pathsep + os.environ.get("PYTHONPATH", ""))
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "examples", "transformer_lm.py"),
          "--dp", "2", "--steps", "2", "--d-model", "32", "--seq", "16",
-         "--batch", "4", "--layer-pattern", "MEM*E", "--experts", "2"],
+         "--batch", "4", "--layer-pattern", pattern, "--experts", "2"],
         env=env, cwd=REPO, capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, (proc.stdout, proc.stderr[-3000:])
     assert "loss" in proc.stdout.lower()

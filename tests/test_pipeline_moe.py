@@ -265,15 +265,19 @@ def ep_mesh():
 FORMS = ("swiglu", "relu2")
 
 
-def _layer_params(seed, first=0, held=E, shared=True, form="swiglu"):
-    """A layer's weights, float32: the router and bias over all ``E``
-    experts, the experts ``first .. first + held``, the shared one.
-    ``form`` "relu2" leaves the gate matrices out: two-matrix experts."""
+def _layer_params(seed, first=0, held=None, shared=True, form="swiglu",
+                  n_experts=E):
+    """A layer's weights, float32: the router and bias over all
+    ``n_experts`` experts, the experts ``first .. first + held`` (all of
+    them where ``held`` is not given), the shared one.  ``form`` "relu2"
+    leaves the gate matrices out: two-matrix experts."""
     rng = np.random.RandomState(seed)
-    p = {"router": rng.randn(DIM, E) * 0.5, "bias": rng.randn(E) * 0.1,
-         "experts": {"w_gate": rng.randn(E, DIM, FFH) * 0.3,
-                     "w_up": rng.randn(E, DIM, FFH) * 0.3,
-                     "w_down": rng.randn(E, FFH, DIM) * 0.3},
+    held = n_experts if held is None else held
+    p = {"router": rng.randn(DIM, n_experts) * 0.5,
+         "bias": rng.randn(n_experts) * 0.1,
+         "experts": {"w_gate": rng.randn(n_experts, DIM, FFH) * 0.3,
+                     "w_up": rng.randn(n_experts, DIM, FFH) * 0.3,
+                     "w_down": rng.randn(n_experts, FFH, DIM) * 0.3},
          "shared": {"w_gate": rng.randn(DIM, FFH) * 0.3,
                     "w_up": rng.randn(DIM, FFH) * 0.3,
                     "w_down": rng.randn(FFH, DIM) * 0.3}}
@@ -354,31 +358,38 @@ def test_moe_grads_flow(ep_mesh, monkeypatch):
                                    atol=2e-5)
 
 
-@pytest.mark.parametrize("form", FORMS)
-def test_the_shares_of_a_layer_add_up_to_the_layer(form):
+@pytest.mark.parametrize("form, n_experts, top_k, scale, shares", [
+    *((form, E, TOP_K, SCALE, (E, E // 4)) for form in FORMS),
+    ("swiglu", 128, 8, 2.826, (16,))],
+    ids=[*FORMS, "top-8-of-128-in-16-shares"])
+def test_the_shares_of_a_layer_add_up_to_the_layer(form, n_experts, top_k,
+                                                   scale, shares):
     """One chip at a time, no exchange: the 16 shares of a 16-expert
     layer (one expert each, told which), with the shared expert, which
     every chip computes alike, counted once, add up to what the uncut
-    reference gives; so do 4 shares of 4.  What a share leaves out is
+    reference gives; so do 4 shares of 4, and the 16 shares of 8 of a
+    128-expert layer routed top-8 at scale 2.826 (the window / full
+    attention expert configuration's cut).  What a share leaves out is
     exactly the other experts' part.  SwiGLU experts and two-matrix
     relu^2 ones: the layer and the reference read the form from the
     weights."""
     x = jnp.asarray(np.random.RandomState(6).randn(T, DIM), jnp.float32)
-    whole = _layer_params(7, form=form)
-    ref = moe.moe_reference(x, whole, top_k=TOP_K, scale=SCALE)
+    whole = _layer_params(7, form=form, n_experts=n_experts)
+    ref = moe.moe_reference(x, whole, top_k=top_k, scale=scale)
     shared = getattr(moe, form)(x, whole["shared"])
-    for held in (1, 4):
+    for held in (n_experts // n for n in shares):
         total, sent = shared, 0
-        for first in range(0, E, held):
-            share = _layer_params(7, first, held, shared=False, form=form)
-            out, pairs = moe.moe_layer(x, share, top_k=TOP_K, scale=SCALE,
+        for first in range(0, n_experts, held):
+            share = _layer_params(7, first, held, shared=False, form=form,
+                                  n_experts=n_experts)
+            out, pairs = moe.moe_layer(x, share, top_k=top_k, scale=scale,
                                        first=first)
-            part = moe.moe_reference(x, share, top_k=TOP_K, scale=SCALE,
+            part = moe.moe_reference(x, share, top_k=top_k, scale=scale,
                                      first=first)
             np.testing.assert_allclose(np.asarray(out), np.asarray(part),
                                        rtol=1e-4, atol=1e-5)
             total, sent = total + out, sent + int(pairs.sum())
-        assert sent == T * TOP_K            # no pair dropped, none twice
+        assert sent == T * top_k            # no pair dropped, none twice
         np.testing.assert_allclose(np.asarray(total), np.asarray(ref),
                                    rtol=1e-4, atol=1e-5)
 
