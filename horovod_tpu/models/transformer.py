@@ -608,33 +608,51 @@ def record_attention(cfg: TransformerConfig, batch: int) -> list:
     pattern to the flight ring: ``layer``, ``layer_kind``, ``window``
     (0 = none: the causal bound alone), ``seq`` (``max_seq``, one
     chip's: ``sp`` 1), the path ``impl`` and the tiles ``block_q`` x
-    ``block_k`` that ``ring_attention`` picks for ``batch`` sequences,
-    and the ``grid`` / ``live`` / ``masked`` tile pairs of one head's
-    call at those tiles (``pallas_attention.causal_tile_counts``: the
-    steps there are, those that do any work, those that build a mask).
-    Returns the records."""
+    ``block_k`` that ``ring_attention`` picks for ``batch`` sequences
+    under that window, and of one head's forward call at those tiles
+    the ``grid`` steps it walks (every tile pair without a window,
+    under one ``band`` K tiles a Q row:
+    ``pallas_attention.walked_steps``; ``band`` 0 = no window) and the
+    ``live`` / ``masked`` tile pairs (``causal_tile_counts``: those
+    that do any work, those that build a mask); the same six of the
+    backward kernels, whose tiles a window cuts to its band, as
+    ``bwd_block_q`` ... ``bwd_masked`` (a head's dQ call; the dK/dV
+    call walks the same count at square tiles).  Returns the
+    records."""
     from horovod_tpu.models.blocks import ATTENTION_KINDS
-    from horovod_tpu.ops.pallas_attention import causal_tile_counts
+    from horovod_tpu.ops.pallas_attention import (causal_tile_counts,
+                                                  walked_steps)
     from horovod_tpu.parallel.ring_attention import _block_sizes, auto_impl
     from horovod_tpu.runtime import flight
 
     seq = cfg.max_seq
     impl = cfg.attn_impl or (auto_impl(batch, cfg.n_heads, seq)
                              if jax.default_backend() == "tpu" else "xla")
-    bq, bk = _block_sizes(seq, seq, cfg.head_dim,
-                          cfg.compute_dtype.itemsize)
+
+    def counts(window, backward):
+        bq, bk = _block_sizes(seq, seq, cfg.head_dim,
+                              cfg.compute_dtype.itemsize, window=window,
+                              backward=backward)
+        if not (bq and bk):
+            return dict(block_q=0, block_k=0, grid=0, band=0, live=0,
+                        masked=0)
+        # the one chunk's offsets are 0 and 0: multiples of the chunk,
+        # as ring_attention tells the kernels
+        grid, band = walked_steps(seq, seq, bq, bk, window, seq)
+        _, live, masked = causal_tile_counts(seq, seq, bq, bk,
+                                             window=window)
+        return dict(block_q=bq, block_k=bk, grid=grid, band=band,
+                    live=live, masked=masked)
+
     records = []
     for layer, kind in enumerate(cfg.layer_pattern):
         if kind not in ATTENTION_KINDS:
             continue
         window = cfg.window if kind == "S" else None
-        grid, live, masked = (causal_tile_counts(seq, seq, bq, bk,
-                                                 window=window)
-                              if bq and bk else (0, 0, 0))
-        records.append(dict(layer=layer, layer_kind=kind,
-                            window=window or 0, seq=seq, impl=impl,
-                            block_q=bq or 0, block_k=bk or 0, grid=grid,
-                            live=live, masked=masked))
+        records.append(dict(
+            layer=layer, layer_kind=kind, window=window or 0, seq=seq,
+            impl=impl, **counts(window, False),
+            **{"bwd_" + k: v for k, v in counts(window, True).items()}))
     for record in records:
         flight.record("hvd_attn_window", **record)
     return records

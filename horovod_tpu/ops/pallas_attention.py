@@ -4,8 +4,8 @@ saved-LSE backward kernels.
 Three entry points (:func:`flash_fwd_step`, :func:`flash_bwd_dq`,
 :func:`flash_bwd_dkv`), none differentiable by itself: the one caller,
 ``parallel/ring_attention``'s ring-level VJP, pairs them, and hands
-them tiles it derives from the chunk length and :data:`VMEM_BUDGET`.
-This module imports nothing from ``parallel/``.
+them tiles it derives from the chunk length, the window and
+:data:`VMEM_BUDGET`.  This module imports nothing from ``parallel/``.
 
 The hot op of ring attention (SURVEY §5.7 — a new TPU capability, absent
 from the reference): one online-softmax accumulation of a local Q chunk
@@ -23,12 +23,22 @@ a second bound on the same predicate: query ``i`` sees key ``j`` iff
 ``0 <= i - j < window``, the causal bound and the window's trailing
 edge.  A tile pair the mask hides whole — past the diagonal or behind
 the window — is neither computed nor fetched (the index maps clamp a
-row's dead steps onto its first or last live tile), and only the pairs
-the diagonal or the trailing edge crosses build the mask
-(:func:`causal_tile_counts` says how many of each).  A windowed call's
-kernels carry names of their own (``hvd_flash_fwd_win`` ...), so that a
-trace tells them from the plain calls; without a window the kernels
-trace what they traced before there was one.
+row's dead steps onto its last live tile), and only the pairs the
+diagonal or the trailing edge crosses build the mask
+(:func:`causal_tile_counts` says how many of each).  Under a window the
+grid does not walk the square at all: its innermost dimension is the
+band, the static count of tiles a row's window can touch
+(:func:`band_steps`; 3 of 16 at 1024×1024 tiles, a window of 2,048 and
+16,384 tokens), and step ``t`` of a row stands for tile ``first + t``,
+``first`` read from the prefetched offsets, so the band's length is
+static and its place is not (:func:`walked_steps` says how many steps a
+call walks).  A dead step in the middle of a row costs 0.15 µs a
+kernel on a v5e, one that ends a row 2.2 µs, because the next row's
+blocks are then fetched behind no work (``PERF.md`` section 6, PR 36):
+the band ends every row but the first few on a live tile.  A windowed
+call's kernels carry names of their own (``hvd_flash_fwd_win`` ...), so
+that a trace tells them from the plain calls; without a window the
+kernels trace what they traced before there was one.
 
 The state goes through HBM only between ring steps.  At the ends of the
 ring the kernel does the state's work where the state is, in VMEM
@@ -47,6 +57,7 @@ exercised by the CPU test mesh.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -147,13 +158,68 @@ def causal_tile_counts(lq: int, lk: int, bq: int, bk: int,
     return grid, live, masked
 
 
+def band_steps(bq: int, bk: int, window: int | None, nq: int, nk: int,
+               offset_multiple: int = 1):
+    """``(K tiles, Q tiles)``: the innermost grid dimension of the
+    forward and dQ kernels and of the dK/dV kernel.  Without a window
+    every tile, ``(nk, nq)``; under one the static lengths of the band
+    that is walked in their place.  A Q row's ``bq + window - 1`` keys
+    touch at most that many K tiles wherever the row's first key falls
+    in a tile, and a K column's ``bk + window - 1`` queries that many Q
+    tiles: ``ceil((bq + window - 2) / bk) + 1`` with nothing known of
+    the offsets (4 at 1024 / 1024 / 2,048), one fewer where ``q_offset
+    - k_offset`` is known to be a multiple of ``offset_multiple`` that
+    the tiles share (3: a row's first key then falls on a known place
+    in its tile).  Never more than the tiles there are."""
+    if window is None:
+        return nk, nq
+    g = math.gcd(offset_multiple, bq, bk)
+    # the latest place in its tile that a row's first key, a column's
+    # first query, can fall on, and the positions that follow it
+    keys = (bk - g) + (1 - window) % g + bq + window - 1
+    queries = (bq - g) + bk + window - 1
+    return min(nk, -(-keys // bk)), min(nq, -(-queries // bq))
+
+
+def walked_steps(lq: int, lk: int, bq: int, bk: int,
+                 window: int | None = None, offset_multiple: int = 1):
+    """``(steps, band)`` of one head's forward or dQ call: the grid
+    steps it walks, ``nq x nk`` without a window and ``nq x band`` under
+    one, ``band`` (0 without a window) being the K tiles of
+    :func:`band_steps`.  (The dK/dV call walks ``nk`` columns of the
+    band's Q tiles: the same count at square tiles.)  Seq 16,384 in
+    1024 x 1024 tiles: 256 and 0; under a window of 2,048 48 and 3 at
+    offsets that are multiples of a tile, 64 and 4 at any."""
+    nq, nk = lq // bq, lk // bk
+    band = band_steps(bq, bk, window, nq, nk, offset_multiple)[0]
+    return nq * band, 0 if window is None else band
+
+
+def _first_k_tile(off_ref, iq, bq: int, bk: int, window: int):
+    """Where Q row ``iq``'s band starts: the K tile that holds the
+    first key its first query sees (tile 0 where that key lies before
+    the block; past the block's last tile where the whole block lies
+    behind the window)."""
+    first_q = off_ref[0] - off_ref[1] + iq * bq - (window - 1)
+    return jax.lax.div(jnp.maximum(first_q, 0), bk)
+
+
+def _first_q_tile(off_ref, ik, bq: int, bk: int):
+    """The first Q tile that sees K column ``ik``: the one that holds
+    the query at the column's first key (tile 0 where that lies before
+    the chunk).  Where a windowed column's band starts."""
+    first_k = off_ref[1] - off_ref[0] + ik * bk
+    return jax.lax.div(jnp.maximum(first_k, 0), bq)
+
+
 def _on_live_tile(off_ref, iq, ik, bq: int, bk: int, causal: bool, body,
-                  window: int | None = None):
+                  window: int | None = None, inside=None):
     """Run ``body(mask)`` for tile pair (iq, ik): not at all where the
     mask hides the whole pair, with the (bq, bk) bool mask where the
     diagonal or the window's trailing edge crosses it, and with ``None``
     where nothing is hidden (no score is -inf there, so the body drops
-    its guards too)."""
+    its guards too).  ``inside``: under a window, whether the band's
+    step stands for a tile of the block at all."""
     if not causal:
         body(None)
         return
@@ -163,6 +229,7 @@ def _on_live_tile(off_ref, iq, ik, bq: int, bk: int, causal: bool, body,
     live = _tile_live(q_start, k_start, bq, bk, window)
     if window is not None:
         masked = masked | _tile_trailing(q_start, k_start, bq, window)
+        live = live & inside
 
     @pl.when(live & masked)
     def _():
@@ -173,30 +240,50 @@ def _on_live_tile(off_ref, iq, ik, bq: int, bk: int, causal: bool, body,
             seen = seen & (qpos - kpos < window)
         body(seen)
 
-    @pl.when(jnp.logical_not(masked))          # implies live
+    # without a window a pair that needs no mask is live
+    @pl.when(jnp.logical_not(masked) if window is None
+             else live & jnp.logical_not(masked))
     def _():
         body(None)
 
 
+def _on_band_tile(off_ref, row, step, bq: int, bk: int, n: int,
+                  causal: bool, body, window: int | None = None,
+                  q_major: bool = True):
+    """:func:`_on_live_tile` for step ``step`` of Q row ``row`` over its
+    ``n`` K tiles (forward, dQ), or with ``q_major`` false of K column
+    ``row`` over its ``n`` Q tiles (dK/dV): tile ``step`` without a
+    window, and under one the ``step``-th tile of the row's band."""
+    tile, inside = step, None
+    if window is not None:
+        first = (_first_k_tile(off_ref, row, bq, bk, window) if q_major
+                 else _first_q_tile(off_ref, row, bq, bk))
+        tile = first + step
+        inside = tile < n
+    iq, ik = (row, tile) if q_major else (tile, row)
+    _on_live_tile(off_ref, iq, ik, bq, bk, causal, body, window, inside)
+
+
 def _q_major_maps(bq: int, bk: int, causal: bool, nk: int,
                   window: int | None = None):
-    """Index maps ``(q_row, kv_row)`` of the (B*H, nq, nk) grid the
-    forward and dQ kernels share.  The K/V map clamps ``ik`` to the Q
-    row's last live tile and, under a ``window``, to its first, so that
-    the dead steps after and before them name a block that is or will
-    be in VMEM and fetch nothing of their own."""
+    """Index maps ``(q_row, kv_row)`` of the grid the forward and dQ
+    kernels share: (B*H, nq, nk), and under a ``window`` (B*H, nq,
+    band), step ``t`` of a row standing for K tile ``first + t``
+    (:func:`_first_k_tile`).  The K/V map clamps the tile to the Q
+    row's last live one (and to the block's last), so that the dead
+    steps after it name a block that is in VMEM and fetch nothing of
+    their own; a band's first step is live wherever any of the row's
+    is."""
     def q_row(b, iq, ik, off_ref):
         return b, iq, 0
 
     def kv_row(b, iq, ik, off_ref):
+        if window is not None:
+            ik = jnp.minimum(_first_k_tile(off_ref, iq, bq, bk, window) + ik,
+                             nk - 1)
         if causal:
             last_q = off_ref[0] - off_ref[1] + (iq + 1) * bq - 1
             ik = jnp.minimum(ik, jax.lax.div(jnp.maximum(last_q, 0), bk))
-        if window is not None:
-            # the first key the row's first query sees
-            first_q = off_ref[0] - off_ref[1] + iq * bq - (window - 1)
-            first = jax.lax.div(jnp.maximum(first_q, 0), bk)
-            ik = jnp.maximum(ik, jnp.minimum(first, nk - 1))
         return b, ik, 0
 
     return q_row, kv_row
@@ -204,19 +291,23 @@ def _q_major_maps(bq: int, bk: int, causal: bool, nk: int,
 
 def _k_major_maps(bq: int, bk: int, causal: bool, nq: int,
                   window: int | None = None):
-    """Index maps ``(q_row, kv_row)`` of the (B*H, nk, nq) grid of the
-    dK/dV kernel.  The Q map clamps ``iq`` up to the K column's first
-    live tile — the dead steps before it fetch that tile's blocks once,
-    and no others — and, under a ``window``, down to its last: the last
-    query that still sees the column's last key."""
+    """Index maps ``(q_row, kv_row)`` of the dK/dV kernel's grid:
+    (B*H, nk, nq), and under a ``window`` (B*H, nk, band), step ``t``
+    of a column standing for Q tile ``first + t``
+    (:func:`_first_q_tile`).  Without a window the Q map clamps ``iq``
+    up to the K column's first live tile: the dead steps before it
+    fetch that tile's blocks once, and no others.  Under one it clamps
+    the band's tile down to the column's last live one: the last query
+    that still sees the column's last key."""
     def q_row(b, ik, iq, off_ref):
+        if causal:
+            first = _first_q_tile(off_ref, ik, bq, bk)
         if window is not None:
             last_k = (off_ref[1] - off_ref[0] + (ik + 1) * bk - 1
                       + (window - 1))
-            iq = jnp.minimum(iq, jax.lax.div(jnp.maximum(last_k, 0), bq))
-        if causal:
-            first_k = off_ref[1] - off_ref[0] + ik * bk
-            first = jax.lax.div(jnp.maximum(first_k, 0), bq)
+            iq = jnp.minimum(jnp.minimum(first + iq, nq - 1),
+                             jax.lax.div(jnp.maximum(last_k, 0), bq))
+        elif causal:
             iq = jnp.maximum(iq, jnp.minimum(first, nq - 1))
         return b, iq, 0
 
@@ -250,10 +341,12 @@ def _compiler_params(bq: int, bk: int, d: int, dv: int, dtype):
 
 
 def _flash_step_kernel(off_ref, q_ref, k_ref, v_ref, *refs, causal: bool,
-                       scale: float, bq: int, bk: int, first: bool,
+                       scale: float, bq: int, bk: int, nk: int, first: bool,
                        last: bool, window: int | None = None):
     """Grid: (B*H, nq, nk) — nk innermost so (m_s, l_s, acc) scratch
-    carries across the K blocks of one Q block.  ``refs`` are the
+    carries across the K blocks of one Q block; under a ``window``
+    (B*H, nq, band), the scratch started and finished on the band's
+    first and last step.  ``refs`` are the
     carried state in (packed m|l, o; none on a ring's ``first`` step,
     where scratch starts at -inf, 0, 0), the results and the scratch.
     A middle step's results are the state out: m_s and l_s repacked
@@ -267,10 +360,10 @@ def _flash_step_kernel(off_ref, q_ref, k_ref, v_ref, *refs, causal: bool,
     if not first:
         mli_ref, oi_ref, *refs = refs
     stat_ref, *o_refs, m_s, l_s, acc = refs
-    ik = pl.program_id(2)
-    nk = pl.num_programs(2)
+    step = pl.program_id(2)
+    steps = pl.num_programs(2)
 
-    @pl.when(ik == 0)
+    @pl.when(step == 0)
     def _():
         if first:
             m_s[:, :] = jnp.full_like(m_s, _NEG_INF)
@@ -308,10 +401,10 @@ def _flash_step_kernel(off_ref, q_ref, k_ref, v_ref, *refs, causal: bool,
         l_s[:, :] = l_new[:, None] + jnp.zeros_like(l_s)
         acc[:, :] = acc[:, :] * alpha[:, None] + pv
 
-    _on_live_tile(off_ref, pl.program_id(1), ik, bq, bk, causal, accumulate,
-                  window)
+    _on_band_tile(off_ref, pl.program_id(1), step, bq, bk, nk, causal,
+                  accumulate, window)
 
-    @pl.when(ik == nk - 1)
+    @pl.when(step == steps - 1)
     def _():
         if last:
             l = l_s[:, :]                   # lane-replicated, as m_s
@@ -359,12 +452,13 @@ _entry_point = functools.partial(jax.jit, inline=True)
 
 
 @_entry_point(static_argnames=(
-    "causal", "block_q", "block_k", "last", "interpret", "window"))
+    "causal", "block_q", "block_k", "last", "interpret", "window",
+    "offset_multiple"))
 def flash_fwd_step(q, k, v, state, q_offset, k_offset, *,
                    causal: bool = True, block_q: int = 128,
                    block_k: int = 128, last: bool = False,
                    interpret: bool | None = None,
-                   window: int | None = None):
+                   window: int | None = None, offset_multiple: int = 1):
     """One step of a ring's forward pass: attend local Q against one
     KV block.
 
@@ -372,7 +466,12 @@ def flash_fwd_step(q, k, v, state, q_offset, k_offset, *,
     latent-attention head has 192 for q/k and 128 for v).
     q_offset / k_offset: global positions of q[:,0]/k[:,0] (traced OK).
     ``window``: a static sliding window on those positions (query ``i``
-    sees key ``j`` iff ``0 <= i - j < window``), or None.
+    sees key ``j`` iff ``0 <= i - j < window``), or None.  Under one the
+    grid walks a row's band of K tiles and not all ``nk``
+    (:func:`band_steps`), the band one tile shorter where the caller
+    knows ``q_offset - k_offset`` to be a multiple of
+    ``offset_multiple`` (static; a ring's offsets are multiples of its
+    chunk) that the tiles share.
     ``state``: the carried ``(m, l, o)`` (m, l: (BH, Lq) fp32 running
     max / denominator; o: (BH, Lq, Dv) fp32 unnormalized numerator),
     or None on the ring's first step — the kernel then starts from
@@ -394,10 +493,12 @@ def flash_fwd_step(q, k, v, state, q_offset, k_offset, *,
     first = state is None
 
     _check_window(causal, window)
+    nq, nk = lq // bq, lk // bk
     kernel = functools.partial(_flash_step_kernel, causal=causal,
-                               scale=scale, bq=bq, bk=bk, first=first,
+                               scale=scale, bq=bq, bk=bk, nk=nk, first=first,
                                last=last, window=window)
-    q_row, kv_row = _q_major_maps(bq, bk, causal, lk // bk, window)
+    q_row, kv_row = _q_major_maps(bq, bk, causal, nk, window)
+    steps = band_steps(bq, bk, window, nq, nk, offset_multiple)[0]
     operands = [q, k, v]
     in_specs = [
         pl.BlockSpec((1, bq, d), q_row),      # q
@@ -424,7 +525,7 @@ def flash_fwd_step(q, k, v, state, q_offset, k_offset, *,
         # them to skip the fetch of dead tiles
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, lq // bq, lk // bk),
+            grid=(bh, nq, steps),
             in_specs=in_specs,
             # m|l and o, or lse and o / l
             out_specs=[pl.BlockSpec((1, bq, 128), q_row)]
@@ -469,14 +570,15 @@ def _recomputed_p_ds(q, k, v, do, ld, mask, scale):
 
 def _flash_bwd_dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
                          dq_ref, dq_acc, *, causal: bool, scale: float,
-                         bq: int, bk: int, window: int | None = None):
-    """dQ backward: grid (B*H, nq, nk), nk innermost so dq_acc carries
-    across the K blocks of one Q block (zero for a row whose every
-    tile is dead)."""
-    ik = pl.program_id(2)
-    nk = pl.num_programs(2)
+                         bq: int, bk: int, nk: int,
+                         window: int | None = None):
+    """dQ backward: grid (B*H, nq, nk), under a ``window`` (B*H, nq,
+    band), innermost so dq_acc carries across the K blocks of one Q
+    block (zero for a row whose every tile is dead)."""
+    step = pl.program_id(2)
+    steps = pl.num_programs(2)
 
-    @pl.when(ik == 0)
+    @pl.when(step == 0)
     def _():
         dq_acc[:, :] = jnp.zeros_like(dq_acc)
 
@@ -488,24 +590,25 @@ def _flash_bwd_dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)        # (bq, d)
 
-    _on_live_tile(off_ref, pl.program_id(1), ik, bq, bk, causal, accumulate,
-                  window)
+    _on_band_tile(off_ref, pl.program_id(1), step, bq, bk, nk, causal,
+                  accumulate, window)
 
-    @pl.when(ik == nk - 1)
+    @pl.when(step == steps - 1)
     def _():
         dq_ref[0] = dq_acc[:, :].astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, causal: bool,
-                          scale: float, bq: int, bk: int,
+                          scale: float, bq: int, bk: int, nq: int,
                           window: int | None = None):
-    """dK/dV backward: grid (B*H, nk, nq), nq innermost so the dk/dv
-    accumulators carry across the Q blocks of one KV block."""
-    iq = pl.program_id(2)
-    nq = pl.num_programs(2)
+    """dK/dV backward: grid (B*H, nk, nq), under a ``window`` (B*H, nk,
+    band), innermost so the dk/dv accumulators carry across the Q
+    blocks of one KV block."""
+    step = pl.program_id(2)
+    steps = pl.num_programs(2)
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _():
         dk_acc[:, :] = jnp.zeros_like(dk_acc)
         dv_acc[:, :] = jnp.zeros_like(dv_acc)
@@ -522,22 +625,23 @@ def _flash_bwd_dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)        # (bk, d)
 
-    _on_live_tile(off_ref, iq, pl.program_id(1), bq, bk, causal, accumulate,
-                  window)
+    _on_band_tile(off_ref, pl.program_id(1), step, bq, bk, nq, causal,
+                  accumulate, window, q_major=False)
 
-    @pl.when(iq == nq - 1)
+    @pl.when(step == steps - 1)
     def _():
         dk_ref[0] = dk_acc[:, :].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:, :].astype(dv_ref.dtype)
 
 
 @_entry_point(static_argnames=(
-    "causal", "block_q", "block_k", "out_dtype", "interpret", "window"))
+    "causal", "block_q", "block_k", "out_dtype", "interpret", "window",
+    "offset_multiple"))
 def flash_bwd_dq(q, k, v, do, lse, delta, q_offset, k_offset, *,
                  causal: bool = True, block_q: int = 128,
                  block_k: int = 128, out_dtype=jnp.float32,
                  interpret: bool | None = None,
-                 window: int | None = None):
+                 window: int | None = None, offset_multiple: int = 1):
     """Flash-attention dQ for one (local Q, one KV block) pair.
 
     q: (BH, Lq, D); k: (BH, Lk, D); v: (BH, Lk, Dv); do: (BH, Lq, Dv)
@@ -547,8 +651,8 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_offset, k_offset, *,
     Returns (BH, Lq, D) in ``out_dtype`` — the dQ contribution of this
     KV block: fp32 where the caller sums over ring steps, the operands'
     type in a one-step ring (the accumulator is fp32 in VMEM either
-    way and is rounded once, as it is written out).  ``window``: as
-    :func:`flash_fwd_step`'s.
+    way and is rounded once, as it is written out).  ``window`` and
+    ``offset_multiple``: as :func:`flash_fwd_step`'s.
     """
     bh, lq, d = q.shape
     _, lk, dv = v.shape
@@ -558,16 +662,19 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_offset, k_offset, *,
     offs = jnp.asarray([q_offset, k_offset], jnp.int32)
     ld = _pack_rows(lse, delta, bh, lq)
     _check_window(causal, window)
+    nq, nk = lq // bq, lk // bk
     kernel = functools.partial(_flash_bwd_dq_kernel, causal=causal,
-                               scale=scale, bq=bq, bk=bk, window=window)
-    q_row, kv_row = _q_major_maps(bq, bk, causal, lk // bk, window)
+                               scale=scale, bq=bq, bk=bk, nk=nk,
+                               window=window)
+    q_row, kv_row = _q_major_maps(bq, bk, causal, nk, window)
+    steps = band_steps(bq, bk, window, nq, nk, offset_multiple)[0]
 
     return pl.pallas_call(
         kernel,
         name=_kernel_name("hvd_flash_bwd_dq", window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, lq // bq, lk // bk),
+            grid=(bh, nq, steps),
             in_specs=[
                 pl.BlockSpec((1, bq, d), q_row),      # q
                 pl.BlockSpec((1, bk, d), kv_row),     # k
@@ -584,18 +691,20 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_offset, k_offset, *,
 
 
 @_entry_point(static_argnames=(
-    "causal", "block_q", "block_k", "out_dtype", "interpret", "window"))
+    "causal", "block_q", "block_k", "out_dtype", "interpret", "window",
+    "offset_multiple"))
 def flash_bwd_dkv(q, k, v, do, lse, delta, q_offset, k_offset, *,
                   causal: bool = True, block_q: int = 128,
                   block_k: int = 128, out_dtype=jnp.float32,
                   interpret: bool | None = None,
-                  window: int | None = None):
+                  window: int | None = None, offset_multiple: int = 1):
     """Flash-attention (dK, dV) for one (local Q, one KV block) pair.
 
     Same contract as :func:`flash_bwd_dq`; returns
     ((BH, Lk, D), (BH, Lk, Dv)) in ``out_dtype`` — this Q chunk's
     contribution to the block's dK/dV (callers in a longer ring
-    accumulate in fp32 while rotating).
+    accumulate in fp32 while rotating).  Under a ``window`` the grid
+    walks a K column's band of Q tiles.
     """
     bh, lq, d = q.shape
     _, lk, dv = v.shape
@@ -605,16 +714,19 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, q_offset, k_offset, *,
     offs = jnp.asarray([q_offset, k_offset], jnp.int32)
     ld = _pack_rows(lse, delta, bh, lq)
     _check_window(causal, window)
+    nq, nk = lq // bq, lk // bk
     kernel = functools.partial(_flash_bwd_dkv_kernel, causal=causal,
-                               scale=scale, bq=bq, bk=bk, window=window)
-    q_row, kv_row = _k_major_maps(bq, bk, causal, lq // bq, window)
+                               scale=scale, bq=bq, bk=bk, nq=nq,
+                               window=window)
+    q_row, kv_row = _k_major_maps(bq, bk, causal, nq, window)
+    steps = band_steps(bq, bk, window, nq, nk, offset_multiple)[1]
 
     return pl.pallas_call(
         kernel,
         name=_kernel_name("hvd_flash_bwd_dkv", window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, lk // bk, lq // bq),
+            grid=(bh, nk, steps),
             in_specs=[
                 pl.BlockSpec((1, bq, d), q_row),      # q
                 pl.BlockSpec((1, bk, d), kv_row),     # k

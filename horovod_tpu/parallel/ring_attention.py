@@ -111,18 +111,37 @@ def _pick_block(n: int) -> int | None:
     return next((c for c in _TILE_LADDER if n % c == 0), None)
 
 
+# Under a window the backward kernels' tiles are cut to the band: the
+# largest edge on the ladder within a quarter of the window, and never
+# under this one (measured on a v5e at head size 128, bf16, ``PERF.md``
+# section 6, PR 36: at a window of 2,048 dQ and dK/dV take 7.60 and
+# 9.55 ms a call of 16,384 tokens in 1024 x 1024 tiles, 6.89 and 8.86
+# in 512 x 512, 11.6 and 14.5 in 256 x 256, which are all fixed cost;
+# at 4,096 they are faster at 1024, at 1,024 at 512).  The forward
+# kernel keeps the chunk's tiles: its row statistics cost by the step,
+# and every window measured runs it fastest at 1024.
+_WINDOW_EDGE = 512
+
+
 def _block_sizes(lc: int, lk: int, d: int, itemsize: int,
-                 dv: int | None = None):
-    """(block_q, block_k) for the Pallas kernel at q/k head size ``d``,
+                 dv: int | None = None, window: int | None = None,
+                 backward: bool = False):
+    """(block_q, block_k) for the Pallas kernels at q/k head size ``d``,
     v head size ``dv`` (``d`` where not given) and
     ``itemsize``-byte operands: the largest tile on the ladder for
-    each side, stepped down the ladder (K first) while the kernels
-    would ask for more than ``VMEM_BUDGET``.  Returns (None, _) when
-    no aligned tiling exists for the Q chunk."""
+    each side — for the ``backward`` kernels under a ``window`` no
+    larger than the band's edge (:data:`_WINDOW_EDGE`) — stepped down
+    the ladder (K first) while the kernels would ask for more than
+    ``VMEM_BUDGET``.  Returns (None, _) when no aligned tiling exists
+    for the Q chunk."""
     from horovod_tpu.ops.pallas_attention import (VMEM_BUDGET,
                                                   tile_vmem_bytes)
 
     bq, bk = _pick_block(lc), _pick_block(lk)
+    if backward and window is not None and bq and bk:
+        edge = max(_WINDOW_EDGE, next(
+            (c for c in _TILE_LADDER if 4 * c <= window), 0))
+        bq, bk = min(bq, edge), min(bk, edge)
     while (bq and bk and max(bq, bk) > 8
            and tile_vmem_bytes(bq, bk, d, itemsize, dv) > VMEM_BUDGET):
         if bk >= bq:
@@ -169,7 +188,7 @@ def _ring_rotate(axis_name, *blocks):
     return tuple(lax.ppermute(x, axis_name, rot) for x in blocks)
 
 
-def _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, bq, bk,
+def _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, tiles,
                          window=None):
     """Pallas ring forward, returning (normalized fp32 out, lse, out in
     the operands' type).
@@ -186,12 +205,14 @@ def _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, bq, bk,
 
     sp = lax.axis_size(axis_name)
     lc = qp.shape[1]
+    bq, bk = tiles[0]
 
     def step(j, state, kj, vj, last=False):
         qo, ko = _ring_offsets(j, axis_name, lc, causal)
+        # a ring's offsets are multiples of its chunk
         return flash_fwd_step(qp, kj, vj, state, qo, ko, causal=causal,
                               block_q=bq, block_k=bk, last=last,
-                              window=window)
+                              window=window, offset_multiple=lc)
 
     if sp == 1:
         return step(0, None, kp, vp, last=True)
@@ -205,8 +226,8 @@ def _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, bq, bk,
     return step(sp - 1, state, kj, vj, last=True)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _ring_flash(qp, kp, vp, axis_name, causal, bq, bk, recomputed,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _ring_flash(qp, kp, vp, axis_name, causal, tiles, recomputed,
                 window=None):
     """Differentiable Pallas ring attention on packed (B*H, Lc, D)
     operands, returning (B*H, Lc, Dv) in their type: forward saves only
@@ -228,23 +249,25 @@ def _ring_flash(qp, kp, vp, axis_name, causal, bq, bk, recomputed,
     needed: with the rule returning the kernel's result the replay
     runs the kernel for it, names or no names.
 
-    ``window`` (static) is the sliding window on global positions that
-    all three kernels mask and skip tiles by, or None."""
-    return _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, bq, bk,
+    ``tiles``: ``((block_q, block_k) of the forward kernel, of the two
+    backward kernels)``.  ``window`` (static) is the sliding window on
+    global positions that all three kernels mask and skip tiles by, or
+    None."""
+    return _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, tiles,
                                 window)[2]
 
 
-def _ring_flash_fwd(qp, kp, vp, axis_name, causal, bq, bk, recomputed,
+def _ring_flash_fwd(qp, kp, vp, axis_name, causal, tiles, recomputed,
                     window):
     out, lse, out_q = _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal,
-                                           bq, bk, window)
+                                           tiles, window)
     if recomputed:
         out, lse = map(checkpoint_name, (out, lse), KEPT_NAMES)
         out_q = out.astype(qp.dtype)
     return out_q, (qp, kp, vp, out, lse)
 
 
-def _ring_flash_bwd(axis_name, causal, bq, bk, recomputed, window, res,
+def _ring_flash_bwd(axis_name, causal, tiles, recomputed, window, res,
                     dout):
     from horovod_tpu.ops.pallas_attention import (flash_bwd_dkv,
                                                   flash_bwd_dq)
@@ -252,6 +275,7 @@ def _ring_flash_bwd(axis_name, causal, bq, bk, recomputed, window, res,
     qp, kp, vp, out, lse = res
     sp = lax.axis_size(axis_name)
     lc = qp.shape[1]
+    bq, bk = tiles[1]
     # dout comes in the operands' type, the products' (bf16-safe); out
     # and delta are fp32
     delta = jnp.sum(dout.astype(jnp.float32) * out, axis=-1)   # (BH, Lc)
@@ -260,7 +284,7 @@ def _ring_flash_bwd(axis_name, causal, bq, bk, recomputed, window, res,
         """This step's (dQ, dK, dV) contributions."""
         qo, ko = _ring_offsets(j, axis_name, lc, causal)
         tiles = dict(causal=causal, block_q=bq, block_k=bk,
-                     out_dtype=out_dtype, window=window)
+                     out_dtype=out_dtype, window=window, offset_multiple=lc)
         return (flash_bwd_dq(qp, kj, vj, dout, lse, delta, qo, ko, **tiles),
                 *flash_bwd_dkv(qp, kj, vj, dout, lse, delta, qo, ko,
                                **tiles))
@@ -358,9 +382,11 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
                          f"got {impl!r}")
 
     if impl == "pallas":
-        # ring KV blocks are lc long too
-        bq, bk = _block_sizes(lc, lc, d, q.dtype.itemsize, dv)
-        if bq is None or bk is None:
+        # ring KV blocks are lc long too; the forward kernel's tiles and
+        # the backward kernels'
+        tiles = tuple(_block_sizes(lc, lc, d, q.dtype.itemsize, dv, window,
+                                   backward) for backward in (False, True))
+        if None in tiles[0]:
             msg = (f"sequence chunk {lc} has no tile size the Pallas "
                    "attention kernel can use (a multiple of 8 dividing "
                    "it)")
@@ -376,7 +402,7 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
         qp = q.transpose(0, 2, 1, 3).reshape(b * h, lc, d)
         kp = k.transpose(0, 2, 1, 3).reshape(b * h, lc, d)
         vp = v.transpose(0, 2, 1, 3).reshape(b * h, lc, dv)
-        out = _ring_flash(qp, kp, vp, axis_name, causal, bq, bk, recomputed,
+        out = _ring_flash(qp, kp, vp, axis_name, causal, tiles, recomputed,
                           window)
         return out.reshape(b, h, lc, dv).transpose(0, 2, 1, 3)
 

@@ -5,7 +5,9 @@ the three Mosaic calls a layer, no ``collective-permute`` under
 ``hvd_attn``, and fewer bytes moved by everything else under that scope.
 And how often each kernel stands in ``joyai-llm-flash.s8192.epshare``'s
 step, whose blocks are recomputed: once a block, the forward kernel too,
-because a recomputed block keeps what that kernel gave.
+because a recomputed block keeps what that kernel gave.  And the grids
+the kernels walk at ``trinity-mini.s16384.epshare``'s attention shape:
+under the window a band of tiles a row, without one every tile pair.
 A compile, not a chip run: it counts bytes and says nothing about time.
 
 The topology is described inside a fixture (never while a module is
@@ -213,3 +215,105 @@ def test_nothing_is_permuted_and_less_is_moved_around_the_kernels(step_text):
     assert permutes == 0
     largest = sorted(rows, reverse=True)[:8]
     assert 8e9 < total < BYTES_AROUND_KERNELS, (total, largest)
+
+
+# ---------------------------------------------------------------------------
+# The grid a windowed call walks, at trinity-mini.s16384.epshare's shape
+# ---------------------------------------------------------------------------
+
+SWA_SHAPE = (1, 16384, 32, 128)        # batch, seq, query heads, head size
+SWA_WINDOW = 2048
+
+
+def _kernel_grids(lowered_text):
+    """``[(kernel name, grid)]`` of a lowered module's Mosaic calls, in
+    order: each call's payload (MLIR bytecode in its ``backend_config``)
+    parsed, its module's name and ``iteration_bounds`` read."""
+    import base64
+
+    from jax._src.lib import tpu  # noqa: F401  (registers the dialect)
+    from jax._src.lib.mlir import ir
+
+    found = []
+    for payload in re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                              lowered_text):
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True
+        printed = ir.Module.parse(base64.b64decode(payload),
+                                  ctx).operation.get_asm()
+        bounds = re.search(r"iteration_bounds = array<i64: ([\d, ]+)>",
+                           printed)
+        found.append((re.search(r"module @(\w+)", printed).group(1),
+                      tuple(int(n) for n in bounds.group(1).split(","))))
+    return found
+
+
+@pytest.mark.parametrize("window", [SWA_WINDOW, None])
+def test_a_windowed_call_walks_the_band_and_a_plain_one_the_square(
+        window, one_chip):
+    """One ``ring_attention`` call, both passes, lowered and compiled for
+    the described chip (Mosaic takes the kernels at these tiles): under
+    the window each ``*_win`` kernel's grid is ``(32, rows, band)``, the
+    band ``band_steps`` gives at the tiles ``_block_sizes`` picks for
+    the window and the pass, and walks at most a quarter of the ``nq x
+    nk`` tile pairs; without one the three grids are ``(bh, nq, nk)`` at
+    1024 x 1024 tiles, as before there was a band."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import shard_map
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from horovod_tpu.ops import pallas_attention as pa
+    from horovod_tpu.parallel import ring_attention as ra
+
+    batch, seq, heads, d = SWA_SHAPE
+    mesh = Mesh(np.array([one_chip]), ("sp",))
+    x = jax.ShapeDtypeStruct(SWA_SHAPE, jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P()))
+
+    def both(q, k, v, do):
+        out, vjp = jax.vjp(lambda *qkv: shard_map(
+            lambda q, k, v: ra.ring_attention(q, k, v, "sp", impl="pallas",
+                                              window=window),
+            mesh=mesh, in_specs=P(None, "sp"), out_specs=P(None, "sp"),
+            check_vma=False)(*qkv), q, k, v)
+        return out, vjp(do)
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    patch = pytest.MonkeyPatch()
+    patch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        lowered = jax.jit(both).lower(x, x, x, x)
+        grids = _kernel_grids(lowered.as_text())
+        assert lowered.compile().as_text().count("tpu_custom_call") >= 3
+    finally:
+        patch.undo()
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+
+    end = "" if window is None else "_win"
+    assert [name for name, _ in grids] == [k + end for k in KERNELS]
+    tiles = [ra._block_sizes(seq, seq, d, 2, d, window, backward)
+             for backward in (False, True, True)]
+    if window is None:
+        assert tiles == [(1024, 1024)] * 3
+        assert [grid for _, grid in grids] == [(batch * heads, 16, 16)] * 3
+        return
+    # the forward kernel at the chunk's tiles, the backward kernels at
+    # the band's: 48 and 160 steps a head for 256 and 1,024
+    assert tiles == [(1024, 1024), (512, 512), (512, 512)]
+    assert [grid for _, grid in grids] == [
+        (batch * heads, 16, 3), (batch * heads, 32, 5),
+        (batch * heads, 32, 5)]
+    for (bq, bk), (_, (_, rows, band)), q_major in zip(
+            tiles, grids, (True, True, False)):
+        nq, nk = seq // bq, seq // bk
+        assert rows * band * 4 <= nq * nk
+        assert band == pa.band_steps(bq, bk, window, nq, nk,
+                                     seq)[0 if q_major else 1]
+        if q_major:
+            assert pa.walked_steps(seq, seq, bq, bk, window, seq) == (
+                rows * band, band)

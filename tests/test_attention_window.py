@@ -4,8 +4,10 @@ sees key ``j`` iff ``0 <= i - j < window``.  The three Pallas kernels
 from positions, values and the three gradients, at windows smaller than
 a tile, of exactly a tile, across several tiles and at least the
 sequence (the causal call, bit for bit), with non-zero global offsets;
-the tile counts against a count over positions; the index maps' clamps;
-the kernels' names."""
+the tile counts against a count over positions; the band a windowed
+call's grid walks and the index maps' clamps; the kernels' names."""
+
+import math
 
 import numpy as np
 import pytest
@@ -148,6 +150,62 @@ def test_ring_attention_carries_the_window_through_both_passes(impl, window):
                                    rtol=2e-3, atol=2e-4)
 
 
+@pytest.mark.parametrize("window,forward,backward", [
+    (None, 1024, 1024), (512, 1024, 512), (1024, 1024, 512),
+    (2048, 1024, 512), (3072, 1024, 512), (4096, 1024, 1024),
+    (8192, 1024, 1024), (16384, 1024, 1024)])
+def test_a_window_cuts_the_backward_kernels_tiles_to_its_band(
+        window, forward, backward):
+    """``_block_sizes`` at the benchmark's chunk (16,384 tokens, heads
+    of 128, bf16): the forward kernel keeps the chunk's tiles whatever
+    the window; the backward kernels take the largest edge on the
+    ladder within a quarter of the window, never under 512; and no
+    window, no cut."""
+    from horovod_tpu.parallel.ring_attention import _block_sizes
+
+    assert _block_sizes(16384, 16384, 128, 2, 128, window) \
+        == (forward, forward)
+    assert _block_sizes(16384, 16384, 128, 2, 128, window, True) \
+        == (backward, backward)
+    # a chunk that has no such tile keeps its own, and the VMEM bound
+    # still steps a wide head's tiles down
+    assert _block_sizes(384, 384, 128, 2, 128, window, True) == (128, 128)
+    assert _block_sizes(8192, 8192, 1024, 4, None, window or 64, True) \
+        == (512, 512)
+
+
+def test_ring_attention_with_the_two_passes_at_tiles_of_their_own():
+    """A chunk of 1,024 under a window of 300: the forward kernel runs
+    one 1024 x 1024 tile a head, the backward kernels 512 x 512 tiles
+    over a band of two; values and gradients against the reference."""
+    from horovod_tpu.parallel.ring_attention import _block_sizes
+
+    n, window = 1024, 300
+    assert (_block_sizes(n, n, D, 4, D, window),
+            _block_sizes(n, n, D, 4, D, window, True)) \
+        == ((1024, 1024), (512, 512))
+    rng = np.random.RandomState(13)
+    q, k, v, w = (jnp.asarray(rng.randn(1, n, 2, D), jnp.float32) * 0.5
+                  for _ in range(4))
+    mesh = Mesh(np.array(jax.devices()[:1]), ("sp",))
+
+    def ring(q, k, v):
+        return shard_map(
+            lambda q, k, v: ring_attention(q, k, v, "sp", impl="pallas",
+                                           window=window),
+            mesh=mesh, in_specs=P(None, "sp"), out_specs=P(None, "sp"),
+            check_vma=False)(q, k, v)
+
+    loss = lambda fn: lambda *a: jnp.sum(fn(*a) * w)
+    want = jax.value_and_grad(loss(lambda *a: reference_attention(
+        *a, window=window)), argnums=(0, 1, 2))(q, k, v)
+    got = jax.value_and_grad(loss(ring), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-3, atol=2e-4)
+
+
 def test_ring_attention_window_over_a_ring_of_two():
     """sp 2, the XLA step: the mask is on global positions, so a window
     that reaches into the other chip's chunk is computed in full (whole
@@ -226,14 +284,25 @@ def test_tile_counts_of_the_benchmarks_window_cell():
     assert 45 * 1024 * 1024 == 47_185_920
 
 
+def _known_multiple(qo, ko):
+    """Something the offsets' difference is a multiple of, as a ring
+    knows its chunk: a power of two (any, where they are equal)."""
+    return math.gcd(abs(qo - ko), 1024)
+
+
 @pytest.mark.parametrize("case", CASES)
 def test_index_maps_send_a_dead_step_to_a_live_tile(case):
-    """The K/V map of the forward and dQ kernels names ``ik`` itself on
-    a live step and, on a dead one, the row's first live tile (before
-    it) or its last (after it); ``flash_bwd_dkv``'s Q map likewise by
-    column.  So a row's steps name a run of blocks without a gap and
-    fetch each live block once and no dead one (a row with no live
-    tile names any one block)."""
+    """Under a window the grid walks a band: step ``t`` of a Q row
+    stands for K tile ``first + t`` (of a K column in
+    ``flash_bwd_dkv``, for Q tile ``first + t``).  Every live tile of
+    a row is named by exactly one step, the one that stands for it; a
+    step that is not live — past the row's last live tile or past the
+    block — names the row's last live block, so a row's steps name a
+    run of blocks without a gap and fetch each live block once and no
+    dead one (a row with no live tile names one block throughout); and
+    the band's static length is never shorter than a row's live run,
+    with nothing known of the offsets and with what a ring knows of
+    them."""
     lq, lk, bq, bk, qo, ko, window = case
     nq, nk = lq // bq, lk // bk
     tiles = _seen(lq, lk, qo, ko, window).reshape(nq, bq, nk, bk)
@@ -241,24 +310,94 @@ def test_index_maps_send_a_dead_step_to_a_live_tile(case):
     offs = np.asarray([qo, ko], np.int32)
     _, kv_row = pa._q_major_maps(bq, bk, True, nk, window)
     q_row, _ = pa._k_major_maps(bq, bk, True, nq, window)
-    for iq in range(nq):
-        named = [int(kv_row(0, iq, ik, offs)[1]) for ik in range(nk)]
-        _check_row(named, live[iq])
-    for ik in range(nk):
-        named = [int(q_row(0, ik, iq, offs)[1]) for iq in range(nq)]
-        _check_row(named, live[:, ik])
+    for multiple in (1, _known_multiple(qo, ko)):
+        band_k, band_q = pa.band_steps(bq, bk, window, nq, nk, multiple)
+        assert 1 <= band_k <= nk and 1 <= band_q <= nq
+        for iq in range(nq):
+            first = int(pa._first_k_tile(offs, iq, bq, bk, window))
+            named = [int(kv_row(0, iq, t, offs)[1]) for t in range(band_k)]
+            _check_band(named, first, live[iq])
+        for ik in range(nk):
+            first = int(pa._first_q_tile(offs, ik, bq, bk))
+            named = [int(q_row(0, ik, t, offs)[1]) for t in range(band_q)]
+            _check_band(named, first, live[:, ik])
+    tight = pa.band_steps(bq, bk, window, nq, nk, _known_multiple(qo, ko))
+    assert all(a <= b for a, b in zip(
+        tight, pa.band_steps(bq, bk, window, nq, nk)))
 
 
-def _check_row(named: list, live) -> None:
+def _check_band(named: list, first: int, live) -> None:
+    """``named[t]`` is the block step ``t`` fetches, ``first + t`` the
+    tile it stands for (and the kernel body computes, or skips)."""
     where = np.flatnonzero(live)
-    assert all(0 <= n < len(named) for n in named)
+    assert all(0 <= n < len(live) for n in named)
     if not where.size:
         assert len(set(named)) == 1
         return
-    first, last = where[0], where[-1]
-    assert live[first:last + 1].all()         # the live tiles are one run
-    for step, n in enumerate(named):
-        assert n == min(max(step, first), last), (step, named)
+    lo, hi = where[0], where[-1]
+    assert live[lo:hi + 1].all()              # the live tiles are one run
+    assert first == lo and len(named) >= hi - lo + 1
+    stood_for = [first + t for t in range(len(named))]
+    assert [t for t in stood_for if t <= hi] == list(range(lo, hi + 1))
+    for tile, n in zip(stood_for, named):
+        assert n == min(tile, hi), (tile, named)
+
+
+@pytest.mark.parametrize("edge,known,steps,band", [
+    (1024, 16384, 48, 3), (512, 16384, 160, 5), (256, 16384, 576, 9),
+    (1024, 1, 64, 4), (512, 1, 192, 6), (256, 1, 640, 10)])
+def test_walked_steps_at_the_benchmarks_sizes(edge, known, steps, band):
+    """Seq 16,384 under a window of 2,048: a head's call walks the
+    band's steps a row — 48 at 1024 x 1024 tiles where it walked 256 —
+    one more a row where nothing is known of the offsets (``askew``
+    above); without a window every tile pair, and ``band`` 0.  The
+    band is the least that holds a row's live run at every row."""
+    seq, window = 16384, 2048
+    n = seq // edge
+    assert pa.walked_steps(seq, seq, edge, edge, window, known) \
+        == (steps, band)
+    assert pa.band_steps(edge, edge, window, n, n, known) == (band, band)
+    assert pa.walked_steps(seq, seq, edge, edge) == (n * n, 0)
+    assert pa.walked_steps(seq, seq, edge, edge)[0] \
+        == pa.causal_tile_counts(seq, seq, edge, edge)[0]
+    # from positions, a row of tiles at a time
+    live = np.stack([_seen(edge, seq, iq * edge, 0, window).reshape(
+        edge, n, edge).any(axis=(0, 2)) for iq in range(n)])
+    assert live.sum(axis=1).max() == live.sum(axis=0).max() \
+        == pa.band_steps(edge, edge, window, n, n, seq)[0]
+    # a window of the sequence or more: the band is every tile
+    assert pa.walked_steps(seq, seq, edge, edge, seq, known) == (n * n, n)
+
+
+@pytest.mark.parametrize("offsets", [(192, 0), (0, 64), (1024, 0)],
+                         ids=["behind-the-window", "in-the-future",
+                              "far-behind"])
+@pytest.mark.parametrize("tiles", [(16, 16), (32, 8), (8, 32)])
+def test_a_row_whose_whole_band_is_dead(offsets, tiles):
+    """A K block that no query of the chunk sees (a ring's step behind
+    the window, or ahead of the chunk): every step of every band is
+    dead.  The last step gives 0 and -inf, a middle step hands the
+    state it was given through unchanged, and dQ, dK, dV are exactly
+    zero (the scratch is started and written on the band's first and
+    last step whatever lies between)."""
+    q_offset, k_offset = offsets
+    window, (bq, bk) = 40, tiles
+    q, k, v, dout = _operands(21)
+    assert not _seen(L, L, q_offset, k_offset, window).any()
+    out, lse, dq, dk, dv = _kernels(q, k, v, dout, q_offset, k_offset,
+                                    window, bq, bk)
+    for got in (out, dq, dk, dv):
+        assert not np.asarray(got).any()
+    assert np.isneginf(np.asarray(lse)).all()
+    rng = np.random.RandomState(2)
+    state = (jnp.asarray(rng.randn(BH, L), jnp.float32),
+             jnp.asarray(rng.rand(BH, L) + 0.5, jnp.float32),
+             jnp.asarray(rng.randn(BH, L, D), jnp.float32))
+    through = pa.flash_fwd_step(q, k, v, state, q_offset, k_offset,
+                                causal=True, block_q=bq, block_k=bk,
+                                interpret=True, window=window)
+    for got, want in zip(through, state):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_without_a_window_the_maps_are_the_causal_ones():

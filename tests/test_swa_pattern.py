@@ -243,7 +243,11 @@ def test_trains_over_dp_with_the_experts_shared_out():
 def test_attention_records_at_the_benchmarks_sizes():
     """One ``hvd_attn_window`` record an attention layer: at 16,384 in
     1024 x 1024 tiles a window of 2,048 leaves 45 of 256 tile pairs
-    live and masks 30; without one 136 and 16."""
+    live and masks 30, and the forward call walks a band of 3 tiles a
+    row, 48 steps for 256; the backward kernels' tiles are cut to 512
+    x 512 under it (150 live, 60 masked, a band of 5, 160 steps for
+    1,024).  Without a window 136 and 16 of 256, every pair walked,
+    either pass."""
     from horovod_tpu.runtime import flight
 
     cfg = TransformerConfig(
@@ -253,10 +257,17 @@ def test_attention_records_at_the_benchmarks_sizes():
     records = record_attention(cfg, batch=1)
     assert [(r["layer"], r["layer_kind"], r["window"]) for r in records] \
         == [(0, "S", 2048), (2, "S", 2048), (4, "G", 0)]
+    keys = ("block_q", "block_k", "grid", "band", "live", "masked")
     for r in records:
-        assert (r["seq"], r["block_q"], r["block_k"]) == (16384, 1024, 1024)
-        assert (r["grid"], r["live"], r["masked"]) == (
-            (256, 45, 30) if r["window"] else (256, 136, 16))
+        assert r["seq"] == 16384
+        forward = tuple(r[k] for k in keys)
+        backward = tuple(r["bwd_" + k] for k in keys)
+        if r["window"]:
+            assert forward == (1024, 1024, 48, 3, 45, 30)
+            assert backward == (512, 512, 160, 5, 150, 60)
+        else:
+            assert forward == backward == (1024, 1024, 256, 0, 136, 16)
     written = [e for e in flight.recorder().snapshot()
                if e["kind"] == "hvd_attn_window"]
     assert len(written) >= 3 and written[-1]["live"] == 136
+    assert written[-3]["grid"] == 48 and written[-3]["bwd_grid"] == 160
