@@ -20,7 +20,16 @@ Typical use::
 
 __version__ = "0.1.0"
 
-from horovod_tpu.common.basics import (  # noqa: F401
+# The first thing the package does (docs/flight-recorder.md): say when
+# this process started and span the package's own import, so a slow
+# start that never reached ``hvd.init()`` is in the ring too.
+from horovod_tpu.runtime import flight as _flight
+
+_flight.record_process()
+_import_span = _flight.span("hvd_import")
+_import_span.__enter__()      # closed at the end of this file
+
+from horovod_tpu.common.basics import (  # noqa: E402,F401
     ccl_built,
     cross_rank,
     cross_size,
@@ -138,3 +147,6 @@ from horovod_tpu.runtime.flight import (  # noqa: F401
 from horovod_tpu.runtime import health  # noqa: E402,F401
 from horovod_tpu import keras  # noqa: E402,F401  (callbacks subpackage)
 from horovod_tpu import elastic  # noqa: E402,F401  (hvd.elastic.run)
+
+_import_span.__exit__(None, None, None)
+del _import_span

@@ -674,10 +674,20 @@ def teardown_distributed(bound_s: float | None = None) -> None:
 
 def shutdown() -> None:
     """Tear down background machinery (reference ``horovod_shutdown``,
-    ``operations.cc:688``)."""
+    ``operations.cc:688``).  A clean exit leaves its flight ring where
+    ``HOROVOD_FLIGHT_DIR`` names a directory, as the failure paths do:
+    what the start of a run cost is read from it afterwards."""
+    if teardown():
+        _flight.dump("shutdown")
+
+
+def teardown() -> bool:
+    """``shutdown()`` without the ring's dump, for an elastic re-form:
+    it has dumped the generation it leaves already, and cleared the
+    ring since.  Returns whether there was anything to tear down."""
     with _state.lock:
         if not _state.initialized:
-            return
+            return False
         _flight.record("shutdown", rank=_state.rank,
                        generation=_state.epoch)
         # The goodput ledger's final accounting: a clean shutdown dumps
@@ -721,6 +731,7 @@ def shutdown() -> None:
         _state.data_axes = None
         _state.initialized = False
         _state.joined = False
+    return True
 
 
 def is_initialized() -> bool:

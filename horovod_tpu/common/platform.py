@@ -15,6 +15,7 @@ This must run BEFORE any JAX backend is initialized.
 from __future__ import annotations
 
 import os
+import threading
 
 _configured = False
 
@@ -58,6 +59,148 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 NAMES_VERSION = "hvd-names-1"
 
 
+# ---------------------------------------------------------------------------
+# A record for every program compiled (docs/flight-recorder.md)
+# ---------------------------------------------------------------------------
+
+# JAX times the three phases of a compile and says so through
+# ``jax.monitoring``, each with the program's ``fun_name``
+# (jax/_src/dispatch.py, pxla.py), and its persistent cache says what it
+# did in between (jax/_src/compiler.py).  They arrive in order on the
+# compiling thread:
+#   trace* -> lower -> backend[requests_use_cache, hits | misses] -> done
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+_CACHE_ASKED = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_SAVED = "/jax/compilation_cache/compile_time_saved_sec"
+
+
+class _Compiling(threading.local):
+    """The program this thread is compiling, as far as JAX has said."""
+
+    def __init__(self):
+        # fun_name -> (start, end) of its longest trace since the last
+        # program.  By name, not in a row: a step's lowering traces
+        # hundreds of small ``jit``s after the step's own trace has ended
+        self.traces: dict = {}
+        self.seconds = 0.0           # of the programs recorded so far
+        self.reset()
+
+    def reset(self) -> None:
+        self.traces.clear()
+        self.lower = None            # (fun_name, start, end)
+        self.forget_cache()
+
+    def forget_cache(self) -> None:
+        self.asked = self.hit = False
+        self.retrieval_s = self.saved_s = None
+
+
+_compiling = _Compiling()
+_listening = False
+
+
+def _on_compile_span(event: str, start: float, end: float,
+                     fun_name: str = "", **_) -> None:
+    """One of the three phases ended (``start`` and ``end`` on
+    ``time.time()``, the ring's ``wall`` clock).  The last one writes
+    the program's ``hvd_compile`` record."""
+    state = _compiling
+    if event == _TRACE:
+        if state.lower is not None:   # lowered and never compiled: dropped
+            state.reset()
+        known = state.traces.get(fun_name)
+        if known is None or end - start >= known[1] - known[0]:
+            if len(state.traces) >= 4096:      # traces no compile follows
+                state.traces.clear()
+            state.traces[fun_name] = (start, end)
+    elif event == _LOWER:
+        state.lower = (fun_name, start, end)
+        state.forget_cache()          # of a compile that raised
+    elif event == _BACKEND:
+        try:
+            _record_program(state, fun_name, start, end)
+        except Exception:             # a record never fails a compile
+            pass
+        state.reset()
+
+
+def _on_cache_event(event: str, **_) -> None:
+    if event == _CACHE_ASKED:
+        _compiling.asked = True
+    elif event == _CACHE_HIT:
+        _compiling.hit = True
+
+
+def _on_cache_seconds(event: str, seconds: float, **_) -> None:
+    if event == _CACHE_RETRIEVAL:
+        _compiling.retrieval_s = seconds
+    elif event == _CACHE_SAVED:
+        _compiling.saved_s = seconds
+
+
+def _record_program(state: _Compiling, fun_name: str, start: float,
+                    end: float) -> None:
+    """``hvd_compile`` in the flight ring, and the same seconds, once,
+    in ``hvd_compile_seconds_total`` (``path="warm"``: the persistent
+    cache served the executable)."""
+    from horovod_tpu.runtime import flight, metrics
+
+    lowered, lower_start, lower_end = state.lower or (fun_name, start, start)
+    # the program's trace is the outermost: the one the lowering names
+    # (``jit(step)`` of ``step``), not the ``jit``s traced inside it
+    traced = lowered[lowered.find("(") + 1:-1] if lowered.endswith(")") \
+        else lowered
+    trace_start, trace_end = state.traces.get(traced,
+                                              (lower_start, lower_start))
+    fields = {
+        "fun_name": fun_name,
+        "trace_s": trace_end - trace_start,
+        "lower_s": lower_end - lower_start,
+        "backend_s": end - start,
+        # no request reached the cache: JAX built no key for the program
+        "cache": ("hit" if state.hit else "miss" if state.asked
+                  else "uncached"),
+        "start_wall": trace_start,
+    }
+    if state.hit:
+        fields.update(retrieval_s=state.retrieval_s, saved_s=state.saved_s)
+    parent = flight.open_span()
+    if parent is not None:
+        fields["parent"] = parent
+    flight.record("hvd_compile", **fields)
+    seconds = fields["trace_s"] + fields["lower_s"] + fields["backend_s"]
+    state.seconds += seconds
+    metrics.counter("hvd_compile_seconds_total").inc(
+        seconds, path="warm" if state.hit else "cold")
+
+
+def compiled_seconds() -> float:
+    """The seconds of the programs this thread has compiled so far, as
+    counted into ``hvd_compile_seconds_total``: who times a stretch that
+    may hold a compile (``runtime/aot_cache``) takes them off, so that
+    each second is counted once."""
+    return _compiling.seconds
+
+
+def _listen_to_compiles() -> None:
+    """Register the listeners above, once a process.  They run only
+    while a program is compiled: a step that compiles nothing pays
+    nothing."""
+    global _listening
+    if _listening:
+        return
+    _listening = True
+    from jax import monitoring
+
+    monitoring.register_event_time_span_listener(_on_compile_span)
+    monitoring.register_event_listener(_on_cache_event)
+    monitoring.register_event_duration_secs_listener(_on_cache_seconds)
+
+
 def ensure_compile_cache() -> str:
     """Place JAX's persistent compile cache; returns the directory.
 
@@ -66,10 +209,12 @@ def ensure_compile_cache() -> str:
     (a fixed place: a directory that moves between runs never hits),
     exported too so spawned ranks and child processes inherit it.  This
     is the only place that names a compile cache path.  Either way
-    ``NAMES_VERSION`` becomes part of every key."""
+    ``NAMES_VERSION`` becomes part of every key, and every program
+    compiled from here on leaves an ``hvd_compile`` record."""
     from jax._src import cache_key
 
     cache_key.custom_hook = lambda: NAMES_VERSION
+    _listen_to_compiles()
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
         path = os.path.join(_CHECKOUT, ".jax_cache")

@@ -620,7 +620,7 @@ def _drain(state: ElasticState, interrupt) -> None:
                        rank=st.rank)
         _flight.dump(f"preempt:g{gen}")
         try:
-            _basics.shutdown()
+            _basics.teardown()
             _basics.teardown_distributed()
         except Exception:
             pass
@@ -842,7 +842,7 @@ def _apply_roster(state: ElasticState, roster: dict, mine: dict) -> dict:
     aot0 = _aot.stats()
     t_td = time.monotonic()
     n, gen = int(roster["size"]), int(roster["gen"])
-    _basics.shutdown()                # background runtime + heartbeats
+    _basics.teardown()                # background runtime + heartbeats
     _basics.teardown_distributed()    # bounded; clears program caches
     teardown_s = time.monotonic() - t_td
     env = os.environ

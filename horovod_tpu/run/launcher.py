@@ -30,6 +30,7 @@ from dataclasses import dataclass
 from horovod_tpu.common import config as _config
 from horovod_tpu.common import logging as _log
 from horovod_tpu.common.platform import cpu_asked_for
+from horovod_tpu.runtime import flight as _flight
 
 
 def _start_metrics_aggregator(base_env: dict, kv, local_only: bool,
@@ -113,16 +114,17 @@ def _stop_metrics_aggregator(agg) -> None:
 
 def _sweep_flight_dir(base_env: dict, context: str) -> list[str]:
     """Flight-recorder sweep (docs/flight-recorder.md): when the job
-    ran with ``--flight-dir``, report which per-rank dumps landed there
-    — at wrap-up and after observed re-forms — and print the one-liner
-    that merges them into a fleet trace.  Purely informational: the
-    dumps are the ranks' own atomic writes; the launcher just makes
-    sure nobody has to remember where the black boxes fell."""
+    ran with a flight directory (``HOROVOD_FLIGHT_DIR``, which the
+    knob's flag ``--flight-dir`` sets and ``--output-filename <dir>``
+    implies as ``<dir>/flight``), report which per-rank dumps landed
+    there — at wrap-up and after observed re-forms — and print the
+    one-liner that merges them into a fleet trace.  Purely
+    informational: the dumps are the ranks' own atomic writes; the
+    launcher just makes sure nobody has to remember where the black
+    boxes fell."""
     d = base_env.get("HOROVOD_FLIGHT_DIR") or ""
     if not d:
         return []
-    from horovod_tpu.runtime import flight as _flight
-
     dumps = _flight.sweep(d)
     if dumps:
         print(f"[hvdrun] flight recorder ({context}): "
@@ -355,17 +357,11 @@ def _pid_is_live(pid: int) -> bool:
         return False
 
 
-def _proc_starttime(pid: int) -> str | None:
-    """Kernel start-tick of ``pid`` (``/proc/<pid>/stat`` field 22) —
-    the cheap process-identity stamp: a recycled pid necessarily has a
-    different starttime.  None when unreadable (gone, or no /proc)."""
-    try:
-        with open(f"/proc/{pid}/stat", "rb") as f:
-            st = f.read()
-        rest = st[st.rfind(b")") + 2:].split()
-        return rest[19].decode()
-    except (OSError, IndexError, ValueError):
-        return None
+def _proc_starttime(pid: int) -> int | None:
+    """Kernel start-tick of ``pid`` — the cheap process-identity stamp:
+    a recycled pid necessarily has a different starttime.  None when
+    unreadable (gone, or no /proc)."""
+    return _flight.start_ticks(pid)
 
 
 def _stamp_identity(proc) -> None:
@@ -696,12 +692,40 @@ def launch(np_: int, command: list[str], hosts=None, hostfile=None,
     key survives — with ``HOROVOD_RESTART_ATTEMPT`` exported, plus
     ``HOROVOD_RESUME_STEP`` pointing at the latest *complete* snapshot
     under ``checkpoint_dir`` (``HOROVOD_CHECKPOINT_DIR``; torn
-    snapshots are refused via :func:`checkpoint.latest_complete`)."""
+    snapshots are refused via :func:`checkpoint.latest_complete`).
+
+    The launcher's own flight ring (docs/flight-recorder.md) spans the
+    job as ``hvd_launch``, around ``hvd_launch.preflight`` /
+    ``.kv_server`` / one ``.spawn`` a rank / ``.wait``, and is dumped
+    beside the ranks' at every wrap-up.  ``output_filename`` implies a
+    flight directory, ``<output_filename>/flight``, where the
+    environment names none: who asked for each rank's output in a
+    directory gets each rank's ring beside it."""
+    env = dict(os.environ if env is None else env)
+    if output_filename and not env.get("HOROVOD_FLIGHT_DIR"):
+        env["HOROVOD_FLIGHT_DIR"] = os.path.join(output_filename, "flight")
+    try:
+        with _flight.span("hvd_launch", np=np_):
+            return _launch_attempts(
+                np_, command, hosts, hostfile, output_filename, verbose,
+                start_timeout, env, kv_server, prefix_timestamp,
+                restart_attempts, checkpoint_dir)
+    finally:
+        _flight.dump("launcher wrap-up",
+                     directory=env.get("HOROVOD_FLIGHT_DIR") or None)
+
+
+def _launch_attempts(np_: int, command: list[str], hosts, hostfile,
+                     output_filename, verbose, start_timeout, env: dict,
+                     kv_server, prefix_timestamp: bool, restart_attempts,
+                     checkpoint_dir) -> int:
+    """``launch()`` inside its span: the job, and its restarts."""
     host_list = (parse_hostfile(hostfile) if hostfile
                  else parse_host_spec(hosts, np_))
     slots = allocate(host_list, np_)
     this_host = socket.gethostname()
-    preflight_hosts(host_list, start_timeout, this_host)
+    with _flight.span("hvd_launch.preflight"):
+        preflight_hosts(host_list, start_timeout, this_host)
     local_only = all(h in ("localhost", this_host, "127.0.0.1")
                      for h, _ in host_list)
     # The KV rendezvous server runs here (launcher host); the jax
@@ -730,11 +754,7 @@ def launch(np_: int, command: list[str], hosts=None, hostfile=None,
               file=sys.stderr)
         attempts = 0
 
-    def _envtruthy(key: str) -> bool:
-        raw = (os.environ if env is None else env).get(key, "")
-        return _config._parse_bool(str(raw))
-
-    elastic = _envtruthy("HOROVOD_ELASTIC")
+    elastic = _config._parse_bool(str(env.get("HOROVOD_ELASTIC", "")))
     extra_env: dict[str, str] = {}
     rc = 1
     for attempt in range(attempts + 1):
@@ -781,9 +801,22 @@ def launch(np_: int, command: list[str], hosts=None, hostfile=None,
 def _spawn_proc(command: list[str], renv: dict, hostname: str,
                 rank_label, this_host: str, output_filename,
                 prefix_timestamp: bool, pumps: list) -> subprocess.Popen:
-    """Spawn one rank process (local subprocess or ssh) with output
-    capture wired up; shared by the classic fail-fast path and the
-    elastic monitor."""
+    """Spawn one rank process under an ``hvd_launch.spawn`` span (its
+    ``E`` carries the ``pid``); shared by the classic fail-fast path
+    and the elastic monitor."""
+    with _flight.span("hvd_launch.spawn", rank=rank_label) as spawning:
+        proc = _popen_rank(command, renv, hostname, rank_label, this_host,
+                           output_filename, prefix_timestamp, pumps)
+        # getattr: tests substitute minimal fake processes
+        spawning.fields["pid"] = getattr(proc, "pid", None)
+    return proc
+
+
+def _popen_rank(command: list[str], renv: dict, hostname: str,
+                rank_label, this_host: str, output_filename,
+                prefix_timestamp: bool, pumps: list) -> subprocess.Popen:
+    """One rank process (local subprocess or ssh) with output capture
+    wired up."""
     if output_filename:
         d = os.path.join(output_filename, f"rank.{rank_label}")
         os.makedirs(d, exist_ok=True)
@@ -885,7 +918,8 @@ def _launch_once(command: list[str], slots: list[SlotInfo], this_host: str,
             _secrets.token_hex(32)
         # a failed native build raises: the launcher does not start a
         # job on another transport than the one it was asked for
-        kv = KVStoreServer(secret=decode_secret(job_secret))
+        with _flight.span("hvd_launch.kv_server"):
+            kv = KVStoreServer(secret=decode_secret(job_secret))
     else:
         job_secret = (env or os.environ).get("HOROVOD_SECRET_KEY", "")
     kv_port = kv.port
@@ -941,33 +975,36 @@ def _launch_once(command: list[str], slots: list[SlotInfo], this_host: str,
         t.start()
 
     try:
-        while any(t.is_alive() for t in threads):
-            if failed.is_set():
-                # one dead rank kills the job (reference gloo_run.py:294)
-                # Signal every rank's GROUP, even ranks that already
-                # exited — a dead group leader can still leave live
-                # helpers in its group (killpg targets the pgid, which
-                # outlives the leader while members remain).
-                for p in procs:
-                    _signal_rank(p, signal.SIGTERM)
-                break
-            for t in threads:
-                t.join(timeout=0.2)
-        # TERM -> KILL escalation on one shared deadline (a rank stuck
-        # in a shutdown barrier must not stall the whole job); the
-        # deadline is HOROVOD_SHUTDOWN_TIMEOUT_SECONDS, the same knob
-        # bounding the ranks' own distributed-shutdown barrier.
-        import time as _time
+        with _flight.span("hvd_launch.wait"):
+            while any(t.is_alive() for t in threads):
+                if failed.is_set():
+                    # one dead rank kills the job (reference
+                    # gloo_run.py:294).  Signal every rank's GROUP, even
+                    # ranks that already exited — a dead group leader
+                    # can still leave live helpers in its group (killpg
+                    # targets the pgid, which outlives the leader while
+                    # members remain).
+                    for p in procs:
+                        _signal_rank(p, signal.SIGTERM)
+                    break
+                for t in threads:
+                    t.join(timeout=0.2)
+            # TERM -> KILL escalation on one shared deadline (a rank
+            # stuck in a shutdown barrier must not stall the whole
+            # job); the deadline is HOROVOD_SHUTDOWN_TIMEOUT_SECONDS,
+            # the same knob bounding the ranks' own distributed-shutdown
+            # barrier.
+            import time as _time
 
-        deadline = _time.monotonic() + max(
-            1, _config.get("shutdown_timeout"))
-        for t in threads:
-            t.join(timeout=max(0.0, deadline - _time.monotonic()))
-        for p in procs:
-            _signal_rank(p, signal.SIGKILL)
-        for t in threads:
-            t.join(timeout=5)
-        _drain_pumps(pumps)
+            deadline = _time.monotonic() + max(
+                1, _config.get("shutdown_timeout"))
+            for t in threads:
+                t.join(timeout=max(0.0, deadline - _time.monotonic()))
+            for p in procs:
+                _signal_rank(p, signal.SIGKILL)
+            for t in threads:
+                t.join(timeout=5)
+            _drain_pumps(pumps)
     finally:
         _sweep_flight_dir(base_env, "wrap-up")
         _sweep_health_dir(base_env)
@@ -1066,7 +1103,8 @@ def _launch_elastic(command: list[str], slots: list[SlotInfo],
             _secrets.token_hex(32)
         # Elastic re-forms need a rendezvous that outlives the jax
         # coordination service; a failed native build raises.
-        kv = KVStoreServer(secret=decode_secret(job_secret))
+        with _flight.span("hvd_launch.kv_server"):
+            kv = KVStoreServer(secret=decode_secret(job_secret))
     else:
         job_secret = (env or os.environ).get("HOROVOD_SECRET_KEY", "")
     kv_port = kv.port
@@ -1371,6 +1409,9 @@ def _launch_elastic(command: list[str], slots: list[SlotInfo],
 
     preempt_req = {"last": None}
     last_status = None
+    # entered and left by hand: the monitor below is 250 lines long
+    waiting = _flight.span("hvd_launch.wait")
+    waiting.__enter__()
     try:
         while live:
             _time.sleep(0.25)
@@ -1602,6 +1643,7 @@ def _launch_elastic(command: list[str], slots: list[SlotInfo],
                 _signal_rank(rec.proc, signal.SIGKILL)
         _drain_pumps(pumps)
     finally:
+        waiting.__exit__(None, None, None)
         if term_installed:
             try:
                 signal.signal(signal.SIGTERM,
@@ -1609,14 +1651,10 @@ def _launch_elastic(command: list[str], slots: list[SlotInfo],
             except (ValueError, OSError):
                 pass
         if ap is not None and ap.actions:
-            # The verdicts live on the launcher's own flight ring —
-            # land them beside the rank dumps so the merged trace
-            # carries every autopilot action with its evidence tuple.
-            from horovod_tpu.runtime import flight as _flight
-
-            _flight.dump("launcher wrap-up",
-                         directory=base_env.get(
-                             "HOROVOD_FLIGHT_DIR") or None)
+            # The verdicts live on the launcher's own flight ring, which
+            # launch() lands beside the rank dumps at every wrap-up: the
+            # merged trace carries every autopilot action with its
+            # evidence tuple.
             ap_stats = ap.stats()
             print(f"[hvdrun autopilot] "
                   f"{ap_stats['actions_total']} verdict(s): "

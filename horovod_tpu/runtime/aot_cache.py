@@ -55,6 +55,7 @@ import time
 
 from horovod_tpu.common import config as _config
 from horovod_tpu.common import logging as _log
+from horovod_tpu.common import platform as _platform
 from horovod_tpu.runtime import metrics as _metrics
 
 SCHEMA = 2
@@ -74,9 +75,10 @@ _M_EVICTIONS = _metrics.counter(
     "version-skewed or wrong-key files) — each eviction recompiles.")
 _M_COMPILE_S = _metrics.counter(
     "hvd_compile_seconds_total",
-    "Wall seconds spent materializing negotiated programs, labeled "
-    "path=cold (trace + lower + XLA compile) vs path=warm (AOT cache "
-    "load).")
+    "Wall seconds spent materializing programs, every jax.jit one and "
+    "the negotiated ones, labeled path=cold (trace + lower + XLA "
+    "compile) vs path=warm (a load from JAX's persistent cache or from "
+    "the AOT cache).")
 
 _warned: set = set()
 _version_cache: tuple | None = None
@@ -294,21 +296,35 @@ def compile_or_load(program_key, build, args):
     binds).  Returns a callable with the program's calling convention
     — a cache-loaded executable on a hit, the AOT-compiled program on
     a miss (persisted for next time), or the plain jitted function if
-    AOT lowering itself fails.  Compile seconds are counted either way
-    (``hvd_compile_seconds_total{path=cold|warm}``)."""
+    AOT lowering itself fails.
+
+    ``hvd_compile_seconds_total{path=cold|warm}`` gets every second
+    once: what JAX compiles here (the miss's ``lower().compile()``, an
+    ``export`` entry's recompile) is counted by its own compile events
+    (``common/platform``, one ``hvd_compile`` record a program); this
+    function adds only the seconds those never see — a load from the
+    entry's file, a lowering that failed."""
     t0 = time.perf_counter()
+    seen0 = _platform.compiled_seconds()
+
+    def unseen_s() -> float:
+        return max(0.0, time.perf_counter() - t0
+                   - (_platform.compiled_seconds() - seen0))
+
     if enabled():
         loaded = _try_load(program_key, args)
         if loaded is not None:
-            dt = time.perf_counter() - t0
+            load_s = unseen_s()
             _M_HITS.inc()
-            _M_COMPILE_S.inc(dt, path="warm")
+            _M_COMPILE_S.inc(load_s, path="warm")
             try:
                 from horovod_tpu.runtime import flight as _flight
 
+                # (``program``, not ``kind``: that is record()'s own
+                # argument, and the TypeError used to be swallowed here)
                 _flight.record("aot", event="hit",
-                               kind=_label(program_key),
-                               load_s=round(dt, 4))
+                               program=_label(program_key),
+                               load_s=round(load_s, 4))
             except Exception:
                 pass
             return loaded
@@ -317,13 +333,11 @@ def compile_or_load(program_key, build, args):
     try:
         compiled = fn.lower(*args).compile()
     except Exception as exc:
-        _M_COMPILE_S.inc(time.perf_counter() - t0, path="cold")
+        _M_COMPILE_S.inc(unseen_s(), path="cold")
         _warn_once("lower", f"AOT lower/compile unavailable for "
                             f"{_label(program_key)} ({exc!r}); using "
                             "lazy jit (not cacheable)")
         return fn
-    compile_s = time.perf_counter() - t0
-    _M_COMPILE_S.inc(compile_s, path="cold")
     if enabled():
         fmt = mode()
         try:
@@ -341,7 +355,9 @@ def compile_or_load(program_key, build, args):
                 "key": _key_material(program_key),
                 "label": _label(program_key),
                 "created": time.time(),
-                "compile_s": round(compile_s, 4),
+                # what a hit saves: the compile as JAX timed it
+                "compile_s": round(
+                    _platform.compiled_seconds() - seen0, 4),
                 "payload": payload,
             })
     return compiled

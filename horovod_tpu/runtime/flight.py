@@ -20,9 +20,10 @@ is.
 
 On :class:`~horovod_tpu.common.types.RanksDownError`, coordinated
 abort, a fatal signal (SIGTERM/SIGABRT — handlers installed at
-``hvd.init()``), an elastic re-form, or an explicit
-``hvd.dump_flight_recorder()``, the ring dumps atomically (tmp +
-rename) as JSONL into ``HOROVOD_FLIGHT_DIR``; the launcher sweeps the
+``hvd.init()``), an elastic re-form, a clean ``hvd.shutdown()``, or
+an explicit ``hvd.dump_flight_recorder()``, the ring dumps atomically
+(tmp + rename) as JSONL into ``HOROVOD_FLIGHT_DIR``; the launcher
+leaves its own ring there too and sweeps the
 directory at wrap-up and on re-forms.  The offline tool
 ``python -m horovod_tpu.trace merge <dir>`` aligns rank clocks from
 the heartbeat-piggybacked offset samples (``clk`` events), emits one
@@ -48,6 +49,13 @@ import time
 _ENV_EVENTS = "HOROVOD_FLIGHT_EVENTS"
 _ENV_DIR = "HOROVOD_FLIGHT_DIR"
 _DEFAULT_EVENTS = 4096
+# Set-up's kinds (the prefixes of ``hvd_process``, ``hvd_import``,
+# ``hvd_init*``, ``hvd_compile``): what a run's start cost is read at
+# its end, after a launched world's background thread has recorded
+# rounds and heartbeats all through the run.  The first _SETUP_KEEP of
+# them are held beside the ring, where its wrap does not reach.
+_SETUP_KINDS = ("hvd_process", "hvd_import", "hvd_init", "hvd_compile")
+_SETUP_KEEP = 512
 
 
 class FlightRecorder:
@@ -70,6 +78,7 @@ class FlightRecorder:
         self._lock = threading.RLock()
         self._slots: list = [None] * self.capacity
         self._seq = 0
+        self._kept: list = []      # set-up's events, at most _SETUP_KEEP
 
     def record(self, kind: str, ph: str = "i", **fields) -> None:
         """Record one event.  ``ph`` follows Chrome-trace phases:
@@ -78,23 +87,30 @@ class FlightRecorder:
         if not self.capacity:
             return
         mono, wall = time.monotonic(), time.time()
+        setup = kind.startswith(_SETUP_KINDS)
         with self._lock:
             s = self._seq
-            self._slots[s % self.capacity] = (s, mono, wall, kind, ph,
-                                              fields or None)
+            slot = self._slots[s % self.capacity] = (s, mono, wall, kind,
+                                                     ph, fields or None)
             self._seq = s + 1
+            if setup and len(self._kept) < _SETUP_KEEP:
+                self._kept.append(slot)
 
     def snapshot(self) -> list[dict]:
-        """Ordered copy of the ring as dicts (oldest first)."""
+        """Ordered copy of the ring as dicts (oldest first), before it
+        the set-up events the ring has since overwritten."""
         with self._lock:
             seq = self._seq
             slots = list(self._slots)
+            kept = list(self._kept)
         if seq <= self.capacity:
             ordered = [s for s in slots[:seq] if s is not None]
         else:
             head = seq % self.capacity
             ordered = [s for s in slots[head:] + slots[:head]
                        if s is not None]
+        oldest = ordered[0][0] if ordered else seq
+        ordered = [s for s in kept if s[0] < oldest] + ordered
         out = []
         for s, mono, wall, kind, ph, fields in ordered:
             ev = {"seq": s, "mono": mono, "wall": wall, "kind": kind,
@@ -119,6 +135,7 @@ class FlightRecorder:
         with self._lock:
             self._slots = [None] * self.capacity
             self._seq = 0
+            self._kept = []
 
     def dump(self, path: str, meta: dict | None = None) -> str:
         """Atomically write the ring as JSONL: a ``{"meta": ...}``
@@ -238,6 +255,49 @@ class span:
         except (AttributeError, ValueError):   # closed on another thread
             pass
         record(self.kind, "E", id=self.id, **self.fields)
+
+
+def open_span() -> int | None:
+    """The id of the innermost span open on this thread."""
+    ids = getattr(_open_spans, "ids", None)
+    return ids[-1] if ids else None
+
+
+def start_ticks(pid="self") -> int | None:
+    """Kernel start tick of ``pid`` (``/proc/<pid>/stat`` field 22, in
+    ticks of 10 ms since boot); ``None`` when unreadable (gone, or no
+    /proc)."""
+    try:
+        with open(f"/proc/{pid}/stat", "rb") as f:
+            stat = f.read()
+        return int(stat[stat.rfind(b")") + 2:].split()[19])
+    except (OSError, IndexError, ValueError):
+        return None
+
+
+def process_started_wall() -> float | None:
+    """When the kernel started this process, on the ``wall`` clock: now
+    less its age, which is ``CLOCK_BOOTTIME`` less its start tick.
+    (``/proc/stat``'s ``btime`` would give the boot's own time, but it
+    is cut to whole seconds.)  ``None`` where the kernel says neither."""
+    ticks = start_ticks()
+    if ticks is None or not hasattr(time, "CLOCK_BOOTTIME"):
+        return None
+    age = (time.clock_gettime(time.CLOCK_BOOTTIME)
+           - ticks / os.sysconf("SC_CLK_TCK"))
+    return time.time() - age
+
+
+def record_process() -> None:
+    """``hvd_process``: this process's start and what it was started as.
+    With the ``hvd_init`` span's ``B`` it bounds what the interpreter
+    and the imports cost before ``hvd.init()`` was entered."""
+    argv = getattr(sys, "orig_argv", sys.argv)
+    # under ``python -m pkg.mod`` argv[0] is still "-m" while pkg imports
+    argv0 = (" ".join(argv[1:3]) if sys.argv[:1] in (["-m"], ["-c"])
+             else "".join(sys.argv[:1]))
+    record("hvd_process", started_wall=process_started_wall(),
+           argv0=argv0[:80])
 
 
 def flight_dir() -> str:

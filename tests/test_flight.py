@@ -111,11 +111,13 @@ def test_zero_capacity_disables_recording():
     assert r.snapshot() == [] and r.recorded_total() == 0
 
 
-def test_record_is_syscall_free_and_bounded():
+@pytest.mark.parametrize("kind", ["hot", "hvd_compile"])
+def test_record_is_syscall_free_and_bounded(kind):
     """Acceptance: recording performs no syscalls (open/socket banned
     during a burst) and ring memory stays at HOROVOD_FLIGHT_EVENTS
     entries regardless of run length — the PR 6 lock-cheap registry
-    bound, applied to the flight ring."""
+    bound, applied to the flight ring.  A set-up kind, of which the
+    first 512 are held beside the ring, costs no more."""
     import builtins
 
     r = flight.FlightRecorder(64)
@@ -133,17 +135,218 @@ def test_record_is_syscall_free_and_bounded():
     try:
         t0 = time.perf_counter()
         for i in range(30000):
-            r.record("hot", round=i, n_req=2)
+            r.record(kind, round=i, n_req=2)
         dt = time.perf_counter() - t0
     finally:
         builtins.open = real_open
         socket.socket = real_socket
     assert r.recorded_total() == 30000
-    assert len(r.snapshot()) == 64
+    kept = 0 if kind == "hot" else flight._SETUP_KEEP
+    assert len(r.snapshot()) == 64 + kept
     assert len(r._slots) == 64  # no allocation growth with run length
+    assert len(r._kept) == kept
     # generous bound for a loaded CI image; a hidden syscall per record
     # would blow far past it
     assert dt < 5.0, f"hot path too slow: {dt:.2f}s for 30k records"
+
+
+def test_set_up_records_survive_a_ring_that_wraps():
+    """Set-up's kinds are read at the end of a run, after a launched
+    world's background thread has wrapped the ring many times: the
+    recorder holds them beside it and ``snapshot()`` merges them by
+    ``seq``, each event once."""
+    r = flight.FlightRecorder(8)
+    r.record("hvd_process", started_wall=1.0)
+    r.record("hvd_import", "B", id=1)
+    r.record("round", round=0)
+    r.record("hvd_init", "B", id=2)
+    r.record("hvd_init.backend", "B", id=3)
+    r.record("hvd_compile", fun_name="jit(step)")
+    # not yet wrapped: nothing twice
+    assert [e["seq"] for e in r.snapshot()] == list(range(6))
+    for i in range(100):
+        r.record("round", round=i)
+    snap = r.snapshot()
+    assert [e["kind"] for e in snap[:5]] == [
+        "hvd_process", "hvd_import", "hvd_init", "hvd_init.backend",
+        "hvd_compile"]
+    assert [e["seq"] for e in snap] == [0, 1, 3, 4, 5] + list(range(98, 106))
+    assert snap[4]["fun_name"] == "jit(step)"
+    assert r.recorded_total() == 106 and len(r._slots) == 8
+    # a new generation starts with neither
+    r.clear()
+    assert r.snapshot() == [] and r._kept == []
+
+
+# ---------------------------------------------------------------------------
+# A record for every program compiled
+# ---------------------------------------------------------------------------
+
+_COMPILE_PROBE = """
+import json, sys
+import jax, jax.numpy as jnp
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+from horovod_tpu.common import platform
+from horovod_tpu.runtime import flight, metrics
+platform.ensure_compile_cache()
+platform.ensure_compile_cache()      # listens once, whoever asks again
+if sys.argv[1] == "off":
+    jax.config.update("jax_enable_compilation_cache", False)
+
+@jax.jit
+def inner(x):
+    return jnp.tanh(x) * 2
+
+def probe(x):
+    return inner(x) + 1
+
+x = jnp.ones(4)
+with flight.span("setup") as setup:
+    jax.jit(probe).lower(x).compile()
+try:
+    jax.jit(lambda y: y.nothing_here).lower(x)    # traced, never compiled
+except AttributeError:
+    pass
+jax.jit(probe).lower(jnp.ones(5)).compile()       # outside any span
+print(json.dumps({
+    "span": setup.id,
+    "records": [e for e in flight.recorder().snapshot()
+                if e["kind"] == "hvd_compile"],
+    "counter": metrics.counter("hvd_compile_seconds_total").series()}))
+"""
+
+
+def _compile_probe(cache_dir: str) -> dict:
+    # the cache is where the variable says (common/platform)
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=cache_dir)
+    done = subprocess.run([sys.executable, "-c", _COMPILE_PROBE,
+                           os.path.basename(cache_dir)],
+                          capture_output=True, text=True, timeout=120,
+                          env=env, cwd=REPO)
+    assert done.returncode == 0, done.stderr[-2000:]
+    return json.loads(done.stdout.strip().splitlines()[-1])
+
+
+def _check_probe(out: dict, cache: str) -> None:
+    """Two programs named ``jit(probe)``, one record each, the first
+    under the span; the counter holds the seconds of the process's
+    records, once."""
+    probes = [e for e in out["records"] if e["fun_name"] == "jit(probe)"]
+    assert len(probes) == 2, out["records"]
+    assert [e["cache"] for e in probes] == [cache, cache]
+    assert probes[0]["parent"] == out["span"] and "parent" not in probes[1]
+    for e in probes:
+        assert e["trace_s"] > 0 and e["lower_s"] > 0 and e["backend_s"] > 0
+        # the ring's wall clock, and the trace is where the program began
+        assert e["start_wall"] + e["trace_s"] <= e["wall"]
+        assert ("retrieval_s" in e) == (cache == "hit")
+    # the program's trace is the outermost one, not the sum with the
+    # ``jit`` traced inside it, and the abandoned lowering left nothing
+    assert not [e for e in out["records"] if "lambda" in e["fun_name"]]
+    seconds = {"warm": 0.0, "cold": 0.0}
+    for e in out["records"]:
+        seconds["warm" if e["cache"] == "hit" else "cold"] += (
+            e["trace_s"] + e["lower_s"] + e["backend_s"])
+    counted = {"warm": 0.0, "cold": 0.0}
+    for series in out["counter"]:
+        counted[series["labels"]["path"]] = series["value"]
+    assert counted["warm"] == pytest.approx(seconds["warm"], abs=1e-9)
+    assert counted["cold"] == pytest.approx(seconds["cold"], abs=1e-9)
+
+
+def test_compile_record_misses_then_hits_in_a_second_process(tmp_path):
+    """Every program compiled leaves one ``hvd_compile`` record:
+    ``cache`` ``miss`` in the process that fills a temporary cache
+    directory, ``hit`` (with what the retrieval cost) in the next, and
+    ``hvd_compile_seconds_total`` holds the same seconds once, by
+    path."""
+    cache_dir = str(tmp_path / "jax_cache")
+    _check_probe(_compile_probe(cache_dir), "miss")
+    _check_probe(_compile_probe(cache_dir), "hit")
+
+
+def test_compile_record_says_uncached_with_the_cache_off(tmp_path):
+    _check_probe(_compile_probe(str(tmp_path / "off")), "uncached")
+
+
+def test_a_programs_trace_is_found_behind_the_traces_its_lowering_makes(
+        monkeypatch):
+    """The events as JAX sends them for a step whose lowering traces
+    hundreds of small ``jit``s after the step's own trace has ended
+    (``gpt2-124m.s1024`` on the chip, PR 37): the record's ``trace_s``
+    is the step's, the longest of its name, and a trace that no
+    compile followed is dropped with the next program's first event."""
+    from horovod_tpu.common import platform
+
+    monkeypatch.setattr(flight, "_recorder", flight.FlightRecorder(16))
+    monkeypatch.setattr(platform, "_compiling", platform._Compiling())
+    send = platform._on_compile_span
+    send(platform._TRACE, 50.0, 51.0, fun_name="abandoned")
+    send(platform._LOWER, 51.0, 52.0, fun_name="jit(abandoned)")
+    send(platform._TRACE, 100.5, 100.75, fun_name="tanh")   # inside step's
+    send(platform._TRACE, 100.0, 103.0, fun_name="step")
+    for i in range(300):
+        send(platform._TRACE, 103.5, 103.5 + 2 ** -10, fun_name=f"small{i}")
+    send(platform._TRACE, 103.75, 103.75 + 2 ** -10, fun_name="step")
+    send(platform._LOWER, 103.25, 104.25, fun_name="jit(step)")
+    platform._on_cache_event(platform._CACHE_ASKED)
+    send(platform._BACKEND, 104.5, 106.5, fun_name="jit(step)")
+    send(platform._LOWER, 107.0, 107.5, fun_name="jit(cached_trace)")
+    send(platform._BACKEND, 107.5, 107.75, fun_name="jit(cached_trace)")
+    first, second = [e for e in flight.recorder().snapshot()
+                     if e["kind"] == "hvd_compile"]
+    assert (first["fun_name"], first["trace_s"], first["lower_s"],
+            first["backend_s"], first["cache"], first["start_wall"]) == (
+        "jit(step)", 3.0, 1.0, 2.0, "miss", 100.0)
+    assert (second["trace_s"], second["lower_s"], second["cache"],
+            second["start_wall"]) == (0.0, 0.5, "uncached", 107.0)
+    assert platform.compiled_seconds() == 6.75
+
+
+def test_compile_seconds_reach_the_counter_once_through_the_aot_cache(
+        tmp_path, monkeypatch):
+    """``aot_cache.compile_or_load`` adds to
+    ``hvd_compile_seconds_total`` only what JAX's compile events do not
+    see: a miss's ``lower().compile()`` is counted by its
+    ``hvd_compile`` record alone, a hit from the ``.aot`` file (which
+    compiles nothing) by the ``aot`` record's ``load_s`` alone."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.runtime import aot_cache, metrics
+
+    monkeypatch.setenv("HOROVOD_AOT_CACHE_DIR", str(tmp_path / "aot"))
+    monkeypatch.delenv("HOROVOD_AOT_CACHE_MODE", raising=False)
+    monkeypatch.setattr(flight, "_recorder", flight.FlightRecorder(256))
+    counter = metrics.counter("hvd_compile_seconds_total")
+    x = jnp.arange(7.0)
+
+    def build():
+        return jax.jit(lambda v: v * 3 - 1)
+
+    def grown_and_recorded(run) -> tuple:
+        before, total0 = flight.recorder().recorded_total(), counter.total()
+        fn = run()
+        assert float(fn(x)[2]) == 5.0
+        events = flight.recorder().snapshot()[before:]
+        compiled = sum(e["trace_s"] + e["lower_s"] + e["backend_s"]
+                       for e in events if e["kind"] == "hvd_compile")
+        loaded = sum(e["load_s"] for e in events if e["kind"] == "aot"
+                     and e["event"] == "hit")
+        return counter.total() - total0, compiled, loaded
+
+    key = ("t_flight_once", (7,), "f32")
+    grew, compiled, loaded = grown_and_recorded(
+        lambda: aot_cache.compile_or_load(key, build, [x]))
+    assert compiled > 0 and loaded == 0
+    assert grew == pytest.approx(compiled, abs=1e-9)
+    grew, compiled, loaded = grown_and_recorded(
+        lambda: aot_cache.compile_or_load(key, build, [x]))
+    assert compiled == 0 and loaded > 0
+    # load_s is rounded to 0.1 ms in the ring
+    assert grew == pytest.approx(loaded, abs=1e-4)
 
 
 # ---------------------------------------------------------------------------
@@ -379,7 +582,9 @@ def test_sigterm_dumps_ring(tmp_path):
     d = dumps[0]
     assert d.rank == 3 and d.meta["reason"] == "signal:SIGTERM"
     kinds = [e["kind"] for e in d.events]
-    assert kinds[0] == "round" and "signal" in kinds
+    # the ring opens with the process's own start (the package's import)
+    assert kinds[:4] == ["hvd_process", "hvd_import", "hvd_import", "round"]
+    assert "signal" in kinds
 
 
 def test_failure_dump_flushes_terminal_metrics(tmp_path, monkeypatch):

@@ -168,6 +168,64 @@ def test_hvdrun_end_to_end(tmp_path):
 
 
 @pytest.mark.multiprocess
+@pytest.mark.parametrize("explicit", [False, True])
+def test_output_filename_leaves_every_ring_beside_the_logs(tmp_path,
+                                                           explicit):
+    """``--output-filename <dir>`` implies ``HOROVOD_FLIGHT_DIR=<dir>/
+    flight``: a clean ``hvd.shutdown()`` dumps each rank's ring there
+    and the launcher its own, each with the process's start and, in a
+    rank, a closed ``hvd_init``.  A flight directory the environment
+    names still wins."""
+    from horovod_tpu.trace.merge import load_dumps
+
+    out_dir = tmp_path / "logs"
+    flight_dir = tmp_path / "elsewhere" if explicit else out_dir / "flight"
+    env = dict(os.environ)
+    env.pop("HOROVOD_FLIGHT_DIR", None)
+    env.update({"PYTHONPATH": REPO, "HOROVOD_PLATFORM": "cpu",
+                "XLA_FLAGS": "--xla_force_host_platform_device_count=1"})
+    if explicit:
+        env["HOROVOD_FLIGHT_DIR"] = str(flight_dir)
+    rc = subprocess.run(
+        [sys.executable, "-m", "horovod_tpu.run", "-np", "2",
+         "--output-filename", str(out_dir), "--",
+         sys.executable, "-c",
+         "import horovod_tpu as hvd\n"
+         "hvd.init()\n"
+         "hvd.shutdown()\n"],
+        env=env, capture_output=True, text=True, timeout=180)
+    assert rc.returncode == 0, rc.stderr
+    assert (out_dir / "flight").exists() == (not explicit)
+    dumps = load_dumps(str(flight_dir))
+    names = sorted(os.path.basename(d.path) for d in dumps)
+    assert [n.rsplit("-p", 1)[0] for n in names] == [
+        "flight-r0-g0", "flight-r0-g1", "flight-r1-g1"], names
+    launcher, *ranks = dumps        # sorted by (generation, rank)
+    assert launcher.meta["reason"] == "launcher wrap-up"
+    for d in dumps:
+        (process,) = d.of_kind("hvd_process")
+        assert process["started_wall"] <= process["wall"]
+        assert [e["ph"] for e in d.of_kind("hvd_import")] == ["B", "E"]
+    # the launcher's ring: its spans, closed, one spawn a rank
+    assert [e["ph"] for e in launcher.of_kind("hvd_launch")] == ["B", "E"]
+    spawned = [e for e in launcher.of_kind("hvd_launch.spawn")
+               if e["ph"] == "E"]
+    assert [e["rank"] for e in spawned] == [0, 1]
+    assert {e["pid"] for e in spawned} == {d.meta["pid"] for d in ranks}
+    for kind in ("preflight", "kv_server", "wait"):
+        assert [e["ph"] for e in launcher.of_kind(f"hvd_launch.{kind}")] \
+            == ["B", "E"]
+    assert not launcher.of_kind("hvd_init")
+    for r, d in enumerate(ranks):
+        assert d.rank == r and d.meta["reason"] == "shutdown"
+        assert [e["ph"] for e in d.of_kind("hvd_init")] == ["B", "E"]
+        # a rank started after its launcher, and inside its spawn span
+        assert d.of_kind("hvd_process")[0]["started_wall"] >= \
+            launcher.of_kind("hvd_process")[0]["started_wall"]
+        assert d.of_kind("shutdown")
+
+
+@pytest.mark.multiprocess
 def test_hvdrun_failing_rank_kills_job(tmp_path):
     env = dict(os.environ)
     env.update({"PYTHONPATH": REPO, "HOROVOD_PLATFORM": "cpu",

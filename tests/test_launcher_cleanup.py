@@ -39,8 +39,12 @@ def _spawn_job(tmp_path, np_=2, sleep_s=120, prelude=""):
         import os, signal, time
         {prelude}
         rank = os.environ["HOROVOD_RANK"]
-        with open(os.path.join({str(tmp_path)!r}, "pid." + rank), "w") as f:
+        # written whole, then renamed: the test polls for the name and
+        # must never read it empty
+        part = os.path.join({str(tmp_path)!r}, "part." + rank)
+        with open(part, "w") as f:
             f.write(str(os.getpid()))
+        os.replace(part, os.path.join({str(tmp_path)!r}, "pid." + rank))
         time.sleep({sleep_s})
     """))
     env = dict(os.environ)
