@@ -453,7 +453,10 @@ def _stack(params, tokens, cfg: TransformerConfig):
 
 
 # Recomputed in the backward pass where the configuration asks for it
-# (``remat``): a loss head then keeps only its inputs, and a block its
+# (``remat``): a loss head then keeps only its inputs (the stream, the
+# gain, the table, the targets; its replay rebuilds the compute-type
+# logits and each row's log-sum-exp, and neither pass writes an f32
+# (b, lc, vocab) tensor: ``_table_nll``), and a block its
 # input and what the attention kernel gave (fp32 ``out`` and ``lse``,
 # under ``ring_attention.KEPT_NAMES``), 270 MB a block in the expert
 # cell for a forward kernel that is not run a second time (PERF.md
@@ -467,26 +470,90 @@ _remat_block = jax.checkpoint(
 
 
 def _logits(cfg: TransformerConfig, x, gain, table):
-    """Final norm and f32 logits through ``table`` (``embed``,
-    transposed, or the untied head), (b, lc, vocab)."""
-    cd = cfg.compute_dtype
-    x = _rmsnorm(x, gain, cfg.norm_eps)
+    """Final norm and logits through ``table`` (``embed``, transposed,
+    or the untied head), (b, lc, vocab) f32: the product of the
+    compute-type operands, rounded once to the compute type; the cast
+    adds no bit.  For callers that want logits (:func:`forward`); the
+    loss reads them in the compute type (:func:`_head_nll`)."""
+    rows = _rmsnorm(x, gain, cfg.norm_eps).astype(cfg.compute_dtype)
     with jax.named_scope("hvd_loss_head"):
-        return (x.astype(cd)
-                @ (table.astype(cd).T if cfg.tied_head
-                   else table.astype(cd))).astype(jnp.float32)
+        return _table_logits(cfg.tied_head, rows,
+                             table).astype(jnp.float32)
+
+
+def _table_logits(tied: bool, rows, table):
+    """``rows`` (b, lc, dm) through ``table`` ((vocab, dm) if ``tied``,
+    else (dm, vocab)), both in ``rows``' type, as the result."""
+    table = table.astype(rows.dtype)
+    return rows @ (table.T if tied else table)
+
+
+def _is_target(logits, targets):
+    """(b, lc, vocab) bool: the target's place in each row."""
+    return lax.broadcasted_iota(jnp.int32, logits.shape,
+                                logits.ndim - 1) == targets[..., None]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _table_nll(tied: bool, rows, table, targets):
+    """The targets' negative log likelihood, (b, lc) f32, under the
+    logits of :func:`_table_logits`, by a rule of its own in both
+    passes: nothing of shape (b, lc, vocab) is written in f32 where the
+    compute type is narrower (PERF.md section 6, PR 39: the f32
+    ``log_softmax`` was 3.3 GB and 7.5 ms a step in ``gpt2-124m.s1024``
+    for one reader, the targets' gather)."""
+    return _table_nll_fwd(tied, rows, table, targets)[0]
+
+
+def _table_nll_fwd(tied, rows, table, targets):
+    # the row's log-sum-exp and the target's logit, in f32 arithmetic
+    # inside reductions that read the compute-type logits.  Kept: the
+    # rows, the table, those logits, ``lse``, the targets.
+    with jax.named_scope("hvd_loss_head"):
+        logits = _table_logits(tied, rows, table)
+        l32 = logits.astype(jnp.float32)
+        top = jnp.max(l32, axis=-1)
+        lse = top + jnp.log(jnp.sum(jnp.exp(l32 - top[..., None]), axis=-1))
+        hit = _is_target(logits, targets)
+        picked = jnp.sum(jnp.where(hit, l32, 0.0), axis=-1)
+        return lse - picked, (rows, table, logits, lse, targets)
+
+
+def _table_nll_bwd(tied, kept, g):
+    # d nll / d logits = softmax - [v == target], times the row's
+    # cotangent, rounded to the compute type where each of the two
+    # products reads it: XLA builds it inside their operands, no scatter
+    # into (b, lc, vocab) zeros and no f32 copy.
+    rows, table, logits, lse, targets = kept
+    with jax.named_scope("hvd_loss_head"):
+        hit = _is_target(logits, targets)
+        dl = ((jnp.exp(logits.astype(jnp.float32) - lse[..., None])
+               - hit.astype(jnp.float32)) * g[..., None]).astype(rows.dtype)
+        cast = table.astype(rows.dtype)
+        if tied:
+            d_rows = dl @ cast
+            d_table = jnp.einsum("...v,...d->vd", dl, rows)
+        else:
+            d_rows = dl @ cast.T
+            d_table = jnp.einsum("...d,...v->dv", rows, dl)
+        return d_rows, d_table.astype(table.dtype), None
+
+
+_table_nll.defvjp(_table_nll_fwd, _table_nll_bwd)
 
 
 def _head_nll(cfg: TransformerConfig, x, gain, table, targets):
-    """The targets' negative log likelihood under :func:`_logits`,
-    (b, lc)."""
-    logits = _logits(cfg, x, gain, table)
-    with jax.named_scope("hvd_loss_head"):
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        return -jnp.take_along_axis(logp, targets[..., None],
-                                    axis=-1)[..., 0]
+    """The targets' negative log likelihood, (b, lc) f32: the final
+    norm, differentiated by JAX's own rule, then :func:`_table_nll`.
+    The backward pass keeps the normalised rows in the compute type, the
+    table, the compute-type logits, each row's log-sum-exp and the
+    targets; no f32 (b, lc, vocab) tensor is written in either pass."""
+    rows = _rmsnorm(x, gain, cfg.norm_eps).astype(cfg.compute_dtype)
+    return _table_nll(cfg.tied_head, rows, table, targets)
 
 
+# :func:`_head_nll` keeping only its inputs: the replay rebuilds what
+# the backward rule reads (the comment above ``_remat_block``).
 _remat_head_nll = jax.checkpoint(_head_nll, static_argnums=(0,))
 
 

@@ -134,8 +134,8 @@ def one_chip():
     return topo.devices[0]
 
 
-def _step_text(cell_name, one_chip):
-    """A cell's compiled step as text, built as the two files of
+def compiled_step(cell_name, one_chip):
+    """A cell's compiled step, built as the two files of
     ``tests/benchmark_suite/`` build theirs."""
     import jax
     import optax
@@ -181,7 +181,7 @@ def _step_text(cell_name, one_chip):
             (job["batch_per_chip"], job["seq"]), "int32", sharding=here)
         return transformer.make_train_step(cfg, mesh, opt).lower(
             shapes(params), shapes(jax.eval_shape(opt.init, params)),
-            ids, ids).compile().as_text()
+            ids, ids).compile()
     finally:
         patch.undo()
         jax.config.update("jax_enable_compilation_cache", True)
@@ -191,7 +191,8 @@ def _step_text(cell_name, one_chip):
 @pytest.fixture(scope="module")
 def step_texts(one_chip):
     """``cell -> text``, each cell compiled once."""
-    return functools.cache(lambda cell: _step_text(cell, one_chip))
+    return functools.cache(
+        lambda cell: compiled_step(cell, one_chip).as_text())
 
 
 @pytest.fixture(scope="module")

@@ -308,7 +308,11 @@ def test_the_compiled_step_names_its_parts():
     for n in names:      # neither is inside the differentiated function
         if "hvd_grad_reduce" in n or "hvd_optimizer" in n:
             assert "jvp(" not in n, n
-    assert shown("hvd_loss_head", "log_softmax")
+    # the head's own rule: the products and the row reductions of both
+    # passes, and no gather out of the logits (tests/test_loss_head.py)
+    assert shown("jvp(hvd_loss_head)/reduce_max")
+    assert shown("transpose(jvp(hvd_loss_head))", "dot_general")
+    assert not shown("hvd_loss_head", "gather")
     assert not shown("hvd_attn", "hvd_loss_head")
 
 
