@@ -62,7 +62,13 @@ def main() -> None:
                         "positions, G over the whole past with none, D "
                         "a dense SwiGLU FFN; with S, G or D every "
                         "sub-layer also gets a norm after it and the "
-                        "experts are SwiGLU (e.g. SDSESESEGE). Replaces "
+                        "experts are SwiGLU (e.g. SDSESESEGE); I "
+                        "grouped-query attention over the seq / 4 keys "
+                        "an indexer of 2 heads scores highest for each "
+                        "query, with QK-norm and rotary positions in "
+                        "three sections, and with it a softmax router "
+                        "over SwiGLU experts, no shared one (e.g. "
+                        "IEIE). Replaces "
                         "--n-layers; dp only (no pp, tp or sp)")
     p.add_argument("--pp-schedule", default="gpipe",
                    choices=["gpipe", "interleaved"],
@@ -121,6 +127,16 @@ def main() -> None:
             # times sqrt(d), SwiGLU experts
             kinds.update(window=max(1, args.seq // 4), post_norm=True,
                          embed_scale=args.d_model ** 0.5,
+                         expert_form="swiglu")
+        if "I" in args.layer_pattern:
+            # a sparse-attention stack: a quarter of the sequence kept
+            # for each query, positions of three (here equal) components
+            pairs = args.d_model // 8
+            kinds.update(index_heads=2, index_head_dim=16,
+                         index_topk=max(1, args.seq // 4),
+                         rope_sections=(pairs - 2 * (pairs // 3),
+                                        pairs // 3, pairs // 3),
+                         router="softmax", shared_experts=0,
                          expert_form="swiglu")
     else:
         kinds = dict(mlp="swiglu", n_dense_layers=1,

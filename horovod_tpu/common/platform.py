@@ -56,7 +56,7 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 # (``jax_compilation_cache_include_metadata_in_key`` would key on every
 # file path and line number as well).  Raise it with a change to a
 # scope's or a kernel's name.
-NAMES_VERSION = "hvd-names-1"
+NAMES_VERSION = "hvd-names-2"
 
 
 # ---------------------------------------------------------------------------

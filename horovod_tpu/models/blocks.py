@@ -9,7 +9,12 @@ attention / expert configurations (``nemotron_h``) are made of — and
 gated grouped-query attention with QK-norm, under a sliding window with
 rotary positions or over the whole past with none, a dense SwiGLU FFN,
 and a second norm after a sub-layer — what the window / full attention
-expert configurations (``afmoe``) are made of.
+expert configurations (``afmoe``) are made of — and grouped-query
+attention over a selection: an indexer scores every earlier key for each
+query, the ``index_topk`` highest are kept, and softmax attention runs
+over those alone (DeepSeek sparse attention on grouped-query heads, with
+QK-norm and rotary positions of three components: the ``KeyeVL2``
+language stack).
 
 Imported by :mod:`horovod_tpu.models.transformer` only where a
 ``TransformerConfig`` asks for one of them; a GPT-2-shaped configuration
@@ -34,6 +39,7 @@ layer of the kind, in the pattern's order::
     swa     ln wq wk wv wg wo q_norm k_norm                       "S"
     gattn   ln wq wk wv wg wo q_norm k_norm                       "G"
     dense   ln w_gate w_up w_down                                 "D"
+    dsa     ln wq wk wv wo q_norm k_norm wq_idx wk_idx ww_idx     "I"
 
 and ``ln_post`` in every stack where the configuration has a
 ``post_norm``.
@@ -49,7 +55,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from horovod_tpu.models.transformer import TransformerConfig, _rmsnorm
 from horovod_tpu.parallel import moe
-from horovod_tpu.parallel.ring_attention import KEPT_NAMES, ring_attention
+from horovod_tpu.parallel.ring_attention import (KEPT_NAMES, KEPT_SELECTION,
+                                                 ring_attention)
 from horovod_tpu.parallel.sharding import copy_to_tp, reduce_from_tp
 
 
@@ -95,11 +102,12 @@ def _init_expert(norm, cfg: TransformerConfig, lead: tuple, ff: int) -> dict:
 
 def _init_moe(norm, cfg: TransformerConfig, n: int, ep: int) -> dict:
     dm = cfg.d_model
-    p = {"router": norm(n, dm, cfg.n_experts, scale=dm ** -0.5),
-         # the selection bias: a buffer, drawn once and never updated
-         "bias": norm(n, cfg.n_experts, scale=0.01),
-         "experts": _init_expert(norm, cfg, (n, ep * cfg.experts_held),
-                                 cfg.d_expert)}
+    p = {"router": norm(n, dm, cfg.n_experts, scale=dm ** -0.5)}
+    if cfg.router == "sigmoid":
+        # the selection bias: a buffer, drawn once and never updated
+        p["bias"] = norm(n, cfg.n_experts, scale=0.01)
+    p["experts"] = _init_expert(norm, cfg, (n, ep * cfg.experts_held),
+                                cfg.d_expert)
     if cfg.shared_experts:
         p["shared"] = _init_expert(norm, cfg, (n,),
                                    cfg.shared_experts * cfg.d_expert)
@@ -145,6 +153,8 @@ def _moe_specs(cfg: TransformerConfig) -> dict:
         "w_up", "w_down")
     specs = {"router": P(), "bias": P(),
              "experts": dict.fromkeys(names, P(None, "dp"))}
+    if cfg.router == "softmax":
+        del specs["bias"]
     if cfg.shared_experts:
         specs["shared"] = dict.fromkeys(names, P())
     return specs
@@ -178,15 +188,25 @@ def extra_specs(cfg: TransformerConfig) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def rotary(x, positions, theta: float, halves: bool = False):
+def rotary(x, positions, theta: float, halves: bool = False,
+           sections: tuple = ()):
     """Rotary embedding over interleaved pairs ``(x[2i], x[2i+1])`` of
     the last axis — with ``halves`` over the pairs ``(x[i], x[i + d/2])``
     of its two halves, the half-rotation layout — frequencies
     ``theta ** (-2i / d)``, no scaling.  x: (b, l, d) or (b, l, h, d);
-    positions: (l,) global.  Computed in float32."""
+    positions: (l,) global.  With ``sections`` (three counts that add up
+    to d / 2) positions are (3, l), a row a component, and pair ``i``
+    turns by the component whose section holds it: the first
+    ``sections[0]`` pairs by the first, the next ``sections[1]`` by the
+    second, the rest by the third.  Computed in float32."""
     d = x.shape[-1]
     inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angle = positions.astype(jnp.float32)[:, None] * inv      # (l, d/2)
+    if sections:
+        # (3, l) -> (l, d/2): each pair's own component
+        positions = positions[np.repeat(np.arange(3), sections)].T
+        angle = positions.astype(jnp.float32) * inv
+    else:
+        angle = positions.astype(jnp.float32)[:, None] * inv  # (l, d/2)
     if x.ndim == 4:
         angle = angle[:, None, :]
     cos, sin = jnp.cos(angle), jnp.sin(angle)
@@ -319,10 +339,10 @@ def mtp_loss(cfg: TransformerConfig, params, x, targets, block, head_nll):
 
 # the stack that holds the weights of each kind of layer
 STACK_OF = {"M": "ssm", "*": "attn", "E": "moe", "S": "swa", "G": "gattn",
-            "D": "dense"}
+            "D": "dense", "I": "dsa"}
 # the kinds that call ``ring_attention`` over ``n_heads`` query heads on
 # ``n_kv_heads`` key/value heads
-ATTENTION_KINDS = "*SG"
+ATTENTION_KINDS = "*SGI"
 # the f32 result of an expert layer that a post-norm reads, under
 # ``jax.ad_checkpoint.checkpoint_name``: kept by a recomputed layer
 KEPT_EXPERT_OUT = "hvd_moe_out"
@@ -378,6 +398,20 @@ def _init_gated_gqa(norm, cfg: TransformerConfig, n: int) -> dict:
             "k_norm": np.ones((n, cfg.head_dim), np.float32)}
 
 
+def _init_indexed_gqa(norm, cfg: TransformerConfig, n: int) -> dict:
+    """``_init_gqa`` with the gains of the two per-head norms and the
+    indexer's three matrices: its ``index_heads`` query heads of
+    ``index_head_dim``, its one key head, and a weight a query head."""
+    dm = cfg.d_model
+    return {**_init_gqa(norm, cfg, n),
+            "q_norm": np.ones((n, cfg.head_dim), np.float32),
+            "k_norm": np.ones((n, cfg.head_dim), np.float32),
+            "wq_idx": norm(n, dm, cfg.index_heads * cfg.index_head_dim,
+                           scale=dm ** -0.5),
+            "wk_idx": norm(n, dm, cfg.index_head_dim, scale=dm ** -0.5),
+            "ww_idx": norm(n, dm, cfg.index_heads, scale=dm ** -0.5)}
+
+
 def init_pattern(norm, rng, cfg: TransformerConfig, ep: int) -> dict:
     """Everything but ``embed``: a stack a kind the pattern holds, the
     final norm and the untied head.  ``rng.rand`` draws the uniform
@@ -400,6 +434,8 @@ def init_pattern(norm, rng, cfg: TransformerConfig, ep: int) -> dict:
         n = kinds.count("D")
         p["dense"] = {"ln": np.ones((n, dm), np.float32),
                       **_init_swiglu(norm, (n,), dm, cfg.d_ff)}
+    if "I" in kinds:
+        p["dsa"] = _init_indexed_gqa(norm, cfg, kinds.count("I"))
     if cfg.post_norm:
         for kind in set(kinds):
             p[STACK_OF[kind]]["ln_post"] = np.ones((kinds.count(kind), dm),
@@ -416,6 +452,7 @@ def init_pattern(norm, rng, cfg: TransformerConfig, ep: int) -> dict:
                              (p.get("attn", {}), "wo"),
                              (p.get("swa", {}), "wo"),
                              (p.get("gattn", {}), "wo"),
+                             (p.get("dsa", {}), "wo"),
                              (p.get("dense", {}), "w_down"),
                              (moe_.get("experts", {}), "w_down"),
                              (moe_.get("shared", {}), "w_down")):
@@ -434,7 +471,9 @@ def pattern_specs(cfg: TransformerConfig) -> dict:
                      "d", "norm", "w_out"),
              "attn": ("ln", "wq", "wk", "wv", "wo"),
              "swa": gated, "gattn": gated,
-             "dense": ("ln", "w_gate", "w_up", "w_down")}
+             "dense": ("ln", "w_gate", "w_up", "w_down"),
+             "dsa": ("ln", "wq", "wk", "wv", "wo", "q_norm", "k_norm",
+                     "wq_idx", "wk_idx", "ww_idx")}
     specs = {"head": P()}
     for kind in set(cfg.layer_pattern):
         stack = STACK_OF[kind]
@@ -572,8 +611,8 @@ def gated_gqa(cfg: TransformerConfig, lp, h, positions, sliding: bool):
                  lp["k_norm"], cfg.norm_eps)
     v = (h @ lp["wv"].astype(cd)).reshape(b, lc, nkv, hd)
     if sliding:
-        q, k = (rotary(t, positions, cfg.rope_theta, halves=True)
-                for t in (q, k))
+        q, k = (rotary(t, positions, cfg.rope_theta, halves=True,
+                       sections=cfg.rope_sections) for t in (q, k))
     k, v = (_over_query_heads(t, nh // nkv) for t in (k, v))
     with jax.named_scope("hvd_attn"):
         attn = ring_attention(q, k, v, "sp", causal=True,
@@ -582,6 +621,129 @@ def gated_gqa(cfg: TransformerConfig, lp, h, positions, sliding: bool):
     gate = jax.nn.sigmoid((h @ lp["wg"].astype(cd)).astype(jnp.float32))
     attn = attn.reshape(b, lc, nh * hd).astype(jnp.float32) * gate
     return (attn.astype(cd) @ lp["wo"].astype(cd)).astype(jnp.float32)
+
+
+# rows of queries whose indexer scores against every key are alive at
+# once: (rows, index_heads, seq) products and (rows, seq) scores in f32,
+# 270 MB and 17 MB a sequence of 16,384
+_INDEX_ROWS = 256
+
+
+def _kth_largest(keys, k: int):
+    """The ``k``-th largest of the uint32 ``keys`` along the last axis,
+    exactly: the largest ``t`` that at least ``k`` keys reach, built bit
+    by bit from the top, a count over the row a bit."""
+    def bit(i, t):
+        trial = t | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        reach = jnp.sum(keys >= trial[..., None], axis=-1, dtype=jnp.int32)
+        return jnp.where(reach >= k, trial, t)
+
+    return lax.fori_loop(0, 32, bit,
+                         jnp.zeros(keys.shape[:-1], jnp.uint32))
+
+
+def select_keys(cfg: TransformerConfig, lp, h):
+    """The indexer's selection for the normalised stream ``h`` (b, lc,
+    dm), packed as ``ring_attention``'s ``keep``: (b, lc, W) int32.
+
+    ``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s]) / sqrt(index_head_dim)``
+    over the ``index_heads`` query heads ``j`` and the one key head, ``w
+    = (h W_w) / sqrt(index_heads)``, no positions: the products of
+    compute-type operands, everything after them in float32.  Query
+    ``t`` keeps the ``index_topk`` keys ``s <= t`` of largest ``I[t,
+    s]``, a tie going to the lower ``s``, and every key where ``t <
+    index_topk``: exact (the threshold is the row's ``index_topk``-th
+    largest score itself, :func:`_kth_largest` over the scores' bits in
+    an order-preserving integer form).  By blocks of ``_INDEX_ROWS``
+    queries, so that no (lc, lc) tensor exists.  No gradient passes: the
+    indexer reads the stream detached, and the selection is discrete."""
+    from horovod_tpu.ops.pallas_attention import pack_keep
+
+    b, lc, _ = h.shape
+    cd = cfg.compute_dtype
+    heads, size = cfg.index_heads, cfg.index_head_dim
+    topk = min(cfg.index_topk, lc)
+    h = lax.stop_gradient(h).astype(cd)
+    q = (h @ lp["wq_idx"].astype(cd)).reshape(b, lc, heads, size)
+    k = h @ lp["wk_idx"].astype(cd)                          # (b, lc, size)
+    w = (h @ lp["ww_idx"].astype(cd)).astype(jnp.float32) * heads ** -0.5
+    rows = min(_INDEX_ROWS, lc)
+    if lc % rows:
+        raise ValueError(f"the indexer scores {rows} queries at a time: "
+                         f"the sequence chunk {lc} is no multiple of it")
+    keys = jnp.arange(lc)
+
+    def block(start):
+        qb = lax.dynamic_slice_in_dim(q, start, rows, axis=1)
+        wb = lax.dynamic_slice_in_dim(w, start, rows, axis=1)
+        products = jnp.einsum("bqjd,bkd->bqjk", qb, k,
+                              preferred_element_type=jnp.float32)
+        scores = jnp.sum(wb[..., None] * jax.nn.relu(products),
+                         axis=2) * size ** -0.5          # (b, rows, lc)
+        seen = (start + jnp.arange(rows))[:, None] >= keys[None, :]
+        # -0.0 and 0.0 are one score; what a query cannot see is below
+        # every score
+        scores = jnp.where(seen, jnp.where(scores == 0.0, 0.0, scores),
+                           -jnp.inf)
+        # float32 -> uint32, order kept: the sign bit set on a positive
+        # number, every bit turned on a negative one
+        bits = lax.bitcast_convert_type(scores, jnp.uint32)
+        order = jnp.where(bits >> 31 == 0, bits | jnp.uint32(1 << 31),
+                          ~bits)
+        least = _kth_largest(order, topk)[..., None]
+        above, level = order > least, order == least
+        # of the keys level with the threshold, the first that are needed
+        needed = topk - jnp.sum(above, axis=-1, keepdims=True,
+                                dtype=jnp.int32)
+        first = jnp.cumsum(level, axis=-1, dtype=jnp.int32) <= needed
+        return pack_keep((above | (level & first)) & seen)
+
+    words = lax.map(block, jnp.arange(0, lc, rows))   # (blocks, b, rows, W)
+    return lax.stop_gradient(
+        jnp.moveaxis(words, 0, 1).reshape(b, lc, words.shape[-1]))
+
+
+def indexed_gqa(cfg: TransformerConfig, lp, h, positions):
+    """Grouped-query attention over a selection on the normalised stream
+    ``h``: :func:`select_keys` picks each query's keys, one selection for
+    all heads, and softmax attention runs over those alone — :func:`gqa`'s
+    heads with an RMSNorm over each head's ``q`` and ``k`` (one learned
+    gain of ``head_dim`` for all query heads, one for all key heads) and
+    rotary positions (all of ``head_dim``, the half-rotation layout;
+    ``positions`` (3, lc) and ``cfg.rope_sections`` where the
+    configuration has sections, else (lc,)).  A recomputed layer keeps
+    the selection (``KEPT_SELECTION``): it is made once a step, and the
+    backward kernels read the forward pass's.  Returns ``(f32 output
+    projection, the packed selection (b, lc, W) int32)``."""
+    _whole_axes("attention over an indexer's selection", {
+        "tp": "the key/value heads, fewer than the query heads, are not "
+              "shared out over the tp ranks, and every rank would score "
+              "and select alike",
+        "sp": "a query's selection is over the keys of the whole "
+              "sequence: the indexer's keys are not gathered over the "
+              "ring, and a ring step's part of a selection is not cut "
+              "out of its words"})
+    b, lc, _ = h.shape
+    cd = cfg.compute_dtype
+    nh, hd = cfg.n_heads, cfg.head_dim
+    nkv = cfg.n_kv_heads or nh
+    with jax.named_scope("hvd_dsa_index"):
+        keep = checkpoint_name(select_keys(cfg, lp, h), KEPT_SELECTION)
+    h = h.astype(cd)
+    q = _rmsnorm((h @ lp["wq"].astype(cd)).reshape(b, lc, nh, hd),
+                 lp["q_norm"], cfg.norm_eps)
+    k = _rmsnorm((h @ lp["wk"].astype(cd)).reshape(b, lc, nkv, hd),
+                 lp["k_norm"], cfg.norm_eps)
+    v = (h @ lp["wv"].astype(cd)).reshape(b, lc, nkv, hd)
+    q, k = (rotary(t, positions, cfg.rope_theta, halves=True,
+                   sections=cfg.rope_sections) for t in (q, k))
+    k, v = (_over_query_heads(t, nh // nkv) for t in (k, v))
+    with jax.named_scope("hvd_attn"):
+        attn = ring_attention(q, k, v, "sp", causal=True,
+                              impl=cfg.attn_impl, recomputed=cfg.remat,
+                              keep=keep)
+    return (attn.reshape(b, lc, nh * hd).astype(cd)
+            @ lp["wo"].astype(cd)).astype(jnp.float32), keep
 
 
 def pattern_dense(cfg: TransformerConfig, lp, h):
@@ -599,9 +761,11 @@ def pattern_layer(cfg: TransformerConfig, kind: str, lp, x, positions):
     """One layer of a pattern: ``x + f(RMSNorm(x))`` with ``f`` the
     sub-layer of ``kind`` — ``x + RMSNorm(f(RMSNorm(x)))`` where the
     configuration has a ``post_norm``.  ``positions``: (lc,) global, for
-    the kind that takes rotary ones.  Returns ``(x, report)``: the pairs
+    the kinds that take rotary ones ((3, lc) where the configuration has
+    ``rope_sections``).  Returns ``(x, report)``: the pairs
     an expert layer's routing sent each of all its experts, a
-    state-space layer's least log-decay, else ``None``."""
+    state-space layer's least log-decay, an indexed attention layer's
+    packed selection, else ``None``."""
     h = _rmsnorm(x, lp["ln"], cfg.norm_eps)
     report = None
     if kind == "M":
@@ -617,6 +781,9 @@ def pattern_layer(cfg: TransformerConfig, kind: str, lp, x, positions):
             out = gated_gqa(cfg, lp, h, positions, sliding=False)
     elif kind == "D":
         out = pattern_dense(cfg, lp, h)
+    elif kind == "I":
+        with jax.named_scope("hvd_dsa"):
+            out, report = indexed_gqa(cfg, lp, h, positions)
     else:
         out, report = expert_ffn(cfg, lp, h, count_all=True)
         if cfg.post_norm:
@@ -633,11 +800,14 @@ def pattern_layer(cfg: TransformerConfig, kind: str, lp, x, positions):
 # backward pass reads it, and without the name the replay would run the
 # held experts' sort, gather, grouped products and scatter-add a second
 # time for it (15 grouped products a layer where 12 do; without a norm
-# after it nothing reads the result and the compiler drops that replay)
+# after it nothing reads the result and the compiler drops that replay);
+# and an indexed attention layer its selection, 34 MB a layer of 16,384
+# tokens: the backward kernels read the bits the forward pass ran under,
+# and the replay holds no indexer
 _remat_layer = jax.checkpoint(
     pattern_layer, static_argnums=(0, 1),
     policy=jax.checkpoint_policies.save_only_these_names(
-        *KEPT_NAMES, KEPT_EXPERT_OUT))
+        *KEPT_NAMES, KEPT_EXPERT_OUT, KEPT_SELECTION))
 
 
 def pattern_stack(cfg: TransformerConfig, params, x, pos):
@@ -645,9 +815,12 @@ def pattern_stack(cfg: TransformerConfig, params, x, pos):
     takes the next row of its kind's stack.  ``x``: the tokens' rows of
     the embedding (b, lc, dm), float32, which enter the stream times
     ``embed_scale`` in the compute type; ``pos``: (lc,) global
-    positions.  Returns ``(x, pos, reports)`` as ``transformer._stack``
-    does, ``reports`` a dict ``{"loads": [an "E" layer's each],
-    "least_log_decay": [an "M" layer's each]}``."""
+    positions, handed to the layers as three equal components where the
+    configuration has ``rope_sections`` (text: a token's temporal,
+    height and width positions are its index).  Returns ``(x, pos,
+    reports)`` as ``transformer._stack`` does, ``reports`` a dict
+    ``{"loads": [an "E" layer's each], "least_log_decay": [an "M"
+    layer's each], "selections": [an "I" layer's each]}``."""
     if lax.axis_size("pp") > 1:
         raise NotImplementedError(
             "a layer pattern under pp > 1 is not supported: its weights "
@@ -658,14 +831,15 @@ def pattern_stack(cfg: TransformerConfig, params, x, pos):
     x = x.astype(cfg.compute_dtype)
     layer = _remat_layer if cfg.remat else pattern_layer
     rows = dict.fromkeys(STACK_OF, 0)
-    reports = {"loads": [], "least_log_decay": []}
+    reports = {"loads": [], "least_log_decay": [], "selections": []}
+    report_of = {"E": "loads", "M": "least_log_decay", "I": "selections"}
+    positions = (jnp.broadcast_to(pos, (3,) + pos.shape)
+                 if cfg.rope_sections else pos)
     for kind in cfg.layer_pattern:
         lp = jax.tree_util.tree_map(lambda a: a[rows[kind]],
                                     params[STACK_OF[kind]])
         rows[kind] += 1
-        x, report = layer(cfg, kind, lp, x, pos)
-        if kind == "E":
-            reports["loads"].append(report)
-        elif kind == "M":
-            reports["least_log_decay"].append(report)
+        x, report = layer(cfg, kind, lp, x, positions)
+        if kind in report_of:
+            reports[report_of[kind]].append(report)
     return x, pos, reports

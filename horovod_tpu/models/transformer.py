@@ -27,8 +27,10 @@ where a configuration asks for one of these).  The defaults are the
 GPT-2 block.  With a ``layer_pattern`` a layer is instead ONE
 pre-normed sub-layer with one residual — a Mamba-2 mixer, grouped-query
 attention without positions, gated grouped-query attention with QK-norm
-under a sliding window with rotary positions or full without, a dense
-SwiGLU FFN or an expert FFN alone — of the kind the pattern gives it,
+under a sliding window with rotary positions or full without,
+grouped-query attention over the keys an indexer selects for each
+query, a dense SwiGLU FFN or an expert FFN alone — of the kind the
+pattern gives it,
 its weights stacked per kind (``blocks.pattern_stack``), with a second
 norm after the sub-layer where the configuration states one.
 
@@ -94,6 +96,9 @@ class TransformerConfig:
     d_expert: int = 0
     shared_experts: int = 0  # a shared expert of this many x d_expert
     routed_scale: float = 1.0
+    # the router's kind: "sigmoid" scores with a selection bias (a
+    # buffer), or a "softmax" over all experts with none
+    router: str = "sigmoid"
     n_dense_layers: int = 0
     # multi-token prediction: one module predicting token i + 2, its
     # cross entropy weighted mtp_lambda (depth 0 = none, 1)
@@ -110,7 +115,10 @@ class TransformerConfig:
     # positions, "E" the expert layer alone; "S" and "G" gated
     # grouped-query attention with an RMSNorm on each head's q and k,
     # "S" under the sliding window with rotary positions, "G" over the
-    # whole past with none; "D" a dense SwiGLU FFN of d_ff
+    # whole past with none; "D" a dense SwiGLU FFN of d_ff; "I"
+    # grouped-query attention with QK-norm and rotary positions over the
+    # index_topk keys that an indexer of index_heads heads of
+    # index_head_dim (one key head) scores highest for each query
     # (blocks.STACK_OF is the table).  Empty: the uniform stack of
     # attention + MLP blocks above.  n_layers is the pattern's length.
     layer_pattern: tuple = ()
@@ -128,6 +136,14 @@ class TransformerConfig:
     # are held); 0 = fan-in scaling alone
     rescale_depth: int = 0
     n_kv_heads: int = 0      # "*": key/value heads (0 = n_heads)
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # "I": positions have three components, and of a head's head_dim / 2
+    # rotary frequency pairs the first rope_sections[0] turn by the
+    # first, the next rope_sections[1] by the second, the rest by the
+    # third (text: the three equal).  Empty: one component
+    rope_sections: tuple = ()
     # "M": ssm_heads heads of ssm_head_dim, B and C in ssm_groups groups
     # of ssm_state, a causal depthwise convolution of ssm_conv taps,
     # the scan in chunks of ssm_chunk steps
@@ -165,6 +181,10 @@ class TransformerConfig:
         if self.mlp not in ("gelu", "swiglu"):
             raise ValueError(f"mlp must be 'gelu' or 'swiglu', got "
                              f"{self.mlp!r}")
+        if self.router not in ("sigmoid", "softmax"):
+            raise ValueError(f"router must be 'sigmoid' or 'softmax', got "
+                             f"{self.router!r}")
+        object.__setattr__(self, "rope_sections", tuple(self.rope_sections))
         if self.expert_form not in ("swiglu", "relu2"):
             raise ValueError(f"expert_form must be 'swiglu' or 'relu2', "
                              f"got {self.expert_form!r}")
@@ -206,6 +226,16 @@ class TransformerConfig:
         if "S" in kinds and (self.window < 1 or self.head_dim % 2):
             raise ValueError("'S' layers need window >= 1 and an even "
                              "head_dim (rotary positions)")
+        if "I" in kinds and (min(self.index_heads, self.index_head_dim,
+                                 self.index_topk) < 1 or self.head_dim % 2):
+            raise ValueError("'I' layers need index_heads, index_head_dim, "
+                             "index_topk and an even head_dim (rotary "
+                             "positions)")
+        if self.rope_sections and (len(self.rope_sections) != 3 or sum(
+                self.rope_sections) != self.head_dim // 2):
+            raise ValueError(
+                f"rope_sections {self.rope_sections} are three counts that "
+                f"add up to head_dim / 2 = {self.head_dim // 2}")
         if "M" in kinds and (self.ssm_heads < 1 or self.ssm_head_dim < 1
                              or self.ssm_state < 1
                              or self.ssm_heads % self.ssm_groups):
@@ -589,7 +619,9 @@ def loss_fn(params, tokens, targets, cfg: TransformerConfig,
     here or not (what ``moe.settle_bias`` balances; the held experts'
     columns are the pairs computed); ``least_log_decay`` (state-space
     layers,) float32, the least logarithm of a whole chunk's decay
-    (:func:`record_scan`).
+    (:func:`record_scan`); ``selections`` (indexed attention layers, b,
+    lc, W) int32, the packed selection each ran under
+    (:func:`record_selection`).
     """
     x, pos, pairs = _stack(params, tokens, cfg)
     head_nll = _remat_head_nll if cfg.remat else _head_nll
@@ -722,6 +754,40 @@ def record_attention(cfg: TransformerConfig, batch: int) -> list:
             **{"bwd_" + k: v for k, v in counts(window, True).items()}))
     for record in records:
         flight.record("hvd_attn_window", **record)
+    return records
+
+
+def record_selection(cfg: TransformerConfig, selections) -> list:
+    """Write one ``hvd_dsa_select`` record an indexed attention layer to
+    the flight ring from ``selections``, :func:`loss_and_routing`'s
+    (layers, batch, seq, W) packed words of one chip (``sp`` 1):
+    ``layer``, ``seq``, ``topk``, the ``kept_pairs`` the words hold and
+    the ``causal_pairs`` of that many sequences (a head's), the
+    ``operand`` the kernels read the selection from and its
+    ``operand_bytes`` a layer, and of one head's forward call the path
+    ``impl``, its ``block_q`` x ``block_k``, the ``tiles`` its grid
+    walks and the ``live_tiles`` that do any work (all of them build a
+    mask from the words).  Returns the records."""
+    from horovod_tpu.ops.pallas_attention import causal_tile_counts
+    from horovod_tpu.parallel.ring_attention import _block_sizes, auto_impl
+    from horovod_tpu.runtime import flight
+
+    words = np.asarray(selections)
+    batch, seq = words.shape[1:3]
+    impl = cfg.attn_impl or (auto_impl(batch, cfg.n_heads, seq)
+                             if jax.default_backend() == "tpu" else "xla")
+    bq, bk = _block_sizes(seq, seq, cfg.head_dim, cfg.compute_dtype.itemsize)
+    tiles, live, _ = (causal_tile_counts(seq, seq, bq, bk) if bq and bk
+                      else (0, 0, 0))
+    records = [dict(layer=i, seq=seq, topk=cfg.index_topk,
+                    kept_pairs=int(np.unpackbits(row.view(np.uint8)).sum()),
+                    causal_pairs=batch * seq * (seq + 1) // 2,
+                    operand="packed_mask", operand_bytes=int(row.nbytes),
+                    impl=impl, block_q=bq or 0, block_k=bk or 0,
+                    tiles=tiles, live_tiles=live)
+               for i, row in enumerate(words)]
+    for record in records:
+        flight.record("hvd_dsa_select", **record)
     return records
 
 

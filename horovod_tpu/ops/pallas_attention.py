@@ -40,6 +40,17 @@ call's kernels carry names of their own (``hvd_flash_fwd_win`` ...), so
 that a trace tells them from the plain calls; without a window the
 kernels trace what they traced before there was one.
 
+A selection (``keep``) is a third bound, by data and not by position:
+one bit a (query, key) pair, shared by the heads of a batch row, packed
+32 keys to an int32 word (:func:`pack_keep` has the layout, chosen so
+that a (bq, 128) block of words unpacks onto a K tile with shifts and
+lane-aligned joins alone).  A pair is seen iff the causal bound and its
+bit allow it.  Which tiles are live is still decided from positions —
+at 16,384 tokens and 2,048 kept keys a row no 1024 x 1024 tile is
+without a kept key — and every live tile builds its mask from the
+words; the kernels are then named ``hvd_flash_fwd_sel`` ... .  Without
+a selection the kernels trace what they trace without one.
+
 The state goes through HBM only between ring steps.  At the ends of the
 ring the kernel does the state's work where the state is, in VMEM
 (:func:`flash_fwd_step`): the first step is handed no (m, l, o) and
@@ -81,6 +92,64 @@ _L_LANE = 64
 # Tiles are sized so that a kernel asks for at most about half of the
 # 128 MiB of VMEM a v5e (or v4, v6e) TensorCore has.
 VMEM_BUDGET = 64 << 20
+
+# A selection's packed form: a row of KEEP_LANES int32 words covers
+# KEEP_SPAN keys, bit ``b`` of word ``c`` standing for key ``b *
+# KEEP_LANES + c`` of the span — so the ``bk / KEEP_LANES`` bits that a
+# K tile of ``bk`` keys takes of each word lie side by side, and the
+# tile's (bq, bk) mask is that many shifts of one (bq, KEEP_LANES) block
+# joined along the lanes.
+KEEP_LANES = 128
+KEEP_SPAN = 32 * KEEP_LANES
+
+
+def pack_keep(mask):
+    """(..., Lq, Lk) bool -> (..., Lq, W) int32, ``W = KEEP_LANES *
+    ceil(Lk / KEEP_SPAN)``: key ``s`` of a row is bit ``(s % KEEP_SPAN)
+    // KEEP_LANES`` of word ``(s // KEEP_SPAN) * KEEP_LANES + s %
+    KEEP_LANES``; the bits past ``Lk`` are 0."""
+    *lead, lk = mask.shape
+    spans = -(-lk // KEEP_SPAN)
+    bits = jnp.pad(mask, [(0, 0)] * len(lead) + [(0, spans * KEEP_SPAN - lk)])
+    bits = bits.reshape(*lead, spans, 32, KEEP_LANES).astype(jnp.uint32)
+    words = jnp.sum(bits << jnp.arange(32, dtype=jnp.uint32)[:, None],
+                    axis=-2, dtype=jnp.uint32)
+    return jax.lax.bitcast_convert_type(
+        words.reshape(*lead, spans * KEEP_LANES), jnp.int32)
+
+
+def unpack_keep(words, lk: int):
+    """:func:`pack_keep`'s inverse: (..., Lq, W) int32 -> (..., Lq, lk)
+    bool."""
+    *lead, width = words.shape
+    spans = width // KEEP_LANES
+    words = jax.lax.bitcast_convert_type(words, jnp.uint32).reshape(
+        *lead, spans, 1, KEEP_LANES)
+    bits = (words >> jnp.arange(32, dtype=jnp.uint32)[:, None]) & 1
+    return bits.reshape(*lead, spans * KEEP_SPAN)[..., :lk] != 0
+
+
+def keep_tiles_ok(bk: int) -> bool:
+    """Whether K tiles of ``bk`` keys can read a packed selection: whole
+    lanes of bits, and a tile never astride two spans."""
+    return bk % KEEP_LANES == 0 and KEEP_SPAN % bk == 0
+
+
+def _keep_block(ik, bk: int):
+    """The block of words, along the last axis, that K tile ``ik``'s
+    bits lie in."""
+    return jax.lax.div(ik * bk, KEEP_SPAN)
+
+
+def _keep_mask(keep_ref, ik, bk: int):
+    """K tile ``ik``'s (bq, bk) bool mask from the (bq, KEEP_LANES)
+    block of words that holds its bits."""
+    words = keep_ref[0]
+    first = jax.lax.div(jax.lax.rem(ik * bk, KEEP_SPAN), KEEP_LANES)
+    lanes = [jax.lax.shift_right_logical(
+        words, jnp.full_like(words, first + j)) & 1
+        for j in range(bk // KEEP_LANES)]
+    return jnp.concatenate(lanes, axis=1) != 0
 
 
 def tile_vmem_bytes(bq: int, bk: int, d: int, itemsize: int,
@@ -213,19 +282,36 @@ def _first_q_tile(off_ref, ik, bq: int, bk: int):
 
 
 def _on_live_tile(off_ref, iq, ik, bq: int, bk: int, causal: bool, body,
-                  window: int | None = None, inside=None):
+                  window: int | None = None, inside=None, keep_ref=None):
     """Run ``body(mask)`` for tile pair (iq, ik): not at all where the
     mask hides the whole pair, with the (bq, bk) bool mask where the
     diagonal or the window's trailing edge crosses it, and with ``None``
     where nothing is hidden (no score is -inf there, so the body drops
     its guards too).  ``inside``: under a window, whether the band's
-    step stands for a tile of the block at all."""
+    step stands for a tile of the block at all.  ``keep_ref``: the
+    block of a selection's words that holds the tile's bits; every live
+    pair then builds its mask from them, and the pairs the diagonal
+    crosses from the positions too."""
     if not causal:
         body(None)
         return
     q_start = off_ref[0] + iq * bq
     k_start = off_ref[1] + ik * bk
     masked = _tile_diagonal(q_start, k_start, bk)
+    if keep_ref is not None:
+        live = _tile_live(q_start, k_start, bq)
+
+        @pl.when(live & masked)
+        def _():
+            qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+            kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            body(_keep_mask(keep_ref, ik, bk) & (qpos >= kpos))
+
+        @pl.when(live & jnp.logical_not(masked))
+        def _():
+            body(_keep_mask(keep_ref, ik, bk))
+
+        return
     live = _tile_live(q_start, k_start, bq, bk, window)
     if window is not None:
         masked = masked | _tile_trailing(q_start, k_start, bq, window)
@@ -249,7 +335,7 @@ def _on_live_tile(off_ref, iq, ik, bq: int, bk: int, causal: bool, body,
 
 def _on_band_tile(off_ref, row, step, bq: int, bk: int, n: int,
                   causal: bool, body, window: int | None = None,
-                  q_major: bool = True):
+                  q_major: bool = True, keep_ref=None):
     """:func:`_on_live_tile` for step ``step`` of Q row ``row`` over its
     ``n`` K tiles (forward, dQ), or with ``q_major`` false of K column
     ``row`` over its ``n`` Q tiles (dK/dV): tile ``step`` without a
@@ -261,7 +347,8 @@ def _on_band_tile(off_ref, row, step, bq: int, bk: int, n: int,
         tile = first + step
         inside = tile < n
     iq, ik = (row, tile) if q_major else (tile, row)
-    _on_live_tile(off_ref, iq, ik, bq, bk, causal, body, window, inside)
+    _on_live_tile(off_ref, iq, ik, bq, bk, causal, body, window, inside,
+                  keep_ref)
 
 
 def _q_major_maps(bq: int, bk: int, causal: bool, nk: int,
@@ -317,8 +404,46 @@ def _k_major_maps(bq: int, bk: int, causal: bool, nq: int,
     return q_row, kv_row
 
 
-def _kernel_name(name: str, window: int | None) -> str:
+def _keep_row(q_row, kv_row, bk: int, heads: int):
+    """The index map of a selection's words beside a grid's ``(q_row,
+    kv_row)``: the row of words of the ``heads`` heads' batch row, the
+    Q tile that ``q_row`` names, and the block of words that holds the
+    bits of the K tile that ``kv_row`` names — the maps' own clamps, so
+    a dead step fetches no words of its own either."""
+    def keep_row(*step):
+        return (step[0] // heads, q_row(*step)[1],
+                _keep_block(kv_row(*step)[1], bk))
+
+    return keep_row
+
+
+def _kernel_name(name: str, window: int | None, keep=None) -> str:
+    if keep is not None:
+        return name + "_sel"
     return name if window is None else name + "_win"
+
+
+def _check_keep(causal: bool, window: int | None, keep, bh: int, lq: int,
+                lk: int, bk: int) -> int:
+    """The heads that share one row of the selection ``keep`` ((B, Lq,
+    W) int32, :func:`pack_keep`'s); 1 without one."""
+    if keep is None:
+        return 1
+    if not causal or window is not None:
+        raise ValueError("a selection is a bound beside the causal one and "
+                         "has no window beside it: it needs causal=True and "
+                         f"window=None, got causal={causal}, window={window}")
+    if not keep_tiles_ok(bk):
+        raise ValueError(f"K tiles of {bk} keys cannot read a packed "
+                         f"selection: a multiple of {KEEP_LANES} that "
+                         f"divides {KEEP_SPAN}")
+    width = KEEP_LANES * -(-lk // KEEP_SPAN)
+    if (keep.ndim != 3 or keep.shape[1:] != (lq, width)
+            or bh % keep.shape[0] or keep.dtype != jnp.int32):
+        raise ValueError(f"a selection for {lq} queries on {lk} keys is "
+                         f"(B, {lq}, {width}) int32 with B dividing "
+                         f"{bh}, got {keep.shape} {keep.dtype}")
+    return bh // keep.shape[0]
 
 
 def _check_window(causal: bool, window: int | None) -> None:
@@ -342,11 +467,13 @@ def _compiler_params(bq: int, bk: int, d: int, dv: int, dtype):
 
 def _flash_step_kernel(off_ref, q_ref, k_ref, v_ref, *refs, causal: bool,
                        scale: float, bq: int, bk: int, nk: int, first: bool,
-                       last: bool, window: int | None = None):
+                       last: bool, window: int | None = None,
+                       selected: bool = False):
     """Grid: (B*H, nq, nk) — nk innermost so (m_s, l_s, acc) scratch
     carries across the K blocks of one Q block; under a ``window``
     (B*H, nq, band), the scratch started and finished on the band's
-    first and last step.  ``refs`` are the
+    first and last step.  ``refs`` are the block of a selection's
+    words (where the call is ``selected``), the
     carried state in (packed m|l, o; none on a ring's ``first`` step,
     where scratch starts at -inf, 0, 0), the results and the scratch.
     A middle step's results are the state out: m_s and l_s repacked
@@ -357,6 +484,9 @@ def _flash_step_kernel(off_ref, q_ref, k_ref, v_ref, *refs, causal: bool,
     the caller asked, rounded to another type beside it.  Entry and
     exit run on every row, also one whose every tile is dead: it hands
     the carried state through unchanged."""
+    keep_ref = None
+    if selected:
+        keep_ref, *refs = refs
     if not first:
         mli_ref, oi_ref, *refs = refs
     stat_ref, *o_refs, m_s, l_s, acc = refs
@@ -402,7 +532,7 @@ def _flash_step_kernel(off_ref, q_ref, k_ref, v_ref, *refs, causal: bool,
         acc[:, :] = acc[:, :] * alpha[:, None] + pv
 
     _on_band_tile(off_ref, pl.program_id(1), step, bq, bk, nk, causal,
-                  accumulate, window)
+                  accumulate, window, keep_ref=keep_ref)
 
     @pl.when(step == steps - 1)
     def _():
@@ -458,7 +588,8 @@ def flash_fwd_step(q, k, v, state, q_offset, k_offset, *,
                    causal: bool = True, block_q: int = 128,
                    block_k: int = 128, last: bool = False,
                    interpret: bool | None = None,
-                   window: int | None = None, offset_multiple: int = 1):
+                   window: int | None = None, offset_multiple: int = 1,
+                   keep=None):
     """One step of a ring's forward pass: attend local Q against one
     KV block.
 
@@ -472,6 +603,10 @@ def flash_fwd_step(q, k, v, state, q_offset, k_offset, *,
     knows ``q_offset - k_offset`` to be a multiple of
     ``offset_multiple`` (static; a ring's offsets are multiples of its
     chunk) that the tiles share.
+    ``keep``: a selection, (B, Lq, W) int32 (:func:`pack_keep`'s) with
+    B dividing BH, a row for the BH / B heads that follow each other:
+    query ``i`` sees key ``j`` iff ``j <= i`` and bit ``j`` of its row
+    is set; with ``causal`` and no ``window``.  None: no such bound.
     ``state``: the carried ``(m, l, o)`` (m, l: (BH, Lq) fp32 running
     max / denominator; o: (BH, Lq, Dv) fp32 unnormalized numerator),
     or None on the ring's first step — the kernel then starts from
@@ -493,10 +628,13 @@ def flash_fwd_step(q, k, v, state, q_offset, k_offset, *,
     first = state is None
 
     _check_window(causal, window)
+    heads = _check_keep(causal, window, keep, bh, lq, lk, bk)
     nq, nk = lq // bq, lk // bk
     kernel = functools.partial(_flash_step_kernel, causal=causal,
                                scale=scale, bq=bq, bk=bk, nk=nk, first=first,
                                last=last, window=window)
+    if keep is not None:
+        kernel = functools.partial(kernel, selected=True)
     q_row, kv_row = _q_major_maps(bq, bk, causal, nk, window)
     steps = band_steps(bq, bk, window, nq, nk, offset_multiple)[0]
     operands = [q, k, v]
@@ -505,6 +643,10 @@ def flash_fwd_step(q, k, v, state, q_offset, k_offset, *,
         pl.BlockSpec((1, bk, d), kv_row),     # k
         pl.BlockSpec((1, bk, dv), kv_row),    # v
     ]
+    if keep is not None:
+        operands.append(keep)
+        in_specs.append(pl.BlockSpec(
+            (1, bq, KEEP_LANES), _keep_row(q_row, kv_row, bk, heads)))
     if not first:
         m, l, o = state
         operands += [_pack_rows(m, l, bh, lq), o]
@@ -520,7 +662,7 @@ def flash_fwd_step(q, k, v, state, q_offset, k_offset, *,
 
     stat, *o = pl.pallas_call(
         kernel,
-        name=_kernel_name("hvd_flash_fwd", window),
+        name=_kernel_name("hvd_flash_fwd", window, keep),
         # the offsets are prefetched scalars: the K/V index map reads
         # them to skip the fetch of dead tiles
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -569,12 +711,16 @@ def _recomputed_p_ds(q, k, v, do, ld, mask, scale):
 
 
 def _flash_bwd_dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
-                         dq_ref, dq_acc, *, causal: bool, scale: float,
+                         *refs, causal: bool, scale: float,
                          bq: int, bk: int, nk: int,
                          window: int | None = None):
     """dQ backward: grid (B*H, nq, nk), under a ``window`` (B*H, nq,
     band), innermost so dq_acc carries across the K blocks of one Q
-    block (zero for a row whose every tile is dead)."""
+    block (zero for a row whose every tile is dead).  ``refs``: the
+    block of a selection's words where there is one, dQ out, its
+    accumulator."""
+    *keep_ref, dq_ref, dq_acc = refs
+    keep_ref = keep_ref[0] if keep_ref else None
     step = pl.program_id(2)
     steps = pl.num_programs(2)
 
@@ -591,7 +737,7 @@ def _flash_bwd_dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
             preferred_element_type=jnp.float32)        # (bq, d)
 
     _on_band_tile(off_ref, pl.program_id(1), step, bq, bk, nk, causal,
-                  accumulate, window)
+                  accumulate, window, keep_ref=keep_ref)
 
     @pl.when(step == steps - 1)
     def _():
@@ -599,12 +745,15 @@ def _flash_bwd_dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
 
 
 def _flash_bwd_dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
-                          dk_ref, dv_ref, dk_acc, dv_acc, *, causal: bool,
+                          *refs, causal: bool,
                           scale: float, bq: int, bk: int, nq: int,
                           window: int | None = None):
     """dK/dV backward: grid (B*H, nk, nq), under a ``window`` (B*H, nk,
     band), innermost so the dk/dv accumulators carry across the Q
-    blocks of one KV block."""
+    blocks of one KV block.  ``refs``: the block of a selection's words
+    where there is one, dK and dV out, their accumulators."""
+    *keep_ref, dk_ref, dv_ref, dk_acc, dv_acc = refs
+    keep_ref = keep_ref[0] if keep_ref else None
     step = pl.program_id(2)
     steps = pl.num_programs(2)
 
@@ -626,7 +775,7 @@ def _flash_bwd_dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, ld_ref,
             preferred_element_type=jnp.float32)        # (bk, d)
 
     _on_band_tile(off_ref, pl.program_id(1), step, bq, bk, nq, causal,
-                  accumulate, window, q_major=False)
+                  accumulate, window, q_major=False, keep_ref=keep_ref)
 
     @pl.when(step == steps - 1)
     def _():
@@ -641,7 +790,8 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_offset, k_offset, *,
                  causal: bool = True, block_q: int = 128,
                  block_k: int = 128, out_dtype=jnp.float32,
                  interpret: bool | None = None,
-                 window: int | None = None, offset_multiple: int = 1):
+                 window: int | None = None, offset_multiple: int = 1,
+                 keep=None):
     """Flash-attention dQ for one (local Q, one KV block) pair.
 
     q: (BH, Lq, D); k: (BH, Lk, D); v: (BH, Lk, Dv); do: (BH, Lq, Dv)
@@ -651,8 +801,9 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_offset, k_offset, *,
     Returns (BH, Lq, D) in ``out_dtype`` — the dQ contribution of this
     KV block: fp32 where the caller sums over ring steps, the operands'
     type in a one-step ring (the accumulator is fp32 in VMEM either
-    way and is rounded once, as it is written out).  ``window`` and
-    ``offset_multiple``: as :func:`flash_fwd_step`'s.
+    way and is rounded once, as it is written out).  ``window``,
+    ``offset_multiple`` and ``keep`` (the selection the forward pass
+    ran under): as :func:`flash_fwd_step`'s.
     """
     bh, lq, d = q.shape
     _, lk, dv = v.shape
@@ -662,16 +813,19 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_offset, k_offset, *,
     offs = jnp.asarray([q_offset, k_offset], jnp.int32)
     ld = _pack_rows(lse, delta, bh, lq)
     _check_window(causal, window)
+    heads = _check_keep(causal, window, keep, bh, lq, lk, bk)
     nq, nk = lq // bq, lk // bk
     kernel = functools.partial(_flash_bwd_dq_kernel, causal=causal,
                                scale=scale, bq=bq, bk=bk, nk=nk,
                                window=window)
     q_row, kv_row = _q_major_maps(bq, bk, causal, nk, window)
     steps = band_steps(bq, bk, window, nq, nk, offset_multiple)[0]
+    selection = [] if keep is None else [(keep, pl.BlockSpec(
+        (1, bq, KEEP_LANES), _keep_row(q_row, kv_row, bk, heads)))]
 
     return pl.pallas_call(
         kernel,
-        name=_kernel_name("hvd_flash_bwd_dq", window),
+        name=_kernel_name("hvd_flash_bwd_dq", window, keep),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, nq, steps),
@@ -681,13 +835,13 @@ def flash_bwd_dq(q, k, v, do, lse, delta, q_offset, k_offset, *,
                 pl.BlockSpec((1, bk, dv), kv_row),    # v
                 pl.BlockSpec((1, bq, dv), q_row),     # do
                 pl.BlockSpec((1, bq, 128), q_row),    # ld
-            ],
+            ] + [spec for _, spec in selection],
             out_specs=pl.BlockSpec((1, bq, d), q_row),
             scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((bh, lq, d), out_dtype),
         compiler_params=_compiler_params(bq, bk, d, dv, q.dtype),
         interpret=interpret,
-    )(offs, q, k, v, do, ld)
+    )(offs, q, k, v, do, ld, *(words for words, _ in selection))
 
 
 @_entry_point(static_argnames=(
@@ -697,7 +851,8 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, q_offset, k_offset, *,
                   causal: bool = True, block_q: int = 128,
                   block_k: int = 128, out_dtype=jnp.float32,
                   interpret: bool | None = None,
-                  window: int | None = None, offset_multiple: int = 1):
+                  window: int | None = None, offset_multiple: int = 1,
+                  keep=None):
     """Flash-attention (dK, dV) for one (local Q, one KV block) pair.
 
     Same contract as :func:`flash_bwd_dq`; returns
@@ -714,16 +869,19 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, q_offset, k_offset, *,
     offs = jnp.asarray([q_offset, k_offset], jnp.int32)
     ld = _pack_rows(lse, delta, bh, lq)
     _check_window(causal, window)
+    heads = _check_keep(causal, window, keep, bh, lq, lk, bk)
     nq, nk = lq // bq, lk // bk
     kernel = functools.partial(_flash_bwd_dkv_kernel, causal=causal,
                                scale=scale, bq=bq, bk=bk, nq=nq,
                                window=window)
     q_row, kv_row = _k_major_maps(bq, bk, causal, nq, window)
     steps = band_steps(bq, bk, window, nq, nk, offset_multiple)[1]
+    selection = [] if keep is None else [(keep, pl.BlockSpec(
+        (1, bq, KEEP_LANES), _keep_row(q_row, kv_row, bk, heads)))]
 
     return pl.pallas_call(
         kernel,
-        name=_kernel_name("hvd_flash_bwd_dkv", window),
+        name=_kernel_name("hvd_flash_bwd_dkv", window, keep),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, nk, steps),
@@ -733,7 +891,7 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, q_offset, k_offset, *,
                 pl.BlockSpec((1, bk, dv), kv_row),    # v
                 pl.BlockSpec((1, bq, dv), q_row),     # do
                 pl.BlockSpec((1, bq, 128), q_row),    # ld
-            ],
+            ] + [spec for _, spec in selection],
             out_specs=[
                 pl.BlockSpec((1, bk, d), kv_row),
                 pl.BlockSpec((1, bk, dv), kv_row),
@@ -746,4 +904,4 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, q_offset, k_offset, *,
         ],
         compiler_params=_compiler_params(bq, bk, d, dv, q.dtype),
         interpret=interpret,
-    )(offs, q, k, v, do, ld)
+    )(offs, q, k, v, do, ld, *(words for words, _ in selection))

@@ -5,7 +5,9 @@ Absent from the reference (SURVEY §2.7); TPU extension.  The layer is
 told which experts it holds (``first``, and as many as its weights have
 rows), routes every token over **all** ``E`` experts — sigmoid scores,
 the top ``k`` of score + correction bias, the selected scores
-renormalised and scaled (the DeepSeek-V3 ``noaux_tc`` rule) — and adds
+renormalised and scaled (the DeepSeek-V3 ``noaux_tc`` rule); or, where
+the layer has no such bias, a softmax over all ``E``, the top ``k`` of
+the probabilities, renormalised — and adds
 up what its own experts give for the (token, expert) pairs sent to
 them.  A shared expert, where the weights have one, is computed whole
 for every token.  What absent experts would have added is left out:
@@ -71,12 +73,20 @@ def route(x, router_w, bias, top_k: int, scale: float):
     """``(ids (T, k) int32, weights (T, k) f32)``: sigmoid scores over
     all experts in float32, the top ``k`` of ``score + bias`` (the bias
     selects and takes no gradient), weights ``scale * s_i / (sum of the
-    selected s + 1e-20)``."""
+    selected s + 1e-20)``.  The router's kind is read from its weights,
+    as the experts' form is: with no ``bias`` (None) the scores are a
+    softmax over all experts and the top ``k`` are the largest of them,
+    weighted alike."""
     with jax.named_scope("hvd_moe_route"):
-        scores = jax.nn.sigmoid(jnp.dot(
+        logits = jnp.dot(
             x.astype(jnp.float32), router_w.astype(jnp.float32),
-            precision=lax.Precision.HIGHEST))
-        _, ids = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+            precision=lax.Precision.HIGHEST)
+        if bias is None:
+            scores = jax.nn.softmax(logits, axis=-1)
+            _, ids = lax.top_k(scores, top_k)
+        else:
+            scores = jax.nn.sigmoid(logits)
+            _, ids = lax.top_k(scores + bias.astype(jnp.float32), top_k)
         picked = jnp.take_along_axis(scores, ids, axis=-1)
         weights = scale * picked / (
             jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
@@ -237,7 +247,8 @@ def moe_layer(x, params, *, top_k: int, scale: float, first=0,
     """One expert layer on (T, d) tokens in the compute dtype.
 
     ``params``: ``router`` (d, E) and ``bias`` (E,) over all ``E``
-    experts; ``experts`` ``{"w_gate", "w_up": (held, d, f), "w_down":
+    experts (no ``bias``: a softmax router, :func:`route`); ``experts``
+    ``{"w_gate", "w_up": (held, d, f), "w_down":
     (held, f, d)}``, the experts ``first .. first + held`` of the ``E``
     (without ``w_gate`` they are relu^2 experts); ``shared`` (optional)
     one expert's weights of either form.  Over ``axis_name`` (an
@@ -264,8 +275,8 @@ def moe_layer(x, params, *, top_k: int, scale: float, first=0,
     if ep > 1:
         tokens = lax.all_gather(x, axis_name, axis=0, tiled=True)
         first = first + lax.axis_index(axis_name) * held
-    ids, weights = route(tokens, params["router"], params["bias"], top_k,
-                         scale)
+    ids, weights = route(tokens, params["router"], params.get("bias"),
+                         top_k, scale)
     with jax.named_scope("hvd_moe_experts"):
         out = expert_share(tokens, experts, ids, weights, first, n_experts)
     if ep > 1:
@@ -287,16 +298,20 @@ def moe_reference(x, params, *, top_k: int, scale: float, first: int = 0,
     experts ``first .. first + held`` (all of ``params["experts"]``
     where ``held`` is not given), every expert on every token under a
     mask.  The shared expert is added where ``params`` has one.  The
-    experts' form is read from the weights, as the layer reads it."""
+    experts' form and the router's kind are read from the weights, as
+    the layer reads them."""
 
     def expert(w):
         if "w_gate" in w:
             return (jax.nn.silu(x @ w["w_gate"]) * (x @ w["w_up"])) @ w["w_down"]
         return jnp.square(jax.nn.relu(x @ w["w_up"])) @ w["w_down"]
 
-    scores = jax.nn.sigmoid(x @ params["router"])
-    ids = jnp.argsort(-(scores + params["bias"]), axis=-1,
-                      stable=True)[:, :top_k]
+    if "bias" in params:
+        scores = jax.nn.sigmoid(x @ params["router"])
+        chosen = scores + params["bias"]
+    else:
+        chosen = scores = jax.nn.softmax(x @ params["router"], axis=-1)
+    ids = jnp.argsort(-chosen, axis=-1, stable=True)[:, :top_k]
     picked = jnp.take_along_axis(scores, ids, axis=-1)
     weights = scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)
     experts = params["experts"]

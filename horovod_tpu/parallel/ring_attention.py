@@ -59,10 +59,15 @@ from horovod_tpu.common import logging as _log
 # ``jax.ad_checkpoint.checkpoint_name``, given where the caller's block
 # is recomputed: what that block's policy keeps beside its input.
 KEPT_NAMES = ("hvd_attn_out", "hvd_attn_lse")
+# A selection (``keep``) under that same mechanism: the caller that makes
+# one in a recomputed block names it so, and its policy keeps it, so
+# that the backward kernels read the selection the forward pass ran
+# under and nothing makes it a second time.
+KEPT_SELECTION = "hvd_dsa_keep"
 
 
 def xla_block_step(q, k, v, m, l, o, q_offset, k_offset, *,
-                   causal: bool, window: int | None = None):
+                   causal: bool, window: int | None = None, keep=None):
     """One online-softmax accumulation in the packed layout.
 
     q: (BH, Lq, D); k: (BH, Lk, D); v: (BH, Lk, Dv), Dv any size;
@@ -71,6 +76,9 @@ def xla_block_step(q, k, v, m, l, o, q_offset, k_offset, *,
     q_offset/k_offset: global positions of q[:, 0] / k[:, 0].
     ``window``: query ``i`` sees key ``j`` iff ``0 <= i - j < window``
     (a second bound beside the causal one; None: the causal bound alone).
+    ``keep``: (B, Lq, Lk) bool with B dividing BH, this block's part of
+    a selection, one row for the BH / B heads that follow each other: a
+    third bound, query ``i`` sees key ``j`` only where it is set.
     Matmuls stay in the input dtype (bf16-friendly), softmax state fp32.
     """
     lq, lk = q.shape[1], k.shape[1]
@@ -83,6 +91,10 @@ def xla_block_step(q, k, v, m, l, o, q_offset, k_offset, *,
         if window is not None:
             seen = seen & (qpos[:, None] - kpos[None, :] < window)
         s = jnp.where(seen, s, -jnp.inf)
+    if keep is not None:
+        rows = keep.shape[0]
+        s = jnp.where(keep[:, None], s.reshape(rows, -1, lq, lk),
+                      -jnp.inf).reshape(s.shape)
     m_cur = jnp.max(s, axis=-1)                      # (BH, Lq)
     m_new = jnp.maximum(m, m_cur)
     # guard fully-masked rows (max = -inf)
@@ -189,7 +201,7 @@ def _ring_rotate(axis_name, *blocks):
 
 
 def _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, tiles,
-                         window=None):
+                         window=None, keep=None):
     """Pallas ring forward, returning (normalized fp32 out, lse, out in
     the operands' type).
 
@@ -210,9 +222,10 @@ def _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, tiles,
     def step(j, state, kj, vj, last=False):
         qo, ko = _ring_offsets(j, axis_name, lc, causal)
         # a ring's offsets are multiples of its chunk
+        # (a selection runs a ring of one: the whole of it is this step's)
         return flash_fwd_step(qp, kj, vj, state, qo, ko, causal=causal,
                               block_q=bq, block_k=bk, last=last,
-                              window=window, offset_multiple=lc)
+                              window=window, offset_multiple=lc, keep=keep)
 
     if sp == 1:
         return step(0, None, kp, vp, last=True)
@@ -228,7 +241,7 @@ def _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, tiles,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _ring_flash(qp, kp, vp, axis_name, causal, tiles, recomputed,
-                window=None):
+                window=None, keep=None):
     """Differentiable Pallas ring attention on packed (B*H, Lc, D)
     operands, returning (B*H, Lc, Dv) in their type: forward saves only
     (q, k, v, out, lse), ``out`` in fp32 (``delta`` = rowsum(dO ∘ out)
@@ -252,19 +265,21 @@ def _ring_flash(qp, kp, vp, axis_name, causal, tiles, recomputed,
     ``tiles``: ``((block_q, block_k) of the forward kernel, of the two
     backward kernels)``.  ``window`` (static) is the sliding window on
     global positions that all three kernels mask and skip tiles by, or
-    None."""
+    None.  ``keep``: a packed selection ((B, Lc, W) int32) that all
+    three kernels mask by, the backward ones reading the forward pass's,
+    or None; it takes no gradient."""
     return _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal, tiles,
-                                window)[2]
+                                window, keep)[2]
 
 
 def _ring_flash_fwd(qp, kp, vp, axis_name, causal, tiles, recomputed,
-                    window):
+                    window, keep):
     out, lse, out_q = _ring_flash_fwd_impl(qp, kp, vp, axis_name, causal,
-                                           tiles, window)
+                                           tiles, window, keep)
     if recomputed:
         out, lse = map(checkpoint_name, (out, lse), KEPT_NAMES)
         out_q = out.astype(qp.dtype)
-    return out_q, (qp, kp, vp, out, lse)
+    return out_q, (qp, kp, vp, out, lse, keep)
 
 
 def _ring_flash_bwd(axis_name, causal, tiles, recomputed, window, res,
@@ -272,7 +287,7 @@ def _ring_flash_bwd(axis_name, causal, tiles, recomputed, window, res,
     from horovod_tpu.ops.pallas_attention import (flash_bwd_dkv,
                                                   flash_bwd_dq)
 
-    qp, kp, vp, out, lse = res
+    qp, kp, vp, out, lse, keep = res
     sp = lax.axis_size(axis_name)
     lc = qp.shape[1]
     bq, bk = tiles[1]
@@ -284,7 +299,8 @@ def _ring_flash_bwd(axis_name, causal, tiles, recomputed, window, res,
         """This step's (dQ, dK, dV) contributions."""
         qo, ko = _ring_offsets(j, axis_name, lc, causal)
         tiles = dict(causal=causal, block_q=bq, block_k=bk,
-                     out_dtype=out_dtype, window=window, offset_multiple=lc)
+                     out_dtype=out_dtype, window=window, offset_multiple=lc,
+                     keep=keep)
         return (flash_bwd_dq(qp, kj, vj, dout, lse, delta, qo, ko, **tiles),
                 *flash_bwd_dkv(qp, kj, vj, dout, lse, delta, qo, ko,
                                **tiles))
@@ -293,7 +309,7 @@ def _ring_flash_bwd(axis_name, causal, tiles, recomputed, window, res,
         # every block is at home and nothing is summed over steps: the
         # kernels round their fp32 accumulators once, as they write
         dq, dk, dv = step(0, kp, vp, qp.dtype)
-        return dq, dk.astype(kp.dtype), dv.astype(vp.dtype)
+        return dq, dk.astype(kp.dtype), dv.astype(vp.dtype), None
 
     def middle(j, carry):
         dq, kj, vj, dkj, dvj = carry
@@ -311,7 +327,7 @@ def _ring_flash_bwd(axis_name, causal, tiles, recomputed, window, res,
     # rotation brings each block's home
     dk, dv = _ring_rotate(axis_name, dkj + dk_p, dvj + dv_p)
     return ((dq + dq_p).astype(qp.dtype), dk.astype(kp.dtype),
-            dv.astype(vp.dtype))
+            dv.astype(vp.dtype), None)
 
 
 _ring_flash.defvjp(_ring_flash_fwd, _ring_flash_bwd)
@@ -319,7 +335,8 @@ _ring_flash.defvjp(_ring_flash_fwd, _ring_flash_bwd)
 
 def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
                    impl: str | None = None, layout: str = "contiguous",
-                   recomputed: bool = False, window: int | None = None):
+                   recomputed: bool = False, window: int | None = None,
+                   keep=None):
     """Multi-head attention with the sequence sharded over ``axis_name``.
 
     q, k: (B, Lc, H, D), v: (B, Lc, H, Dv) — the local sequence chunk
@@ -341,6 +358,18 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
     own (``hvd_flash_fwd_win`` ...).  Over a ring every step still
     runs: whole steps behind the window are not skipped.  ``None``: the
     causal bound alone, traced as before there was a window.
+
+    ``keep``: a selection, a third bound that is data and not
+    positions: (B, Lc, W) int32, one bit a (query, key) pair in the
+    packed form of ``pallas_attention.pack_keep``, one row of words a
+    query, shared by all heads; query ``i`` sees key ``j`` iff ``j <=
+    i`` and its bit is set.  Both paths mask by it (the kernels then
+    carry the names ``hvd_flash_fwd_sel`` ...), the backward pass by
+    the forward pass's; it takes no gradient.  It needs ``causal``, no
+    ``window``, the contiguous layout and an ``axis_name`` of size 1: a
+    row's bits are over the keys of the whole sequence, and which of
+    them a ring step's block holds is not built.  ``None``: no such
+    bound, traced as before there was one.
 
     ``layout``: how the global sequence maps onto ranks.
 
@@ -366,6 +395,21 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
         raise ValueError("a sliding window needs causal=True, window >= 1 "
                          f"and the contiguous layout, got causal={causal}, "
                          f"window={window}, layout={layout!r}")
+    if keep is not None:
+        for wrong, why in (
+                (not causal, "it is a bound beside the causal one (a "
+                 "query's kept keys lie in its past): causal=True"),
+                (window is not None, "a window beside it is not built into "
+                 "the kernels: window=None (clear the bits instead)"),
+                (layout != "contiguous", "the zigzag layout's half-chunks "
+                 "would each need their own columns of the words: "
+                 "layout='contiguous'"),
+                (lax.axis_size(axis_name) > 1, "a row's words are over the "
+                 "whole sequence's keys, and a ring step's block of them "
+                 f"is not cut out: {axis_name} = 1")):
+            if wrong:
+                raise ValueError("a selection (keep) cannot run here: "
+                                 + why)
     if layout == "zigzag":
         return _ring_attention_zigzag(q, k, v, axis_name, causal)
     sp = lax.axis_size(axis_name)
@@ -382,14 +426,18 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
                          f"got {impl!r}")
 
     if impl == "pallas":
+        from horovod_tpu.ops.pallas_attention import keep_tiles_ok
+
         # ring KV blocks are lc long too; the forward kernel's tiles and
         # the backward kernels'
         tiles = tuple(_block_sizes(lc, lc, d, q.dtype.itemsize, dv, window,
                                    backward) for backward in (False, True))
-        if None in tiles[0]:
+        if None in tiles[0] or (keep is not None and not all(
+                keep_tiles_ok(bk) for _, bk in tiles)):
             msg = (f"sequence chunk {lc} has no tile size the Pallas "
                    "attention kernel can use (a multiple of 8 dividing "
-                   "it)")
+                   "it" + ("" if keep is None else
+                           ", of 128 under a selection") + ")")
             if asked:
                 raise ValueError(msg + "; impl='pallas' was asked for")
             if lc not in _warned_untiled:
@@ -403,7 +451,7 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
         kp = k.transpose(0, 2, 1, 3).reshape(b * h, lc, d)
         vp = v.transpose(0, 2, 1, 3).reshape(b * h, lc, dv)
         out = _ring_flash(qp, kp, vp, axis_name, causal, tiles, recomputed,
-                          window)
+                          window, keep)
         return out.reshape(b, h, lc, dv).transpose(0, 2, 1, 3)
 
     qp = q.transpose(0, 2, 1, 3).reshape(b * h, lc, d)
@@ -413,6 +461,10 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
     l0 = jnp.zeros((b * h, lc), jnp.float32)
     o0 = jnp.zeros((b * h, lc, dv), jnp.float32)
     rot = [(i, (i + 1) % sp) for i in range(sp)]
+    if keep is not None:
+        from horovod_tpu.ops.pallas_attention import unpack_keep
+
+        keep = unpack_keep(keep, lc)
 
     def step(j, carry):
         m, l, o, kj, vj = carry
@@ -422,7 +474,7 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
         # chain (see _ring_flash_fwd_impl).
         qo, ko = (idx * lc, ((idx - j) % sp) * lc) if causal else (0, 0)
         m, l, o = xla_block_step(qp, kj, vj, m, l, o, qo, ko,
-                                 causal=causal, window=window)
+                                 causal=causal, window=window, keep=keep)
         # Rotate KV around the ring (overlaps next block's compute).
         kj = lax.ppermute(kj, axis_name, rot)
         vj = lax.ppermute(vj, axis_name, rot)
@@ -435,12 +487,13 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
 
 
 def blockwise_attention(q, k, v, causal: bool = True,
-                        block_k: int = 512, window: int | None = None):
+                        block_k: int = 512, window: int | None = None,
+                        keep=None):
     """Single-device flash-style attention: online softmax over KV
     blocks, O(L * block_k) memory instead of the O(L^2) score matrix.
     q/k/v: (B, L, H, D); returns (B, L, H, D).  The local building
-    block Ulysses runs after its head-scatter.  ``window``: as
-    :func:`ring_attention`'s (every block is still visited)."""
+    block Ulysses runs after its head-scatter.  ``window`` and ``keep``:
+    as :func:`ring_attention`'s (every block is still visited)."""
     b, l_, h, d = q.shape
     bk = min(block_k, l_)
     while l_ % bk:
@@ -453,13 +506,19 @@ def blockwise_attention(q, k, v, causal: bool = True,
     m0 = jnp.full((b * h, l_), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((b * h, l_), jnp.float32)
     o0 = jnp.zeros((b * h, l_, d), jnp.float32)
+    if keep is not None:
+        from horovod_tpu.ops.pallas_attention import unpack_keep
+
+        keep = unpack_keep(keep, l_)
 
     def step(j, carry):
         m, l, o = carry
         kj = lax.dynamic_slice_in_dim(kp, j * bk, bk, axis=1)
         vj = lax.dynamic_slice_in_dim(vp, j * bk, bk, axis=1)
+        kept = (None if keep is None else
+                lax.dynamic_slice_in_dim(keep, j * bk, bk, axis=2))
         return xla_block_step(qp, kj, vj, m, l, o, 0, j * bk,
-                              causal=causal, window=window)
+                              causal=causal, window=window, keep=kept)
 
     m, l, o = lax.fori_loop(0, n_blocks, step, (m0, l0, o0))
     l = jnp.where(l == 0.0, 1.0, l)
@@ -578,10 +637,12 @@ def _ring_attention_zigzag(q, k, v, axis_name: str, causal: bool):
 
 
 def reference_attention(q, k, v, causal: bool = True,
-                        window: int | None = None):
+                        window: int | None = None, keep=None):
     """Dense single-device attention for tests: (B, L, H, D) global.
     ``window``: query ``i`` sees key ``j`` iff ``0 <= i - j < window``
-    (with ``causal``), the golden model of the kernels' second bound."""
+    (with ``causal``), the golden model of the kernels' second bound;
+    ``keep``: a packed selection ((B, L, W) int32, as
+    :func:`ring_attention`'s), of their third."""
     b, l_, h, d = q.shape
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) / (d ** 0.5)
     if causal:
@@ -589,5 +650,9 @@ def reference_attention(q, k, v, causal: bool = True,
         if window is not None:
             mask = mask & ~jnp.tril(jnp.ones((l_, l_), bool), -window)
         s = jnp.where(mask[None, None], s, -jnp.inf)
+    if keep is not None:
+        from horovod_tpu.ops.pallas_attention import unpack_keep
+
+        s = jnp.where(unpack_keep(keep, l_)[:, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v).astype(q.dtype)
