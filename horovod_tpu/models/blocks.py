@@ -14,7 +14,10 @@ attention over a selection: an indexer scores every earlier key for each
 query, the ``index_topk`` highest are kept, and softmax attention runs
 over those alone (DeepSeek sparse attention on grouped-query heads, with
 QK-norm and rotary positions of three components: the ``KeyeVL2``
-language stack).
+language stack) — and a gated short convolution, ``C ⊙ conv(B ⊙ x)``
+over three projections of the stream, beside grouped-query attention
+with QK-norm and rotary positions over the whole causal past (the
+``lfm2_moe`` stack).
 
 Imported by :mod:`horovod_tpu.models.transformer` only where a
 ``TransformerConfig`` asks for one of them; a GPT-2-shaped configuration
@@ -40,6 +43,8 @@ layer of the kind, in the pattern's order::
     gattn   ln wq wk wv wg wo q_norm k_norm                       "G"
     dense   ln w_gate w_up w_down                                 "D"
     dsa     ln wq wk wv wo q_norm k_norm wq_idx wk_idx ww_idx     "I"
+    conv    ln w_in conv_w w_out                                  "C"
+    rattn   ln wq wk wv wo q_norm k_norm                          "R"
 
 and ``ln_post`` in every stack where the configuration has a
 ``post_norm``.
@@ -339,13 +344,16 @@ def mtp_loss(cfg: TransformerConfig, params, x, targets, block, head_nll):
 
 # the stack that holds the weights of each kind of layer
 STACK_OF = {"M": "ssm", "*": "attn", "E": "moe", "S": "swa", "G": "gattn",
-            "D": "dense", "I": "dsa"}
+            "D": "dense", "I": "dsa", "C": "conv", "R": "rattn"}
 # the kinds that call ``ring_attention`` over ``n_heads`` query heads on
 # ``n_kv_heads`` key/value heads
-ATTENTION_KINDS = "*SGI"
+ATTENTION_KINDS = "*SGIR"
 # the f32 result of an expert layer that a post-norm reads, under
 # ``jax.ad_checkpoint.checkpoint_name``: kept by a recomputed layer
 KEPT_EXPERT_OUT = "hvd_moe_out"
+# the taps ``init_pattern`` gives a gated short convolution ("C"); a layer
+# runs as many as its ``conv_w`` holds
+CONV_TAPS = 3
 # how the Mamba-2 reference code draws a state-space layer's own
 # parameters: the time step log-uniform in [DT_MIN, DT_MAX] and floored,
 # kept as what softplus maps onto it; the decay rate A uniform in
@@ -387,29 +395,43 @@ def _init_gqa(norm, cfg: TransformerConfig, n: int) -> dict:
             "wo": norm(n, width, dm, scale=width ** -0.5)}
 
 
-def _init_gated_gqa(norm, cfg: TransformerConfig, n: int) -> dict:
-    """``_init_gqa`` with the output gate's matrix and the gains of the
-    two per-head norms, one of ``head_dim`` for all query heads and one
-    for all key heads."""
-    dm, width = cfg.d_model, cfg.n_heads * cfg.head_dim
+def _init_normed_gqa(norm, cfg: TransformerConfig, n: int) -> dict:
+    """``_init_gqa`` with the gains of the two per-head norms, one of
+    ``head_dim`` for all query heads and one for all key heads."""
     return {**_init_gqa(norm, cfg, n),
-            "wg": norm(n, dm, width, scale=dm ** -0.5),
             "q_norm": np.ones((n, cfg.head_dim), np.float32),
             "k_norm": np.ones((n, cfg.head_dim), np.float32)}
 
 
+def _init_gated_gqa(norm, cfg: TransformerConfig, n: int) -> dict:
+    """``_init_normed_gqa`` with the output gate's matrix."""
+    dm, width = cfg.d_model, cfg.n_heads * cfg.head_dim
+    return {**_init_normed_gqa(norm, cfg, n),
+            "wg": norm(n, dm, width, scale=dm ** -0.5)}
+
+
 def _init_indexed_gqa(norm, cfg: TransformerConfig, n: int) -> dict:
-    """``_init_gqa`` with the gains of the two per-head norms and the
-    indexer's three matrices: its ``index_heads`` query heads of
-    ``index_head_dim``, its one key head, and a weight a query head."""
+    """``_init_normed_gqa`` with the indexer's three matrices: its
+    ``index_heads`` query heads of ``index_head_dim``, its one key head,
+    and a weight a query head."""
     dm = cfg.d_model
-    return {**_init_gqa(norm, cfg, n),
-            "q_norm": np.ones((n, cfg.head_dim), np.float32),
-            "k_norm": np.ones((n, cfg.head_dim), np.float32),
+    return {**_init_normed_gqa(norm, cfg, n),
             "wq_idx": norm(n, dm, cfg.index_heads * cfg.index_head_dim,
                            scale=dm ** -0.5),
             "wk_idx": norm(n, dm, cfg.index_head_dim, scale=dm ** -0.5),
             "ww_idx": norm(n, dm, cfg.index_heads, scale=dm ** -0.5)}
+
+
+def _init_conv(norm, cfg: TransformerConfig, n: int) -> dict:
+    """A gated short convolution's in-projection to ``B | C | x``, its
+    taps (a framework's default for a convolution's weight, uniform in
+    +-1 / sqrt(taps), as a normal of the same variance) and its
+    out-projection."""
+    dm, taps = cfg.d_model, CONV_TAPS
+    return {"ln": np.ones((n, dm), np.float32),
+            "w_in": norm(n, dm, 3 * dm, scale=dm ** -0.5),
+            "conv_w": norm(n, taps, dm, scale=(3 * taps) ** -0.5),
+            "w_out": norm(n, dm, dm, scale=dm ** -0.5)}
 
 
 def init_pattern(norm, rng, cfg: TransformerConfig, ep: int) -> dict:
@@ -436,6 +458,10 @@ def init_pattern(norm, rng, cfg: TransformerConfig, ep: int) -> dict:
                       **_init_swiglu(norm, (n,), dm, cfg.d_ff)}
     if "I" in kinds:
         p["dsa"] = _init_indexed_gqa(norm, cfg, kinds.count("I"))
+    if "C" in kinds:
+        p["conv"] = _init_conv(norm, cfg, kinds.count("C"))
+    if "R" in kinds:
+        p["rattn"] = _init_normed_gqa(norm, cfg, kinds.count("R"))
     if cfg.post_norm:
         for kind in set(kinds):
             p[STACK_OF[kind]]["ln_post"] = np.ones((kinds.count(kind), dm),
@@ -453,6 +479,8 @@ def init_pattern(norm, rng, cfg: TransformerConfig, ep: int) -> dict:
                              (p.get("swa", {}), "wo"),
                              (p.get("gattn", {}), "wo"),
                              (p.get("dsa", {}), "wo"),
+                             (p.get("conv", {}), "w_out"),
+                             (p.get("rattn", {}), "wo"),
                              (p.get("dense", {}), "w_down"),
                              (moe_.get("experts", {}), "w_down"),
                              (moe_.get("shared", {}), "w_down")):
@@ -466,14 +494,15 @@ def pattern_specs(cfg: TransformerConfig) -> dict:
     ``dp`` (= ep): no kind of a pattern runs under ``pp`` or ``tp``."""
     from jax.sharding import PartitionSpec as P
 
-    gated = ("ln", "wq", "wk", "wv", "wg", "wo", "q_norm", "k_norm")
+    normed = ("ln", "wq", "wk", "wv", "wo", "q_norm", "k_norm")
+    gated = normed + ("wg",)
     names = {"ssm": ("ln", "w_in", "conv_w", "conv_b", "dt_bias", "a_log",
                      "d", "norm", "w_out"),
              "attn": ("ln", "wq", "wk", "wv", "wo"),
              "swa": gated, "gattn": gated,
              "dense": ("ln", "w_gate", "w_up", "w_down"),
-             "dsa": ("ln", "wq", "wk", "wv", "wo", "q_norm", "k_norm",
-                     "wq_idx", "wk_idx", "ww_idx")}
+             "dsa": normed + ("wq_idx", "wk_idx", "ww_idx"),
+             "conv": ("ln", "w_in", "conv_w", "w_out"), "rattn": normed}
     specs = {"head": P()}
     for kind in set(cfg.layer_pattern):
         stack = STACK_OF[kind]
@@ -493,17 +522,18 @@ def _whole_axes(what: str, reasons: dict) -> None:
                 f"{what} under {axis} > 1 is not supported: {why}")
 
 
-def causal_conv(xbc, weight, bias):
+def causal_conv(xbc, weight, bias=None):
     """A causal depthwise convolution over time as shifted
     multiply-adds in float32.  ``xbc``: (b, lc, channels); ``weight``:
     (taps, channels), tap ``k`` weighing the input ``taps - 1 - k`` steps
     back (before the first step there is nothing); ``bias``:
-    (channels,)."""
+    (channels,), or None for a convolution without one."""
     taps, steps = weight.shape[0], xbc.shape[1]
     earlier = jnp.pad(xbc, ((0, 0), (taps - 1, 0), (0, 0)))
-    out = bias.astype(jnp.float32)
+    out = None if bias is None else bias.astype(jnp.float32)
     for k in range(taps):
-        out = out + earlier[:, k:k + steps].astype(jnp.float32) * weight[k]
+        term = earlier[:, k:k + steps].astype(jnp.float32) * weight[k]
+        out = term if out is None else out + term
     return out
 
 
@@ -554,6 +584,31 @@ def _over_query_heads(t, times: int):
     return jnp.repeat(t, times, axis=2)
 
 
+def _normed_heads(cfg: TransformerConfig, lp, h, positions=None):
+    """``(q, k, v)`` of :func:`gqa`'s heads from the normalised stream
+    ``h`` in the compute type, with an RMSNorm over each head's ``q``
+    and ``k`` (one learned gain of ``head_dim`` for all query heads, one
+    for all key heads) and, where ``positions`` are given, rotary
+    positions (all of ``head_dim``, the half-rotation layout, in
+    ``cfg.rope_sections`` where the configuration has them); ``k`` and
+    ``v`` broadcast over their query heads."""
+    b, lc, _ = h.shape
+    cd = cfg.compute_dtype
+    nh, hd = cfg.n_heads, cfg.head_dim
+    nkv = cfg.n_kv_heads or nh
+    h = h.astype(cd)
+    q = _rmsnorm((h @ lp["wq"].astype(cd)).reshape(b, lc, nh, hd),
+                 lp["q_norm"], cfg.norm_eps)
+    k = _rmsnorm((h @ lp["wk"].astype(cd)).reshape(b, lc, nkv, hd),
+                 lp["k_norm"], cfg.norm_eps)
+    v = (h @ lp["wv"].astype(cd)).reshape(b, lc, nkv, hd)
+    if positions is not None:
+        q, k = (rotary(t, positions, cfg.rope_theta, halves=True,
+                       sections=cfg.rope_sections) for t in (q, k))
+    k, v = (_over_query_heads(t, nh // nkv) for t in (k, v))
+    return q, k, v
+
+
 def gqa(cfg: TransformerConfig, lp, h):
     """Grouped-query attention on the normalised stream ``h``, no
     positional encoding: ``n_heads`` query heads, query head ``i`` on
@@ -602,24 +657,14 @@ def gated_gqa(cfg: TransformerConfig, lp, h, positions, sliding: bool):
               "steps are not skipped"})
     b, lc, _ = h.shape
     cd = cfg.compute_dtype
-    nh, hd = cfg.n_heads, cfg.head_dim
-    nkv = cfg.n_kv_heads or nh
     h = h.astype(cd)
-    q = _rmsnorm((h @ lp["wq"].astype(cd)).reshape(b, lc, nh, hd),
-                 lp["q_norm"], cfg.norm_eps)
-    k = _rmsnorm((h @ lp["wk"].astype(cd)).reshape(b, lc, nkv, hd),
-                 lp["k_norm"], cfg.norm_eps)
-    v = (h @ lp["wv"].astype(cd)).reshape(b, lc, nkv, hd)
-    if sliding:
-        q, k = (rotary(t, positions, cfg.rope_theta, halves=True,
-                       sections=cfg.rope_sections) for t in (q, k))
-    k, v = (_over_query_heads(t, nh // nkv) for t in (k, v))
+    q, k, v = _normed_heads(cfg, lp, h, positions if sliding else None)
     with jax.named_scope("hvd_attn"):
         attn = ring_attention(q, k, v, "sp", causal=True,
                               impl=cfg.attn_impl, recomputed=cfg.remat,
                               window=cfg.window if sliding else None)
     gate = jax.nn.sigmoid((h @ lp["wg"].astype(cd)).astype(jnp.float32))
-    attn = attn.reshape(b, lc, nh * hd).astype(jnp.float32) * gate
+    attn = attn.reshape(b, lc, -1).astype(jnp.float32) * gate
     return (attn.astype(cd) @ lp["wo"].astype(cd)).astype(jnp.float32)
 
 
@@ -725,25 +770,62 @@ def indexed_gqa(cfg: TransformerConfig, lp, h, positions):
               "out of its words"})
     b, lc, _ = h.shape
     cd = cfg.compute_dtype
-    nh, hd = cfg.n_heads, cfg.head_dim
-    nkv = cfg.n_kv_heads or nh
     with jax.named_scope("hvd_dsa_index"):
         keep = checkpoint_name(select_keys(cfg, lp, h), KEPT_SELECTION)
-    h = h.astype(cd)
-    q = _rmsnorm((h @ lp["wq"].astype(cd)).reshape(b, lc, nh, hd),
-                 lp["q_norm"], cfg.norm_eps)
-    k = _rmsnorm((h @ lp["wk"].astype(cd)).reshape(b, lc, nkv, hd),
-                 lp["k_norm"], cfg.norm_eps)
-    v = (h @ lp["wv"].astype(cd)).reshape(b, lc, nkv, hd)
-    q, k = (rotary(t, positions, cfg.rope_theta, halves=True,
-                   sections=cfg.rope_sections) for t in (q, k))
-    k, v = (_over_query_heads(t, nh // nkv) for t in (k, v))
+    q, k, v = _normed_heads(cfg, lp, h, positions)
     with jax.named_scope("hvd_attn"):
         attn = ring_attention(q, k, v, "sp", causal=True,
                               impl=cfg.attn_impl, recomputed=cfg.remat,
                               keep=keep)
-    return (attn.reshape(b, lc, nh * hd).astype(cd)
+    return (attn.reshape(b, lc, -1).astype(cd)
             @ lp["wo"].astype(cd)).astype(jnp.float32), keep
+
+
+def normed_gqa(cfg: TransformerConfig, lp, h, positions):
+    """Grouped-query attention on the normalised stream ``h`` over the
+    whole causal past, ungated: :func:`gqa`'s heads with an RMSNorm over
+    each head's ``q`` and ``k`` and rotary positions (all of
+    ``head_dim``, the half-rotation layout; :func:`_normed_heads`).  A
+    recomputed layer keeps what its kernel gave, as every attention kind
+    does.  Returns the f32 output projection."""
+    _whole_axes("grouped-query attention with QK-norm", {
+        "tp": "the key/value heads, fewer than the query heads, are not "
+              "shared out over the tp ranks",
+        "sp": "k and v are broadcast over their query heads before the "
+              "ring, which would pass every key/value head round once "
+              "for each of its query heads"})
+    b, lc, _ = h.shape
+    cd = cfg.compute_dtype
+    q, k, v = _normed_heads(cfg, lp, h, positions)
+    with jax.named_scope("hvd_attn"):
+        attn = ring_attention(q, k, v, "sp", causal=True,
+                              impl=cfg.attn_impl, recomputed=cfg.remat)
+    return (attn.reshape(b, lc, -1).astype(cd)
+            @ lp["wo"].astype(cd)).astype(jnp.float32)
+
+
+def conv_mix(cfg: TransformerConfig, lp, h):
+    """The gated short convolution on the normalised stream ``h`` (b, lc,
+    dm): one in-projection split ``B | C | x`` in that order;
+    ``y = C * v`` with ``v_t = sum_k w_k * (B * x)_{t - taps + 1 + k}``, a
+    causal depthwise convolution of ``conv_w``'s taps without bias,
+    nothing before the first step; the out-projection.  ``u = B * x`` in
+    the compute type, as the published model computes it; the taps' sum
+    and ``C * v`` in float32, ``y`` rounded; the gates and the taps under
+    the scope ``hvd_conv_mix``.  No nonlinearity but the two gates.
+    Returns the f32 output projection."""
+    _whole_axes("a gated short convolution", {
+        "tp": "the in-projection's B | C | x parts and the convolution's "
+              "channels are not shared out over the tp ranks",
+        "sp": "a chunk's first outputs need the last taps - 1 inputs of "
+              "the chunk before it, which would have to cross a ring "
+              "boundary, and no such pass is built"})
+    cd = cfg.compute_dtype
+    b, c, x = jnp.split(h.astype(cd) @ lp["w_in"].astype(cd), 3, axis=-1)
+    with jax.named_scope("hvd_conv_mix"):
+        y = (c.astype(jnp.float32)
+             * causal_conv(b * x, lp["conv_w"])).astype(cd)
+    return (y @ lp["w_out"].astype(cd)).astype(jnp.float32)
 
 
 def pattern_dense(cfg: TransformerConfig, lp, h):
@@ -760,9 +842,11 @@ def pattern_dense(cfg: TransformerConfig, lp, h):
 def pattern_layer(cfg: TransformerConfig, kind: str, lp, x, positions):
     """One layer of a pattern: ``x + f(RMSNorm(x))`` with ``f`` the
     sub-layer of ``kind`` — ``x + RMSNorm(f(RMSNorm(x)))`` where the
-    configuration has a ``post_norm``.  ``positions``: (lc,) global, for
-    the kinds that take rotary ones ((3, lc) where the configuration has
-    ``rope_sections``).  Returns ``(x, report)``: the pairs
+    configuration has a ``post_norm``; ``C`` (a gated short convolution)
+    under the scope ``hvd_conv``, ``R`` (ungated attention with QK-norm
+    and rotary positions) under ``hvd_rattn``.  ``positions``: (lc,)
+    global, for the kinds that take rotary ones ((3, lc) where the
+    configuration has ``rope_sections``).  Returns ``(x, report)``: the pairs
     an expert layer's routing sent each of all its experts, a
     state-space layer's least log-decay, an indexed attention layer's
     packed selection, else ``None``."""
@@ -784,6 +868,12 @@ def pattern_layer(cfg: TransformerConfig, kind: str, lp, x, positions):
     elif kind == "I":
         with jax.named_scope("hvd_dsa"):
             out, report = indexed_gqa(cfg, lp, h, positions)
+    elif kind == "C":
+        with jax.named_scope("hvd_conv"):
+            out = conv_mix(cfg, lp, h)
+    elif kind == "R":
+        with jax.named_scope("hvd_rattn"):
+            out = normed_gqa(cfg, lp, h, positions)
     else:
         out, report = expert_ffn(cfg, lp, h, count_all=True)
         if cfg.post_norm:

@@ -29,8 +29,9 @@ pre-normed sub-layer with one residual — a Mamba-2 mixer, grouped-query
 attention without positions, gated grouped-query attention with QK-norm
 under a sliding window with rotary positions or full without,
 grouped-query attention over the keys an indexer selects for each
-query, a dense SwiGLU FFN or an expert FFN alone — of the kind the
-pattern gives it,
+query, ungated grouped-query attention with QK-norm and rotary positions,
+a gated short convolution, a dense SwiGLU FFN or an expert FFN alone —
+of the kind the pattern gives it,
 its weights stacked per kind (``blocks.pattern_stack``), with a second
 norm after the sub-layer where the configuration states one.
 
@@ -118,8 +119,11 @@ class TransformerConfig:
     # whole past with none; "D" a dense SwiGLU FFN of d_ff; "I"
     # grouped-query attention with QK-norm and rotary positions over the
     # index_topk keys that an indexer of index_heads heads of
-    # index_head_dim (one key head) scores highest for each query
-    # (blocks.STACK_OF is the table).  Empty: the uniform stack of
+    # index_head_dim (one key head) scores highest for each query; "R"
+    # grouped-query attention with QK-norm and rotary positions over the
+    # whole causal past, ungated; "C" a gated short convolution of
+    # blocks.CONV_TAPS taps, C * conv(B * x) over three projections of
+    # the stream (blocks.STACK_OF is the table).  Empty: the uniform stack of
     # attention + MLP blocks above.  n_layers is the pattern's length.
     layer_pattern: tuple = ()
     # "S": query i sees key j iff 0 <= i - j < window
@@ -226,6 +230,9 @@ class TransformerConfig:
         if "S" in kinds and (self.window < 1 or self.head_dim % 2):
             raise ValueError("'S' layers need window >= 1 and an even "
                              "head_dim (rotary positions)")
+        if "R" in kinds and self.head_dim % 2:
+            raise ValueError("'R' layers need an even head_dim (rotary "
+                             "positions)")
         if "I" in kinds and (min(self.index_heads, self.index_head_dim,
                                  self.index_topk) < 1 or self.head_dim % 2):
             raise ValueError("'I' layers need index_heads, index_head_dim, "
@@ -761,6 +768,29 @@ def record_attention(cfg: TransformerConfig, batch: int) -> list:
             **{"bwd_" + k: v for k, v in counts(window, True).items()}))
     for record in records:
         flight.record("hvd_attn_window", **record)
+    return records
+
+
+def record_conv(cfg: TransformerConfig, batch: int) -> list:
+    """Write one ``hvd_conv_mix`` record a gated short-convolution layer
+    of the pattern to the flight ring: ``layer`` (its place in the
+    pattern), ``seq`` (``max_seq``, one chip's: ``sp`` 1), ``channels``,
+    ``taps`` (``blocks.CONV_TAPS``), ``impl`` (``xla``: the compiler's
+    fusions compute the gates and the convolution) and
+    ``required_bytes``, what one pass over ``batch`` sequences requires:
+    ``B``, ``C`` and ``x`` read and ``y`` written once at the compute
+    type (the taps are negligible).  Returns the records."""
+    from horovod_tpu.models import blocks
+    from horovod_tpu.runtime import flight
+
+    pass_bytes = (4 * batch * cfg.max_seq * cfg.d_model
+                  * cfg.compute_dtype.itemsize)
+    records = [dict(layer=layer, seq=cfg.max_seq, channels=cfg.d_model,
+                    taps=blocks.CONV_TAPS, impl="xla",
+                    required_bytes=pass_bytes)
+               for layer, kind in enumerate(cfg.layer_pattern) if kind == "C"]
+    for record in records:
+        flight.record("hvd_conv_mix", **record)
     return records
 
 

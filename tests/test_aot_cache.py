@@ -44,11 +44,26 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 @pytest.fixture()
 def cache_dir(tmp_path, monkeypatch):
+    """An AOT cache of the test's own, and JAX's persistent compilation
+    cache off while the test runs: a CPU executable that JAX loaded from
+    its own cache and that is serialized again into this one cannot run
+    when read back (NOT_FOUND for its fused functions), and whether JAX
+    wrote it there turns on what ran earlier in the process (a benchmark
+    run sets the cache's minimum compile time to zero)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
     d = str(tmp_path / "aot")
     monkeypatch.setenv("HOROVOD_AOT_CACHE_DIR", d)
     monkeypatch.delenv("HOROVOD_AOT_CACHE_MODE", raising=False)
     aot_cache.reset_warnings()
-    yield d
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield d
+    finally:
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        compilation_cache.reset_cache()
 
 
 def _build():

@@ -94,7 +94,7 @@ def test_the_pattern_decides_the_stacks():
 
 @pytest.mark.parametrize("change,message", [
     (dict(layer_pattern="SXE"), r"layer_pattern holds 'M', '\*', 'E', 'S', "
-                                r"'G', 'D', 'I': \['X'\]"),
+                                r"'G', 'D', 'I', 'C', 'R': \['X'\]"),
     (dict(window=0), "'S' layers need window >= 1"),
     (dict(n_kv_heads=3), "no multiple"),
     (dict(layer_pattern="GE", n_kv_heads=3), "no multiple"),
@@ -106,7 +106,7 @@ def test_configuration_checks(change, message):
 
 
 def test_the_kinds_come_from_one_table():
-    assert set(blocks.STACK_OF) == set("M*ESGDI")
+    assert set(blocks.STACK_OF) == set("M*ESGDICR")
     assert TransformerConfig._check_pattern.__doc__.count("STACK_OF") == 1
     # a pattern of full layers alone needs no window
     dataclasses.replace(CFG, layer_pattern="GD", window=0)
