@@ -93,13 +93,71 @@ def route(x, router_w, bias, top_k: int, scale: float):
     return ids, weights
 
 
+def _through(a, b):
+    """``a b^T``: (..., m) by (n, m), f32 accumulation."""
+    return lax.dot_general(a, b, (((a.ndim - 1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _over_rows(a, b):
+    """``a^T b`` summed over every leading axis: (..., m) by (..., n) ->
+    (m, n), f32 accumulation."""
+    rows = tuple(range(a.ndim - 1))
+    return lax.dot_general(a, b, ((rows, rows), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+@jax.custom_vjp
 def swiglu(x, w):
     """``(silu(x W_gate) * (x W_up)) W_down`` with f32 accumulation;
-    the shared expert and the dense layers' MLP."""
+    the shared expert and the dense layers' MLP.  ``x`` (..., d) and
+    ``w`` in the compute dtype.
+
+    A backward rule of its own: JAX's would hand the five backward
+    products f32 operands built from the two f32 (tokens x f) products
+    ``gate`` and ``up``, and XLA fuses that elementwise producer into
+    each product, which then reads both f32 tensors (PERF.md section
+    6).  The rule rounds ``hidden``, ``d_gate`` and ``d_up`` to the
+    compute dtype once, in one elementwise pass, and the products read
+    those: the rounding a product at default precision gives an f32
+    operand anyway, at another place.  ``relu2`` needs no rule: XLA
+    fuses its one elementwise step into its one product's
+    compute-dtype output."""
+    return _swiglu_fwd(x, w)[0]
+
+
+def _swiglu_fwd(x, w):
     gate = jnp.dot(x, w["w_gate"], preferred_element_type=jnp.float32)
     up = jnp.dot(x, w["w_up"], preferred_element_type=jnp.float32)
     hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
-    return jnp.dot(hidden, w["w_down"], preferred_element_type=jnp.float32)
+    # kept: what the backward reads; under a caller's checkpoint its
+    # policy decides whether gate and up are kept or computed again
+    return jnp.dot(hidden, w["w_down"],
+                   preferred_element_type=jnp.float32), (x, w, gate, up)
+
+
+def _swiglu_bwd(kept, g):
+    x, w, gate, up = kept
+    cd = x.dtype
+    d_hidden = _through(g, w["w_down"]).astype(cd)
+    sig = jax.nn.sigmoid(gate)
+    silu = gate * sig
+    hidden = (silu * up).astype(cd)
+    d_gate = (d_hidden * up * sig * (1 + gate * (1 - sig))).astype(cd)
+    d_up = (d_hidden * silu).astype(cd)
+    # written once, in the compute dtype: without the barrier XLA fuses
+    # the pass back into some of the five products (which two or three
+    # depends on the surrounding step), and each such product reads the
+    # f32 gate and up again, tile by tile
+    hidden, d_gate, d_up = lax.optimization_barrier((hidden, d_gate, d_up))
+    dx = _through(d_gate, w["w_gate"]) + _through(d_up, w["w_up"])
+    grads = {"w_gate": _over_rows(x, d_gate), "w_up": _over_rows(x, d_up),
+             "w_down": _over_rows(hidden, g)}
+    return dx.astype(cd), {name: grads[name].astype(a.dtype)
+                           for name, a in w.items()}
+
+
+swiglu.defvjp(_swiglu_fwd, _swiglu_bwd)
 
 
 def relu2(x, w):
