@@ -146,6 +146,7 @@ def measure(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
     compiles_in_window = compiles.requests - requests_before
 
     losses = [float(np.asarray(v).reshape(-1)[0]) for v in losses]
+    first_pass, last_pass = loss_passes(losses, job["batch_pool"])
     window_losses = losses[warm_losses:]
     steps = len(window_losses)
     per_chip = steps * trainer.samples_per_step / window_s / cell.chips
@@ -161,6 +162,7 @@ def measure(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
         "failed": sum(1 for v in window_losses if not math.isfinite(v)),
         "reference": reference, "first_update": probe,
         "loss_step0": losses[0], "loss_last": window_losses[-1],
+        "loss_first_pass": first_pass, "loss_last_pass": last_pass,
         "compiles_in_window": compiles_in_window,
         "params_sha256": digest.hexdigest(),
         "end_to_end": {
@@ -188,6 +190,7 @@ def measure(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
         memory_stats=device.memory_stats(),
         step_ms_p50=statistics.median(chunk_walls) / chunk_steps * 1e3,
         loss_step0=losses[0], loss_last=window_losses[-1],
+        loss_first_pass=first_pass, loss_last_pass=last_pass,
         **record["end_to_end"])
 
     if trace:
@@ -201,6 +204,25 @@ def measure(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
             record["per_layer"] = _read_layers(
                 cell, traced, record["counters"], from_trace=True)
     return record
+
+
+def loss_passes(losses: list, pool: int) -> tuple:
+    """The two losses ``loss_fell`` compares, from the loss of every step
+    run (step ``i`` at ``losses[i]``, reading batch ``i % pool`` of the
+    job's pool): the mean over the first ``pool`` steps and the mean over
+    the last ``pool``.  Each is one pass over the whole pool, so neither
+    the number of chunks the program's speed allows nor the batch the
+    last step happens to read decides the comparison.  A run of fewer
+    than two passes compares step 0 with the newest step that read step
+    0's batch; one with no such step gives ``(None, None)``, which
+    fails."""
+    if len(losses) >= 2 * pool:
+        return (statistics.fmean(losses[:pool]),
+                statistics.fmean(losses[-pool:]))
+    aligned = (len(losses) - 1) // pool * pool
+    if aligned == 0:
+        return None, None
+    return losses[0], losses[aligned]
 
 
 def _memory_peak_bytes(device) -> int:
@@ -288,26 +310,90 @@ def _worst_counters(records: list) -> dict:
     return merged
 
 
+def _fell(record: dict) -> bool:
+    return (record["loss_last_pass"] is not None
+            and record["loss_last_pass"] < record["loss_first_pass"])
+
+
+def _compared(records: list, update) -> dict:
+    """Each number that ``correct`` compares, beside its limit, as
+    ``[value, relation, limit]``; of a world, the rank that read it
+    worst.  ``update`` is the first-update check's record, or ``None``."""
+    compared = {}
+
+    def worst(name, values, relation, limit):
+        compared[name] = [(min if relation == ">=" else max)(values),
+                          relation, limit]
+
+    refs = [r["reference"] for r in records
+            if "loss_rel_err" in r["reference"]]
+    if refs:
+        worst("loss_rel_err", [x["loss_rel_err"] for x in refs], "<",
+              refs[0]["loss_rtol"])
+        limits = refs[0]["grad_norm_rtol"]
+        if isinstance(limits, dict):
+            # a group without a limit of its own takes no gradient
+            for group in refs[0]["grad_norm"]:
+                if group in limits:
+                    worst(f"grad_norm_rel_err.{group}",
+                          [x["grad_norm_rel_err"][group] for x in refs],
+                          "<", limits[group])
+                else:
+                    worst(f"grad_norm.{group}",
+                          [x["grad_norm"][group] for x in refs], "==", 0)
+        else:
+            worst("grad_norm_rel_err",
+                  [x["grad_norm_rel_err"] for x in refs], "<", limits)
+        selections = [x["selection"] for x in refs if "selection" in x]
+        if selections:
+            worst("selection_rows_with_another_count",
+                  [s["rows_with_another_count"] for s in selections], "==", 0)
+            worst("selection_common_share",
+                  [min(s["common_share"]) for s in selections], ">=",
+                  selections[0]["common_share_limit"])
+    if update is not None:
+        compared["first_update_rel_err"] = [
+            update["bias_update_rel_err"], "<", update["tolerance"]]
+    # the rank that failed, else the one whose loss fell least
+    least_fall = max(records, key=lambda r: (not _fell(r), (
+        r["loss_last_pass"] - r["loss_first_pass"]
+        if r["loss_last_pass"] is not None else math.inf)))
+    compared["loss_last_pass"] = [least_fall["loss_last_pass"], "<",
+                                  least_fall["loss_first_pass"]]
+    worst("nonfinite_losses", [r["failed"] for r in records], "==", 0)
+    worst("compiles_in_window", [r["compiles_in_window"] for r in records],
+          "==", 0)
+    compared["params_sha256_distinct"] = [
+        len({r["params_sha256"] for r in records}), "==", 1]
+    # a NaN or an infinity is no number JSON knows: it is written as text
+    return {name: [x if not isinstance(x, float) or math.isfinite(x)
+                   else repr(x) for x in entry]
+            for name, entry in compared.items()}
+
+
 def result_line(cell: manifest.Cell, records: list, trace: bool,
                 world_ok: bool = True) -> dict:
     """The contract's object from the records of every rank (one for an
     inline cell).  ``world_ok`` false — a rank died or left with another
-    code than 0 — fails every step."""
+    code than 0 — fails every step.  Its last key, ``compared``, holds
+    each number compared beside its limit (``_compared``)."""
     first = records[0]
     if not world_ok:
         return {"correct": False, "attempted": first["attempted"],
                 "failed": first["attempted"], "metrics": {},
-                "device": first["device"]}
+                "device": first["device"],
+                "compared": {"ranks_left_with_exit_0": [0, "==", 1]}}
     family = manifest.load_family(cell)
     checks = {
         "reference": all(r["reference"]["ok"] for r in records),
         "losses_finite": all(r["failed"] == 0 for r in records),
-        "loss_fell": all(r["loss_last"] < r["loss_step0"] for r in records),
+        "loss_fell": all(_fell(r) for r in records),
         "no_compile_in_window":
             all(r["compiles_in_window"] == 0 for r in records),
         "params_identical":
             len({r["params_sha256"] for r in records}) == 1,
     }
+    update = None
     if first["first_update"] is not None:
         update = family.check_first_update(
             [r["first_update"] for r in records])
@@ -336,4 +422,5 @@ def result_line(cell: manifest.Cell, records: list, trace: bool,
             "metrics": metrics, "device": device}
     if trace:
         line["breakdown"] = first["breakdown"]
+    line["compared"] = _compared(records, update)
     return line

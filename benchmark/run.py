@@ -119,6 +119,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool, t0: float,
     with open(os.path.join(cell.out_dir, "records.json"), "w",
               encoding="utf-8") as f:
         json.dump(records, f)
+    for name, (value, relation, limit) in line["compared"].items():
+        print(f"compared {name}: {value} {relation} {limit}",
+              file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0 if ok else 1
 

@@ -1,15 +1,15 @@
 """The ``keye-vl-2.0-30b-a3b.s16384.epshare`` cell's real train step,
-compiled here for a described ``v5e:2x2`` chip: the three flash-attention
-kernels under a selection (``hvd_flash_*_sel``) run once in each of the
+compiled here for a described ``v5e:2x2`` chip: the forward flash-attention
+kernel under a selection (``hvd_flash_fwd_sel``) runs once in each of the
 six indexed attention sub-layers (a recomputed sub-layer keeps what its
-forward kernel gave) and the selection is made once a layer — one loop
-over blocks of query rows under ``hvd_dsa_index``, in the forward pass
-alone, because a recomputed sub-layer keeps its selection; everything
-else is XLA's — no other Mosaic call of the program's, only the
-compiler's own grouped-product kernels for the experts, at most three
-products forward and twice that backward in each of six expert
-sub-layers (no norm after a sub-layer reads its result, so the replay
-runs none: ``trinity-mini``'s rule); and the step — 6.92 GB of
+forward kernel gave), and a backward kernel under a selection at least
+once a layer; the selection is made once a layer — in the forward pass
+alone under ``hvd_dsa_index``, because a recomputed sub-layer keeps its
+selection; every other Mosaic call is one of the program's own named
+kernels (``hvd_*``) or the compiler's grouped-product kernels for the
+experts, at most three products forward and twice that backward in each
+of six expert sub-layers (no norm after a sub-layer reads its result, so
+the replay runs none: ``trinity-mini``'s rule); and the step — 6.92 GB of
 parameters, gradients and AdamW moments plus the activations of 16,384
 tokens with every sub-layer recomputed — fits the chip's memory with
 room to spare: no (T, T) float32 tensor is alive across blocks of rows.
@@ -121,28 +121,36 @@ def test_selection_kernels_once_a_layer_and_no_plain_or_windowed_one(
         compiled_step, kernel):
     """Six indexed attention sub-layers of twelve sub-layers, each
     recomputed under a policy that keeps the forward kernel's ``out``
-    and ``lse``: each ``*_sel`` kernel is in the step exactly six times,
-    under ``hvd_dsa`` and ``hvd_attn``; no attention call of this cell
-    runs without a selection."""
+    and ``lse``: the forward ``*_sel`` kernel is in the step exactly six
+    times, under ``hvd_dsa`` and ``hvd_attn``; each backward kernel at
+    most as often, under the same scopes, and the backward kernels under
+    a selection, however the backward pass is split among them, at least
+    once a layer; no attention call of this cell runs without a
+    selection."""
     text = compiled_step.as_text()
+    forward = _calls_named(text, "hvd_flash_fwd_sel")
+    assert len(forward) == LAYERS, [name for name, _ in forward]
     selected = _calls_named(text, kernel + "_sel")
-    assert len(selected) == LAYERS, (kernel, [name for name, _ in selected])
+    assert len(selected) <= len(forward), [name for name, _ in selected]
     for _, line in selected:
         assert re.search(r'op_name="[^"]*hvd_dsa[^"]*hvd_attn', line), \
             line[-300:]
+    backward = [name for name in _mosaic_calls(text)
+                if re.fullmatch(r"hvd_flash_bwd\w*_sel(\.\d+)?", name)]
+    assert len(backward) >= LAYERS, backward
     assert not _calls_named(text, kernel)
     assert not _calls_named(text, kernel + "_win")
 
 
 def test_one_selection_a_layer(compiled_step):
-    """The selection is one loop over blocks of query rows under
-    ``hvd_dsa_index`` (inside it the scores' product, the search for the
-    rows' thresholds, the packing): the scores' product is in the step
-    six times, once a layer, and every operation under that scope in the
-    forward pass
-    (``jvp(``, never ``transpose(``: no gradient passes the selection)
-    and none in a replay (``rematted_computation``): a recomputed layer
-    keeps its selection, and the backward kernels read that one."""
+    """The selection is made under ``hvd_dsa_index`` (the scores'
+    product, the search for the rows' thresholds, the packing): every
+    operation under that scope in the forward pass (``jvp(``, never
+    ``transpose(``: no gradient passes the selection) and none in a
+    replay (``rematted_computation``): a recomputed layer keeps its
+    selection, and the backward kernels read that one.  The scores'
+    product, where XLA computes it, is under that scope and in the step
+    at most six times, once a layer."""
     text = compiled_step.as_text()
     names = [re.search(r'op_name="([^"]*)"', line).group(1)
              for line in text.splitlines()
@@ -154,20 +162,23 @@ def test_one_selection_a_layer(compiled_step):
     products = [line for line in text.splitlines()
                 if re.search(r" (convolution|dot)\(", line)
                 and "bqjd,bkd->bqjk" in line]
-    assert len(products) == LAYERS, [line[:120] for line in products]
+    assert len(products) <= LAYERS, [line[:120] for line in products]
+    for line in products:
+        assert "hvd_dsa_index" in line, line[:120]
 
 
 def test_no_other_mosaic_call_of_the_programs(compiled_step):
-    """Everything else is XLA's.  The compiler's own grouped-product
-    kernels for ``lax.ragged_dot`` are custom calls too: gate, up and
-    down, forward and twice that backward, and a replay only where
-    something reads its result (nothing does here: no norm after a
-    sub-layer), in each of 6 expert sub-layers: at most 6 x 3 x 4 (at
-    most, and not exactly: a backward rule that needs fewer products
-    must not fail here; PERF.md section 7)."""
+    """Every Mosaic call is a kernel the program names (``hvd_*``) or
+    one of the compiler's own grouped-product kernels for
+    ``lax.ragged_dot``: gate, up and down, forward and twice that
+    backward, and a replay only where something reads its result
+    (nothing does here: no norm after a sub-layer), in each of 6 expert
+    sub-layers: at most 6 x 3 x 4 (at most, and not exactly: a backward
+    rule that needs fewer products must not fail here).  A Mosaic call
+    with neither name fails."""
     text = compiled_step.as_text()
     others = [name for name in _mosaic_calls(text)
-              if not name.startswith(KERNELS)]
+              if not name.startswith("hvd_")]
     assert others and all(name.startswith(experts.GROUPED)
                           for name in others), others
     grouped = [name for name in others

@@ -82,11 +82,17 @@ def test_untraced_line_is_the_contracts(toy_root, capfd, workload):
     of its ranks' reference gradients)."""
     code, line, cell = run_cell(toy_root, capfd, workload, False)
     assert code == 0
-    assert set(line) == LINE_KEYS
+    assert set(line) == LINE_KEYS | {"compared"}
+    assert list(line)[-1] == "compared"
     assert set(line["device"]) == DEVICE_KEYS
     assert line["device"]["count"] >= cell.chips
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0
+    # every number compared, beside its limit, and inside it
+    assert {"loss_rel_err", "loss_last_pass", "compiles_in_window"} \
+        <= set(line["compared"])
+    for name, (value, relation, limit) in line["compared"].items():
+        assert _holds(value, relation, limit), name
     assert set(line["metrics"]) == {m["name"] for m in cell.end_to_end}
     for metric in cell.end_to_end:
         got = line["metrics"][metric["name"]]
@@ -112,7 +118,8 @@ def test_untraced_line_is_the_contracts(toy_root, capfd, workload):
 def test_traced_line_has_the_per_layer_metrics(toy_root, capfd, workload):
     code, line, cell = run_cell(toy_root, capfd, workload, True)
     assert code == 0
-    assert set(line) == LINE_KEYS | {"breakdown"}
+    assert set(line) == LINE_KEYS | {"breakdown", "compared"}
+    assert list(line)[-1] == "compared"
     assert set(line["device"]) == DEVICE_KEYS | {"busy_s", "window_s"}
     assert 0 < line["device"]["busy_s"] <= line["device"]["window_s"]
     # every per-layer metric of the cell, the dropped-in one among them
@@ -314,3 +321,209 @@ def test_first_update_check_tells_mean_from_sum():
     assert not family.check_first_update(probes(-0.01 * 4 * mean))["ok"]
     assert not family.check_first_update(probes(-0.01 * grads[0]))["ok"]
     assert not family.check_first_update(probes(-0.01 * mean, ranks=3))["ok"]
+
+
+# ---------------------------------------------------------------------------
+# loss_fell: the first pass over the batch pool against the last
+# ---------------------------------------------------------------------------
+
+# a pool of four batches that differ by more than the loss falls in a run
+OFFSETS = (0.0, 0.3, -0.2, 0.1)
+RATE = 0.002
+CHUNK_STEPS = 3          # a last step on every batch as the chunks grow
+
+
+def _stub_family(rate: float):
+    """A family whose program has no model: its state is one number that
+    falls by ``rate`` a step (rises where ``rate`` is negative), and a
+    step's loss is the state plus its batch's offset."""
+    import types
+
+    class Trainer:
+        samples_per_step = units_per_sample = 1
+
+        def __init__(self, config, job, seed, hvd):
+            import jax.numpy as jnp
+
+            self.state = jnp.asarray(5.0, jnp.float32)
+            self.batches = tuple(jnp.asarray(o, jnp.float32)
+                                 for o in OFFSETS[:job["batch_pool"]])
+
+        def check_reference(self):
+            return {"ok": True}
+
+        def compile(self):
+            pass
+
+        def compiled_text(self):
+            return ""
+
+        def run_step(self, i):
+            loss = self.state + self.batches[i % len(self.batches)]
+            self.state = self.state - rate
+            return loss
+
+        def params(self):
+            return {"state": self.state}
+
+    return types.SimpleNamespace(
+        Trainer=Trainer, model_flops_per_sample=lambda config, job: 1.0)
+
+
+def _stub_run(toy_root, rate: float, chunks: int, pool: int = 4) -> tuple:
+    """``harness.measure`` and ``result_line`` over the stub, the window
+    ``chunks`` chunks long: (the record, the line)."""
+    import dataclasses
+
+    import horovod_tpu as hvd
+    from benchmark import harness
+
+    base = manifest.load_cell("toy-lm.s32",
+                              os.path.join(toy_root, "BENCHMARK.json"))
+    cell = dataclasses.replace(base, job=dict(
+        base.job, chunk_steps=CHUNK_STEPS, batch_pool=pool))
+    patch = pytest.MonkeyPatch()
+    patch.setattr(manifest, "load_family", lambda _cell: _stub_family(rate))
+    try:
+        record = harness.measure(cell, 0, 0.01, False, time.time(),
+                                 allow_cpu=True, agree=lambda _n: chunks)
+        return record, harness.result_line(cell, [record], False)
+    finally:
+        patch.undo()
+        hvd.shutdown()
+
+
+def _old_rule(record: dict) -> bool:
+    return record["loss_last"] < record["loss_step0"]
+
+
+def _holds(value, relation: str, limit) -> bool:
+    return {"<": value < limit, "==": value == limit,
+            ">=": value >= limit}[relation]
+
+
+# 2 to 5 chunks of 3 steps after steps 0..3: the last step is 9, 12, 15
+# or 18, which read batches 1, 0, 3 and 2
+@pytest.mark.parametrize("chunks", [2, 3, 4, 5])
+def test_loss_fell_reads_a_slow_fall_on_every_last_batch(toy_root, chunks):
+    """A program that learns by 0.002 a step while its batches differ by
+    up to 0.5 reads true whichever batch the last step reads; the old
+    rule, the last step against step 0, reads false where the last batch
+    lies above batch 0 by more than the fall, so its verdict flips from
+    one run length to the next."""
+    record, line = _stub_run(toy_root, RATE, chunks)
+    last = CHUNK_STEPS * (1 + chunks)
+    assert record["attempted"] == CHUNK_STEPS * chunks
+    assert line["correct"] is True
+    assert record["loss_last_pass"] < record["loss_first_pass"]
+    fall = RATE * last
+    assert _old_rule(record) is (OFFSETS[last % 4] - OFFSETS[0] < fall)
+    assert _old_rule(record) is (chunks % 2 == 1)
+    value, relation, limit = line["compared"]["loss_last_pass"]
+    assert (value, relation, limit) == (
+        record["loss_last_pass"], "<", record["loss_first_pass"])
+    for name, (value, relation, limit) in line["compared"].items():
+        assert _holds(value, relation, limit), name
+
+
+@pytest.mark.parametrize("chunks", [2, 3, 4, 5])
+def test_loss_fell_refuses_a_rising_loss_on_every_last_batch(toy_root,
+                                                              chunks):
+    """The same program with the loss rising: false at every length,
+    also where the old rule read true because the last batch lies below
+    batch 0 (batch 2, five chunks)."""
+    record, line = _stub_run(toy_root, -RATE, chunks)
+    assert line["correct"] is False
+    assert record["loss_last_pass"] > record["loss_first_pass"]
+    assert _old_rule(record) is (chunks == 5)
+    assert not _holds(*line["compared"]["loss_last_pass"])
+
+
+@pytest.mark.parametrize("losses, pool, want", [
+    ([4.0, 3.0, 5.0, 2.0, 3.9, 2.9, 4.9, 1.9], 4, (3.5, 3.4)),
+    ([4.0, 3.0, 5.0, 2.0, 3.9, 2.9, 4.9], 4, (4.0, 3.9)),
+    ([4.0, 3.0, 5.0, 2.0], 4, (None, None)),
+], ids=["two passes", "fewer: step 4 against step 0", "no such step"])
+def test_loss_passes_of_short_runs(losses, pool, want):
+    from benchmark import harness
+
+    got = harness.loss_passes(losses, pool)
+    if want[0] is None:
+        assert got == want
+    else:
+        assert got == pytest.approx(want)
+
+
+def test_loss_fell_falls_back_where_a_run_holds_fewer_than_two_passes(
+        toy_root):
+    """One chunk of 3 after steps 0..3: 7 steps, under two passes of 4,
+    so step 4, the newest that read step 0's batch, against step 0; a
+    pool of 8 leaves no such step and reads false."""
+    record, line = _stub_run(toy_root, RATE, 1)
+    assert line["correct"] is True
+    assert record["loss_first_pass"] == record["loss_step0"]
+    assert record["loss_last_pass"] == pytest.approx(
+        record["loss_step0"] - 4 * RATE)
+    record, line = _stub_run(toy_root, RATE, 1, pool=8)
+    assert record["loss_first_pass"] is None
+    assert line["correct"] is False
+    assert line["compared"]["loss_last_pass"] == [None, "<", None]
+
+
+# each family of the manifest, with the toy files its own tests use
+TOY_OF_FAMILY = {"lm_mesh": ("toy-lm", "toy-s32"),
+                 "resnet_dp": ("toy-resnet", "toy-b8"),
+                 "lm_moe_mla": ("toy-moe", "toy-moe-s64"),
+                 "lm_hybrid_ssm": ("toy-hybrid", "toy-hybrid-s64"),
+                 "lm_swa_moe": ("toy-swa", "toy-swa-s64"),
+                 "lm_dsa_moe": ("toy-dsa", "toy-dsa-s64"),
+                 "lm_conv_moe": ("toy-conv", "toy-conv-s64")}
+
+
+def test_every_family_of_the_manifest_has_a_toy():
+    with open(manifest.MANIFEST, encoding="utf-8") as f:
+        listed = json.load(f)
+    families = {manifest.load_cell(w["name"]).config["family"]
+                for w in listed["workloads"]}
+    assert families == set(TOY_OF_FAMILY)
+
+
+@pytest.mark.parametrize("family_name", sorted(TOY_OF_FAMILY))
+def test_every_family_reads_batch_step_mod_pool(family_name):
+    """``run_step(i)`` hands the program batch ``i % batch_pool`` of a
+    pool of ``batch_pool``, so that any ``batch_pool`` steps in a row
+    read each batch once: what ``loss_passes`` relies on.  A pool of 3,
+    which no traffic file holds, so that the length is the job's."""
+    import horovod_tpu as hvd
+
+    def toy(kind, name):
+        with open(os.path.join(TOY, kind, name + ".json"),
+                  encoding="utf-8") as f:
+            return json.load(f)
+
+    config_name, traffic = TOY_OF_FAMILY[family_name]
+    config = toy("configs", config_name)
+    assert config["family"] == family_name
+    family = manifest._load_module(os.path.join(
+        manifest.ROOT, "benchmark", "families", family_name + ".py"))
+    hvd.init()
+    try:
+        trainer = family.Trainer(config, dict(toy("traffic", traffic),
+                                              batch_pool=3), 11, hvd)
+        assert len(trainer.batches) == 3
+        trainer.compile()               # the state as the window gets it
+        held, seen = len(trainer.state), []
+
+        def compiled(*args):
+            seen.append(args[held:])
+            return (*args[:held], len(seen))
+
+        trainer.compiled = compiled
+        for i in range(8):
+            assert trainer.run_step(i) == i + 1
+        for i, batch in enumerate(seen):
+            want = trainer.batches[i % 3]
+            assert len(batch) == len(want)
+            assert all(a is b for a, b in zip(batch, want)), i
+    finally:
+        hvd.shutdown()

@@ -317,11 +317,14 @@ def test_the_file_states_every_published_width_and_lists_its_cuts():
     for key in ("deployment", "assumed", "departures"):
         assert config[key], key
     assert config["deployment"]["chips_that_share_a_layer"] == 16
-    # the cell: one chip, the sixth cell, one of the six on four chips
+    # the cell: one chip, among at least six cells, of which at most a
+    # quarter (one where there are fewer than eight) hold four chips
     workload = next(w for w in listed["workloads"] if w["name"] == CELL)
     assert workload["chips"] == 1 and workload["traffic"] == "s8192.epshare"
-    assert [w["chips"] for w in listed["workloads"]].count(4) == 1
-    assert len(listed["workloads"]) == 6
+    cells = len(listed["workloads"])
+    assert cells >= 6
+    assert [w["chips"] for w in listed["workloads"]].count(4) \
+        <= max(1, cells // 4)
     # the arithmetic of the cut, from the program's own parameter tree
     import jax
 
