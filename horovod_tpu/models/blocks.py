@@ -669,8 +669,9 @@ def gated_gqa(cfg: TransformerConfig, lp, h, positions, sliding: bool):
 
 
 # rows of queries whose indexer scores against every key are alive at
-# once: (rows, index_heads, seq) products and (rows, seq) scores in f32,
-# 270 MB and 17 MB a sequence of 16,384
+# once in the XLA form of the selection (the kernel's blocks are its
+# own, ``pallas_index.BLOCK_Q``): (rows, index_heads, seq) products and
+# (rows, seq) scores in f32, 270 MB and 17 MB a sequence of 16,384
 _INDEX_ROWS = 256
 
 
@@ -687,6 +688,39 @@ def _kth_largest(keys, k: int):
                          jnp.zeros(keys.shape[:-1], jnp.uint32))
 
 
+def index_impl(cfg: TransformerConfig, lc: int) -> str:
+    """The path that makes an indexed attention layer's selection over a
+    chunk of ``lc`` queries: ``"kernel"`` (``ops/pallas_index``, over the
+    keys each block of queries can see) where the backend is a TPU,
+    ``cfg.attn_impl`` does not force ``"xla"`` and the chunk is a whole
+    number of the kernel's row blocks and key tiles; else ``"xla"``.
+    The two give the same words."""
+    from horovod_tpu.ops import pallas_index
+
+    if (jax.default_backend() == "tpu" and cfg.attn_impl != "xla"
+            and pallas_index.tiles_fit(lc, cfg.index_heads,
+                                       cfg.index_head_dim,
+                                       cfg.compute_dtype.itemsize)):
+        return "kernel"
+    return "xla"
+
+
+def index_inputs(cfg: TransformerConfig, lp, h):
+    """The indexer's three projections of the normalised stream ``h``
+    (b, lc, dm), detached, in the compute type: ``q`` (b, lc,
+    index_heads, index_head_dim), ``k`` (b, lc, index_head_dim) and the
+    head weights ``w = (h W_w) / sqrt(index_heads)`` (b, lc,
+    index_heads) in float32."""
+    b, lc, _ = h.shape
+    cd = cfg.compute_dtype
+    heads, size = cfg.index_heads, cfg.index_head_dim
+    h = lax.stop_gradient(h).astype(cd)
+    q = (h @ lp["wq_idx"].astype(cd)).reshape(b, lc, heads, size)
+    k = h @ lp["wk_idx"].astype(cd)                          # (b, lc, size)
+    w = (h @ lp["ww_idx"].astype(cd)).astype(jnp.float32) * heads ** -0.5
+    return q, k, w
+
+
 def select_keys(cfg: TransformerConfig, lp, h):
     """The indexer's selection for the normalised stream ``h`` (b, lc,
     dm), packed as ``ring_attention``'s ``keep``: (b, lc, W) int32.
@@ -698,20 +732,26 @@ def select_keys(cfg: TransformerConfig, lp, h):
     ``t`` keeps the ``index_topk`` keys ``s <= t`` of largest ``I[t,
     s]``, a tie going to the lower ``s``, and every key where ``t <
     index_topk``: exact (the threshold is the row's ``index_topk``-th
-    largest score itself, :func:`_kth_largest` over the scores' bits in
-    an order-preserving integer form).  By blocks of ``_INDEX_ROWS``
-    queries, so that no (lc, lc) tensor exists.  No gradient passes: the
-    indexer reads the stream detached, and the selection is discrete."""
+    largest score itself, found bit by bit over the scores in an
+    order-preserving integer form).  Made by the kernel of
+    ``ops/pallas_index`` where :func:`index_impl` says so, over the keys
+    each block of queries can see; else here, by blocks of
+    ``_INDEX_ROWS`` queries against every key (:func:`_kth_largest`), so
+    that no (lc, lc) tensor exists.  No gradient passes: the indexer
+    reads the stream detached, and the selection is discrete."""
     from horovod_tpu.ops.pallas_attention import pack_keep
 
     b, lc, _ = h.shape
-    cd = cfg.compute_dtype
-    heads, size = cfg.index_heads, cfg.index_head_dim
+    size = cfg.index_head_dim
     topk = min(cfg.index_topk, lc)
-    h = lax.stop_gradient(h).astype(cd)
-    q = (h @ lp["wq_idx"].astype(cd)).reshape(b, lc, heads, size)
-    k = h @ lp["wk_idx"].astype(cd)                          # (b, lc, size)
-    w = (h @ lp["ww_idx"].astype(cd)).astype(jnp.float32) * heads ** -0.5
+    q, k, w = index_inputs(cfg, lp, h)
+    if index_impl(cfg, lc) == "kernel":
+        from horovod_tpu.ops.pallas_index import index_select
+
+        # the kernel defines no derivative: its operands carry none (the
+        # projections' weights would, and the result drops it)
+        q, k, w = lax.stop_gradient((jnp.swapaxes(q, 1, 2), k, w))
+        return lax.stop_gradient(index_select(q, k, w, topk=topk))
     rows = min(_INDEX_ROWS, lc)
     if lc % rows:
         raise ValueError(f"the indexer scores {rows} queries at a time: "
@@ -772,6 +812,12 @@ def indexed_gqa(cfg: TransformerConfig, lp, h, positions):
     cd = cfg.compute_dtype
     with jax.named_scope("hvd_dsa_index"):
         keep = checkpoint_name(select_keys(cfg, lp, h), KEPT_SELECTION)
+    # the heads' projections after the selection exists: on a TPU the
+    # compiler then copies the kernel's words into VMEM behind them and
+    # the forward kernel reads its masks from there, as it read the XLA
+    # form's (a fusion wrote them there); from HBM it took 0.9 ms longer
+    # a layer on a v5e at 16,384 tokens
+    keep, h = lax.optimization_barrier((keep, h))
     q, k, v = _normed_heads(cfg, lp, h, positions)
     with jax.named_scope("hvd_attn"):
         attn = ring_attention(q, k, v, "sp", causal=True,

@@ -804,8 +804,15 @@ def record_selection(cfg: TransformerConfig, selections) -> list:
     ``operand_bytes`` a layer, and of one head's forward call the path
     ``impl``, its ``block_q`` x ``block_k``, the ``tiles`` its grid
     walks and the ``live_tiles`` that do any work (all of them build a
-    mask from the words).  Returns the records."""
+    mask from the words); and of the indexer the path ``index_impl``
+    that made the selection (``blocks.index_impl``: ``"kernel"`` or
+    ``"xla"``) and the ``index_pairs`` it scored for that many sequences
+    (the XLA form every key of every row, ``seq²`` a sequence; the
+    kernel the key tiles up to each row block's last row, none where
+    every row of a block keeps all it sees).  Returns the records."""
+    from horovod_tpu.models import blocks
     from horovod_tpu.ops.pallas_attention import causal_tile_counts
+    from horovod_tpu.ops.pallas_index import scored_pairs
     from horovod_tpu.parallel.ring_attention import _block_sizes, auto_impl
     from horovod_tpu.runtime import flight
 
@@ -816,12 +823,16 @@ def record_selection(cfg: TransformerConfig, selections) -> list:
     bq, bk = _block_sizes(seq, seq, cfg.head_dim, cfg.compute_dtype.itemsize)
     tiles, live, _ = (causal_tile_counts(seq, seq, bq, bk) if bq and bk
                       else (0, 0, 0))
+    index = blocks.index_impl(cfg, seq)
+    scored = (scored_pairs(seq, min(cfg.index_topk, seq)) if index == "kernel"
+              else seq * seq)
     records = [dict(layer=i, seq=seq, topk=cfg.index_topk,
                     kept_pairs=int(np.unpackbits(row.view(np.uint8)).sum()),
                     causal_pairs=batch * seq * (seq + 1) // 2,
                     operand="packed_mask", operand_bytes=int(row.nbytes),
                     impl=impl, block_q=bq or 0, block_k=bk or 0,
-                    tiles=tiles, live_tiles=live)
+                    tiles=tiles, live_tiles=live, index_impl=index,
+                    index_pairs=batch * scored)
                for i, row in enumerate(words)]
     for record in records:
         flight.record("hvd_dsa_select", **record)
